@@ -4,7 +4,7 @@ PY ?= python
 
 .PHONY: all native test check bench bench-regress audit asan \
 	metrics-smoke mesh-smoke chaos-smoke megastep-smoke body-smoke \
-	staging-smoke timeline-smoke \
+	staging-smoke timeline-smoke chip-smoke \
 	clean analyze analyze-abi analyze-lint analyze-tidy analyze-tsan \
 	fuzz prove ringcheck surface
 
@@ -79,6 +79,12 @@ fuzz: native
 
 bench: native
 	$(PY) bench.py
+
+# Served-path proof on the accelerator (ISSUE 21): config 2 through
+# `python -m pingoo_tpu --native-plane`, every status against the
+# interpreter. Exits non-zero where JAX finds no accelerator.
+chip-smoke:
+	$(PY) chip_smoke.py
 
 # Bench trajectory gate (ISSUE 5 satellite): `bench.py --history`
 # appends each run to BENCH_history.jsonl; this compares the latest run

@@ -15,6 +15,7 @@ import subprocess
 import sys
 
 from .config import DEFAULT_CONFIG_FILE, ConfigError, load_and_validate
+from .host.captcha import DEFAULT_JWKS_PATH
 from .logging_utils import get_logger, init_logging
 
 log = get_logger("pingoo_tpu")
@@ -45,6 +46,9 @@ def main(argv: list[str] | None = None) -> int:
     parser.add_argument("--upstream-ca", default=None,
                         help="PEM trust bundle for TLS upstream hops "
                              "(native plane; system roots by default)")
+    parser.add_argument("--captcha-jwks", default=DEFAULT_JWKS_PATH,
+                        help="captcha signing-key JWKS file (created on "
+                             "first boot)")
     args = parser.parse_args(argv)
 
     init_logging()
@@ -52,6 +56,22 @@ def main(argv: list[str] | None = None) -> int:
         config = load_and_validate(args.config)
     except ConfigError as exc:
         log.error(str(exc))
+        return 1
+
+    # Backend selection: JAX decides, once, in this process. --no-device
+    # pins the CPU before first use; otherwise backend_info() takes what
+    # jax.devices() gives and lets JAX's own error fail the boot — no
+    # probe child, no silent CPU pin (pingoo_tpu/backend.py). The
+    # compile cache is placed before anything jits.
+    from .backend import backend_info, force_cpu_backend, place_compile_cache
+
+    if args.no_device:
+        force_cpu_backend()
+    compile_cache = place_compile_cache()
+    try:
+        backend = backend_info()
+    except RuntimeError as exc:
+        log.error(f"jax backend initialisation failed: {exc}")
         return 1
 
     child = None
@@ -68,6 +88,8 @@ def main(argv: list[str] | None = None) -> int:
         "rules": len(config.rules),
         "device": not args.no_device,
         "native_plane": args.native_plane,
+        **backend,
+        "compile_cache": compile_cache,
     }})
     try:
         if args.native_plane:
@@ -80,6 +102,7 @@ def main(argv: list[str] | None = None) -> int:
                 use_device=not args.no_device,
                 enable_docker=not args.no_docker,
                 cache_dir=args.cache_dir,
+                captcha_jwks_path=args.captcha_jwks,
                 bot_score_params_path=args.bot_score_params))
         else:
             from .host.server import run
@@ -87,6 +110,7 @@ def main(argv: list[str] | None = None) -> int:
             asyncio.run(run(config, use_device=not args.no_device,
                             enable_docker=not args.no_docker,
                             cache_dir=args.cache_dir,
+                            captcha_jwks_path=args.captcha_jwks,
                             bot_score_params_path=args.bot_score_params))
     except KeyboardInterrupt:
         pass

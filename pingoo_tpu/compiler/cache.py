@@ -35,13 +35,15 @@ from ..expr.values import Ip
 from .obligations import PlanProof, proof_block_valid, prove_plan, require
 from .plan import RulesetPlan, compile_ruleset, split_config_token
 
-FORMAT_VERSION = 12  # bump when plan/table layout changes
+FORMAT_VERSION = 13  # bump when plan/table layout changes
 # v8: scan_plans (per-bank strategy selection, halo partition sub-banks)
 # v9: PrefilterPlan + pf_<field> factor tables (literal-prefilter cascade)
 # v10: bitsplit-DFA lowering — dfa_<field> DfaTables, NfaScanPlan
 #      dfa_key/dfa_strategy/dfa_auto, RulesetPlan.dfa_default_mode
 # v11: compact staging — RulesetPlan.staging_required/staging_caps
 # v12: plan_proof block — discharged obligation ledger rides the artifact
+# v13: default scan-strategy selection no longer picks the fused Pallas
+#      kernel without a measured cost (a v12 artifact may carry it)
 
 
 def _prove_enabled() -> bool:

@@ -68,15 +68,15 @@ from ..ops.window_match import build_window_table
 
 # Relative cost of ONE scan-loop iteration per strategy kind. The
 # defaults are placeholders that encode the dispatch-bound ordering the
-# roofline measured (a fused kernel iteration ~ the execution floor, a
-# pair iteration slightly dearer than a single gather but half as many
-# of them); bench.py --autotune replaces them with measured values on a
-# live backend.
+# roofline modeled (a pair iteration slightly dearer than a single
+# gather but half as many of them); bench.py --autotune replaces them
+# with measured values on a live backend. The fused Pallas kernel
+# (ops/pallas_scan.py) has NO default: it is a candidate only where the
+# cost dict carries a value measured for it, so plan-time selection
+# never routes a bank through a kernel nobody timed on this backend.
 DEFAULT_STEP_COSTS = {
     "scan": 1.0,        # lax.scan, one [256/C, W] gather per byte
     "pair": 1.3,        # lax.scan, one [C^2, 2W] gather per TWO bytes
-    "pallas": 0.25,     # fused kernel, one fused lookup+advance per byte
-    "pallas_pair": 0.35,  # fused kernel, two bytes per loop iteration
     # Bitsplit DFA (ISSUE 8): one [S, C]-row gather per byte, ~4
     # lane-ops/byte, no dependent matmul and no opt-propagation passes.
     "dfa": 0.15,
@@ -168,30 +168,21 @@ class NfaScanPlan:
     dfa_auto: bool = False
 
 
-def _pallas_ok() -> bool:
-    try:
-        from ..ops.pallas_scan import pallas_available
-
-        return pallas_available()
-    except Exception:
-        return False
-
-
 def select_scan_strategy(tables, costs: dict | None = None,
-                         pallas_ok: bool | None = None,
                          source: str = "default") -> ScanStrategy:
     """Pick the cheapest (kind, pair) for one bank under a per-iteration
     cost model; iteration counts scale the pair variants by 1/2, so the
-    ranking is independent of the (trace-time) field length. halo_k is
-    eligibility metadata: halo re-checks profitability at trace time."""
+    ranking is independent of the (trace-time) field length. The fused
+    Pallas kinds compete only with a measured cost in `costs`. halo_k
+    is eligibility metadata: halo re-checks profitability at trace
+    time."""
     c = dict(costs or {})
-    if pallas_ok is None:
-        pallas_ok = _pallas_ok()
     cands = [("scan", False, _kind_cost(c, "scan")),
              ("scan", True, _kind_cost(c, "pair") / 2)]
-    if pallas_ok:
-        cands += [("pallas", False, _kind_cost(c, "pallas")),
-                  ("pallas", True, _kind_cost(c, "pallas_pair") / 2)]
+    if c.get("pallas") is not None:
+        cands.append(("pallas", False, float(c["pallas"])))
+    if c.get("pallas_pair") is not None:
+        cands.append(("pallas", True, float(c["pallas_pair"]) / 2))
     kind, pair, cost = min(cands, key=lambda x: x[2])
     halo_k = 8 if tables.halo_ok else 1
     return ScanStrategy(kind=kind, pair=pair, halo_k=halo_k,

@@ -120,7 +120,7 @@ class BodyRule:
 
 
 #: Seed ruleset: CRS-staple payload classes (SQLi / XSS / traversal /
-#: RCE probes — the WAMM payload-class taxonomy, PAPERS.md), literal
+#: RCE probes — the WAMM payload classes, PAPERS.md), literal
 #: patterns only so every rule has a necessary factor and the lazy
 #: prefilter cascade stays armed by default.
 DEFAULT_BODY_RULES: tuple[BodyRule, ...] = (

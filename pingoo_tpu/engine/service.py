@@ -25,6 +25,7 @@ from typing import Optional
 
 import numpy as np
 
+from ..backend import backend_info
 from ..compiler.plan import RulesetPlan
 from ..config.schema import Action
 from ..expr import execute_as_bool
@@ -100,75 +101,6 @@ class _StageBudgetExceeded(RuntimeError):
             f"({elapsed_ms:.3f} ms since launch)")
         self.stage = stage
         self.elapsed_ms = elapsed_ms
-
-
-def force_cpu_backend() -> None:
-    """Pin jax to the CPU platform before any device op runs.
-
-    The ambient environment may pin JAX_PLATFORMS to an accelerator
-    plugin that overrides the env var at registration time, so the
-    config update (not the env var) is the authoritative pin."""
-    import os
-
-    os.environ["JAX_PLATFORMS"] = "cpu"
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
-
-
-def ensure_jax_backend(probe_timeout_s: float | None = None) -> bool:
-    """Probe the jax backend, degrading accelerator failures to CPU.
-
-    The ambient environment may pin JAX_PLATFORMS to an accelerator
-    backend whose registration failed or whose transport is wedged
-    (e.g. a dropped device tunnel). A failed registration makes any jax
-    array op raise later; a wedged transport makes backend init HANG —
-    so the probe runs `jax.devices()` in a SUBPROCESS with a deadline
-    (PINGOO_DEVICE_PROBE_TIMEOUT_S, default 60 s; the first accelerator
-    handshake is slow but bounded). On probe failure or timeout the
-    process pins the CPU platform BEFORE its own first device op, which
-    is what makes the device->CPU-XLA->interpreter degradation ladder
-    reachable at all. Returns True if some backend works (possibly
-    CPU), False if jax is unusable entirely.
-    """
-    import os
-    import subprocess
-    import sys
-
-    try:
-        import jax
-    except Exception:
-        return False
-
-    if probe_timeout_s is None:
-        probe_timeout_s = float(
-            os.environ.get("PINGOO_DEVICE_PROBE_TIMEOUT_S", "60"))
-    platforms = os.environ.get("JAX_PLATFORMS", "")
-    if platforms != "cpu":
-        # An accelerator may be in play (explicitly requested, or — with
-        # the env var unset — auto-registered by an installed PJRT
-        # plugin): probe it out-of-process so a hung transport cannot
-        # hang us. The probe child inherits our env and so makes the
-        # same backend choice this process would.
-        try:
-            proc = subprocess.run(
-                [sys.executable, "-c",
-                 "import jax; jax.devices(); print('ok')"],
-                timeout=probe_timeout_s, capture_output=True)
-            if proc.returncode != 0 or b"ok" not in proc.stdout:
-                raise RuntimeError(proc.stderr.decode()[-200:])
-        except Exception:
-            force_cpu_backend()
-    try:
-        try:
-            jax.devices()
-            return True
-        except RuntimeError:
-            force_cpu_backend()
-            jax.devices()
-            return True
-    except Exception:
-        return False
 
 
 @dataclass
@@ -366,14 +298,12 @@ class VerdictService:
         self._perf.ensure_instruments("python")
         self._timeline = get_timeline()
         self._timeline.ensure_instruments("python")
-        self._backend_label = "host"
-        if use_device:
-            try:
-                import jax
-
-                self._backend_label = str(jax.default_backend())
-            except Exception:
-                pass
+        # What JAX gave this process (None on the interpreter-only
+        # plane): logged at boot, served in the metrics JSON, and the
+        # cost ledger's backend key.
+        self.backend = backend_info() if use_device else None
+        self._backend_label = \
+            self.backend["platform"] if use_device else "host"
         self.cost_ledger_result = load_cost_ledger(
             self.sched.cost, backend=self._backend_label,
             fingerprint=self._plan_fp, plane="python")
@@ -475,14 +405,12 @@ class VerdictService:
         # reconciliation after a mid-window SIGKILL is traceable per
         # window instead of per anonymous batch.
         self._mega_window_seq = 0
-        if use_device and ensure_jax_backend():
+        if use_device:
             state = self._build_engine_state(plan, device)
             if state is None:
                 self.use_device = False
             else:
                 self._adopt_engine_state(state)
-        else:
-            self.use_device = False
 
     def _build_engine_state(self, plan: RulesetPlan,
                             device: Optional[object] = None

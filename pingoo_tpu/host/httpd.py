@@ -940,6 +940,9 @@ class HttpListener:
             "captcha_served": self.stats.captcha_served,
             "fail_open": self.stats.fail_open,
             "req_per_s": round(self.stats.requests / uptime, 2) if uptime else 0,
+            # What jax serves on in this process (platform,
+            # device_kind, device_count); null on --no-device.
+            "backend": self.verdict.backend,
             "verdict": self.verdict.stats.snapshot(),
             "pipeline": self.verdict.pipeline_snapshot(),
             "ladder": self.verdict.ladder.snapshot(),

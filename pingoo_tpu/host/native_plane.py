@@ -133,13 +133,10 @@ class NativePlane:
         from .. import native_ring
         from ..native_ring import Ring, RingSidecar
 
-        if not native_ring.ensure_built():
+        if not await asyncio.to_thread(native_ring.ensure_built):
             raise RuntimeError(
-                "native data plane requested but the C++ toolchain is "
-                "unavailable (make -C pingoo_tpu/native)")
-        await asyncio.to_thread(
-            subprocess.run, ["make", "-C", native_ring.NATIVE_DIR, "httpd"],
-            check=True, capture_output=True)
+                "native data plane requested but the C++ build failed "
+                "(make -C pingoo_tpu/native)")
         os.makedirs(self.state_dir, exist_ok=True)
         # 0600 + file (not argv): /proc/<pid>/cmdline is world-readable.
         fd = os.open(self._token_path,

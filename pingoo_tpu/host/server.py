@@ -87,18 +87,6 @@ class Server:
         self.geoip = geoip
         self.lists = lists
 
-        # Probe the accelerator before table building touches jax at all;
-        # a dead backend degrades to CPU XLA (or pure interpreter). With
-        # --no-device, pin CPU outright: plan assembly below still
-        # builds jax arrays, and an ambient accelerator plugin with a
-        # wedged transport would otherwise hang that first device op.
-        from ..engine.service import ensure_jax_backend, force_cpu_backend
-
-        if self.use_device:
-            use_device = ensure_jax_backend()
-        else:
-            force_cpu_backend()
-            use_device = False
         from ..compiler.cache import compile_ruleset_cached
 
         # Serving-mesh + scheduler knobs (ISSUE 6, docs/SCHEDULER.md):
@@ -131,7 +119,11 @@ class Server:
             from ..models.botscore import load_params
 
             bot_params = load_params(self.bot_score_params_path)
-        self.verdict = VerdictService(plan, lists, use_device=use_device,
+        # The backend is whatever jax initialises in THIS process — no
+        # probe, no fallback; --no-device pinned the CPU in __main__
+        # before first use (pingoo_tpu/backend.py).
+        self.verdict = VerdictService(plan, lists,
+                                      use_device=self.use_device,
                                       bot_score_params=bot_params)
         await self.verdict.start()
         # Boot-time degradation surface (ISSUE 10, docs/RESILIENCE.md):

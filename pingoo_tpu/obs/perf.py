@@ -22,14 +22,14 @@ event:
     `pingoo_compile_total{plane,fn,kind}` counter +
     `pingoo_compile_ms{plane,fn}` histogram, and — when
     `PINGOO_PERF_LEDGER` names a file — one JSONL line per event in
-    `PERF_LEDGER.jsonl`, so compile counts survive the process and
+    `COMPILE_LEDGER.jsonl`, so compile counts survive the process and
     cross-check against the counter.
 
 Gating: unset/0 `PINGOO_PERF_LEDGER` makes `instrument_jit` return the
 callable UNCHANGED — zero added work on the hot path (the metric
 instruments are still created eagerly at zero so the inventory is
 scrapeable either way). `1`/`on` enables with the default
-`PERF_LEDGER.jsonl`; any other value is the ledger path.
+`COMPILE_LEDGER.jsonl`; any other value is the ledger path.
 
 `kind` classifies the event: `cold` = the wrapper's first compile (the
 expected warm-up), `warm` = a later retrace (new shape under live
@@ -60,7 +60,7 @@ COMPILE_FN_KINDS = ("verdict", "lanes", "prefilter", "megastep", "score")
 COMPILE_BUCKETS_MS = (1.0, 5.0, 25.0, 100.0, 250.0, 500.0, 1000.0,
                       2500.0, 5000.0, 10000.0, 30000.0)
 
-DEFAULT_LEDGER_FILE = "PERF_LEDGER.jsonl"
+DEFAULT_LEDGER_FILE = "COMPILE_LEDGER.jsonl"
 _EVENTS_CAP = 1024
 
 

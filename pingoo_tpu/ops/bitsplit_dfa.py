@@ -15,6 +15,9 @@ ops/prefilter.py's structure:
                         whole byte loop (one-hot f32 matmul lookups,
                         exact for values < 2^16; same trick as
                         ops/pallas_scan.py), `interpret=True` off-TPU.
+                        Mosaic refuses it as written (PR 21 chip run:
+                        the f32 -> uint32 convert recurses in the
+                        lowering), so no knob selects it.
 
 Accept semantics (see DfaBank's docstring): sticky accepts fire per
 consumed byte through `step_accept[state]` OR-ed into H; absolute-end
@@ -31,16 +34,9 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
 
 from ..compiler.nfa import DfaBank
-
-try:  # pallas ships with jax; guard anyway so import never kills the engine
-    from jax.experimental import pallas as pl
-
-    PALLAS_AVAILABLE = True
-except Exception:  # pragma: no cover - environment without pallas
-    pl = None
-    PALLAS_AVAILABLE = False
 
 # Batch tile for the fused kernel (matches the VPU lane width).
 B_TILE = 128
@@ -205,7 +201,7 @@ def dfa_finalize(tables: DfaTables, state: jax.Array, H: jax.Array,
 def dfa_scan(tables: DfaTables, data: jax.Array, lengths: jax.Array,
              backend: str | None = None) -> jax.Array:
     """Scan one field's [B, L] bytes -> per-slot hits [B, P] bool."""
-    if backend == "pallas" and PALLAS_AVAILABLE:
+    if backend == "pallas":
         return _fused_dfa(tables, data, lengths)
     B, L = data.shape
     lens = lengths.astype(jnp.int32)
@@ -296,8 +292,8 @@ def _fused_dfa(tables: DfaTables, data: jax.Array, lengths: jax.Array,
     """Fused-kernel variant of dfa_scan (same contract + extraction)."""
     B, L = data.shape
     lens = lengths.astype(jnp.int32)
-    if not PALLAS_AVAILABLE or L == 0:  # pragma: no cover - env guard
-        return dfa_scan(tables, data, lengths, backend=None)
+    if L == 0:
+        return dfa_scan(tables, data, lengths)
     if interpret is None:
         interpret = _use_interpret()
     cls = jnp.take(tables.byte_cls, data.astype(jnp.int32))  # [B, L]

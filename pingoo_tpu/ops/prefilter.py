@@ -47,6 +47,7 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
 
 WORD_BITS = 32
 
@@ -232,13 +233,10 @@ def prefilter_scan(tables: PrefilterTables, data: jax.Array,
 
 # -- fused Pallas variant -----------------------------------------------------
 
-try:  # pallas ships with jax; guard so import never kills the engine
-    from jax.experimental import pallas as pl
-
-    PALLAS_AVAILABLE = True
-except Exception:  # pragma: no cover - environment without pallas
-    pl = None
-    PALLAS_AVAILABLE = False
+# Status (PR 21, TPU v5e): Mosaic refuses `_pf_kernel` as written
+# (`dynamic_index_in_dim` on a loaded value), so no knob routes Stage A
+# here; `backend="pallas"` runs it (interpret mode off the TPU) or
+# raises. See ops/pallas_scan.py for the repair sketch.
 
 B_TILE = 128  # VPU lane width, same tiling as ops/pallas_scan.py
 
@@ -286,8 +284,6 @@ def _fused_prefilter(tables: PrefilterTables, data: jax.Array,
     """Fused shift-AND over one field -> hit-accumulator H [B, Wp]."""
     import functools
 
-    if not PALLAS_AVAILABLE:  # pragma: no cover - environment guard
-        raise RuntimeError("pallas unavailable")
     B, Lc = data.shape
     W = tables.init.shape[0]
     lens = lengths.astype(jnp.int32)

@@ -36,19 +36,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..ops.nfa_scan import NfaTables, extract_slots, init_scan_state, scan_chunk
 
 
-def _shard_map(f=None, **kwargs):
-    """Version-portable shard_map: `jax.shard_map` with `check_vma`
-    (new API) when present, else `jax.experimental.shard_map.shard_map`
-    with the old `check_rep` spelling."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, **kwargs) if f else jax.shard_map(**kwargs)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    if "check_vma" in kwargs:
-        kwargs["check_rep"] = kwargs.pop("check_vma")
-    return _sm(f, **kwargs) if f else (lambda fn: _sm(fn, **kwargs))
-
-
 def ring_nfa_scan(
     mesh: Mesh,
     tables: NfaTables,
@@ -63,7 +50,7 @@ def ring_nfa_scan(
     Lc = L // sp
 
     @partial(
-        _shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(), P("dp", "sp"), P("dp")),
         out_specs=P("dp", None),
@@ -121,7 +108,7 @@ def halo_nfa_scan(
     assert H <= Lc, f"halo {H} exceeds chunk {Lc}; use ring_nfa_scan"
 
     @partial(
-        _shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(), P("dp", "sp"), P("dp")),
         out_specs=P("dp", None),

@@ -71,13 +71,15 @@ BAD_EXACT = [
 ]
 
 
-def generate_ruleset(
+def generate_rule_sources(
     num_rules: int = 500,
     seed: int = 20260728,
     with_lists: bool = True,
     list_sizes: tuple[int, int] = (4096, 512),
-) -> tuple[list[RuleConfig], dict[str, list]]:
-    """Deterministic CRS-style corpus of ~num_rules rules + lists."""
+) -> tuple[list[tuple[str, str]], dict[str, list]]:
+    """The corpus as (rule name, expression source) pairs + lists —
+    what a `pingoo.yml` is written from (chip_smoke.py); every rule's
+    action is Block. `generate_ruleset` compiles exactly these."""
     rng = random.Random(seed)
     sources: list[tuple[str, str]] = []  # (name, expression)
 
@@ -149,8 +151,18 @@ def generate_ruleset(
         else:
             add("gen", f'http_request.url.matches("(?i){token}[0-9a-f]*")')
         sig += 1
-    sources = sources[:num_rules]
+    return sources[:num_rules], lists
 
+
+def generate_ruleset(
+    num_rules: int = 500,
+    seed: int = 20260728,
+    with_lists: bool = True,
+    list_sizes: tuple[int, int] = (4096, 512),
+) -> tuple[list[RuleConfig], dict[str, list]]:
+    """Deterministic CRS-style corpus of ~num_rules rules + lists."""
+    sources, lists = generate_rule_sources(num_rules, seed, with_lists,
+                                           list_sizes)
     rules = [
         RuleConfig(name=name, expression=compile_expression(src),
                    actions=(Action.BLOCK,))

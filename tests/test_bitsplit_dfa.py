@@ -284,17 +284,32 @@ class TestVerdictParity:
                                             lists)
             np.testing.assert_array_equal(outs["off"], outs["force"])
 
-    def test_pallas_backend_parity(self, crs_plan, monkeypatch):
+    def test_no_knob_reaches_the_fused_kernels(self, crs_plan,
+                                               monkeypatch):
+        """ISSUE 21: Mosaic refuses the fused kernels on the chip, so
+        the *_KERNEL knobs that routed the served verdict through them
+        are gone — with them set, the traced program holds no
+        pallas_call and the default cost model never picks the Pallas
+        NFA kernel. (The kernels keep their direct interpret-mode
+        parity tests: TestKernelDifferential here, test_pallas_scan,
+        test_prefilter.)"""
+        import jax
+
         rules, lists, plan, _, batch = crs_plan
+        assert all(e.strategy.kind != "pallas"
+                   for e in plan.scan_plans.values())
         tables = plan.device_tables()
-        monkeypatch.setenv("PINGOO_DFA", "off")
-        want = evaluate_batch(plan, make_verdict_fn(plan), tables, batch,
-                              lists)
         monkeypatch.setenv("PINGOO_DFA", "force")
         monkeypatch.setenv("PINGOO_DFA_KERNEL", "pallas")
-        got = evaluate_batch(plan, make_verdict_fn(plan), tables, batch,
-                             lists)
-        np.testing.assert_array_equal(want, got)
+        monkeypatch.setenv("PINGOO_PREFILTER_KERNEL", "pallas")
+        monkeypatch.setenv("PINGOO_PREFILTER", "banks")
+        b2 = bucket_arrays(batch.arrays)
+        jaxpr = jax.make_jaxpr(
+            lambda t, a: make_verdict_fn(plan)(t, a))(tables, b2)
+        assert "pallas_call" not in str(jaxpr)
+        monkeypatch.setenv("PINGOO_SCAN_STRATEGY", "pallas")
+        with pytest.raises(ValueError, match="PINGOO_SCAN_STRATEGY"):
+            make_verdict_fn(plan)(tables, b2)
 
     def test_state_budget_fallback_keeps_nfa(self, monkeypatch):
         """PINGOO_DFA_STATES=2: nothing lowers, force mode degrades to
@@ -465,9 +480,9 @@ class TestCacheRoundTrip:
     def test_format_version_bumped(self):
         from pingoo_tpu.compiler.cache import FORMAT_VERSION
 
-        # 12: artifacts carry the discharged plan_proof block (ISSUE 18)
-        # — a cache hit is also a proof hit.
-        assert FORMAT_VERSION == 12
+        # 13: default strategy selection dropped the unmeasured Pallas
+        # kinds (ISSUE 21); 12 added the plan_proof block (ISSUE 18).
+        assert FORMAT_VERSION == 13
 
     def test_dfa_tables_survive_cache(self, tmp_path, monkeypatch):
         from pingoo_tpu.compiler.cache import compile_ruleset_cached

@@ -1782,6 +1782,10 @@ class TestNativePlaneRunner:
             assert status == 403
             status, _ = get_until("/p?x=<script>alert(1)</script>", 403, 30)
             assert status == 403
+            # The first /hello may have been released by the fail-open
+            # deadline while the lane program compiled (it proxies
+            # either way); this one is answered by a verdict.
+            assert get("/hello") == (200, b"up:/hello")
             # Native metrics surface reachable on the public port.
             status, body = get_until("/__pingoo/metrics", 200, 30)
             assert status == 200

@@ -157,17 +157,36 @@ class TestStrategySelection:
             assert entry.strategy.source == "default"
 
     def test_env_override_strategies_agree(self, monkeypatch):
+        """The env override (scan/pair) and a MEASURED selection of the
+        fused kernel (the only route to it since ISSUE 21 — interpret
+        mode here) all serve the same verdicts."""
+        import copy
+
+        from pingoo_tpu.compiler.plan import reselect_scan_strategies
+
         rules, plan = self._plan()
         batch = encode_requests(
             [RequestTuple(path=p, url=u)
              for p, u in [("/admin", "/?q=union  select"),
                           ("/etc/passwd", "/x"), ("/ok", "/%3Cscript")]])
         results = {}
-        for mode in ("", "scan", "pair", "pallas", "pallas_single"):
+        for mode in ("", "scan", "pair"):
             monkeypatch.setenv("PINGOO_SCAN_STRATEGY", mode)
             verdict_fn = make_verdict_fn(plan)
             results[mode] = evaluate_batch(
                 plan, verdict_fn, plan.device_tables(), batch, {})
+        monkeypatch.delenv("PINGOO_SCAN_STRATEGY")
+        monkeypatch.setenv("PINGOO_DFA", "off")
+        for mode, costs in (("pallas", {"pallas": 0.01}),
+                            ("pallas_pair", {"pallas_pair": 0.01})):
+            tuned = copy.deepcopy(plan)
+            reselect_scan_strategies(tuned, costs)
+            assert all(e.strategy.kind == "pallas"
+                       and e.strategy.pair == (mode == "pallas_pair")
+                       for e in tuned.scan_plans.values())
+            results[mode] = evaluate_batch(
+                tuned, make_verdict_fn(tuned), tuned.device_tables(),
+                batch, {})
         base = results[""]
         for mode, got in results.items():
             np.testing.assert_array_equal(got, base, err_msg=mode)

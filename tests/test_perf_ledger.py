@@ -147,7 +147,7 @@ class _FakeJit:
 class TestCompileLedger:
     def _ledger(self, tmp_path):
         return perf.CompileLedger(
-            path=str(tmp_path / "PERF_LEDGER.jsonl"),
+            path=str(tmp_path / "COMPILE_LEDGER.jsonl"),
             registry=MetricRegistry())
 
     def test_cold_then_warm_events(self, tmp_path):
@@ -198,6 +198,16 @@ class TestCompileLedger:
         assert perf.perf_ledger_path() is None
         monkeypatch.setenv("PINGOO_PERF_LEDGER", "1")
         assert perf.perf_ledger_path() == perf.DEFAULT_LEDGER_FILE
+        # PERF_LEDGER.jsonl is the DRIVER's on-chip record (ISSUE 21):
+        # the program neither writes that name nor hides it from git.
+        assert perf.DEFAULT_LEDGER_FILE == "COMPILE_LEDGER.jsonl"
+        import os
+
+        ignored = [ln.strip() for ln in open(os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            ".gitignore")) if not ln.startswith("#")]
+        assert "PERF_LEDGER.jsonl" not in ignored
+        assert "COMPILE_LEDGER.jsonl" in ignored
         monkeypatch.setenv("PINGOO_PERF_LEDGER", "/tmp/x.jsonl")
         assert perf.perf_ledger_path() == "/tmp/x.jsonl"
 
@@ -321,17 +331,17 @@ class TestBenchRegressRefusal:
 
     def test_cross_backend_refused(self, tmp_path, capsys):
         rc = self._run(tmp_path, [
-            {"ts": 1, "backend": "device", "value": 100},
-            {"ts": 2, "backend": "cpu-diagnostic", "value": 5},
+            {"ts": 1, "backend": "tpu", "value": 100},
+            {"ts": 2, "backend": "cpu", "value": 5},
         ])
         assert rc == 0
         out = capsys.readouterr().out
         assert "REFUSED" in out
-        assert "cpu-diagnostic" in out and "device" in out
+        assert "'cpu'" in out and "'tpu'" in out
 
     def test_unstamped_latest_is_an_error(self, tmp_path, capsys):
         rc = self._run(tmp_path, [
-            {"ts": 1, "backend": "device", "value": 100},
+            {"ts": 1, "backend": "tpu", "value": 100},
             {"ts": 2, "value": 90},
         ])
         assert rc == 2
@@ -339,8 +349,8 @@ class TestBenchRegressRefusal:
 
     def test_same_backend_still_compares(self, tmp_path, capsys):
         rc = self._run(tmp_path, [
-            {"ts": 1, "backend": "device", "value": 100},
-            {"ts": 2, "backend": "device", "value": 101},
+            {"ts": 1, "backend": "tpu", "value": 100},
+            {"ts": 2, "backend": "tpu", "value": 101},
         ])
         assert rc == 0
         assert "bench-regress: OK" in capsys.readouterr().out

@@ -586,9 +586,9 @@ class TestBenchRegress:
         from tools import bench_regress
 
         path = self._write_history(tmp_path, [
-            {"ts": 1, "backend": "device", "value": 1000.0,
+            {"ts": 1, "backend": "tpu", "value": 1000.0,
              "p_batch_ms": 1.0},
-            {"ts": 2, "backend": "device", "value": 800.0,
+            {"ts": 2, "backend": "tpu", "value": 800.0,
              "p_batch_ms": 1.05},
         ])
         assert bench_regress.main(["--file", path]) == 1
@@ -600,9 +600,9 @@ class TestBenchRegress:
         from tools import bench_regress
 
         path = self._write_history(tmp_path, [
-            {"ts": 1, "backend": "device", "value": 1000.0,
+            {"ts": 1, "backend": "tpu", "value": 1000.0,
              "p_batch_ms": 1.0},
-            {"ts": 2, "backend": "device", "value": 950.0,
+            {"ts": 2, "backend": "tpu", "value": 950.0,
              "p_batch_ms": 1.02},
         ])
         assert bench_regress.main(["--file", path]) == 0
@@ -614,19 +614,19 @@ class TestBenchRegress:
         from tools import bench_regress
 
         path = self._write_history(tmp_path, [
-            {"ts": 1, "backend": "device", "value": 1000.0},
-            {"ts": 2, "backend": "cpu-diagnostic", "value": 5.0},
+            {"ts": 1, "backend": "tpu", "value": 1000.0},
+            {"ts": 2, "backend": "cpu", "value": 5.0},
         ])
-        # latest is cpu-diagnostic; only a device prior exists
+        # latest is a cpu run; only a tpu prior exists
         assert bench_regress.main(["--file", path]) == 0
 
     def test_baseline_picks_same_backend(self, tmp_path, capsys):
         from tools import bench_regress
 
         path = self._write_history(tmp_path, [
-            {"ts": 1, "backend": "device", "value": 1000.0},
-            {"ts": 2, "backend": "cpu-diagnostic", "value": 5.0},
-            {"ts": 3, "backend": "device", "value": 990.0},
+            {"ts": 1, "backend": "tpu", "value": 1000.0},
+            {"ts": 2, "backend": "cpu", "value": 5.0},
+            {"ts": 3, "backend": "tpu", "value": 990.0},
         ])
         assert bench_regress.main(["--file", path]) == 0
         assert "ts=1" in capsys.readouterr().out
@@ -637,7 +637,7 @@ class TestBenchRegress:
         assert bench_regress.main(
             ["--file", str(tmp_path / "nope.jsonl")]) == 0
         path = self._write_history(tmp_path, [
-            {"ts": 1, "backend": "device", "value": 1.0}])
+            {"ts": 1, "backend": "tpu", "value": 1.0}])
         assert bench_regress.main(["--file", path]) == 0
 
     def test_bench_emit_appends_history(self, tmp_path, monkeypatch):

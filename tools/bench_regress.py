@@ -5,8 +5,8 @@ satellite).
 `bench.py --history` appends every emitted result line to
 BENCH_history.jsonl (one JSON object per run, wall-clock stamped).
 This tool compares the LATEST run against the most recent previous run
-with the SAME backend label (a cpu-diagnostic floor is never comparable
-to a device number) under a configurable relative threshold:
+with the SAME backend label (the platform the run names: a `cpu` run
+is never comparable to a `tpu` number) under a configurable relative threshold:
 
     python tools/bench_regress.py [--threshold 0.10] [--file PATH]
 
@@ -182,8 +182,8 @@ def main(argv=None) -> int:
         print(f"bench-regress: REFUSED — latest run is backend="
               f"{latest.get('backend')!r} but every prior run is "
               f"backend in {others}; cross-backend numbers are not "
-              f"comparable (a cpu-diagnostic floor vs a device run "
-              f"measures the host, not the change)")
+              f"comparable (a cpu run against a tpu run measures the "
+              f"host, not the change)")
         return 0
     regressions, report = compare(latest, baseline, args.threshold)
     print(f"bench-regress: latest ts={latest.get('ts')} vs baseline "

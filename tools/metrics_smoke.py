@@ -50,7 +50,7 @@ os.environ.setdefault("PINGOO_PARITY_FAULT_INJECT", FAULT_PATH)
 _PERF_TMP = tempfile.mkdtemp(prefix="pingoo-perf-smoke-")
 os.environ.setdefault("PINGOO_TIMELINE_SAMPLE", "1")
 os.environ.setdefault("PINGOO_PERF_LEDGER",
-                      os.path.join(_PERF_TMP, "PERF_LEDGER.jsonl"))
+                      os.path.join(_PERF_TMP, "COMPILE_LEDGER.jsonl"))
 os.environ.setdefault("PINGOO_COST_LEDGER",
                       os.path.join(_PERF_TMP, "COST_LEDGER.json"))
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))

@@ -59,7 +59,7 @@ def parent() -> int:
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
     env["PINGOO_TIMELINE_SAMPLE"] = "1"
-    env["PINGOO_PERF_LEDGER"] = os.path.join(tmp, "PERF_LEDGER.jsonl")
+    env["PINGOO_PERF_LEDGER"] = os.path.join(tmp, "COMPILE_LEDGER.jsonl")
     env["PINGOO_COST_LEDGER"] = os.path.join(tmp, "COST_LEDGER.json")
     env["PINGOO_COMPILE_SURFACE"] = os.path.join(
         tmp, "COMPILE_SURFACE.json")
@@ -148,7 +148,7 @@ def _python_plane() -> dict:
     with open(ledger.path) as f:
         lines = [json.loads(ln) for ln in f if ln.strip()]
     check(len(lines) == snap["compiles_total"] and not snap["io_errors"],
-          f"PERF_LEDGER.jsonl agrees with in-memory totals "
+          f"COMPILE_LEDGER.jsonl agrees with in-memory totals "
           f"({len(lines)} == {snap['compiles_total']})")
     check(all(ln.get("fingerprint") == svc._plan_fp for ln in lines
               if ln.get("plane") == "python"),
