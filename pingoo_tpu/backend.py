@@ -46,12 +46,23 @@ def place_compile_cache() -> str:
     directory and return it. `JAX_COMPILATION_CACHE_DIR`, when set, is
     read by JAX itself and nothing is set in code; otherwise the cache
     lives at DEFAULT_COMPILE_CACHE_DIR. Call before anything jits. The
-    cache thresholds stay at JAX's defaults."""
+    cache thresholds stay at JAX's defaults.
+
+    The cache's key covers the operations' NAMES and not their source
+    lines. By default JAX strips all debug info from the key, so a
+    cache filled before a `jax.named_scope` existed (engine/verdict.py's
+    device-trace vocabulary) would go on serving programs without it —
+    a profiler trace taken after an upgrade would name nothing (met on
+    the chip, PR 28). With the names in the key such a program compiles
+    once more; with the tracebacks out of the locations, moving code
+    does not."""
+    import jax
+
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    jax.config.update("jax_traceback_in_locations_limit", 0)
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env
-    import jax
-
     jax.config.update("jax_compilation_cache_dir",
                       DEFAULT_COMPILE_CACHE_DIR)
     return DEFAULT_COMPILE_CACHE_DIR

@@ -55,6 +55,21 @@ from ..ops.window_match import window_hits
 
 I64_MIN = -(2**63)
 
+# Device-trace scopes (docs/OBSERVABILITY.md "Spans and scopes"): every
+# unit of device work traces under ONE fixed vocabulary, `<kind>/<key>`
+# or a bare kind, so that a profiler trace's operations split by bank
+# and not by HLO line. Metadata only: the compiled programs are the
+# same. Scopes nest (`act/bool/nfa/url/...`); a reader takes the
+# deepest component of the vocabulary.
+SCOPE_KINDS = ("unpack", "pf", "nfa", "dfa", "win", "grp", "list", "num",
+               "bool", "act")
+
+
+def _bank_scope(kind: str, key: str):
+    """`nfa/<bank>` / `dfa/<bank>`: an NFA bank's key without its
+    `nfa_` prefix (`@short`/`@rest` sub-banks keep their suffix)."""
+    return jax.named_scope(f"{kind}/{key.removeprefix('nfa_')}")
+
 # Scan execution selection: the default comes from the PLAN-TIME
 # strategy selector (compiler/plan.py select_scan_strategy — recorded in
 # plan.scan_plans, persisted through the artifact cache, re-tunable from
@@ -307,15 +322,14 @@ def _eval_leaves(plan: RulesetPlan, tables, arrays, B, pf_hits=None):
             table = tables[key]
             data = arrays[f"{field}_bytes"]
             lens = arrays[f"{field}_len"]
-            if kind == "eq":
-                group_cols[key] = eq_match(data, lens, table)
-            elif kind == "prefix":
-                group_cols[key] = prefix_match(data, lens, table)
-            else:
-                group_cols[key] = suffix_match(data, lens, table)
+            match = {"eq": eq_match, "prefix": prefix_match}.get(
+                kind, suffix_match)
+            with jax.named_scope(f"grp/{key}"):
+                group_cols[key] = match(data, lens, table)
         return group_cols[key]
 
     nfa_cache: dict[str, Any] = {}
+    bank_kind: dict[str, str] = {}  # banks that ran their lowered DFA
 
     def nfa_result(key, field):
         return nfa_cache[key]  # pre-filled by run_packed_scans
@@ -353,9 +367,10 @@ def _eval_leaves(plan: RulesetPlan, tables, arrays, B, pf_hits=None):
         Stage-A pass, or traced inline exactly once per field)."""
         if field not in pf_field_hits:
             ff = pf.fields[field]
-            pf_field_hits[field] = prefilter_scan(
-                tables[ff.table_key], arrays[f"{field}_bytes"],
-                arrays[f"{field}_len"])
+            with jax.named_scope(f"pf/{field}"):
+                pf_field_hits[field] = prefilter_scan(
+                    tables[ff.table_key], arrays[f"{field}_bytes"],
+                    arrays[f"{field}_len"])
         return pf_field_hits[field]
 
     def bank_skip_result(bank, lens):
@@ -430,10 +445,11 @@ def _eval_leaves(plan: RulesetPlan, tables, arrays, B, pf_hits=None):
             base_fn)
 
     def gated_bank_hits(key, bank, strat, data, lens):
-        return gated_scan(
-            key, data, lens,
-            lambda d, l: bank_hits(bank, strat, d, l),
-            lambda: bank_skip_result(bank, lens))
+        with _bank_scope("nfa", key):
+            return gated_scan(
+                key, data, lens,
+                lambda d, l: bank_hits(bank, strat, d, l),
+                lambda: bank_skip_result(bank, lens))
 
     def dfa_cascade_hits(key, dtab, data, lens, recheck_rows,
                          recheck_base):
@@ -465,10 +481,11 @@ def _eval_leaves(plan: RulesetPlan, tables, arrays, B, pf_hits=None):
 
     def dfa_bank_hits(key, entry, bank, data, lens):
         strat = _resolve_strategy(entry.strategy)
-        return dfa_cascade_hits(
-            key, tables[entry.dfa_key], data, lens,
-            lambda d, l: bank_hits(bank, strat, d, l),
-            lambda: bank_skip_result(bank, lens))
+        with _bank_scope("dfa", key):
+            return dfa_cascade_hits(
+                key, tables[entry.dfa_key], data, lens,
+                lambda d, l: bank_hits(bank, strat, d, l),
+                lambda: bank_skip_result(bank, lens))
 
     def gated_window_hits(key, field):
         """The window bank under the same cascade: a gated win bank's
@@ -493,11 +510,13 @@ def _eval_leaves(plan: RulesetPlan, tables, arrays, B, pf_hits=None):
         if dkey and dkey in tables \
                 and _dfa_win_active(plan, key, dfa_mode) \
                 and tables[dkey].num_slots == P:
-            return dfa_cascade_hits(key, tables[dkey], data, lens,
-                                    win_rows, win_base)
-        if pf is None or key not in pf.slot_codes:
-            return win_rows(data, lens)
-        return gated_scan(key, data, lens, win_rows, win_base)
+            with _bank_scope("dfa", key):
+                return dfa_cascade_hits(key, tables[dkey], data, lens,
+                                        win_rows, win_base)
+        with jax.named_scope(f"win/{field}"):
+            if pf is None or key not in pf.slot_codes:
+                return win_rows(data, lens)
+            return gated_scan(key, data, lens, win_rows, win_base)
 
     def run_packed_scans(groups: dict[str, tuple[str, list]]) -> None:
         """Run every NFA bank through its plan-selected strategy
@@ -513,15 +532,17 @@ def _eval_leaves(plan: RulesetPlan, tables, arrays, B, pf_hits=None):
                 key=key, strategy=ScanStrategy())
             if entry.split is not None:
                 skey, rkey = entry.split
-                hits = jnp.concatenate(
-                    [gated_bank_hits(skey, tables[skey],
-                                     _resolve_strategy(entry.short_strategy),
-                                     data, lens),
-                     gated_bank_hits(rkey, tables[rkey],
-                                     _resolve_strategy(entry.rest_strategy),
-                                     data, lens)], axis=1)
+                parts = [
+                    gated_bank_hits(skey, tables[skey],
+                                    _resolve_strategy(entry.short_strategy),
+                                    data, lens),
+                    gated_bank_hits(rkey, tables[rkey],
+                                    _resolve_strategy(entry.rest_strategy),
+                                    data, lens)]
                 perm = jnp.asarray(entry.slot_perm, dtype=jnp.int32)
-                nfa_cache[key] = jnp.take(hits, perm, axis=1)
+                with _bank_scope("nfa", key):
+                    nfa_cache[key] = jnp.take(
+                        jnp.concatenate(parts, axis=1), perm, axis=1)
                 continue
             if _dfa_bank_active(plan, entry, dfa_mode) \
                     and entry.dfa_key in tables \
@@ -529,6 +550,7 @@ def _eval_leaves(plan: RulesetPlan, tables, arrays, B, pf_hits=None):
                         == tables[key].accept_member.shape[1]:
                 nfa_cache[key] = dfa_bank_hits(key, entry, tables[key],
                                                data, lens)
+                bank_kind[key] = "dfa"
                 continue
             strat = _resolve_strategy(entry.strategy)
             if strat.source != "env" and SCAN_PACK_MODE != "field":
@@ -538,21 +560,24 @@ def _eval_leaves(plan: RulesetPlan, tables, arrays, B, pf_hits=None):
                 if HALO_SPLIT:  # legacy halo-first, as before packing
                     k = halo_split_k(tables[key], int(data.shape[1]))
                     if k > 1:
-                        nfa_cache[key] = halo_split_scan(
-                            tables[key], data, lens, k)
+                        with _bank_scope("nfa", key):
+                            nfa_cache[key] = halo_split_scan(
+                                tables[key], data, lens, k)
                         continue
                 packed[key] = (tables[key], data, lens)
                 continue
             nfa_cache[key] = gated_bank_hits(key, tables[key], strat,
                                              data, lens)
-        if packed:
-            states = packed_scan_states(
-                {k: v[0] for k, v in packed.items()},
-                {k: v[1] for k, v in packed.items()},
-                {k: v[2] for k, v in packed.items()},
-                mode=SCAN_PACK_MODE)
+        if packed:  # several banks in one scan: no bank to name
+            with jax.named_scope("nfa/@packed"):
+                states = packed_scan_states(
+                    {k: v[0] for k, v in packed.items()},
+                    {k: v[1] for k, v in packed.items()},
+                    {k: v[2] for k, v in packed.items()},
+                    mode=SCAN_PACK_MODE)
             for k, (bank, _data, lens) in packed.items():
-                nfa_cache[k] = extract_slots(bank, states[k], lens)
+                with _bank_scope("nfa", k):
+                    nfa_cache[k] = extract_slots(bank, states[k], lens)
 
     # Per-leaf NFA/window extraction: leaves own contiguous slot spans;
     # doing a per-leaf slice+any would issue hundreds of tiny ops, so
@@ -560,16 +585,18 @@ def _eval_leaves(plan: RulesetPlan, tables, arrays, B, pf_hits=None):
     # once (MXU does the OR as a count > 0).
     leaf_matrix_cache: dict[str, Any] = {}
 
-    def span_leaf_matrix(key, hits_fn, spans):
+    def span_leaf_matrix(key, hits_fn, spans, scope):
         if key not in leaf_matrix_cache:
             hits = hits_fn()
             P = hits.shape[1]
             member = np.zeros((P, len(spans)), dtype=np.float32)
             for j, (lo, hi) in enumerate(spans):
                 member[lo:hi, j] = 1.0
-            counts = jnp.dot(hits.astype(jnp.float32), jnp.asarray(member),
-                             preferred_element_type=jnp.float32)
-            leaf_matrix_cache[key] = counts > 0.0
+            with scope:
+                counts = jnp.dot(hits.astype(jnp.float32),
+                                 jnp.asarray(member),
+                                 preferred_element_type=jnp.float32)
+                leaf_matrix_cache[key] = counts > 0.0
         return leaf_matrix_cache[key]
 
     ip_one_cache: Any = None
@@ -607,7 +634,9 @@ def _eval_leaves(plan: RulesetPlan, tables, arrays, B, pf_hits=None):
             field, members = nfa_groups[key]
             mat = span_leaf_matrix(key, lambda key=key, field=field:
                                    nfa_result(key, field),
-                                   [span for _, span in members])
+                                   [span for _, span in members],
+                                   _bank_scope(bank_kind.get(key, "nfa"),
+                                               key))
             results[leaf_id] = (mat[:, col], no_err)
         elif k == "window":
             key, col = win_leaf_col[leaf_id]
@@ -615,7 +644,8 @@ def _eval_leaves(plan: RulesetPlan, tables, arrays, B, pf_hits=None):
             mat = span_leaf_matrix(
                 key,
                 lambda key=key, field=field: gated_window_hits(key, field),
-                [span for _, span in members])
+                [span for _, span in members],
+                jax.named_scope(f"win/{field}"))
             results[leaf_id] = (mat[:, col], no_err)
         elif k == "str_list":
             table = tables[binding.table_key]
@@ -625,31 +655,38 @@ def _eval_leaves(plan: RulesetPlan, tables, arrays, B, pf_hits=None):
             if hi == lo:  # all entries were non-byte strings
                 results[leaf_id] = (jnp.zeros((B,), dtype=bool), no_err)
             else:
-                eqs = eq_match(data, lens, table)
-                results[leaf_id] = (jnp.any(eqs[:, lo:hi], axis=1), no_err)
+                with jax.named_scope(f"list/{binding.table_key}"):
+                    eqs = eq_match(data, lens, table)
+                    results[leaf_id] = (jnp.any(eqs[:, lo:hi], axis=1),
+                                        no_err)
         elif k == "ip_one":
             if ip_one_cache is None:
                 t = tables["ip_preds"]
                 ips = arrays["ip"]
-                diff = (ips[:, None, :] & t["masks"][None]) ^ t["nets"][None]
-                ip_one_cache = jnp.all(diff == 0, axis=2)  # [B, N]
+                with jax.named_scope("list/ip_preds"):
+                    diff = (ips[:, None, :] & t["masks"][None]) \
+                        ^ t["nets"][None]
+                    ip_one_cache = jnp.all(diff == 0, axis=2)  # [B, N]
             results[leaf_id] = (ip_one_cache[:, binding.col], no_err)
-        elif k == "ip_list_small":
-            results[leaf_id] = (
-                cidr_contains(tables[binding.table_key], arrays["ip"]), no_err)
-        elif k == "ip_list_large":
-            results[leaf_id] = (
-                v4_buckets_contains(tables[binding.table_key], arrays["ip"]),
-                no_err)
+        elif k in ("ip_list_small", "ip_list_large"):
+            contains = cidr_contains if k == "ip_list_small" \
+                else v4_buckets_contains
+            with jax.named_scope(f"list/{binding.table_key}"):
+                results[leaf_id] = (
+                    contains(tables[binding.table_key], arrays["ip"]),
+                    no_err)
         elif k == "int_list":
-            pv, pe = _eval_num(binding.pred, arrays, B)
-            hit = int_set_contains(tables[binding.table_key], pv)
+            with jax.named_scope("num"):
+                pv, pe = _eval_num(binding.pred, arrays, B)
+            with jax.named_scope(f"list/{binding.table_key}"):
+                hit = int_set_contains(tables[binding.table_key], pv)
             results[leaf_id] = (hit, pe)
         elif k == "num_cmp":
             cmp: NumCmp = binding.pred
-            lv, le = _eval_num(cmp.left, arrays, B)
-            rv, re_ = _eval_num(cmp.right, arrays, B)
-            results[leaf_id] = (_CMP[cmp.op](lv, rv), le | re_)
+            with jax.named_scope("num"):
+                lv, le = _eval_num(cmp.left, arrays, B)
+                rv, re_ = _eval_num(cmp.right, arrays, B)
+                results[leaf_id] = (_CMP[cmp.op](lv, rv), le | re_)
         else:
             raise AssertionError(k)
     return results
@@ -692,6 +729,7 @@ def _eval_bool(ir, leaves, B):
 # -- public API --------------------------------------------------------------
 
 
+@jax.named_scope("bool")
 def _matched_cols(plan: RulesetPlan, tables, arrays, pf_hits=None):
     """Traced body shared by the verdict/lane functions:
     (tables, arrays) -> [B, R_dev] bool in device_rule_indices order.
@@ -772,6 +810,7 @@ def make_verdict_fn(plan: RulesetPlan, donate: bool = False):
 # -- compact staging: device-side decode (ISSUE 15) ---------------------------
 
 
+@jax.named_scope("unpack")
 def unpack_staged(packed, layout):
     """Decode ONE packed staging buffer on device: [B, layout.width]
     uint8 -> the standard per-field arrays dict the traced evaluator
@@ -864,19 +903,22 @@ def _make_prefilter_body(plan: RulesetPlan):
     def stage_a(tables, arrays):
         hits = {}
         for field, ff in pf.fields.items():
-            hits[field] = prefilter_scan(
-                tables[ff.table_key], arrays[f"{field}_bytes"],
-                arrays[f"{field}_len"])
+            with jax.named_scope(f"pf/{field}"):
+                hits[field] = prefilter_scan(
+                    tables[ff.table_key], arrays[f"{field}_bytes"],
+                    arrays[f"{field}_len"])
         cand_rows = jnp.int32(0)
         skipped = jnp.int32(len(gated) - len(masks))  # never-only banks
         bank_cands = []
         bank_skips = []
         for k, mask in masks.items():
-            cand = jnp.any(hits[pf.bank_field[k]] & mask[None, :], axis=1)
-            n_cand = cand.sum(dtype=jnp.int32)
-            skip = jnp.where(jnp.any(cand), 0, 1).astype(jnp.int32)
-            cand_rows = cand_rows + n_cand
-            skipped = skipped + skip
+            field = pf.bank_field[k]
+            with jax.named_scope(f"pf/{field}"):  # the bank's candidates
+                cand = jnp.any(hits[field] & mask[None, :], axis=1)
+                n_cand = cand.sum(dtype=jnp.int32)
+                skip = jnp.where(jnp.any(cand), 0, 1).astype(jnp.int32)
+                cand_rows = cand_rows + n_cand
+                skipped = skipped + skip
             bank_cands.append(n_cand)
             bank_skips.append(skip)
         return hits, jnp.stack([cand_rows, skipped]
@@ -1032,6 +1074,7 @@ def _make_lane_body(plan: RulesetPlan, groups: list[list[str]],
         if dev_route else None
         for dev_route in group_routes]
 
+    @jax.named_scope("act")
     def lanes(tables, arrays, pf_hits=None, n_valid=None):
         matched = _matched_cols(plan, tables, arrays, pf_hits)  # [B, C]
         B = arrays["asn"].shape[0]

@@ -561,15 +561,14 @@ class _MegaSlice:
     in-flight tuple, parked until the window's single device sync."""
 
     __slots__ = ("parts", "slots", "raw", "n", "skip_masks", "slot_buf",
-                 "pipe_slot", "epoch", "oldest_enq_ms")
+                 "spans", "epoch", "oldest_enq_ms")
 
 
 class _MegaWindow:
     """One in-flight K-slice megastep window (ISSUE 12): the deque
     entry `_complete_inflight` routes to `_complete_megastep`."""
 
-    __slots__ = ("slices", "k", "k_ship", "dev_out", "t_launch",
-                 "window_id")
+    __slots__ = ("slices", "k", "k_ship", "dev_out", "window_id")
 
 
 class RingSidecar:
@@ -819,6 +818,14 @@ class RingSidecar:
             for stage in ("sched", "encode", "prefilter",
                           "device_dispatch", "device_compute", "resolve",
                           "provenance")}
+        # The drain loop's ONE span source (obs/pipeline.py): every
+        # stage boundary below is a `self._pipe.stage(...)` block, which
+        # feeds these histograms, the executor's occupancy/overlap, the
+        # scheduler's stage costs, the loop-phase account and — as
+        # `sidecar/<phase>` annotations — the profiler's trace. Only the
+        # `sched` label is observed directly: it is an age, not a span.
+        self._pipe.attach_loop(self._stage, self.sched.observe_stage_cost,
+                               self.max_batch, self._queued_depth)
         # Compact staging (ISSUE 15): bytes staged to the device per
         # verdict batch, by PINGOO_STAGING arm — same series the Python
         # listener plane exports.
@@ -1182,7 +1189,7 @@ class RingSidecar:
         from .engine.hotswap import note_swap, set_epoch_gauge
 
         t0 = time.monotonic()
-        with self._hb_busy():
+        with self._hb_busy(), self._pipe.stage("swap"):
             if pend_parts:
                 # Megastep boundary (ISSUE 12): pending slots join the
                 # OPEN window when one exists — launching them per-batch
@@ -1268,6 +1275,7 @@ class RingSidecar:
         # view — the buffer travels with the batch and returns to the
         # pool when `_complete` finishes it.
         pend_buf = self._take_slot_buf() if self._zero_copy else None
+        self._pipe.loop_start()
         while not self._stop:
             # Liveness heartbeat (ring v5): one relaxed shm store per
             # ring per poll cycle. Deliberately stamped from THIS loop
@@ -1286,7 +1294,8 @@ class RingSidecar:
             # a flow's body verdict never waits a full cycle behind the
             # metadata batch that admitted it.
             if self._body_scan is not None:
-                self._drain_bodies()
+                with self._pipe.stage("bodies"):
+                    self._drain_bodies()
             # Ruleset hot-swap boundary (ISSUE 11). The swap-storm
             # chaos rung re-requests the CURRENT plan so any verdict
             # drift it produces is a swap-protocol bug by construction
@@ -1335,6 +1344,8 @@ class RingSidecar:
                     if oldest_enq_ms is None or first < oldest_enq_ms:
                         oldest_enq_ms = first
             pend_n += got
+            if got:
+                self._pipe.wake()
             launch = False
             if pend_n:
                 if not continuous or pend_n >= self.max_batch:
@@ -1376,6 +1387,7 @@ class RingSidecar:
                 if not pend_parts and max_requests is not None \
                         and self.processed >= max_requests:
                     break
+                self._pipe.idle()
                 time.sleep(self.idle_sleep_s)
             if max_requests is not None and self.processed >= max_requests \
                     and not inflight and not pend_parts \
@@ -1402,7 +1414,8 @@ class RingSidecar:
         # Final body drain: FINAL windows already in the ring still get
         # verdicts (else their held requests eat the fail-open timeout).
         if self._body_scan is not None:
-            self._drain_bodies()
+            with self._pipe.stage("bodies"):
+                self._drain_bodies()
         # A swap that never reached a batch boundary before shutdown is
         # rejected, not leaked: wake its requester.
         with self._swap_lock:
@@ -1415,6 +1428,7 @@ class RingSidecar:
                 handle.resolve(self.ruleset_epoch, 0.0,
                                result="rejected",
                                error=RuntimeError("sidecar stopped"))
+        self._pipe.loop_stop()
         return self.processed
 
     def _drain_bodies(self) -> None:
@@ -1490,147 +1504,130 @@ class RingSidecar:
         returns the in-flight tuple `_complete` consumes."""
         from .engine.batch import RequestBatch, bucket_arrays, pad_batch
 
-        pipe_slot = self._pipe.enter(self.pipeline_mode)
-        self.chaos.stage("encode")
-        t0 = time.monotonic()
-        batch = raw = None
-        if slot_buf is not None:
-            # Zero-copy plane (PINGOO_PIPELINE=on): the dequeue FFI
-            # already landed every part contiguously in `slot_buf`, so
-            # the merged batch is one view — no concatenate — and the
-            # staging encoder fills its reused bucketed+padded
-            # matrices straight from the slot fields (no
-            # slots_to_arrays intermediates, no bucket/pad copies).
-            # `raw` is the unpadded row view of the same staging
-            # arrays: bucketed columns are a superset of every row's
-            # length, and every consumer (host_rule_lanes,
-            # batch_to_contexts) reads data[:len].
-            slots = slot_buf[:n]
-            if self.ladder.try_rung("pipeline"):
+        rec = self._pipe.begin(self.pipeline_mode, n)
+        with self._pipe.stage("encode", rec) as sp:
+            self.chaos.stage("encode")
+            batch = raw = None
+            if slot_buf is not None:
+                # Zero-copy plane (PINGOO_PIPELINE=on): the dequeue FFI
+                # already landed every part contiguously in `slot_buf`, so
+                # the merged batch is one view — no concatenate — and the
+                # staging encoder fills its reused bucketed+padded
+                # matrices straight from the slot fields (no
+                # slots_to_arrays intermediates, no bucket/pad copies).
+                # `raw` is the unpadded row view of the same staging
+                # arrays: bucketed columns are a superset of every row's
+                # length, and every consumer (host_rule_lanes,
+                # batch_to_contexts) reads data[:len].
+                slots = slot_buf[:n]
+                if self.ladder.try_rung("pipeline"):
+                    try:
+                        batch = self._staging.encode_slots(
+                            slots, pad_to=self.max_batch)
+                        raw = RequestBatch(
+                            size=n,
+                            arrays={k: v[:n]
+                                    for k, v in batch.arrays.items()},
+                            overflow=(batch.overflow[:n]
+                                      if batch.overflow is not None
+                                      else None))
+                        self.ladder.note_success("pipeline")
+                    except Exception as exc:
+                        # Ladder pipeline rung: a broken staging encoder
+                        # demotes THIS plane to the legacy encode chain
+                        # below (bit-identical, tests/test_pipeline.py)
+                        # until a backoff probe re-promotes it.
+                        self.ladder.note_failure("pipeline", exc)
+                        batch = raw = None
+            else:
+                slots = parts[0][1] if len(parts) == 1 else np.concatenate(
+                    [s for _, s in parts])
+            if batch is None:
+                # Legacy encode chain (PINGOO_PIPELINE=off, or the ladder's
+                # pipeline rung demoted): pad the batch axis to one fixed
+                # shape (a partial batch would otherwise be a new XLA
+                # program — compile stall on the serving path) and bucket
+                # field lengths to powers of two so the NFA scan walks the
+                # batch's longest value, not the 2048-byte slot capacity
+                # (at most log2(cap) shapes per field).
+                raw = RequestBatch(size=n, arrays=slots_to_arrays(slots))
+                batch = pad_batch(
+                    RequestBatch(size=n, arrays=bucket_arrays(raw.arrays)),
+                    self.max_batch)
+            # Mesh placement (ISSUE 6): the device programs read the
+            # dp-sharded view; `raw` stays host-resident for host-rule
+            # interpretation and spill re-evaluation.
+            arrays = batch.arrays
+            if self.mesh.active:
+                arrays = self.mesh.shard_batch(arrays)
+            sp.next("prefilter")
+            self.chaos.stage("dispatch")
+            pf_hits = pf_aux = None
+            rule_hits = None
+            dev = None
+            self._dfa_rung_tick()
+            # Ladder device rung: while demoted, skip the dispatch
+            # entirely (the interpreter serves in `_complete`) except for
+            # backoff probes; a dispatch-time exception demotes — it no
+            # longer kills the drain thread.
+            if self.ladder.try_rung("device"):
                 try:
-                    batch = self._staging.encode_slots(
-                        slots, pad_to=self.max_batch)
-                    raw = RequestBatch(
-                        size=n,
-                        arrays={k: v[:n]
-                                for k, v in batch.arrays.items()},
-                        overflow=(batch.overflow[:n]
-                                  if batch.overflow is not None
-                                  else None))
-                    self.ladder.note_success("pipeline")
-                except Exception as exc:
-                    # Ladder pipeline rung: a broken staging encoder
-                    # demotes THIS plane to the legacy encode chain
-                    # below (bit-identical, tests/test_pipeline.py)
-                    # until a backoff probe re-promotes it.
-                    self.ladder.note_failure("pipeline", exc)
-                    batch = raw = None
-        else:
-            slots = parts[0][1] if len(parts) == 1 else np.concatenate(
-                [s for _, s in parts])
-        if batch is None:
-            # Legacy encode chain (PINGOO_PIPELINE=off, or the ladder's
-            # pipeline rung demoted): pad the batch axis to one fixed
-            # shape (a partial batch would otherwise be a new XLA
-            # program — compile stall on the serving path) and bucket
-            # field lengths to powers of two so the NFA scan walks the
-            # batch's longest value, not the 2048-byte slot capacity
-            # (at most log2(cap) shapes per field).
-            raw = RequestBatch(size=n, arrays=slots_to_arrays(slots))
-            batch = pad_batch(
-                RequestBatch(size=n, arrays=bucket_arrays(raw.arrays)),
-                self.max_batch)
-        # Mesh placement (ISSUE 6): the device programs read the
-        # dp-sharded view; `raw` stays host-resident for host-rule
-        # interpretation and spill re-evaluation.
-        arrays = batch.arrays
-        if self.mesh.active:
-            arrays = self.mesh.shard_batch(arrays)
-        t1 = time.monotonic()
-        self.chaos.stage("dispatch")
-        pf_hits = pf_aux = None
-        rule_hits = None
-        dev = None
-        tpf = t1
-        self._dfa_rung_tick()
-        # Ladder device rung: while demoted, skip the dispatch entirely
-        # (the interpreter serves in `_complete`) except for backoff
-        # probes; a dispatch-time exception demotes — it no longer
-        # kills the drain thread.
-        if self.ladder.try_rung("device"):
-            try:
-                self.chaos.maybe_xla_error(self.batches)
-                # True padded lane batch for the compile ledger's
-                # surface check (packed blobs hide the batch axis).
-                from .obs.perf import batch_leading_dim, \
-                    set_dispatch_context
-                set_dispatch_context(batch=batch_leading_dim(arrays))
-                # Busy window: the jitted calls return async once
-                # compiled, but the FIRST call per pow2 bucket blocks
-                # in XLA for seconds — the watchdog heartbeats through
-                # it so the data plane doesn't flip degraded.
-                with self._hb_busy():
-                    # Compact staging (ISSUE 15): ONE device_put of the
-                    # packed buffer replaces the per-field transfers;
-                    # the packed twins slice the fields back out on
-                    # device. Mesh stays on the per-field path (the
-                    # shard plan addresses named arrays).
-                    use_packed = (
-                        batch.packed is not None
-                        and self._packed_lane_fn is not None
-                        and not self.mesh.active)
-                    if use_packed:
-                        import jax
+                    self.chaos.maybe_xla_error(self.batches)
+                    # True padded lane batch for the compile ledger's
+                    # surface check (packed blobs hide the batch axis).
+                    from .obs.perf import batch_leading_dim, \
+                        set_dispatch_context
+                    set_dispatch_context(batch=batch_leading_dim(arrays))
+                    # Busy window: the jitted calls return async once
+                    # compiled, but the FIRST call per pow2 bucket
+                    # blocks in XLA for seconds — the watchdog heartbeats
+                    # through it so the data plane doesn't flip degraded.
+                    with self._hb_busy():
+                        # Compact staging (ISSUE 15): ONE device_put of
+                        # the packed buffer replaces the per-field
+                        # transfers; the packed twins slice the fields
+                        # back out on device. Mesh stays on the per-field
+                        # path (the shard plan addresses named arrays).
+                        use_packed = (
+                            batch.packed is not None
+                            and self._packed_lane_fn is not None
+                            and not self.mesh.active)
+                        if use_packed:
+                            import jax
 
-                        dev_packed = jax.device_put(batch.packed)
-                        if self._packed_pf_fn is not None:
-                            pf_hits, pf_aux = self._packed_pf_fn(
-                                self._tables, dev_packed,
-                                batch.layout)  # async
-                        tpf = time.monotonic()
-                        if self._provenance_on:
-                            dev, rule_hits = self._packed_lane_fn(
-                                self._tables, dev_packed, batch.layout,
-                                pf_hits, np.int32(n))  # async
+                            dev_packed = jax.device_put(batch.packed)
+                            if self._packed_pf_fn is not None:
+                                pf_hits, pf_aux = self._packed_pf_fn(
+                                    self._tables, dev_packed,
+                                    batch.layout)  # async
+                            sp.next("dispatch")
+                            if self._provenance_on:
+                                dev, rule_hits = self._packed_lane_fn(
+                                    self._tables, dev_packed, batch.layout,
+                                    pf_hits, np.int32(n))  # async
+                            else:
+                                dev = self._packed_lane_fn(
+                                    self._tables, dev_packed, batch.layout,
+                                    pf_hits)  # async
                         else:
-                            dev = self._packed_lane_fn(
-                                self._tables, dev_packed, batch.layout,
-                                pf_hits)  # async
-                    else:
-                        if self._pf_fn is not None:
-                            pf_hits, pf_aux = self._pf_fn(
-                                self._tables, arrays)  # async
-                        tpf = time.monotonic()
-                        if self._provenance_on:
-                            # Attribution aux lane rides the SAME
-                            # dispatch; the traced n masks
-                            # batch-padding rows on device.
-                            dev, rule_hits = self._lane_fn(
-                                self._tables, arrays, pf_hits,
-                                np.int32(n))  # async
-                        else:
-                            dev = self._lane_fn(self._tables, arrays,
-                                                pf_hits)  # async
-            except Exception as exc:
-                self._note_device_failure(exc)
-                pf_hits = pf_aux = rule_hits = dev = None
-                tpf = time.monotonic()
-        t2 = time.monotonic()
-        self._stage["encode"].observe((t1 - t0) * 1e3)
-        self._stage["prefilter"].observe((tpf - t1) * 1e3)
-        self._stage["device_dispatch"].observe((t2 - tpf) * 1e3)
-        # Pipeline telemetry + per-stage cost feed (ISSUE 9): the
-        # executor stages are encode (staging fill + mesh placement)
-        # and dispatch (prefilter + lane-fn issue); feeding them to the
-        # stage-aware cost model keeps should_launch's slack estimate
-        # honest once stages of different batches overlap (the single
-        # launch->result wall would double-count overlapped host work).
-        self._pipe.note_stage(pipe_slot, "encode", t0, t1)
-        self._pipe.note_stage(pipe_slot, "dispatch", t1, t2)
-        self.sched.observe_stage_cost("encode", self.max_batch,
-                                      (t1 - t0) * 1e3)
-        self.sched.observe_stage_cost("dispatch", self.max_batch,
-                                      (t2 - t1) * 1e3)
+                            if self._pf_fn is not None:
+                                pf_hits, pf_aux = self._pf_fn(
+                                    self._tables, arrays)  # async
+                            sp.next("dispatch")
+                            if self._provenance_on:
+                                # Attribution aux lane rides the SAME
+                                # dispatch; the traced n masks
+                                # batch-padding rows on device.
+                                dev, rule_hits = self._lane_fn(
+                                    self._tables, arrays, pf_hits,
+                                    np.int32(n))  # async
+                            else:
+                                dev = self._lane_fn(self._tables, arrays,
+                                                    pf_hits)  # async
+                except Exception as exc:
+                    self._note_device_failure(exc)
+                    pf_hits = pf_aux = rule_hits = dev = None
+            sp.next("dispatch")  # no-op when a branch above got there
         # Staged-bytes accounting (ISSUE 15): the transfer volume
         # behind this dispatch window, on the metrics surface AND into
         # the scheduler's bytes-keyed dispatch EWMA.
@@ -1638,8 +1635,8 @@ class RingSidecar:
             self._staged_bytes_counter[
                 "compact" if batch.packed is not None
                 else "full"].inc(batch.staged_bytes)
-            self.sched.observe_dispatch_bytes(batch.staged_bytes,
-                                              (t2 - t1) * 1e3)
+            self.sched.observe_dispatch_bytes(
+                batch.staged_bytes, rec.span_ms("prefilter", "dispatch"))
         # Scheduler accounting at launch: occupancy + queue depth, the
         # sidecar's `sched` stage (oldest enqueue -> launch hold on the
         # ring clock), and the fail-open mask for rows whose deadline
@@ -1660,14 +1657,13 @@ class RingSidecar:
                 parts, now_ms,
                 est_ms=self.sched.cost.estimate_stage(
                     "compute", self.max_batch))
-        # `meta` rides the in-flight tuple into _complete (ISSUE 17):
-        # the dispatch-side time points feed the cross-plane timeline's
-        # stage spans, and the staging mode lands in every flight row.
-        meta = {"t0": t0, "t1": t1, "tpf": tpf, "t2": t2,
-                "staging_mode": ("compact" if batch.packed is not None
-                                 else "full")}
+        # `rec` rides the in-flight tuple into _complete: its recorded
+        # points feed the compute window and the sampled timeline, and
+        # the staging mode lands in every flight row.
+        rec.tags["staging_mode"] = ("compact" if batch.packed is not None
+                                    else "full")
         return (parts, slots, raw, dev, rule_hits, pf_aux, n, skip_masks,
-                time.monotonic(), slot_buf, pipe_slot, meta)
+                slot_buf, rec)
 
     def _failopen_late_rows(self, parts, now_ms: int,
                             est_ms: Optional[float] = None) -> list:
@@ -1768,43 +1764,38 @@ class RingSidecar:
         buffer set is checked out again, nbuf-1 windows later)."""
         from .engine.batch import RequestBatch, bucket_arrays, pad_batch
 
-        pipe_slot = self._pipe.enter(self.pipeline_mode)
-        self.chaos.stage("encode")
-        t0 = time.monotonic()
-        batch = None
-        if slot_buf is not None:
-            slots = slot_buf[:n]
-            if self.ladder.try_rung("pipeline"):
-                try:
-                    batch = self._staging.encode_slots(
-                        slots, pad_to=self.max_batch)
-                    self.ladder.note_success("pipeline")
-                except Exception as exc:
-                    self.ladder.note_failure("pipeline", exc)
-                    batch = None
-        else:
-            slots = parts[0][1] if len(parts) == 1 else np.concatenate(
-                [s for _, s in parts])
-        if batch is None:
-            batch = pad_batch(RequestBatch(
-                size=n, arrays=bucket_arrays(slots_to_arrays(slots))),
-                self.max_batch)
-        j = len(self._mega_staged)
-        self._mega_queue.fill_slice(self._mega_buf_id, j, batch.arrays,
-                                    n, self.ruleset_epoch)
-        # Compact staging (ISSUE 15): the capped views ride the
-        # existing fill_slice width logic; carry the encoder's depth-
-        # overflow flags so `_complete` re-serves those rows from the
-        # full slot view, same as the per-batch path.
-        raw = RequestBatch(size=n, arrays=self._mega_queue.slice_view(
-            self._mega_buf_id, j, n),
-            overflow=(batch.overflow[:n]
-                      if batch.overflow is not None else None))
-        t1 = time.monotonic()
-        self._stage["encode"].observe((t1 - t0) * 1e3)
-        self._pipe.note_stage(pipe_slot, "encode", t0, t1)
-        self.sched.observe_stage_cost("encode", self.max_batch,
-                                      (t1 - t0) * 1e3)
+        spans = self._pipe.begin(self.pipeline_mode, n)
+        with self._pipe.stage("encode", spans):
+            self.chaos.stage("encode")
+            batch = None
+            if slot_buf is not None:
+                slots = slot_buf[:n]
+                if self.ladder.try_rung("pipeline"):
+                    try:
+                        batch = self._staging.encode_slots(
+                            slots, pad_to=self.max_batch)
+                        self.ladder.note_success("pipeline")
+                    except Exception as exc:
+                        self.ladder.note_failure("pipeline", exc)
+                        batch = None
+            else:
+                slots = parts[0][1] if len(parts) == 1 else np.concatenate(
+                    [s for _, s in parts])
+            if batch is None:
+                batch = pad_batch(RequestBatch(
+                    size=n, arrays=bucket_arrays(slots_to_arrays(slots))),
+                    self.max_batch)
+            j = len(self._mega_staged)
+            self._mega_queue.fill_slice(self._mega_buf_id, j, batch.arrays,
+                                        n, self.ruleset_epoch)
+            # Compact staging (ISSUE 15): the capped views ride the
+            # existing fill_slice width logic; carry the encoder's depth-
+            # overflow flags so `_complete` re-serves those rows from the
+            # full slot view, same as the per-batch path.
+            raw = RequestBatch(size=n, arrays=self._mega_queue.slice_view(
+                self._mega_buf_id, j, n),
+                overflow=(batch.overflow[:n]
+                          if batch.overflow is not None else None))
         # Staging IS this batch's admission: scheduler launch
         # accounting and the fail-open sweep happen here, charging late
         # rows the REMAINING cost — the whole window's estimate, since
@@ -1826,7 +1817,7 @@ class RingSidecar:
                 est_ms=self.sched.cost.estimate_megastep(
                     self._mega_target, self.max_batch))
         rec.slot_buf = slot_buf
-        rec.pipe_slot = pipe_slot
+        rec.spans = spans
         rec.epoch = self.ruleset_epoch
         rec.oldest_enq_ms = oldest_enq_ms
         self._mega_staged.append(rec)
@@ -1853,14 +1844,17 @@ class RingSidecar:
         k_ship = max(k, min(k_ship, self._mega_k))
         self.chaos.stage("dispatch")
         self._dfa_rung_tick()
-        t0 = time.monotonic()
         dev_out = None
+        # The window's dispatch and its one device wait are recorded on
+        # its first slice, whose compute cost is split over the k.
+        staged[0].spans.k = k
         try:
             self.chaos.maybe_xla_error(self.batches)
             # Busy window: the first call per (K, widths) signature
             # blocks in XLA for seconds; the watchdog heartbeats
             # through it.
-            with self._hb_busy():
+            with self._hb_busy(), \
+                    self._pipe.stage("dispatch", staged[0].spans):
                 stacked, nv, ep = self._mega_queue.device_stack(
                     self._mega_buf_id, k, pad_to=k_ship)
                 from .obs.perf import set_dispatch_context
@@ -1874,11 +1868,6 @@ class RingSidecar:
         except Exception as exc:
             self.ladder.note_failure("megastep", exc)
             dev_out = None
-        t1 = time.monotonic()
-        self._stage["device_dispatch"].observe((t1 - t0) * 1e3)
-        self._pipe.note_stage(staged[0].pipe_slot, "dispatch", t0, t1)
-        self.sched.observe_stage_cost("dispatch", self.max_batch,
-                                      (t1 - t0) * 1e3)
         self._pipe.note_megastep(k, self._mega_mode)
         self.mega_windows += 1
         win = _MegaWindow()
@@ -1886,7 +1875,6 @@ class RingSidecar:
         win.k = k
         win.k_ship = k_ship
         win.dev_out = dev_out
-        win.t_launch = t1
         # Window id (ISSUE 17 satellite): stamps every flight row this
         # window serves, so stranded-slice reconciliation after a
         # mid-window SIGKILL is traceable per window.
@@ -1900,7 +1888,8 @@ class RingSidecar:
         if isinstance(entry, _MegaWindow):
             self._complete_megastep(entry)
         else:
-            self._complete(*entry)
+            with self._pipe.stage("host_rules", entry[-1]) as sp:
+                self._complete(sp, *entry)
 
     def _complete_megastep(self, win: _MegaWindow) -> None:
         """Resolve one in-flight megastep window: host-rule lanes for
@@ -1913,58 +1902,50 @@ class RingSidecar:
         window bit-identically from the interpreter."""
         from .engine.verdict import host_rule_lanes
 
-        hosts = [host_rule_lanes(self.plan, s.raw, self.lists)
-                 for s in win.slices]
+        rec = win.slices[0].spans
         lanes = hits = aux = ep_out = None
-        t0 = time.time()
-        if win.dev_out is not None:
-            try:
-                with self._hb_busy():  # one sync per K slices
-                    lanes = np.asarray(win.dev_out[0])
-                    hits = np.asarray(win.dev_out[1])
-                    aux = np.asarray(win.dev_out[2])
-                    ep_out = np.asarray(win.dev_out[3])
-                self._note_device_success()
-                self.ladder.note_success("megastep")
-            except Exception as exc:
-                self.ladder.note_failure("megastep", exc)
-                lanes = None
-        wait_s = time.time() - t0
-        self.device_wait_s += wait_s
-        self._stage["device_compute"].observe(wait_s * 1e3)
-        t_sync = time.monotonic()
-        self._pipe.note_stage(win.slices[0].pipe_slot, "compute",
-                              win.t_launch, t_sync)
-        window_ms = (t_sync - win.t_launch) * 1e3
-        # Cost feed: the window wall teaches the megastep EWMA (K
-        # sizing) and, split per slice, the compute-stage EWMA
-        # (admission slack) — never K near-zero syncs.
-        self.sched.observe_stage_cost("compute", self.max_batch,
-                                      window_ms / max(1, win.k))
-        # EWMA keyed by the SHIPPED K (the compiled shape that set the
-        # window's cost), not the filled count.
+        with self._pipe.stage("host_rules", rec) as sp:
+            hosts = [host_rule_lanes(self.plan, s.raw, self.lists)
+                     for s in win.slices]
+            sp.next("device_wait")
+            if win.dev_out is not None:
+                try:
+                    with self._hb_busy():  # one sync per K slices
+                        lanes = np.asarray(win.dev_out[0])
+                        hits = np.asarray(win.dev_out[1])
+                        aux = np.asarray(win.dev_out[2])
+                        ep_out = np.asarray(win.dev_out[3])
+                    self._note_device_success()
+                    self.ladder.note_success("megastep")
+                except Exception as exc:
+                    self.ladder.note_failure("megastep", exc)
+                    lanes = None
+        # The wait's exit fed the window wall (launch -> results), split
+        # per slice, to the compute-stage EWMA (admission slack) — never
+        # K near-zero syncs; the megastep EWMA (K sizing) is keyed by
+        # the SHIPPED K, the compiled shape that set the window's cost.
+        self.device_wait_s += rec.span_ms("device_wait", "device_wait") / 1e3
         self.sched.observe_megastep_cost(win.k_ship, self.max_batch,
-                                         window_ms)
+                                         rec.compute_ms)
         for j, s in enumerate(win.slices):
             if ep_out is not None and int(ep_out[j]) != s.epoch:
                 # The device program echoes each slice's staged epoch
                 # untouched; a mismatch would mean a slice crossed a
                 # swap boundary (tests assert this stays 0).
                 self.mega_echo_mismatch += 1
-            self._complete(
-                s.parts, s.slots, s.raw, None,
-                (hits[j] if lanes is not None and self._provenance_on
-                 else None),
-                (aux[j] if lanes is not None and self._pf_fn is not None
-                 else None),
-                s.n, skip_masks=s.skip_masks, t_disp=None,
-                slot_buf=s.slot_buf, pipe_slot=s.pipe_slot,
-                meta={"megastep_window": win.window_id,
-                      "megastep_k": win.k_ship,
-                      "staging_mode": "full"},
-                host=hosts[j],
-                dev_lanes=(lanes[j][:, :s.n] if lanes is not None
-                           else None))
+            s.spans.tags.update(megastep_window=win.window_id,
+                                megastep_k=win.k_ship, staging_mode="full")
+            with self._pipe.stage("resolve", s.spans) as sp:
+                self._complete(
+                    sp, s.parts, s.slots, s.raw, None,
+                    (hits[j] if lanes is not None and self._provenance_on
+                     else None),
+                    (aux[j] if lanes is not None
+                     and self._pf_fn is not None else None),
+                    s.n, s.skip_masks, s.slot_buf, s.spans,
+                    host=hosts[j], pre=True,
+                    dev_lanes=(lanes[j][:, :s.n] if lanes is not None
+                               else None))
 
     def _enrich_slots(self, slots: np.ndarray) -> None:
         """Fill asn/country in place for rows the producer enqueued with
@@ -1990,10 +1971,11 @@ class RingSidecar:
             if len(cc) == 2:
                 slots["country"][i] = cc
 
-    def _complete(self, parts, slots, raw_batch, dev, rule_hits, pf_aux,
-                  n: int, skip_masks=None, t_disp=None, slot_buf=None,
-                  pipe_slot=None, meta=None, host=None,
-                  dev_lanes=None) -> None:
+    def _complete(self, sp, parts, slots, raw_batch, dev, rule_hits,
+                  pf_aux, n: int, skip_masks, slot_buf, rec, host=None,
+                  pre=False, dev_lanes=None) -> None:
+        """Resolve one batch inside the caller's stage block `sp`
+        (opened on `host_rules`, or on `resolve` for a megastep slice)."""
         from .engine.verdict import host_rule_lanes, merge_lanes
 
         # Megastep slices (ISSUE 12) arrive with host AND device lanes
@@ -2001,50 +1983,35 @@ class RingSidecar:
         # `pre` skips the per-batch sync and its compute-cost feeds
         # (the window attributed them once; K near-zero observations
         # would drag the compute EWMA toward zero).
-        pre = dev_lanes is not None
-        # Host-interpreted rules run on the UNPADDED batch while the
-        # device lanes are still in flight (jax dispatch is async).
-        if host is None:
+        wait_s = 0.0
+        if not pre:
+            # Host-interpreted rules run on the UNPADDED batch while the
+            # device lanes are still in flight (jax dispatch is async).
             host = host_rule_lanes(self.plan, raw_batch, self.lists)
-        tc0 = time.monotonic()
-        t0 = time.time()
-        if not pre and dev is not None:
-            try:
-                with self._hb_busy():  # device sync can block for ms-s
-                    dev_lanes = np.asarray(dev)[:, :n]  # drop padding
-                self._note_device_success()
-            except Exception as exc:
-                # jax dispatch is async — a device/runtime error only
-                # surfaces at this sync. Demote (ladder device rung)
-                # and serve the batch from the interpreter below
-                # instead of killing the drain thread.
-                self._note_device_failure(exc)
-        wait_s = time.time() - t0
-        tc1 = time.monotonic()
-        self.device_wait_s += wait_s
-        if not pre:
-            self._stage["device_compute"].observe(wait_s * 1e3)
-        # The pipeline's compute window runs dispatch-end -> results
-        # ready, NOT just the residual block at the sync (which shrinks
-        # to ~0 precisely when overlap works): it is the window the
-        # executor hides other batches' host stages behind (the
-        # overlap-ratio denominator, obs/pipeline.py) and the cost a
-        # row's deadline must still cover after launch (the compute
-        # budget slice _dispatch charges in _failopen_late_rows).
-        tcs = t_disp if t_disp is not None else tc0
-        if not pre:
-            if pipe_slot is not None:
-                self._pipe.note_stage(pipe_slot, "compute", tcs, tc1)
-            self.sched.observe_stage_cost("compute", self.max_batch,
-                                          (tc1 - tcs) * 1e3)
-        if t_disp is not None:
-            # EWMA cost-model feedback: launch -> device result wall
-            # for the padded size. With stage observations present the
-            # cost model estimates from per-stage EWMAs (this wall
-            # double-counts host work overlapped with OTHER batches);
-            # the legacy wall still feeds the baseline fallback.
-            self.sched.observe_cost(self.max_batch,
-                                    (time.monotonic() - t_disp) * 1e3)
+            sp.next("device_wait")
+            if dev is not None:
+                try:
+                    with self._hb_busy():  # the sync can block for ms-s
+                        dev_lanes = np.asarray(dev)[:, :n]  # drop padding
+                    self._note_device_success()
+                except Exception as exc:
+                    # jax dispatch is async — a device/runtime error
+                    # only surfaces at this sync. Demote (ladder device
+                    # rung) and serve the batch from the interpreter
+                    # below instead of killing the drain thread.
+                    self._note_device_failure(exc)
+            # The wait's exit also fed the executor's compute window,
+            # which runs dispatch-end -> results ready, NOT just this
+            # residual block (it shrinks to ~0 precisely when overlap
+            # works): the window other batches' host stages hide behind
+            # (the overlap-ratio denominator, obs/pipeline.py) and the
+            # cost a row's deadline must still cover after launch. The
+            # same wall is the legacy cost-model feedback (it
+            # double-counts host work overlapped with OTHER batches, so
+            # it only feeds the baseline fallback).
+            wait_s = sp.next("resolve")
+            self.device_wait_s += wait_s
+            self.sched.observe_cost(self.max_batch, rec.compute_ms)
         if pf_aux is not None:
             # Resolved long before the lane sync above; aux int32 lanes.
             vals = np.asarray(pf_aux)
@@ -2063,7 +2030,6 @@ class RingSidecar:
                 ctr.inc(dfa_banks)
             if dfa_rechecks:
                 self._dfa_recheck_counter.inc(dfa_rechecks)
-        t_resolve = time.monotonic()
         self.chaos.stage("resolve")
         self.batches += 1
         route = None
@@ -2215,8 +2181,7 @@ class RingSidecar:
                 done += ring.post_verdicts(tickets[done:], pacts[done:])
                 if done < k:
                     if self._stop:  # a dead consumer must not wedge stop()
-                        if pipe_slot is not None:
-                            self._pipe.exit()
+                        self._pipe.finish()
                         return
                     time.sleep(self.idle_sleep_s)
             # Telemetry: enqueue -> verdict-post wall time for this
@@ -2237,55 +2202,38 @@ class RingSidecar:
         self.sched.note_misses(int(
             ((post_ms - slots["enq_ms"].astype(np.int64))
              > self.sched.config.deadline_ms).sum()))
-        t_res_end = time.monotonic()
-        self._stage["resolve"].observe((t_res_end - t_resolve) * 1e3)
-        if pipe_slot is not None:
-            self._pipe.note_stage(pipe_slot, "resolve", t_resolve,
-                                  t_res_end)
-        t_prov = time.monotonic()
+        sp.next("provenance")
         if self._attribution is not None and dev_lanes is not None:
             # Interpreter-served batches (device rung demoted) skip
             # attribution/parity: the aux lane never ran, and auditing
             # the oracle against itself proves nothing.
             self._observe_provenance(slots, rule_hits, dev_lanes, host,
                                      raw_batch, unverified,
-                                     verified_block, wait_s, n,
-                                     pipe_slot=pipe_slot, meta=meta)
-        self._stage["provenance"].observe(
-            (time.monotonic() - t_prov) * 1e3)
+                                     verified_block, wait_s, n, rec)
         # Cross-plane timeline (ISSUE 17): per-batch cost while
         # unsampled is the one add+compare inside sample(). The rows'
         # enq_ms stamps are the NATIVE producer's ring clock — same
-        # CLOCK_MONOTONIC timebase as the sidecar stamps, which is what
-        # joins the ring-wait span across planes.
+        # CLOCK_MONOTONIC timebase as the batch's recorded points, which
+        # is what joins the ring-wait span across planes.
         if self._timeline.sample():
-            m = meta or {}
-            tl_args = {"staging_mode": m.get("staging_mode", "full")}
-            if "megastep_window" in m:
-                tl_args["megastep_window"] = m["megastep_window"]
-                tl_args["megastep_k"] = m.get("megastep_k")
             self._timeline.batch_sidecar(
-                t0=m.get("t0", 0.0), t1=m.get("t1", 0.0),
-                tpf=m.get("tpf", 0.0), t2=m.get("t2", 0.0),
-                t_sync=tc1, t_resolve=t_resolve, t_end=t_res_end,
+                points=rec.points,
                 rows=[(f"t-{int(slots['ticket'][i])}",
                        int(slots["enq_ms"][i]))
                       for i in range(
                           min(n, self._timeline.rows_per_batch))],
-                args=tl_args)
+                args=rec.tags)
         self.processed += n
         # The batch is fully resolved: its accumulation buffer returns
         # to the pool and its pipeline slot retires.
         if slot_buf is not None:
             self._slot_pool.append(slot_buf)
-        if pipe_slot is not None:
-            self._pipe.exit()
+        self._pipe.finish()
         self.chaos.on_batch_done(self.batches)
 
     def _observe_provenance(self, slots, rule_hits, dev_lanes, host,
                             raw_batch, unverified, verified_block,
-                            device_wait_s, n: int,
-                            pipe_slot=None, meta=None) -> None:
+                            device_wait_s, n: int, rec) -> None:
         """Sidecar-plane provenance (ISSUE 5): fold the on-device
         attribution aux lane, flight-record the batch, and hand the
         FINAL served lanes (spill rewrites included) to the parity
@@ -2322,22 +2270,14 @@ class RingSidecar:
                     0, now_ms - int(enq_ms[i])),
                 "device_compute_ms": compute_ms,
             }
-            if pipe_slot is not None:
-                # Pipeline slot id (ISSUE 9): lines this record up
-                # against the pingoo_pipeline_* series — which batches
-                # were in flight together when this request was served.
-                stages["pipeline_slot"] = int(pipe_slot)
-            if meta is not None:
-                # Window id + K rung + staging mode (ISSUE 17
-                # satellite): flight rows predate the megastep —
-                # without these, stranded-slice reconciliation after a
-                # mid-window SIGKILL cannot tell which window a row
-                # rode.
-                if "megastep_window" in meta:
-                    stages["megastep_window"] = meta["megastep_window"]
-                    stages["megastep_k"] = meta.get("megastep_k")
-                stages["staging_mode"] = meta.get("staging_mode",
-                                                  "full")
+            # Pipeline slot id (ISSUE 9): lines this record up against
+            # the pingoo_pipeline_* series and the `batch` stat of the
+            # trace's sidecar/* spans. Window id + K rung + staging mode
+            # (ISSUE 17 satellite): without these, stranded-slice
+            # reconciliation after a mid-window SIGKILL cannot tell
+            # which window a row rode.
+            stages["pipeline_slot"] = rec.seq
+            stages.update(rec.tags)
             recorder.record(
                 trace_id=trace_ids[i],
                 digest=f"{crc & 0xFFFFFFFF:08x}",

@@ -22,6 +22,25 @@ family on its plane label:
     by executor mode (on = staged overlap, off = legacy lockstep), so
     an A/B drive can attribute throughput to the arm that produced it.
 
+The ring sidecar also records its drain loop's stage boundaries here,
+and nowhere else (docs/OBSERVABILITY.md "Spans and scopes"):
+`stage(name, rec)` is a context manager that enters a
+`jax.profiler.TraceAnnotation("sidecar/<phase>", batch=, rows=)` — so
+the span lands in the profiler's own file, on the device trace's clock —
+and on exit fans ONE pair of `time.monotonic()` stamps out to every
+sink: the `pingoo_verdict_stage_ms` histogram, the occupancy/overlap
+bookkeeping below, the scheduler's stage costs, the batch's recorded
+points (which the sampled Timeline reads) and the loop-phase account:
+
+  * pingoo_sidecar_loop_ms_total{plane,phase}: drain-thread wall time
+    by LOOP_PHASES, a partition (every instant is in exactly one phase;
+    `idle` is one coalesced span from the first empty pass to the next
+    pass that gets rows). Flushed once per finished batch, or once a
+    second while idle.
+  * pingoo_sidecar_stall_total{plane,phase}: spans of a non-idle phase
+    longer than STALL_MS, each logged once with its batch and the ring
+    depth.
+
 Interval bookkeeping is host-side float math on the plane's own
 serial context (event loop / drain thread): no locks, no arrays, no
 device access. Overlap is computed from (monotonic) stage wall
@@ -32,6 +51,7 @@ first, so each pair is counted exactly once.
 
 from __future__ import annotations
 
+import logging
 import time
 from collections import deque
 
@@ -47,6 +67,70 @@ _HOST_STAGES = frozenset(("encode", "dispatch"))
 
 _EWMA_ALPHA = 0.2
 _RECENT_INTERVALS = 32
+
+# The drain loop's phases. `poll` is the loop's own turn while it has
+# work (dequeue passes that got rows, the launch decision, the
+# heartbeat); the `sched` stage label is an AGE, not a phase.
+LOOP_PHASES = ("poll", "encode", "prefilter", "dispatch", "host_rules",
+               "device_wait", "resolve", "provenance", "bodies", "swap",
+               "idle")
+# The pingoo_verdict_stage_ms{stage} series each phase's spans feed.
+PHASE_STAGE = {"encode": "encode", "prefilter": "prefilter",
+               "dispatch": "device_dispatch",
+               "device_wait": "device_compute", "resolve": "resolve",
+               "provenance": "provenance"}
+# The executor stage (PIPELINE_EXEC_STAGES) a phase's end reports.
+_PHASE_EXEC = {"encode": "encode", "dispatch": "dispatch",
+               "device_wait": "compute", "resolve": "resolve"}
+STALL_MS = 250.0
+_IDLE_FLUSH_S = 1.0
+
+_log = logging.getLogger(__name__)
+
+
+class BatchSpans:
+    """One batch's identity in the drain loop, and the phase boundaries
+    recorded for it: rides the in-flight tuple from launch to resolve.
+    `seq` is the loop's batch counter (the pipeline slot id, and the
+    `batch` stat of every span the batch causes); `points` maps a phase
+    to its (t_start, t_end) in time.monotonic() seconds; `k` is how many
+    batches share the device wait (a megastep window's slices)."""
+
+    __slots__ = ("seq", "rows", "k", "points", "tags", "compute_ms")
+
+    def __init__(self, seq: int, rows: int):
+        self.seq = seq
+        self.rows = rows
+        self.k = 1
+        self.points: dict[str, tuple] = {}
+        self.tags: dict = {}
+        self.compute_ms = 0.0
+
+    def span_ms(self, first: str, last: str) -> float:
+        """Milliseconds from `first`'s start to `last`'s end."""
+        return (self.points[last][1] - self.points[first][0]) * 1e3
+
+
+class _Stage:
+    """PipelineStats.stage()'s context manager (one per with-block)."""
+
+    __slots__ = ("_ps", "_name", "_rec")
+
+    def __init__(self, ps, name, rec):
+        self._ps, self._name, self._rec = ps, name, rec
+
+    def __enter__(self):
+        self._ps._push(self._name, self._rec)
+        return self
+
+    def next(self, name: str) -> float:
+        """End the open phase and begin `name` at the same stamp;
+        returns the seconds the ended phase lasted."""
+        return self._ps._switch(name, self._rec)
+
+    def __exit__(self, *exc) -> bool:
+        self._ps._pop()
+        return False
 
 
 class PipelineStats:
@@ -108,6 +192,7 @@ class PipelineStats:
         self._recent: deque = deque(maxlen=_RECENT_INTERVALS)
         self._overlap_ewma: float | None = None
         self.overlap_events = 0
+        self._loop_ctr: dict[str, object] = {}  # filled by attach_loop
 
     # -- batch lifecycle (hot) ----------------------------------------------
 
@@ -210,6 +295,188 @@ class PipelineStats:
             self._overlap_ewma = prev + _EWMA_ALPHA * (ratio - prev)
         self.overlap_ratio.set(round(self._overlap_ewma, 6))
 
+
+    # -- the drain loop's span source (sidecar) -------------------------------
+
+    def attach_loop(self, stage_hist: dict, observe_cost, cost_size: int,
+                    depth_fn=None) -> None:
+        """Wire the sinks a stage boundary fans out to: `stage_hist`
+        {stage label: pingoo_verdict_stage_ms histogram}, `observe_cost`
+        (the scheduler's observe_stage_cost) at `cost_size` rows, and
+        `depth_fn` (requests still queued, for the stall line)."""
+        from jax.profiler import TraceAnnotation
+
+        from . import schema
+
+        self._annotate = TraceAnnotation
+        self._span_names = {p: f"{self.plane}/{p}" for p in LOOP_PHASES}
+        self._phase_hist = {p: stage_hist[s]
+                            for p, s in PHASE_STAGE.items()}
+        self._observe_cost = observe_cost
+        self._cost_size = cost_size
+        self._depth_fn = depth_fn
+        self._acc = dict.fromkeys(LOOP_PHASES, 0.0)
+        self._loop_ctr = {
+            p: self._registry.counter(
+                "pingoo_sidecar_loop_ms_total",
+                schema.PIPELINE_METRICS["pingoo_sidecar_loop_ms_total"],
+                labels={"plane": self.plane, "phase": p})
+            for p in LOOP_PHASES}
+        self._stall_ctr: dict[str, object] = {}
+        self._stack: list = []      # enclosing with-blocks: (name, rec)
+        self._base = "poll"         # what the loop falls back to
+        self._cur = None            # the open span: name, rec, t0, ann
+        self._cur_rec = None
+        self._cur_t0 = 0.0
+        self._cur_ann = None
+        self._t_flush = 0.0
+
+    def loop_start(self) -> None:
+        """The drain loop begins: everything until loop_stop() is
+        accounted to exactly one phase."""
+        now = time.monotonic()
+        self._stack.clear()
+        self._base = "poll"
+        self._t_flush = now
+        self._open("poll", None, now)
+
+    def loop_stop(self) -> None:
+        if self._cur is not None:
+            now = time.monotonic()
+            self._close(now)
+            self._cur = None
+            self._flush(now)
+
+    def begin(self, mode: str, rows: int) -> BatchSpans:
+        """enter() for the drain loop: the batch's span record."""
+        return BatchSpans(self.enter(mode), rows)
+
+    def finish(self) -> None:
+        """exit() for the drain loop; the phase account reaches the
+        registry here, once per batch."""
+        self.exit()
+        self._flush(time.monotonic())
+
+    def stage(self, name: str, rec: BatchSpans = None) -> _Stage:
+        """`with pipe.stage("encode", rec) as sp:` — the phase lasts to
+        the block's end or to `sp.next(phase)`; a block nested in it
+        suspends it (spans never overlap)."""
+        return _Stage(self, name, rec)
+
+    def idle(self) -> None:
+        """An empty pass with nothing in flight (the loop sleeps next):
+        one clock read and a compare while already idle."""
+        now = time.monotonic()
+        if self._cur != "idle":
+            self._close(now)
+            self._base = "idle"
+            self._open("idle", None, now)
+        elif now - self._t_flush >= _IDLE_FLUSH_S:
+            # fold the stretch so far; its span stays open
+            self._acc["idle"] += (now - self._cur_t0) * 1e3
+            self._cur_t0 = now
+            self._flush(now)
+
+    def wake(self) -> None:
+        """A pass got rows: an idle stretch ends here (the dequeue that
+        ended it counts to it)."""
+        if self._cur == "idle":
+            now = time.monotonic()
+            self._close(now)
+            self._base = "poll"
+            self._open("poll", None, now)
+
+    def _open(self, name: str, rec, t: float) -> None:
+        if rec is None:
+            ann = self._annotate(self._span_names[name])
+        else:
+            ann = self._annotate(self._span_names[name], batch=rec.seq,
+                                 rows=rec.rows)
+        ann.__enter__()
+        self._cur, self._cur_rec, self._cur_t0, self._cur_ann = \
+            name, rec, t, ann
+
+    def _close(self, t: float) -> float:
+        """End the open span at `t`: the one place a stage boundary's
+        stamps are recorded."""
+        name, rec, t0 = self._cur, self._cur_rec, self._cur_t0
+        self._cur_ann.__exit__(None, None, None)
+        ms = (t - t0) * 1e3
+        self._acc[name] += ms
+        hist = self._phase_hist.get(name)
+        if hist is not None:
+            hist.observe(ms)
+        if rec is not None:
+            rec.points[name] = (t0, t)
+            self._note_exec(name, rec, t0, t)
+        if ms > STALL_MS and name != "idle":
+            self._stall(name, ms, rec)
+        return t - t0
+
+    def _note_exec(self, name: str, rec: BatchSpans, t0: float,
+                   t: float) -> None:
+        """The executor's view of a phase: occupancy/overlap stage and
+        the scheduler's stage cost. `dispatch` runs from the prefilter's
+        issue to the lane program's; `compute` from the launch to the
+        results, split over the k batches that shared the wait."""
+        stage = _PHASE_EXEC.get(name)
+        if stage is None:
+            return
+        if stage == "dispatch":
+            t0 = rec.points.get("prefilter", (t0,))[0]
+        elif stage == "compute":
+            t0 = rec.points.get("dispatch", (0, t0))[1]
+            rec.compute_ms = (t - t0) * 1e3
+        self.note_stage(rec.seq, stage, t0, t)
+        if stage != "resolve":  # the cost model has no resolve stage
+            self._observe_cost(stage, self._cost_size, (t - t0) * 1e3
+                               / (rec.k if stage == "compute" else 1))
+
+    def _push(self, name: str, rec) -> None:
+        t = time.monotonic()
+        self._close(t)
+        self._base = "poll"
+        self._stack.append((name, rec))
+        self._open(name, rec, t)
+
+    def _switch(self, name: str, rec) -> float:
+        if name == self._cur:
+            return 0.0
+        t = time.monotonic()
+        dur = self._close(t)
+        self._stack[-1] = (name, rec)
+        self._open(name, rec, t)
+        return dur
+
+    def _pop(self) -> None:
+        t = time.monotonic()
+        self._close(t)
+        self._stack.pop()
+        name, rec = self._stack[-1] if self._stack else (self._base, None)
+        self._open(name, rec, t)
+
+    def _flush(self, now: float) -> None:
+        for phase, ms in self._acc.items():
+            if ms:
+                self._loop_ctr[phase].inc(ms)
+                self._acc[phase] = 0.0
+        self._t_flush = now
+
+    def _stall(self, name: str, ms: float, rec) -> None:
+        from . import schema
+
+        ctr = self._stall_ctr.get(name)
+        if ctr is None:
+            ctr = self._stall_ctr[name] = self._registry.counter(
+                "pingoo_sidecar_stall_total",
+                schema.PIPELINE_METRICS["pingoo_sidecar_stall_total"],
+                labels={"plane": self.plane, "phase": name})
+        ctr.inc()
+        _log.warning("drain loop stalled", extra={"fields": {
+            "phase": name, "ms": round(ms, 1),
+            "batch": rec.seq if rec is not None else None,
+            "ring_depth": self._depth_fn() if self._depth_fn else None}})
+
     def snapshot(self) -> dict:
         wall = max(time.monotonic() - self._t_boot, 1e-9)
         return {
@@ -224,6 +491,8 @@ class PipelineStats:
             "stage_occupancy": {
                 stage: round(self._busy[stage] / wall, 4)
                 for stage in PIPELINE_EXEC_STAGES},
+            "loop_ms": {p: round(c.value, 3)
+                        for p, c in self._loop_ctr.items()},
             "megastep": {
                 "k": self.megastep_k.value,
                 "windows": self.megastep_windows,

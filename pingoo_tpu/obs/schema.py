@@ -154,6 +154,16 @@ PIPELINE_METRICS = {
     "pingoo_megastep_amortization":
         "EWMA batch slices amortized per device dispatch (1.0 = "
         "per-batch dispatch, K = fully amortized megastep windows)",
+    # The sidecar drain loop's phase account (obs/pipeline.LOOP_PHASES):
+    # every instant of the drain thread is in exactly one `phase`, so
+    # the phases' deltas add up to the wall time between two scrapes.
+    "pingoo_sidecar_loop_ms_total":
+        "drain-thread wall time (ms) by loop phase (poll|encode|"
+        "prefilter|dispatch|host_rules|device_wait|resolve|provenance|"
+        "bodies|swap|idle); the phases partition the loop's time",
+    "pingoo_sidecar_stall_total":
+        "spans of one non-idle drain-loop phase longer than 250 ms, by "
+        "phase (each also logs its batch and the ring depth)",
 }
 
 # Continuous-batching scheduler + serving-mesh metrics (ISSUE 6,

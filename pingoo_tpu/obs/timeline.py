@@ -192,37 +192,43 @@ class Timeline:
                           max(0.0, min(t0_us, t_end_us) - adm_us),
                           trace_id, base_args)
 
-    def batch_sidecar(self, *, t0: float, t1: float, tpf: float,
-                      t2: float, t_sync: float, t_resolve: float,
-                      t_end: float, rows: Optional[list] = None,
+    def batch_sidecar(self, *, points: dict,
+                      rows: Optional[list] = None,
                       args: Optional[dict] = None) -> None:
-        """One sampled sidecar batch from native_ring._complete's time
-        points (all time.monotonic() seconds): encode [t0,t1],
-        prefilter [t1,tpf], dispatch [tpf,t2], compute [t2,t_sync],
-        resolve [t_resolve,t_end].
+        """One sampled sidecar batch from the phase boundaries the drain
+        loop's span source recorded for it (obs/pipeline.BatchSpans
+        `points`: phase -> (t_start, t_end), time.monotonic() seconds):
+        encode, prefilter, device_dispatch as recorded, device_compute
+        from the dispatch's end to the device wait's, resolve as
+        recorded. A megastep slice without dispatch points of its own
+        gets a batch span over its resolve.
 
         `rows` entries: (trace_id, enq_ms) with enq_ms the NATIVE
         producer's ring-clock stamp — the ring-wait span is emitted
-        under pid "native" ending at t0 (sidecar pickup). Same
-        monotonic timebase, so the subtraction is the cross-plane join.
+        under pid "native" ending at the batch's start (sidecar pickup).
+        Same monotonic timebase, so the subtraction is the cross-plane
+        join.
         """
         base_args = dict(args or {})
         with self._lock:
             self._seq += 1
             seq = self._seq
         tid = "sidecar/batch"
-        if t0 <= 0.0:
-            # Megastep slices carry no per-slice dispatch points — the
-            # batch span covers the slice's resolve window instead.
-            t0 = t_resolve if 0.0 < t_resolve < t_end else t_end
+        none = (0.0, 0.0)
+        dispatch, wait = points.get("dispatch", none), \
+            points.get("device_wait", none)
+        resolve = points.get("resolve", none)
+        t_end = resolve[1] or wait[1]
+        t0 = points.get("encode", none)[0] or resolve[0] or t_end
         t0_us = t0 * 1e6
         t_end_us = t_end * 1e6
         self.add_span("sidecar", tid, "batch", t0_us,
                       max(0.0, t_end_us - t0_us), f"b-{seq}", base_args)
-        bounds = (("encode", t0, t1), ("prefilter", t1, tpf),
-                  ("device_dispatch", tpf, t2),
-                  ("device_compute", t2, t_sync),
-                  ("resolve", t_resolve, t_end))
+        bounds = (("encode", *points.get("encode", none)),
+                  ("prefilter", *points.get("prefilter", none)),
+                  ("device_dispatch", *dispatch),
+                  ("device_compute", dispatch[1], wait[1]),
+                  ("resolve", *resolve))
         for name, a, b in bounds:
             if b > a > 0.0:
                 self.add_span("sidecar", tid, name, a * 1e6,

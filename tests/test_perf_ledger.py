@@ -248,10 +248,19 @@ class TestTimeline:
 
     def test_batch_sidecar_cross_plane_join(self):
         tl = self._timeline()
-        tl.batch_sidecar(t0=20.0, t1=20.001, tpf=20.0015, t2=20.002,
-                         t_sync=20.004, t_resolve=20.004, t_end=20.005,
-                         rows=[("t-7", 19990.0)])  # enq_ms = 19.99 s
+        tl.batch_sidecar(
+            points={"encode": (20.0, 20.001),
+                    "prefilter": (20.001, 20.0015),
+                    "dispatch": (20.0015, 20.002),
+                    "device_wait": (20.003, 20.004),
+                    "resolve": (20.004, 20.005)},
+            rows=[("t-7", 19990.0)])  # enq_ms = 19.99 s
         spans = list(tl.spans)
+        # device_compute runs from the dispatch's end, not the wait's
+        # start: the window other batches hide behind.
+        compute = [s for s in spans if s[2] == "device_compute"][0]
+        assert compute[3] == pytest.approx(20.002e6)
+        assert compute[4] == pytest.approx(2_000.0)
         join = [s for s in spans if s[0] == "native"
                 and s[2] == "ring_wait"]
         assert len(join) == 1
@@ -260,10 +269,9 @@ class TestTimeline:
 
     def test_batch_sidecar_megastep_slice_fallback(self):
         tl = self._timeline()
-        # No per-slice dispatch points (t0=0): the batch span must
-        # cover the resolve window, not start at monotonic zero.
-        tl.batch_sidecar(t0=0.0, t1=0.0, tpf=0.0, t2=0.0, t_sync=0.0,
-                         t_resolve=30.0, t_end=30.002)
+        # No per-slice dispatch points: the batch span must cover the
+        # resolve window, not start at monotonic zero.
+        tl.batch_sidecar(points={"resolve": (30.0, 30.002)})
         batch = [s for s in tl.spans if s[2] == "batch"][0]
         assert batch[3] == pytest.approx(30.0e6)
 

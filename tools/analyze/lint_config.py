@@ -109,6 +109,25 @@ HOT_FUNCTIONS = frozenset({
     "pingoo_tpu/obs/timeline.py::Timeline.add_span",
     "pingoo_tpu/obs/timeline.py::Timeline.batch_python",
     "pingoo_tpu/obs/timeline.py::Timeline.batch_sidecar",
+    # The drain loop's span source (ISSUE 28): entered and left about
+    # ten times a batch, and `idle` once per empty pass — one
+    # TraceAnnotation, one monotonic stamp and float math into the
+    # sinks, never an array, never a device sync.
+    "pingoo_tpu/obs/pipeline.py::_Stage.__enter__",
+    "pingoo_tpu/obs/pipeline.py::_Stage.next",
+    "pingoo_tpu/obs/pipeline.py::_Stage.__exit__",
+    "pingoo_tpu/obs/pipeline.py::PipelineStats.stage",
+    "pingoo_tpu/obs/pipeline.py::PipelineStats.begin",
+    "pingoo_tpu/obs/pipeline.py::PipelineStats.finish",
+    "pingoo_tpu/obs/pipeline.py::PipelineStats.idle",
+    "pingoo_tpu/obs/pipeline.py::PipelineStats.wake",
+    "pingoo_tpu/obs/pipeline.py::PipelineStats._open",
+    "pingoo_tpu/obs/pipeline.py::PipelineStats._close",
+    "pingoo_tpu/obs/pipeline.py::PipelineStats._note_exec",
+    "pingoo_tpu/obs/pipeline.py::PipelineStats._push",
+    "pingoo_tpu/obs/pipeline.py::PipelineStats._switch",
+    "pingoo_tpu/obs/pipeline.py::PipelineStats._pop",
+    "pingoo_tpu/obs/pipeline.py::PipelineStats._flush",
 })
 
 # Functions traced by jax.jit that the AST cannot see are jitted (they
