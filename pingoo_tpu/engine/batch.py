@@ -25,10 +25,11 @@ from typing import Iterable, Mapping, NamedTuple, Optional
 
 import numpy as np
 
-from ..compiler.lowering import DEFAULT_FIELD_SPECS
+from ..compiler.lowering import DEFAULT_FIELD_SPECS, NfaPred
 from ..compiler.plan import quantize_stage_cap
 from ..expr import Context, Ip
 from ..ops.cidr import ip_to_words
+from ..ops.live_columns import walked_columns
 
 STRING_FIELDS = ("host", "url", "path", "method", "user_agent", "country")
 
@@ -204,6 +205,47 @@ def bucket_len(longest: int, cap: int, min_len: int = 16) -> int:
     while L < longest:
         L *= 2
     return min(L, cap)
+
+
+def scan_columns(arrays: Mapping[str, np.ndarray],
+                 fields: Iterable[str]) -> dict[str, tuple[int, int]]:
+    """{field: (staged, walked)} for an encoded batch: the columns each
+    scanned field is staged at, and those the device's byte loops walk
+    (ops/live_columns: they stop at the batch's longest row). Padding
+    rows carry length 0, so the whole `_len` array is the device's."""
+    out = {}
+    for field in fields:
+        width = arrays[f"{field}_bytes"].shape[1]
+        out[field] = (width,
+                      walked_columns(arrays[f"{field}_len"], width))
+    return out
+
+
+class ScanColumnCounters:
+    """`pingoo_scan_columns_total{plane, field, kind}` (obs/schema.py):
+    per batch and field some contains/regex rule of the plan scans,
+    kind="staged" counts the field's staged width and kind="walked"
+    the columns the dfa/* and pf/* byte loops walk for that batch."""
+
+    def __init__(self, plane: str, plan):
+        from ..obs import REGISTRY
+        from ..obs.schema import STAGING_METRICS
+
+        self.fields = tuple(sorted(
+            {leaf.field for leaf in plan.leaves
+             if isinstance(leaf, NfaPred)}))
+        self._counters = {
+            (field, kind): REGISTRY.counter(
+                "pingoo_scan_columns_total",
+                STAGING_METRICS["pingoo_scan_columns_total"],
+                labels={"plane": plane, "field": field, "kind": kind})
+            for field in self.fields for kind in ("staged", "walked")}
+
+    def note(self, arrays: Mapping[str, np.ndarray]) -> None:
+        for field, (staged, walked) in scan_columns(
+                arrays, self.fields).items():
+            self._counters[field, "staged"].inc(staged)
+            self._counters[field, "walked"].inc(walked)
 
 
 # -- Compact staging (ISSUE 15, docs/EXECUTOR.md "Compact staging") ----------

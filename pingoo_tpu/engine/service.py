@@ -45,6 +45,7 @@ from .batch import (
     DeviceInputQueue,
     RequestBatch,
     RequestTuple,
+    ScanColumnCounters,
     StagingEncoder,
     batch_to_contexts,
     bucket_arrays,
@@ -463,6 +464,7 @@ class VerdictService:
             # slices; built only under PINGOO_STAGING=compact, so the
             # default path compiles nothing new.
             state["stage_caps"] = resolve_stage_caps(plan)
+            state["scan_columns"] = ScanColumnCounters("python", plan)
             state["packed_verdict_fn"] = None
             state["packed_pf_fn"] = None
             if state["stage_caps"] is not None:
@@ -526,6 +528,7 @@ class VerdictService:
         self._stage_caps = state.get("stage_caps")
         self._packed_verdict_fn = state.get("packed_verdict_fn")
         self._packed_pf_fn = state.get("packed_pf_fn")
+        self._scan_columns = state["scan_columns"]
         self._set_cap_gauges()
 
     def _make_staging(self, plan: RulesetPlan) -> StagingEncoder:
@@ -1405,6 +1408,7 @@ class VerdictService:
                     fast = pad_batch(
                         RequestBatch(size=batch.size, arrays=arrays),
                         self._pow2_size(n))
+                self._scan_columns.note(fast.arrays)
                 # Megastep window (ISSUE 12): PINGOO_MEGASTEP=force —
                 # or `auto` with a backlog queued behind this batch —
                 # scans the batch as K row slices through ONE jitted

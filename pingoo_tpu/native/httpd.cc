@@ -2070,6 +2070,48 @@ class Server {
     uint64_t wait_sum_ms = 0;     // for the histogram _sum series
   };
 
+  // Release witness (ISSUE 30, docs/OBSERVABILITY.md "Release
+  // witness"): WHAT let requests through uninspected. Every
+  // stats_.fail_open++ reports its cause here, so the tickets add up to
+  // `fail_open`; an event logs what the plane saw at that moment.
+  // Nothing below runs on a pass that releases nothing, except the two
+  // compares in check_sidecar_liveness on values it already holds.
+  enum ReleaseCause {
+    kRelDeadline,  // sweep_verdict_deadlines: no verdict in kVerdictTimeoutMs
+    kRelDegraded,  // degraded-mode entry: every awaiting ticket at once
+    kRelBypass,    // arrived while degraded: never enqueued
+    kRelRingFull,  // request ring full: never enqueued
+    kRelCauses
+  };
+  static constexpr const char* kReleaseCauseNames[kRelCauses] = {
+      "deadline", "degraded", "bypass", "ring_full"};
+  struct Release {
+    uint64_t events[kRelCauses] = {0, 0, 0, 0};
+    uint64_t tickets[kRelCauses] = {0, 0, 0, 0};
+    uint64_t last_ms[kRelCauses] = {0, 0, 0, 0};
+    // the newest event, as its log line has it
+    uint64_t last_cause = 0, last_tickets = 0, last_oldest_age_ms = 0,
+             last_ring_depth = 0, last_awaiting = 0,
+             last_heartbeat_age_ms = 0, last_at_ms = 0;
+    // which tickets (0..0: never enqueued), against how far the sidecar
+    // had posted (posted_floor) and dequeued (req_tail) at that moment:
+    // below the floor, the verdict was posted and this plane missed it;
+    // between the two, the sidecar held the row; at or past the tail,
+    // it never took it
+    uint64_t last_first_ticket = 0, last_last_ticket = 0,
+             last_posted_floor = 0, last_req_tail = 0;
+    uint64_t oldest_age_max_ms = 0;
+    // near misses, from the liveness check's own reads: the oldest
+    // heartbeat this plane ever saw, how often its age crossed half the
+    // liveness window, and this event loop's longest pass-to-pass gap
+    uint64_t heartbeat_age_max_ms = 0;
+    uint64_t heartbeat_late = 0;
+    bool heartbeat_is_late = false;
+    uint64_t loop_gap_max_ms = 0;
+    uint64_t last_pass_ms = 0;
+    uint64_t log_window_ms = 0, log_lines = 0;
+  };
+
   static uint64_t now_ms() {
     timespec ts;
     clock_gettime(CLOCK_MONOTONIC, &ts);
@@ -2088,6 +2130,42 @@ class Server {
     }
     stats_.wait_hist[b]++;
     stats_.wait_sum_ms += ms;
+  }
+
+  // The release witness as JSON: the stats endpoint's `release` block,
+  // and the last line this plane writes once it has drained (the end of
+  // the log is what a harness keeps, and the SIGTERM flight-recorder
+  // dump pushes the event lines out of it).
+  std::string release_json() const {
+    std::string out = "{";
+    auto kv_u64 = [&out](const std::string& key, uint64_t v) {
+      if (out.size() > 1) out += ", ";
+      out += "\"" + key + "\": " + std::to_string(v);
+    };
+    for (int i = 0; i < kRelCauses; ++i) {
+      std::string cause = kReleaseCauseNames[i];
+      kv_u64("events_" + cause, release_.events[i]);
+      kv_u64("tickets_" + cause, release_.tickets[i]);
+    }
+    out += ", \"last_cause\": \"";
+    out += release_.last_at_ms ? kReleaseCauseNames[release_.last_cause] : "";
+    out += "\"";
+    kv_u64("last_tickets", release_.last_tickets);
+    kv_u64("last_oldest_age_ms", release_.last_oldest_age_ms);
+    kv_u64("last_ring_depth", release_.last_ring_depth);
+    kv_u64("last_awaiting", release_.last_awaiting);
+    kv_u64("last_heartbeat_age_ms", release_.last_heartbeat_age_ms);
+    kv_u64("last_at_ms", release_.last_at_ms);
+    kv_u64("last_first_ticket", release_.last_first_ticket);
+    kv_u64("last_last_ticket", release_.last_last_ticket);
+    kv_u64("last_posted_floor", release_.last_posted_floor);
+    kv_u64("last_req_tail", release_.last_req_tail);
+    kv_u64("oldest_age_max_ms", release_.oldest_age_max_ms);
+    kv_u64("heartbeat_age_max_ms", release_.heartbeat_age_max_ms);
+    kv_u64("heartbeat_late", release_.heartbeat_late);
+    kv_u64("loop_gap_max_ms", release_.loop_gap_max_ms);
+    out += "}";
+    return out;
   }
 
   // JSON body, built with std::string: the old fixed 1024-byte snprintf
@@ -2149,6 +2227,7 @@ class Server {
     kv_u64("h2_skipped", stats_.body_h2_skipped);
     kv_u64("awaiting", body_awaiting_.size());
     out += "}";
+    out += ", \"release\": " + release_json();
     out += ", \"ring\": {";
     kv_u64("enqueued", tel[0], true);
     kv_u64("enqueue_full", tel[1]);
@@ -2203,6 +2282,24 @@ class Server {
     metric("gauge", "pingoo_sidecar_epoch", sidecar_epoch_);
     metric("counter", "pingoo_degraded_entered_total",
            stats_.degraded_entered);
+    // Release witness (ISSUE 30): the fail-opens by cause; the tickets
+    // add up to pingoo_fail_open_total.
+    out += "# TYPE pingoo_release_events_total counter\n";
+    for (int i = 0; i < kRelCauses; ++i)
+      out += std::string("pingoo_release_events_total{plane=\"native\","
+                         "cause=\"") + kReleaseCauseNames[i] + "\"} " +
+             std::to_string(release_.events[i]) + "\n";
+    out += "# TYPE pingoo_released_total counter\n";
+    for (int i = 0; i < kRelCauses; ++i)
+      out += std::string("pingoo_released_total{plane=\"native\","
+                         "cause=\"") + kReleaseCauseNames[i] + "\"} " +
+             std::to_string(release_.tickets[i]) + "\n";
+    metric("gauge", "pingoo_sidecar_heartbeat_age_max_ms",
+           release_.heartbeat_age_max_ms);
+    metric("counter", "pingoo_sidecar_heartbeat_late_total",
+           release_.heartbeat_late);
+    metric("gauge", "pingoo_native_loop_gap_max_ms",
+           release_.loop_gap_max_ms);
     // Streaming body inspection (ISSUE 13, obs/schema.py BODY_METRICS;
     // the carry-depth histogram is scanner-side and lives on the
     // sidecar's exposition). Degrades carry the caller-side reasons.
@@ -3425,6 +3522,71 @@ class Server {
   //      instead of one verdict timeout per request. A fresh heartbeat
   //      (the restarted sidecar's attach bumps the epoch) lifts it.
 
+  // The release witness's one entry: `tickets` requests just went
+  // through uninspected for `cause`, the oldest of them `oldest_age_ms`
+  // after its enqueue (0: never enqueued). The per-request causes
+  // (bypass, ring full) are one event per episode: a new one starts
+  // after a second without a release of that cause.
+  void note_release(ReleaseCause cause, uint64_t tickets,
+                    uint64_t oldest_age_ms, uint64_t first_ticket = 0,
+                    uint64_t last_ticket = 0) {
+    if (tickets == 0) return;
+    Release& r = release_;
+    uint64_t now = now_ms();
+    bool event = cause == kRelDeadline || cause == kRelDegraded ||
+                 now - r.last_ms[cause] >= 1000;
+    r.last_ms[cause] = now;
+    r.tickets[cause] += tickets;
+    if (oldest_age_ms > r.oldest_age_max_ms)
+      r.oldest_age_max_ms = oldest_age_ms;
+    if (!event) return;
+    r.events[cause]++;
+    uint64_t tel[PINGOO_TELEMETRY_WORDS];
+    pingoo_ring_telemetry_snapshot(ring_, tel);
+    uint64_t lv[5];
+    pingoo_ring_liveness(ring_, lv);
+    r.last_cause = cause;
+    r.last_tickets = tickets;
+    r.last_oldest_age_ms = oldest_age_ms;
+    r.last_ring_depth = tel[3];
+    r.last_awaiting = awaiting_.size();
+    r.last_heartbeat_age_ms = (lv[1] != 0 && lv[4] > lv[1]) ? lv[4] - lv[1]
+                                                            : 0;
+    r.last_at_ms = now;
+    r.last_first_ticket = first_ticket;
+    r.last_last_ticket = last_ticket;
+    r.last_posted_floor = lv[2];
+    r.last_req_tail = lv[3];
+    if (now - r.log_window_ms >= 1000) {  // at most 8 lines a second
+      r.log_window_ms = now;
+      r.log_lines = 0;
+    }
+    if (r.log_lines++ >= 8) return;
+    std::fprintf(stderr,
+                 "pingoo-httpd: RELEASED %llu ticket(s) uninspected "
+                 "(cause %s, oldest %llu ms, tickets %llu..%llu, sidecar "
+                 "posted below %llu and dequeued below %llu, ring depth "
+                 "%llu, awaiting %zu, heartbeat %llu ms old, loop gap max "
+                 "%llu ms, at %llu ms)\n",
+                 static_cast<unsigned long long>(tickets),
+                 kReleaseCauseNames[cause],
+                 static_cast<unsigned long long>(oldest_age_ms),
+                 static_cast<unsigned long long>(first_ticket),
+                 static_cast<unsigned long long>(last_ticket),
+                 static_cast<unsigned long long>(lv[2]),
+                 static_cast<unsigned long long>(lv[3]),
+                 static_cast<unsigned long long>(tel[3]), awaiting_.size(),
+                 static_cast<unsigned long long>(r.last_heartbeat_age_ms),
+                 static_cast<unsigned long long>(r.loop_gap_max_ms),
+                 static_cast<unsigned long long>(now));
+  }
+
+  // A request that never got a ticket (run_policy said kFailOpenProxy).
+  void note_unenqueued_release() {
+    stats_.fail_open++;
+    note_release(degraded_ ? kRelBypass : kRelRingFull, 1, 0);
+  }
+
   // Fail one awaiting ticket open and record it. The awaiting_ entry
   // must already be erased (or never inserted) by the caller.
   void fail_open_ticket(Conn* c, int32_t sid, uint64_t ticket) {
@@ -3453,18 +3615,17 @@ class Server {
     // Collect first: fail_open_ticket mutates conns/streams and must
     // not run under the awaiting_ iterator.
     expired_.clear();
+    uint64_t oldest_age = 0, first = UINT64_MAX, last = 0;
     for (const auto& kv : awaiting_) {
-      const Awaiting& aw = kv.second;
-      uint64_t enq = 0;
-      if (aw.sid != 0) {
-        auto sit = aw.conn->h2_streams.find(aw.sid);
-        if (sit != aw.conn->h2_streams.end()) enq = sit->second.enq_ms;
-      } else {
-        enq = aw.conn->enq_ms;
-      }
-      if (enq != 0 && now - enq > kVerdictTimeoutMs)
+      uint64_t enq = enqueued_at(kv.second.conn, kv.second.sid);
+      if (enq != 0 && now - enq > kVerdictTimeoutMs) {
         expired_.push_back(kv.first);
+        if (now - enq > oldest_age) oldest_age = now - enq;
+        if (kv.first < first) first = kv.first;
+        if (kv.first > last) last = kv.first;
+      }
     }
+    uint64_t before = stats_.fail_open;
     for (uint64_t ticket : expired_) {
       auto it = awaiting_.find(ticket);
       if (it == awaiting_.end()) continue;
@@ -3473,6 +3634,16 @@ class Server {
       if (aw.conn->dead) continue;
       fail_open_ticket(aw.conn, aw.sid, ticket);
     }
+    note_release(kRelDeadline, stats_.fail_open - before, oldest_age, first,
+                 last);
+  }
+
+  // When an awaiting ticket was enqueued (this plane's ms clock), 0
+  // when its stream is gone.
+  static uint64_t enqueued_at(const Conn* conn, int32_t sid) {
+    if (sid == 0) return conn->enq_ms;
+    auto sit = conn->h2_streams.find(sid);
+    return sit != conn->h2_streams.end() ? sit->second.enq_ms : 0;
   }
 
   // A request whose metadata verdict already said "proxy" is blocked
@@ -3495,12 +3666,22 @@ class Server {
   void fail_open_all_awaiting() {
     std::vector<std::pair<uint64_t, Awaiting>> inflight;
     inflight.reserve(awaiting_.size());
-    for (const auto& kv : awaiting_) inflight.push_back(kv);
+    uint64_t now = now_ms(), oldest_age = 0, first = UINT64_MAX, last = 0;
+    for (const auto& kv : awaiting_) {
+      inflight.push_back(kv);
+      uint64_t enq = enqueued_at(kv.second.conn, kv.second.sid);
+      if (enq != 0 && now - enq > oldest_age) oldest_age = now - enq;
+      if (kv.first < first) first = kv.first;
+      if (kv.first > last) last = kv.first;
+    }
     awaiting_.clear();
+    uint64_t before = stats_.fail_open;
     for (const auto& kv : inflight) {
       if (kv.second.conn->dead) continue;
       fail_open_ticket(kv.second.conn, kv.second.sid, kv.first);
     }
+    note_release(kRelDegraded, stats_.fail_open - before, oldest_age, first,
+                 last);
   }
 
   bool degraded() const { return degraded_; }
@@ -3517,6 +3698,16 @@ class Server {
     sidecar_seen_ = true;
     uint64_t age = lv[4] > lv[1] ? lv[4] - lv[1] : 0;
     bool stale = age > kSidecarTimeoutMs;
+    // Near misses for the release witness: how old a heartbeat got,
+    // how often it crossed half the window, this loop's longest gap.
+    Release& r = release_;
+    if (age > r.heartbeat_age_max_ms) r.heartbeat_age_max_ms = age;
+    bool late = age > kSidecarTimeoutMs / 2;
+    if (late && !r.heartbeat_is_late) r.heartbeat_late++;
+    r.heartbeat_is_late = late;
+    if (r.last_pass_ms != 0 && lv[4] - r.last_pass_ms > r.loop_gap_max_ms)
+      r.loop_gap_max_ms = lv[4] - r.last_pass_ms;
+    r.last_pass_ms = lv[4];
     if (stale && !degraded_) {
       degraded_ = true;
       stats_.degraded_entered++;
@@ -3774,7 +3965,7 @@ class Server {
         }
         return;
       case Policy::kFailOpenProxy:
-        stats_.fail_open++;
+        note_unenqueued_release();
         flight_record(c->req, UINT64_MAX, 0, 0, 3);  // 3 = fail-open
         fail_open_proxy(c);
         return;
@@ -4046,7 +4237,7 @@ class Server {
           }
           break;
         case Policy::kFailOpenProxy:
-          stats_.fail_open++;
+          note_unenqueued_release();
           flight_record(it->second.p, UINT64_MAX, 0, 0, 3);  // fail-open
           h2_stream_fail_open(c, sid);
           break;
@@ -5536,6 +5727,7 @@ class Server {
   bool sidecar_seen_ = false;    // a sidecar heartbeat has ever landed
   uint64_t sidecar_epoch_ = 0;   // last epoch read from the ring header
   uint64_t last_deadline_sweep_ms_ = 0;
+  Release release_;              // the release witness (ISSUE 30)
   std::vector<uint64_t> expired_;  // sweep_verdict_deadlines scratch
   std::vector<SockRef*> doomed_refs_;  // per-stream refs freed after the batch
   std::unordered_map<SSL*, Conn*> ssl_conn_;
@@ -5906,6 +6098,8 @@ int main(int argc, char** argv) {
     if (draining) {
       size_t live = server.drain_tick();
       if (live == 0 || now - drain_start >= kDrainCapS) {
+        std::fprintf(stderr, "pingoo-httpd: release summary %s\n",
+                     server.release_json().c_str());
         std::printf("{\"drained\": true, \"remaining\": %zu}\n", live);
         std::fflush(stdout);
         return 0;

@@ -17,6 +17,7 @@ from __future__ import annotations
 import contextlib
 import ctypes
 import functools
+import logging
 import mmap
 import os
 import subprocess
@@ -24,6 +25,8 @@ import time
 from typing import Optional
 
 import numpy as np
+
+_log = logging.getLogger(__name__)
 
 NATIVE_DIR = os.path.join(os.path.dirname(__file__), "native")
 LIB_PATH = os.path.join(NATIVE_DIR, "libpingoo_ring.so")
@@ -929,6 +932,17 @@ class RingSidecar:
         import threading as _threading
 
         self._busy_since: Optional[float] = None
+        # The device arrays the loop is blocked on, when the busy window
+        # is a device->host sync: what the watchdog probes when the
+        # sync is overdue (`_probe_overdue_sync`), and what it found.
+        self._busy_sync: Optional[tuple] = None
+        self._sync_probes: _deque = _deque(maxlen=8)
+        self._sync_overdue_ctr = {
+            ready: REGISTRY.counter(
+                "pingoo_sidecar_sync_overdue_total",
+                RESILIENCE_METRICS["pingoo_sidecar_sync_overdue_total"],
+                labels={"plane": "sidecar", "ready": ready})
+            for ready in ("true", "false")}
         self._hb_watchdog = _threading.Thread(
             target=self._heartbeat_watchdog, name="pingoo-hb-watchdog",
             daemon=True)
@@ -942,18 +956,30 @@ class RingSidecar:
     # XLA compile, far below "hung forever".
     _HB_BUSY_GRACE_S = 120.0
 
+    # A device->host sync still blocked after this long is probed, and
+    # again at each later mark while it stays blocked: a sync of the
+    # served programs takes 1-60 ms, and the stalls that release
+    # requests (the "wedge", PERF.md section 7) 2-4 s.
+    _SYNC_PROBE_AT_S = (0.25, 1.0, 2.0)
+
     @contextlib.contextmanager
-    def _hb_busy(self):
+    def _hb_busy(self, sync: Optional[tuple] = None):
         """Declare a known-blocking drain-loop window (XLA compile,
         device sync, interpreter fallback, reattach reconciliation):
-        the heartbeat watchdog stamps only inside these."""
+        the heartbeat watchdog stamps only inside these. `sync` names
+        the device arrays a sync window waits for."""
+        self._busy_sync = sync
         self._busy_since = time.monotonic()
         try:
             yield
         finally:
             self._busy_since = None
+            self._busy_sync = None
 
     def _heartbeat_watchdog(self) -> None:
+        import threading as _threading
+
+        probed = (None, 0)  # (the busy window, probes made of it)
         while not self._stop:
             busy = self._busy_since
             if busy is not None \
@@ -961,7 +987,61 @@ class RingSidecar:
                     and not self.chaos.heartbeat_frozen():
                 for r in self.rings:
                     r.heartbeat()
+                sync = self._busy_sync
+                n = probed[1] if probed[0] == busy else 0
+                if sync is not None and n < len(self._SYNC_PROBE_AT_S) \
+                        and time.monotonic() - busy \
+                        >= self._SYNC_PROBE_AT_S[n]:
+                    probed = (busy, n + 1)
+                    # A thread of its own: if the runtime is stuck the
+                    # probe blocks too, and the heartbeat must go on.
+                    _threading.Thread(
+                        target=self._probe_overdue_sync,
+                        args=(busy, sync, n), name="pingoo-sync-probe",
+                        daemon=True).start()
             time.sleep(0.1)
+
+    def _probe_overdue_sync(self, busy: float, arrays: tuple,
+                            n: int) -> None:
+        """The drain loop has been blocked in one device->host sync
+        since `busy` (monotonic s). Record whether the arrays it waits
+        for are ready on the device, then send the device a round trip
+        of this thread's own (one word up, the same word back) and time
+        it: a runtime that answers here while the loop stays blocked
+        has lost that sync's completion, one that does not answer is
+        stuck as a whole. The round trip is also new device traffic,
+        which is what a lost completion would be waiting for."""
+        import jax
+
+        rec = {"probe": n, "overdue_ms": round(
+            (time.monotonic() - busy) * 1e3, 1)}
+        try:
+            rec["ready"] = [bool(a.is_ready()) for a in arrays]
+            t0 = time.monotonic()
+            word = jax.device_put(np.zeros(1, dtype=np.int32))
+            word.block_until_ready()
+            t1 = time.monotonic()
+            np.asarray(word)
+            t2 = time.monotonic()
+            rec.update(up_ms=round((t1 - t0) * 1e3, 2),
+                       back_ms=round((t2 - t1) * 1e3, 2),
+                       ready_after=[bool(a.is_ready()) for a in arrays])
+            # how soon after the round trip the loop's own sync returned
+            # (None: still blocked half a second later)
+            rec["loop_back_ms"] = None
+            while time.monotonic() - t2 < 0.5:
+                if self._busy_since != busy:
+                    rec["loop_back_ms"] = round(
+                        (time.monotonic() - t2) * 1e3, 1)
+                    break
+                time.sleep(0.005)
+        except Exception as exc:  # a witness never takes the plane down
+            rec["error"] = repr(exc)
+        self._sync_probes.append(rec)
+        if n == 0:
+            ready = all(rec.get("ready") or [False])
+            self._sync_overdue_ctr["true" if ready else "false"].inc()
+        _log.warning("device sync overdue", extra={"fields": rec})
 
     # -- ruleset hot-swap (ISSUE 11, docs/RESILIENCE.md) ----------------------
 
@@ -1131,6 +1211,11 @@ class RingSidecar:
                     "pingoo_staging_field_cap",
                     STAGING_METRICS["pingoo_staging_field_cap"],
                     labels={"field": field}).set(int(cap))
+        # pingoo_scan_columns_total: staged against walked columns of
+        # the fields this plan's byte loops scan (ops/live_columns.py).
+        from .engine.batch import ScanColumnCounters
+
+        self._scan_columns = ScanColumnCounters("sidecar", plan)
         self._plan_state = state
         if self._provenance_on:
             from .obs.flightrecorder import (FlightRecorder,
@@ -1637,6 +1722,7 @@ class RingSidecar:
                 else "full"].inc(batch.staged_bytes)
             self.sched.observe_dispatch_bytes(
                 batch.staged_bytes, rec.span_ms("prefilter", "dispatch"))
+        self._scan_columns.note(batch.arrays)
         # Scheduler accounting at launch: occupancy + queue depth, the
         # sidecar's `sched` stage (oldest enqueue -> launch hold on the
         # ring clock), and the fail-open mask for rows whose deadline
@@ -1785,6 +1871,7 @@ class RingSidecar:
                 batch = pad_batch(RequestBatch(
                     size=n, arrays=bucket_arrays(slots_to_arrays(slots))),
                     self.max_batch)
+            self._scan_columns.note(batch.arrays)
             j = len(self._mega_staged)
             self._mega_queue.fill_slice(self._mega_buf_id, j, batch.arrays,
                                         n, self.ruleset_epoch)
@@ -1991,7 +2078,7 @@ class RingSidecar:
             sp.next("device_wait")
             if dev is not None:
                 try:
-                    with self._hb_busy():  # the sync can block for ms-s
+                    with self._hb_busy(sync=(dev,)):  # can block ms-s
                         dev_lanes = np.asarray(dev)[:, :n]  # drop padding
                     self._note_device_success()
                 except Exception as exc:
@@ -2673,6 +2760,11 @@ class RingSidecar:
         if self._attribution is not None:
             self._attribution.close()
         self._stop = True
+        if self._sync_probes:
+            # Once more at the end of the log, which is what a harness
+            # keeps of it: every overdue device sync this plane probed.
+            _log.warning("device syncs overdue since boot", extra={
+                "fields": {"probes": list(self._sync_probes)}})
         t = self._thread
         if t is not None and t.is_alive()                 and t is not _threading.current_thread():
             t.join(timeout=join_timeout_s)

@@ -225,6 +225,30 @@ RESILIENCE_METRICS = {
     "pingoo_degraded_entered_total":
         "degraded-mode entries (each one failed every awaiting ticket "
         "open at once)",
+    # Release witness (ISSUE 30, native/httpd.cc note_release): what
+    # let requests through uninspected; the tickets add up to
+    # pingoo_fail_open_total.
+    "pingoo_release_events_total":
+        "native counter {cause=deadline|degraded|bypass|ring_full}: "
+        "release events (a deadline sweep that expired tickets, a "
+        "degraded entry, an episode of bypassed or ring-full arrivals)",
+    "pingoo_released_total":
+        "native counter {cause}: requests proxied uninspected, by what "
+        "released them",
+    "pingoo_sidecar_heartbeat_age_max_ms":
+        "native gauge: the oldest sidecar heartbeat this plane has seen "
+        "(degraded mode starts past PINGOO_SIDECAR_TIMEOUT_MS)",
+    "pingoo_sidecar_heartbeat_late_total":
+        "native counter: times the heartbeat's age crossed HALF the "
+        "liveness window (a near miss of degraded mode)",
+    "pingoo_native_loop_gap_max_ms":
+        "native gauge: the longest gap between two passes of the "
+        "httpd event loop (its own stalls)",
+    "pingoo_sidecar_sync_overdue_total":
+        "sidecar counter {ready=true|false}: device->host syncs of the "
+        "drain loop still blocked after 250 ms, by whether the arrays "
+        "waited for were ready on the device when probed (each logs "
+        "`device sync overdue` with the probe's own round trip)",
     "pingoo_reattach_reconciled_total":
         "tickets a restarting sidecar reconciled from the dead epoch, "
         "by action (reeval = slot bytes intact, re-evaluated; "
@@ -299,6 +323,12 @@ STAGING_METRICS = {
     "pingoo_staging_field_cap":
         "per-field staging width in bytes under the adopted plan "
         "(plan-derived cap, quantized to the pow2 rung ladder)",
+    # Live-column walk (ISSUE 29, ops/live_columns.py): per batch and
+    # field a contains/regex rule scans, {plane, field, kind}.
+    "pingoo_scan_columns_total":
+        "byte columns per batch and scanned field, by kind (staged = "
+        "the field's staged width, walked = the columns the dfa/pf "
+        "byte loops walk: the batch's longest row, rounded up to 8)",
 }
 
 # Perf ledger + cross-plane timeline + durable cost ledger (ISSUE 17,

@@ -5,12 +5,19 @@ compiler/nfa.py lowers small/hot NFA banks to byte-indexed DFA tables
 ops/prefilter.py's structure:
 
   * `scan_numpy`      — pure-numpy oracle for differential tests;
-  * `dfa_scan`        — `lax.scan` ladder: per byte, ONE flat-table
+  * `dfa_scan`        — gather ladder: per byte, ONE flat-table
                         gather `trans[state * C + cls]` plus two accept
-                        gathers into the sticky accumulator `H`. The
-                        dependent chain is L scalar-gather steps at ~4
+                        gathers into the sticky accumulator `H`, at ~4
                         lane-ops/byte — the dependent one-hot matmul
-                        chain of the NFA path is gone;
+                        chain of the NFA path is gone. The dependent
+                        chain is NOT the staged width L: the loop
+                        (ops/live_columns.py) runs
+                        ceil(min(max(lengths), L) / 8) blocks of 8
+                        steps, a bound read on the device from the
+                        batch's longest row, and the byte -> class
+                        gather runs per block inside it
+                        (`pingoo_scan_columns_total{kind="walked"}` is
+                        the same count on the host);
   * `_fused_dfa`      — Pallas kernel keeping state + H in VMEM for the
                         whole byte loop (one-hot f32 matmul lookups,
                         exact for values < 2^16; same trick as
@@ -37,6 +44,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 
 from ..compiler.nfa import DfaBank
+from .live_columns import scan_live_columns
 
 # Batch tile for the fused kernel (matches the VPU lane width).
 B_TILE = 128
@@ -164,30 +172,26 @@ def dfa_scan_chunk(tables: DfaTables, data: jax.Array, lengths: jax.Array,
     byte count at global positions (columns with t_offset + i >=
     lengths are padding and leave the carry untouched); `end_accept` is
     deliberately NOT applied here — it reads the final state, which
-    only `dfa_finalize` knows."""
-    B, Lc = data.shape
-    if Lc == 0:
-        return state, H
+    only `dfa_finalize` knows. The walk stops at the longest row's
+    remainder, ceil(clip(max(lengths - t_offset), 0, Lc) / 8) blocks: a
+    chunk wholly past every row runs none."""
     C = tables.num_classes
-    lens = lengths.astype(jnp.int32)
-    t_off = jnp.asarray(t_offset, dtype=jnp.int32)
-    # Byte -> class ids ONCE, outside the loop (byte_cls is [256]).
-    cls = jnp.take(tables.byte_cls, data.astype(jnp.int32))  # [B, Lc]
 
-    def step(carry, xs):
+    def classes(block):
+        # Byte -> class ids for the block being walked only (byte_cls
+        # is [256]); never over the whole staged matrix.
+        return jnp.take(tables.byte_cls, block.astype(jnp.int32))
+
+    def step(carry, c, live):
         state, H = carry
-        c, i = xs
-        live = (t_off + i) < lens  # t_off broadcasts: scalar or [B]
         fire = jnp.take(tables.step_accept, state, axis=0)  # [B, Wh]
         H = jnp.where(live[:, None], H | fire, H)
         nxt = jnp.take(tables.trans_flat, state * C + c)
         state = jnp.where(live, nxt, state)
-        return (state, H), None
+        return state, H
 
-    xs = (cls.T, jnp.arange(Lc, dtype=jnp.int32))
-    (state, H), _ = jax.lax.scan(step, (state, H), xs,
-                                 unroll=8 if Lc >= 8 else 1)
-    return state, H
+    return scan_live_columns(step, (state, H), data, lengths, t_offset,
+                             prepare=classes)
 
 
 def dfa_finalize(tables: DfaTables, state: jax.Array, H: jax.Array,

@@ -31,13 +31,18 @@ accumulator (both [B, Wp] uint32 carries):
 
 A factor hit is its LAST position's bit in H. Inputs beyond each
 request's length are gated exactly like the NFA scan (padding can never
-arm a factor).
+arm a factor), and columns beyond EVERY request's length are not walked
+at all: the loop (ops/live_columns.py) runs
+ceil(min(max(lengths), L) / 8) blocks of 8 steps, a bound read on the
+device from the batch's longest row, not the staged width L
+(`pingoo_scan_columns_total{kind="walked"}` is the same count on the
+host).
 
 `scan_numpy` is the pure-numpy oracle used by the differential property
-tests (tests/test_prefilter.py); `prefilter_scan` is the lax.scan
-device op; `backend="pallas"` routes through a fused kernel keeping
-both carries in VMEM for the whole field (interpret=True off-TPU, the
-same program a chip would compile — mirroring ops/pallas_scan.py).
+tests (tests/test_prefilter.py); `prefilter_scan` is the device op;
+`backend="pallas"` routes through a fused kernel keeping both carries
+in VMEM for the whole field (interpret=True off-TPU, the same program
+a chip would compile — mirroring ops/pallas_scan.py).
 """
 
 from __future__ import annotations
@@ -48,6 +53,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+
+from .live_columns import scan_live_columns
 
 WORD_BITS = 32
 
@@ -183,29 +190,22 @@ def prefilter_scan_chunk(tables: PrefilterTables, data: jax.Array,
     carry-in — no overlap-tail re-scan needed for the prefilter itself
     (engine/bodyscan.py relies on this to decide lazy NFA starts).
     `lengths` is each row's TOTAL live byte count in global positions;
-    `prefilter_scan` below is one chunk at offset 0."""
-    B, Lc = data.shape
-    if Lc == 0:
-        return S, H
-    lens = lengths.astype(jnp.int32)
-    t_off = jnp.asarray(t_offset, dtype=jnp.int32)
+    `prefilter_scan` below is one chunk at offset 0. The walk stops at
+    the longest row's remainder (ceil(clip(max(lengths - t_offset), 0,
+    Lc) / 8) blocks); carried-in H already holds carried-in S."""
     init = tables.init
     one = jnp.uint32(1)
 
-    def step(carry, xs):
+    def step(carry, c, live):
         S, H = carry
-        c, i = xs
         bc = jnp.take(tables.byte_table, c.astype(jnp.int32), axis=0)
         S_new = ((S << one) | init[None, :]) & bc
         # Rows past their length keep S unchanged, so H | S adds
         # nothing for them — no second gate needed.
-        S = jnp.where((t_off + i < lens)[:, None], S_new, S)
-        return (S, H | S), None
+        S = jnp.where(live[:, None], S_new, S)
+        return S, H | S
 
-    (S, H), _ = jax.lax.scan(
-        step, (S, H), (data.T, jnp.arange(Lc, dtype=jnp.int32)),
-        unroll=8 if Lc >= 8 else 1)
-    return S, H
+    return scan_live_columns(step, (S, H), data, lengths, t_offset)
 
 
 def prefilter_extract(tables: PrefilterTables, H: jax.Array) -> jax.Array:

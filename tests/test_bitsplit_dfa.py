@@ -192,6 +192,31 @@ class TestKernelDifferential:
             np.testing.assert_array_equal(got_scan, ref)
             np.testing.assert_array_equal(got_pallas, ref)
 
+    def test_staged_width_length_patterns(self, corpus_bank, live_lengths):
+        """ISSUE 29: the ladder stops at the batch's longest row; at the
+        staged width of 2,048 it equals the oracle, which walks every
+        column, on every edge of that bound (conftest's patterns)."""
+        pats, _ = corpus_bank
+        dfa = lower_bank_to_dfa(pats, state_budget=65536, merge_depths=())
+        tables = dfa_to_tables(dfa)
+        lens, stage = live_lengths
+        rng = random.Random(2929)
+        L = 2048
+        fill = np.zeros((len(lens), L), dtype=np.uint8)
+        for i in range(len(lens)):
+            row = bytearray()
+            while len(row) < L:  # pattern-seeded fragments, no NULs
+                frag, n = _random_rows(rng, pats, 1, 48)
+                row += bytes(b or 0x2F for b in frag[0, :n[0]]) + b"/"
+            fill[i] = np.frombuffer(bytes(row[:L]), dtype=np.uint8)
+        data = stage(fill)
+        ref = dfa_scan_numpy(dfa, data, lens)
+        got = np.asarray(jax.jit(dfa_scan)(
+            tables, jnp.asarray(data), jnp.asarray(lens)))
+        np.testing.assert_array_equal(got, ref)
+        if lens.max() >= 8:
+            assert ref[lens >= 8].any() and not ref.all()
+
     def test_skip_hits_and_row_candidates(self, corpus_bank):
         """dfa_skip_hits is the zero-input base; dfa_row_candidates is
         exactly 'hits exceed the base' — the prune-only gate."""

@@ -182,6 +182,63 @@ def test_prefilter_literal_straddle(plan):
     assert whole.any(), "the union-select factor must be present"
 
 
+def test_chunks_past_every_row_are_identity(plan):
+    """ISSUE 29: the chunk loops run ceil(max(lens - t_offset) / 8)
+    blocks. Rows resume at their OWN offset (per-row t_offset, as ring
+    windows do), the ring keeps handing out windows after the longest
+    row has ended, and a window wholly past every row must run zero
+    blocks: the carry comes back as it went in, whatever bytes the
+    window holds, and the final hits equal the contiguous scan."""
+    rows = [b"x" * 5 + b"union select 1", b"<script>alert(1)</script>",
+            b"", b"q=" + b"../" * 9 + b"etc/passwd", b"eval(abc)"]
+    B, W = len(rows), 16
+    lens = np.array([len(r) for r in rows], dtype=np.int32)
+    start = np.array([0, 3, 0, 7, 1], dtype=np.int32)  # already consumed
+    n_win = 6  # the longest row ends in window 3; 4 and 5 are past all
+    assert (start + n_win * W > lens.max() + 2 * W).all()
+    whole = np.zeros((B, int(lens.max())), dtype=np.uint8)
+    for i, r in enumerate(rows):
+        whole[i, :len(r)] = np.frombuffer(r, np.uint8)
+    jl = jnp.asarray(lens)
+    dt, pt = plan.dfa_tables, plan.pf_tables
+    dfa_chunk, pf_chunk = jax.jit(dfa_scan_chunk), jax.jit(prefilter_scan_chunk)
+    ref_dfa = np.asarray(dfa_scan(dt, jnp.asarray(whole), jl))
+    ref_pf = np.asarray(prefilter_scan(pt, jnp.asarray(whole), jl))
+
+    # The bytes before each row's start, as one first chunk at offset 0.
+    head = np.zeros((B, int(start.max())), dtype=np.uint8)
+    for i, r in enumerate(rows):
+        head[i, :start[i]] = np.frombuffer(r[:start[i]], np.uint8)
+    st, Hd = dfa_chunk(dt, jnp.asarray(head),
+                       jnp.minimum(jl, jnp.asarray(start)),
+                       *dfa_init_state(B, dt.num_words), 0)
+    S, Hp = pf_chunk(pt, jnp.asarray(head),
+                     jnp.minimum(jl, jnp.asarray(start)),
+                     *prefilter_init_state(B, pt.init.shape[0]), 0)
+    past_all = 0
+    for w in range(n_win):
+        t_off = start + w * W
+        win = np.full((B, W), 0x27, dtype=np.uint8)  # garbage past rows
+        for i, r in enumerate(rows):
+            piece = r[t_off[i]:t_off[i] + W]
+            win[i, :len(piece)] = np.frombuffer(piece, np.uint8)
+        before = [np.asarray(a) for a in (st, Hd, S, Hp)]
+        st, Hd = dfa_chunk(dt, jnp.asarray(win), jl, st, Hd,
+                           jnp.asarray(t_off))
+        S, Hp = pf_chunk(pt, jnp.asarray(win), jl, S, Hp,
+                         jnp.asarray(t_off))
+        if (t_off >= lens).all():
+            past_all += 1
+            for a, b in zip(before, (st, Hd, S, Hp)):
+                np.testing.assert_array_equal(np.asarray(b), a)
+    assert past_all >= 2
+    np.testing.assert_array_equal(
+        np.asarray(dfa_finalize(dt, st, Hd, jl)), ref_dfa)
+    np.testing.assert_array_equal(
+        np.asarray(prefilter_extract(pt, Hp)), ref_pf)
+    assert ref_dfa.any() and ref_pf.any()
+
+
 # -- split-anywhere property --------------------------------------------------
 
 

@@ -188,3 +188,88 @@ class TestNativeHttpd:
                 assert data.startswith(b"HTTP/1.1 403")
             else:
                 assert b"upstream:/ok" in data
+
+
+class TestReleaseWitness:
+    """Every request the native plane lets through uninspected names its
+    cause in the stats' `release` block and on stderr (ISSUE 30): a
+    ring nobody drains, a 150 ms verdict deadline, a 100 ms liveness
+    window."""
+
+    def test_each_cause_is_counted_and_logged(self, tmp_path):
+        import json
+
+        class Upstream(http.server.BaseHTTPRequestHandler):
+            def do_GET(self):
+                self.send_response(200)
+                self.send_header("content-length", "2")
+                self.end_headers()
+                self.wfile.write(b"up")
+
+            def log_message(self, *a):
+                pass
+
+        upstream = http.server.HTTPServer(("127.0.0.1", 0), Upstream)
+        threading.Thread(target=upstream.serve_forever, daemon=True).start()
+        ring_path = str(tmp_path / "ring")
+        ring = Ring(ring_path, capacity=64, create=True)
+        port = _free_port()
+        err = open(tmp_path / "httpd.err", "wb")
+        proc = subprocess.Popen(
+            [HTTPD, str(port), ring_path, "127.0.0.1",
+             str(upstream.server_address[1])],
+            stdout=subprocess.PIPE, stderr=err,
+            env=dict(os.environ, PINGOO_VERDICT_TIMEOUT_MS="150",
+                     PINGOO_SIDECAR_TIMEOUT_MS="100"))
+        try:
+            assert b"listening" in proc.stdout.readline()
+            # no sidecar ever attached: the per-ticket deadline governs
+            assert b" 200" in _raw_get(port, "/a").split(b"\r\n", 1)[0]
+            # a heartbeat lands and goes stale under an awaiting ticket:
+            # degraded entry releases it, the next request bypasses
+            ring.sidecar_attach()
+            assert b" 200" in _raw_get(port, "/b").split(b"\r\n", 1)[0]
+            assert b" 200" in _raw_get(port, "/c").split(b"\r\n", 1)[0]
+            body = _raw_get(port, "/__pingoo/metrics",
+                            extra="accept: application/json\r\n"
+                            ).partition(b"\r\n\r\n")[2]
+            prom = _raw_get(port, "/__pingoo/metrics").decode()
+        finally:
+            proc.terminate()
+            proc.wait(timeout=5)
+            err.close()
+            upstream.shutdown()
+            ring.close()
+        m = json.loads(body)
+        rel = m["release"]
+        assert [rel[f"tickets_{c}"] for c in
+                ("deadline", "degraded", "bypass", "ring_full")] \
+            == [1, 1, 1, 0]
+        assert [rel[f"events_{c}"] for c in
+                ("deadline", "degraded", "bypass", "ring_full")] \
+            == [1, 1, 1, 0]
+        assert m["fail_open"] == 3 and m["degraded_entered"] == 1
+        assert rel["last_cause"] == "bypass"
+        assert 150 < rel["oldest_age_max_ms"] < 1000
+        assert rel["heartbeat_age_max_ms"] > 100
+        assert rel["heartbeat_late"] == 1
+        for cause, n in (("deadline", 1), ("degraded", 1), ("bypass", 1),
+                         ("ring_full", 0)):
+            assert (f'pingoo_released_total{{plane="native",'
+                    f'cause="{cause}"}} {n}') in prom
+        log = (tmp_path / "httpd.err").read_text()
+        for cause in ("deadline", "degraded", "bypass"):
+            assert f"RELEASED 1 ticket(s) uninspected (cause {cause}," in log
+        # which tickets, against how far the sidecar had got: nobody
+        # drained this ring, so both were at or past its dequeue mark
+        assert "tickets 0..0, sidecar posted below 0 and dequeued below 0" \
+            in log
+        assert "tickets 1..1, sidecar posted below 0 and dequeued below 0" \
+            in log
+        # and the whole block once more as the drained plane's last
+        # line, where the end of a log still holds it
+        last = [ln for ln in log.splitlines() if "release summary" in ln]
+        summary = json.loads(last[-1].split("release summary ", 1)[1])
+        moving = {"heartbeat_age_max_ms", "loop_gap_max_ms"}  # still stale
+        assert {k: v for k, v in summary.items() if k not in moving} \
+            == {k: v for k, v in rel.items() if k not in moving}

@@ -14,6 +14,7 @@ import asyncio
 import pickle
 import random
 
+import jax
 import numpy as np
 import pytest
 
@@ -162,6 +163,32 @@ class TestPrefilterKernel:
         got_pl = np.asarray(
             prefilter_scan(tables, data, lens, backend="pallas"))
         np.testing.assert_array_equal(got_pl, ref)
+
+    def test_staged_width_length_patterns(self, live_lengths):
+        """ISSUE 29: the scan stops at the batch's longest row; at the
+        staged width of 2,048 it equals the oracle, which walks every
+        column, on every edge of that bound (conftest's patterns)."""
+        rng = random.Random(29)
+        factors = self._random_factors(rng)
+        bank = build_prefilter_bank(factors)
+        tables = bank_to_prefilter_tables(bank)
+        lens, stage = live_lengths
+        L = 2048
+        fill = np.zeros((len(lens), L), dtype=np.uint8)
+        for i in range(len(lens)):
+            row = bytearray()
+            while len(row) < L:  # noise with a factor every few bytes
+                row += bytes(rng.randrange(33, 127)
+                             for _ in range(rng.randrange(0, 9)))
+                fac = factors[rng.randrange(len(factors))]
+                row += bytes(rng.choice(sorted(c)) for c in fac)
+            fill[i] = np.frombuffer(bytes(row[:L]), dtype=np.uint8)
+        data = stage(fill)
+        ref = scan_numpy(bank, data, lens)
+        got = np.asarray(jax.jit(prefilter_scan)(tables, data, lens))
+        np.testing.assert_array_equal(got, ref)
+        if lens.max() >= 16:
+            assert ref.any() and not ref.all()
 
     def test_padding_never_arms_a_factor(self):
         # A factor containing NUL would match the zero padding were the

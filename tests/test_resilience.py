@@ -296,6 +296,53 @@ class TestHeartbeatFreezeDetection:
             ring.close()
 
 
+class TestOverdueSyncProbe:
+    """A device->host sync that outlasts its mark is probed from a
+    thread beside the watchdog (ISSUE 30): is what the loop waits for
+    ready on the device, and does the runtime answer a round trip of
+    the probe's own while the loop stays blocked."""
+
+    class _NeverReady:  # a device array whose program never finishes
+        def is_ready(self):
+            return False
+
+    def test_overdue_sync_is_probed_and_other_windows_are_not(
+            self, tmp_path, plan, caplog):
+        from pingoo_tpu.obs import REGISTRY
+
+        ring = Ring(str(tmp_path / "ring"), capacity=64, create=True)
+        sidecar = RingSidecar(ring, plan, {}, max_batch=16)
+        sidecar._SYNC_PROBE_AT_S = (0.05,)
+        try:
+            hb0 = ring.liveness()["heartbeat_ms"]
+            # a compile window names no arrays: stamped, never probed
+            with sidecar._hb_busy():
+                time.sleep(0.3)
+            assert not sidecar._sync_probes
+            assert ring.liveness()["heartbeat_ms"] > hb0
+            with caplog.at_level("WARNING", logger="pingoo_tpu.native_ring"):
+                with sidecar._hb_busy(sync=(self._NeverReady(),)):
+                    time.sleep(0.3)
+                deadline = time.monotonic() + 5
+                while not sidecar._sync_probes \
+                        and time.monotonic() < deadline:
+                    time.sleep(0.01)
+            (rec,) = sidecar._sync_probes
+            assert rec["probe"] == 0 and rec["overdue_ms"] >= 50
+            assert rec["ready"] == [False] == rec["ready_after"]
+            # the runtime answered the probe while the loop was blocked,
+            # and the loop's own sync came back (the window closed)
+            assert rec["up_ms"] >= 0 and rec["back_ms"] >= 0
+            assert rec["loop_back_ms"] is not None
+            assert "device sync overdue" in caplog.text
+            text = REGISTRY.prometheus_text()
+            assert ('pingoo_sidecar_sync_overdue_total{plane="sidecar",'
+                    'ready="false"} 1') in text
+        finally:
+            sidecar.stop()
+            ring.close()
+
+
 class _FakeClock:
     def __init__(self):
         self.t = 0.0

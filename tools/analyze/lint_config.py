@@ -148,6 +148,9 @@ TRACED_FUNCTIONS = frozenset({
     # program's bank dispatch (engine/verdict run_packed_scans).
     "pingoo_tpu/ops/bitsplit_dfa.py::dfa_scan",
     "pingoo_tpu/ops/bitsplit_dfa.py::_fused_dfa",
+    # The byte loops' shared driver (ISSUE 29): traced from both of the
+    # above through their *_scan_chunk.
+    "pingoo_tpu/ops/live_columns.py::scan_live_columns",
     # Device-resident megastep driver (ISSUE 12): the K-slice lax.scan
     # body and its per-slice step execute at trace time from
     # make_megastep_fn's jit — captured host constants there re-stage

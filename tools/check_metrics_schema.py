@@ -157,7 +157,15 @@ def main() -> int:
     # staged-bytes counter and the per-field cap gauge — the counter is
     # what makes the full-vs-compact byte savings visible per plane,
     # and the gauge publishes the adopted plan's staging widths.
+    # The scan-columns counter's literal lives in engine/batch.py
+    # (ScanColumnCounters, shared); both planes must construct one.
+    if "pingoo_scan_columns_total" not in _read(
+            "pingoo_tpu/engine/batch.py"):
+        problems.append(
+            "engine/batch.py: missing metric pingoo_scan_columns_total")
     for name in schema.STAGING_METRICS:
+        if name == "pingoo_scan_columns_total":
+            name = "ScanColumnCounters"
         if name not in service_src:
             problems.append(f"engine/service.py: missing metric {name}")
         if name not in sidecar_src:
@@ -185,7 +193,12 @@ def main() -> int:
     # the pingoo_degrade_total series exist under both plane labels —
     # and the native plane must carry the liveness detector itself.
     for name in ("pingoo_sidecar_up", "pingoo_degraded_mode",
-                 "pingoo_sidecar_epoch", "pingoo_degraded_entered_total"):
+                 "pingoo_sidecar_epoch", "pingoo_degraded_entered_total",
+                 # the release witness (ISSUE 30)
+                 "pingoo_release_events_total", "pingoo_released_total",
+                 "pingoo_sidecar_heartbeat_age_max_ms",
+                 "pingoo_sidecar_heartbeat_late_total",
+                 "pingoo_native_loop_gap_max_ms"):
         if name not in native_src:
             problems.append(f"native/httpd.cc: missing metric {name}")
     if "check_sidecar_liveness" not in native_src:
@@ -193,7 +206,8 @@ def main() -> int:
             "native/httpd.cc: liveness detector check_sidecar_liveness "
             "missing")
     for name in ("pingoo_reattach_reconciled_total",
-                 "pingoo_sidecar_epoch"):
+                 "pingoo_sidecar_epoch",
+                 "pingoo_sidecar_sync_overdue_total"):
         if name not in sidecar_src:
             problems.append(f"native_ring.py: missing metric {name}")
     ladder_src = _read("pingoo_tpu/engine/ladder.py")
@@ -317,6 +331,8 @@ def main() -> int:
         "plane": "audit", "action": "reeval"}).inc()
     reg.counter("pingoo_degrade_total", "", labels={
         "plane": "audit", "rung": "device"}).inc()
+    reg.counter("pingoo_released_total", "", labels={
+        "plane": "audit", "cause": "deadline"}).inc()
     reg.counter("pingoo_chaos_injected_total", "", labels={
         "plane": "audit", "fault": "verdict_full"}).inc()
     reg.counter("pingoo_body_degrade_total", "", labels={
@@ -325,6 +341,8 @@ def main() -> int:
         "plane": "audit", "mode": "compact"}).inc()
     reg.gauge("pingoo_staging_field_cap", "", labels={
         "field": "url"}).set(256)
+    reg.counter("pingoo_scan_columns_total", "", labels={
+        "plane": "audit", "field": "url", "kind": "walked"}).inc()
     reg.counter("pingoo_compile_total", "", labels={
         "plane": "audit", "fn": "verdict", "kind": "cold"}).inc()
     reg.counter("pingoo_timeline_spans_total", "", labels={
