@@ -20,6 +20,13 @@ from .logging_utils import get_logger, init_logging
 
 log = get_logger("pingoo_tpu")
 
+# What a deployment's file may demand of the build that serves it
+# (`--require`): a build without the capability refuses the command
+# line instead of serving the deployment without it.
+#   listener-metrics: /__pingoo/metrics on a listener's port answers for
+#   all of its --native-workers, whichever of them takes the scrape.
+CAPABILITIES = ("listener-metrics",)
+
 
 def main(argv: list[str] | None = None) -> int:
     parser = argparse.ArgumentParser(prog="pingoo-tpu")
@@ -40,6 +47,11 @@ def main(argv: list[str] | None = None) -> int:
     parser.add_argument("--native-workers", type=int, default=1,
                         help="SO_REUSEPORT httpd workers per listener "
                              "(one verdict ring each)")
+    parser.add_argument("--require", action="append", default=[],
+                        choices=CAPABILITIES, metavar="CAPABILITY",
+                        help="refuse to start unless this build has the "
+                             "capability (may repeat): "
+                             + ", ".join(CAPABILITIES))
     parser.add_argument("--state-dir", default="/var/run/pingoo",
                         help="ring files + services table directory "
                              "(native plane)")
@@ -88,6 +100,7 @@ def main(argv: list[str] | None = None) -> int:
         "rules": len(config.rules),
         "device": not args.no_device,
         "native_plane": args.native_plane,
+        "capabilities": list(CAPABILITIES),
         **backend,
         "compile_cache": compile_cache,
     }})

@@ -118,6 +118,7 @@ class NativePlane:
         self._sidecar_thread = None
         self.rings = []
         self.procs: list[subprocess.Popen] = []
+        self._worker_stats_fds: list[int] = []  # one memfd a listener
         self._republish_task = None
         # Per HTTP listener: its ordered http-service names and its own
         # routing-table file (the reference binds a service list PER
@@ -198,6 +199,16 @@ class NativePlane:
         alpn_dir = os.path.join(tls_dir, "alpn")
         for listener in http_listeners:
             fail_open_port = self._loopback_ports[listener.name]
+            # One counter surface a listener: every worker writes its
+            # own slot of this block and whichever of them the kernel
+            # hands a /__pingoo/metrics scrape answers with all of them
+            # added up (native/httpd.cc WorkerSlot). Anonymous shared
+            # memory the workers inherit, not a file in the state
+            # directory: the kernel writes a file mapping's dirty pages
+            # back, and a counter store into a page under writeback can
+            # hold a worker's event loop for seconds.
+            stats_fd = os.memfd_create(f"pingoo-workers-{listener.name}")
+            self._worker_stats_fds.append(stats_fd)
             for w in range(self.workers):
                 argv = [
                     self.httpd_bin, str(listener.port),
@@ -208,6 +219,8 @@ class NativePlane:
                     "--services", self.services_paths[listener.name],
                     "--bind", listener.host,
                     "--internal-token-file", self._token_path,
+                    "--worker-stats-fd", str(stats_fd),
+                    "--workers", str(self.workers), "--worker", str(w),
                 ]
                 if listener.protocol.is_tls:
                     argv += ["--tls-dir", tls_dir]
@@ -215,7 +228,8 @@ class NativePlane:
                         argv += ["--alpn-dir", alpn_dir]
                 if self.upstream_ca:
                     argv += ["--upstream-ca", self.upstream_ca]
-                proc = subprocess.Popen(argv, stdout=subprocess.PIPE)
+                proc = subprocess.Popen(argv, stdout=subprocess.PIPE,
+                                        pass_fds=(stats_fd,))
                 self.procs.append(proc)  # before the bind check: a
                 # failed worker must still be reaped by stop()
                 try:
@@ -242,6 +256,8 @@ class NativePlane:
                 "address": f"{listener.host}:{listener.port}",
                 "tls": listener.protocol.is_tls,
                 "workers": self.workers,
+                "rings": [os.path.basename(ring_paths[(listener.name, w)])
+                          for w in range(self.workers)],
                 "fail_open": f"127.0.0.1:{fail_open_port}",
             }})
 
@@ -453,6 +469,9 @@ class NativePlane:
             self._sidecar_thread.join(timeout=10)
         for ring in self.rings:
             ring.close()
+        for fd in self._worker_stats_fds:
+            os.close(fd)
+        self._worker_stats_fds.clear()
         await self.server.stop()
 
 

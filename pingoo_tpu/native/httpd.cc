@@ -76,6 +76,7 @@
 #include <cstdio>
 #include <cstdlib>
 #include <cstring>
+#include <new>
 #include <string>
 #include <unordered_map>
 #include <unordered_set>
@@ -1474,7 +1475,8 @@ class Server {
          const sockaddr_in* captcha_upstream, CaptchaGate* gate,
          TlsStore* tls, ServiceTable* services = nullptr,
          SSL_CTX* up_ctx = nullptr, std::string internal_token = "",
-         bool tcp_mode = false)
+         bool tcp_mode = false, void* worker_block = nullptr,
+         int workers = 1, int worker = 0)
       : ep_(ep),
         ring_(ring),
         upstream_(upstream),
@@ -1483,11 +1485,23 @@ class Server {
         services_(services),
         up_ctx_(up_ctx),
         internal_token_(std::move(internal_token)),
-        tcp_mode_(tcp_mode) {
+        tcp_mode_(tcp_mode),
+        slots_(worker_block ? static_cast<WorkerSlot*>(worker_block)
+                            : &own_slot_),
+        workers_(worker_block ? workers : 1),
+        worker_(worker_block ? worker : 0),
+        slot_(new (&slots_[worker_]) WorkerSlot()),  // a restart counts anew
+        stats_(slot_->stats),
+        release_(slot_->release) {
     if (captcha_upstream) {
       captcha_upstream_ = *captcha_upstream;
       has_captcha_upstream_ = true;
     }
+  }
+
+  // Bytes of the shared block for `workers` slots (main() maps it).
+  static size_t worker_block_bytes(int workers) {
+    return sizeof(WorkerSlot) * static_cast<size_t>(workers);
   }
 
   // -- service routing -------------------------------------------------------
@@ -2112,6 +2126,120 @@ class Server {
     uint64_t log_window_ms = 0, log_lines = 0;
   };
 
+  // One counter surface for N workers (ISSUE 31, docs/OBSERVABILITY.md
+  // "Workers"). --native-workers N runs N of these processes on one
+  // SO_REUSEPORT port and the kernel hands a scrape to any of them, so
+  // the counters live in a block the workers share (--worker-stats-fd:
+  // a memfd host/native_plane.py makes and every worker inherits;
+  // anonymous memory, because a store into a page of a FILE's mapping
+  // can wait seconds on the kernel's writeback of it), one single-writer
+  // slot a worker: stats_ and release_ ARE this worker's slot (the
+  // plain stores they always were), the ring telemetry and the gauges
+  // are copied in once a millisecond (publish_slot), and whichever
+  // worker answers /__pingoo/metrics adds the slots up
+  // (listener_totals). Without the flag the one slot is a member.
+  struct alignas(64) WorkerSlot {
+    Stats stats;
+    Release release;
+    uint64_t release_seq = 0;  // odd while note_release rewrites last_*
+    uint64_t tel[PINGOO_TELEMETRY_WORDS] = {};
+    uint64_t awaiting = 0, body_awaiting = 0, connections = 0,
+             pooled_upstreams = 0, degraded = 0, sidecar_up = 0,
+             sidecar_epoch = 0, published_ms = 0;
+  };
+
+  // A sibling's slot as it stands: word by word (its owner is writing),
+  // again while a release event was being rewritten under the copy.
+  static void read_slot(const WorkerSlot* src, WorkerSlot* dst) {
+    constexpr size_t kWords = sizeof(WorkerSlot) / sizeof(uint64_t);
+    static_assert(sizeof(WorkerSlot) % sizeof(uint64_t) == 0, "slot words");
+    uint64_t words[kWords];
+    const uint64_t* p = reinterpret_cast<const uint64_t*>(src);
+    for (int attempt = 0; attempt < 4; ++attempt) {
+      uint64_t seq = __atomic_load_n(&src->release_seq, __ATOMIC_ACQUIRE);
+      for (size_t i = 0; i < kWords; ++i)
+        words[i] = __atomic_load_n(p + i, __ATOMIC_RELAXED);
+      __atomic_thread_fence(__ATOMIC_ACQUIRE);
+      if (!(seq & 1) &&
+          seq == __atomic_load_n(&src->release_seq, __ATOMIC_RELAXED))
+        break;
+    }
+    std::memcpy(static_cast<void*>(dst), words, sizeof(*dst));
+  }
+
+  // This worker's ring telemetry and gauges into its slot: once a
+  // millisecond from the event loop, and before it answers a scrape.
+  void publish_slot(bool force = false) {
+    uint64_t now = now_ms();
+    if (!force && now == slot_->published_ms) return;
+    pingoo_ring_telemetry_snapshot(ring_, slot_->tel);
+    size_t pooled = 0;
+    for (const auto& kv : upstream_pool_) pooled += kv.second.size();
+    slot_->awaiting = awaiting_.size();
+    slot_->body_awaiting = body_awaiting_.size();
+    slot_->connections = conns_.size();
+    slot_->pooled_upstreams = pooled;
+    slot_->degraded = degraded_ ? 1 : 0;
+    slot_->sidecar_up = (sidecar_seen_ && !degraded_) ? 1 : 0;
+    slot_->sidecar_epoch = sidecar_epoch_;
+    slot_->published_ms = now;
+  }
+
+  // The listener's numbers: counters are the sum over the workers,
+  // high-water marks and maxima the largest, `degraded` any worker's,
+  // `sidecar_up` every worker's, and the release block's last_* the
+  // newest event of any worker (`*newest` says whose). `per` gets each
+  // worker's slot as read.
+  WorkerSlot listener_totals(std::vector<WorkerSlot>* per, int* newest) {
+    publish_slot(true);
+    per->resize(workers_);
+    for (int w = 0; w < workers_; ++w) read_slot(&slots_[w], &(*per)[w]);
+    WorkerSlot sum = (*per)[0];
+    *newest = 0;
+    for (int w = 1; w < workers_; ++w) {
+      if ((*per)[w].release.last_at_ms > sum.release.last_at_ms) {
+        sum.release = (*per)[w].release;  // for its last_*; the rest below
+        *newest = w;
+      }
+    }
+    Release& r = sum.release;
+    r.heartbeat_late = r.oldest_age_max_ms = r.heartbeat_age_max_ms =
+        r.loop_gap_max_ms = 0;
+    std::memset(r.events, 0, sizeof(r.events));
+    std::memset(r.tickets, 0, sizeof(r.tickets));
+    auto add = [](uint64_t* into, const uint64_t* from, size_t words) {
+      for (size_t i = 0; i < words; ++i) into[i] += from[i];
+    };
+    auto larger = [](uint64_t* into, uint64_t v) {
+      if (v > *into) *into = v;
+    };
+    static_assert(sizeof(Stats) % sizeof(uint64_t) == 0, "stats words");
+    for (int w = 0; w < workers_; ++w) {
+      const WorkerSlot& s = (*per)[w];
+      add(r.events, s.release.events, kRelCauses);
+      add(r.tickets, s.release.tickets, kRelCauses);
+      r.heartbeat_late += s.release.heartbeat_late;
+      larger(&r.oldest_age_max_ms, s.release.oldest_age_max_ms);
+      larger(&r.heartbeat_age_max_ms, s.release.heartbeat_age_max_ms);
+      larger(&r.loop_gap_max_ms, s.release.loop_gap_max_ms);
+      if (w == 0) continue;  // `sum` began as worker 0's slot
+      add(reinterpret_cast<uint64_t*>(&sum.stats),
+          reinterpret_cast<const uint64_t*>(&s.stats),
+          sizeof(Stats) / sizeof(uint64_t));  // every field is a counter
+      uint64_t hwm = sum.tel[4];
+      add(sum.tel, s.tel, PINGOO_TELEMETRY_WORDS);
+      sum.tel[4] = hwm > s.tel[4] ? hwm : s.tel[4];  // depth_hwm
+      sum.awaiting += s.awaiting;
+      sum.body_awaiting += s.body_awaiting;
+      sum.connections += s.connections;
+      sum.pooled_upstreams += s.pooled_upstreams;
+      larger(&sum.degraded, s.degraded);
+      if (s.sidecar_up < sum.sidecar_up) sum.sidecar_up = s.sidecar_up;
+      larger(&sum.sidecar_epoch, s.sidecar_epoch);
+    }
+    return sum;
+  }
+
   static uint64_t now_ms() {
     timespec ts;
     clock_gettime(CLOCK_MONOTONIC, &ts);
@@ -2136,7 +2264,9 @@ class Server {
   // and the last line this plane writes once it has drained (the end of
   // the log is what a harness keeps, and the SIGTERM flight-recorder
   // dump pushes the event lines out of it).
-  std::string release_json() const {
+  std::string release_json() const { return release_json(release_, worker_); }
+
+  static std::string release_json(const Release& rel, int worker) {
     std::string out = "{";
     auto kv_u64 = [&out](const std::string& key, uint64_t v) {
       if (out.size() > 1) out += ", ";
@@ -2144,26 +2274,27 @@ class Server {
     };
     for (int i = 0; i < kRelCauses; ++i) {
       std::string cause = kReleaseCauseNames[i];
-      kv_u64("events_" + cause, release_.events[i]);
-      kv_u64("tickets_" + cause, release_.tickets[i]);
+      kv_u64("events_" + cause, rel.events[i]);
+      kv_u64("tickets_" + cause, rel.tickets[i]);
     }
     out += ", \"last_cause\": \"";
-    out += release_.last_at_ms ? kReleaseCauseNames[release_.last_cause] : "";
+    out += rel.last_at_ms ? kReleaseCauseNames[rel.last_cause] : "";
     out += "\"";
-    kv_u64("last_tickets", release_.last_tickets);
-    kv_u64("last_oldest_age_ms", release_.last_oldest_age_ms);
-    kv_u64("last_ring_depth", release_.last_ring_depth);
-    kv_u64("last_awaiting", release_.last_awaiting);
-    kv_u64("last_heartbeat_age_ms", release_.last_heartbeat_age_ms);
-    kv_u64("last_at_ms", release_.last_at_ms);
-    kv_u64("last_first_ticket", release_.last_first_ticket);
-    kv_u64("last_last_ticket", release_.last_last_ticket);
-    kv_u64("last_posted_floor", release_.last_posted_floor);
-    kv_u64("last_req_tail", release_.last_req_tail);
-    kv_u64("oldest_age_max_ms", release_.oldest_age_max_ms);
-    kv_u64("heartbeat_age_max_ms", release_.heartbeat_age_max_ms);
-    kv_u64("heartbeat_late", release_.heartbeat_late);
-    kv_u64("loop_gap_max_ms", release_.loop_gap_max_ms);
+    kv_u64("last_tickets", rel.last_tickets);
+    kv_u64("last_oldest_age_ms", rel.last_oldest_age_ms);
+    kv_u64("last_ring_depth", rel.last_ring_depth);
+    kv_u64("last_awaiting", rel.last_awaiting);
+    kv_u64("last_heartbeat_age_ms", rel.last_heartbeat_age_ms);
+    kv_u64("last_at_ms", rel.last_at_ms);
+    kv_u64("last_first_ticket", rel.last_first_ticket);
+    kv_u64("last_last_ticket", rel.last_last_ticket);
+    kv_u64("last_posted_floor", rel.last_posted_floor);
+    kv_u64("last_req_tail", rel.last_req_tail);
+    kv_u64("oldest_age_max_ms", rel.oldest_age_max_ms);
+    kv_u64("heartbeat_age_max_ms", rel.heartbeat_age_max_ms);
+    kv_u64("heartbeat_late", rel.heartbeat_late);
+    kv_u64("loop_gap_max_ms", rel.loop_gap_max_ms);
+    kv_u64("worker", static_cast<uint64_t>(worker));  // of the last_* event
     out += "}";
     return out;
   }
@@ -2175,11 +2306,11 @@ class Server {
   // under "ring", and the legacy 7-bucket "verdict_wait_ms_hist" folds
   // the new le1000 bucket into its "inf" key.
   std::string metrics_body() {
-    uint64_t tel[PINGOO_TELEMETRY_WORDS];
-    pingoo_ring_telemetry_snapshot(ring_, tel);
-    uint64_t ring_pending = tel[3];
-    size_t pooled = 0;
-    for (const auto& kv : upstream_pool_) pooled += kv.second.size();
+    std::vector<WorkerSlot> per;
+    int newest = 0;
+    const WorkerSlot sum = listener_totals(&per, &newest);
+    const Stats& st = sum.stats;  // the listener's, not this worker's
+    const uint64_t* tel = sum.tel;
     std::string out = "{";
     auto kv_u64 = [&out](const char* key, uint64_t v, bool first = false) {
       if (!first) out += ", ";
@@ -2188,15 +2319,15 @@ class Server {
       out += "\": ";
       out += std::to_string(v);
     };
-    kv_u64("requests", stats_.requests, true);
-    kv_u64("blocked", stats_.blocked);
-    kv_u64("captcha", stats_.captcha);
-    kv_u64("ua_rejected", stats_.ua_rejected);
-    kv_u64("fail_open", stats_.fail_open);
-    kv_u64("no_service", stats_.no_service);
-    kv_u64("upstream_fail", stats_.upstream_fail);
-    kv_u64("upstream_tls_fail", stats_.upstream_tls_fail);
-    kv_u64("verdicts", stats_.verdicts);
+    kv_u64("requests", st.requests, true);
+    kv_u64("blocked", st.blocked);
+    kv_u64("captcha", st.captcha);
+    kv_u64("ua_rejected", st.ua_rejected);
+    kv_u64("fail_open", st.fail_open);
+    kv_u64("no_service", st.no_service);
+    kv_u64("upstream_fail", st.upstream_fail);
+    kv_u64("upstream_tls_fail", st.upstream_tls_fail);
+    kv_u64("verdicts", st.verdicts);
     out += ", \"verdict_wait_ms_hist\": {";
     static const char* kHistKeys[6] = {"le1",  "le2",  "le5",
                                        "le10", "le50", "le100"};
@@ -2205,29 +2336,29 @@ class Server {
       out += "\"";
       out += kHistKeys[i];
       out += "\": ";
-      out += std::to_string(stats_.wait_hist[i]);
+      out += std::to_string(st.wait_hist[i]);
     }
     out += ", \"inf\": " +
-           std::to_string(stats_.wait_hist[6] + stats_.wait_hist[7]);
+           std::to_string(st.wait_hist[6] + st.wait_hist[7]);
     out += "}";
-    kv_u64("ring_pending", ring_pending);
-    kv_u64("awaiting", awaiting_.size());
-    kv_u64("connections", conns_.size());
-    kv_u64("pooled_upstreams", pooled);
-    kv_u64("degraded", degraded_ ? 1 : 0);
-    kv_u64("degraded_entered", stats_.degraded_entered);
-    kv_u64("sidecar_up", (sidecar_seen_ && !degraded_) ? 1 : 0);
-    kv_u64("sidecar_epoch", sidecar_epoch_);
+    kv_u64("ring_pending", tel[3]);
+    kv_u64("awaiting", sum.awaiting);
+    kv_u64("connections", sum.connections);
+    kv_u64("pooled_upstreams", sum.pooled_upstreams);
+    kv_u64("degraded", sum.degraded);
+    kv_u64("degraded_entered", st.degraded_entered);
+    kv_u64("sidecar_up", sum.sidecar_up);
+    kv_u64("sidecar_epoch", sum.sidecar_epoch);
     out += ", \"body\": {";
-    kv_u64("flows", stats_.body_flows, true);
-    kv_u64("windows", stats_.body_windows);
-    kv_u64("bytes", stats_.body_bytes);
-    kv_u64("verdicts", stats_.body_verdicts);
-    kv_u64("fail_open", stats_.body_fail_open);
-    kv_u64("h2_skipped", stats_.body_h2_skipped);
-    kv_u64("awaiting", body_awaiting_.size());
+    kv_u64("flows", st.body_flows, true);
+    kv_u64("windows", st.body_windows);
+    kv_u64("bytes", st.body_bytes);
+    kv_u64("verdicts", st.body_verdicts);
+    kv_u64("fail_open", st.body_fail_open);
+    kv_u64("h2_skipped", st.body_h2_skipped);
+    kv_u64("awaiting", sum.body_awaiting);
     out += "}";
-    out += ", \"release\": " + release_json();
+    out += ", \"release\": " + release_json(sum.release, newest);
     out += ", \"ring\": {";
     kv_u64("enqueued", tel[0], true);
     kv_u64("enqueue_full", tel[1]);
@@ -2237,17 +2368,45 @@ class Server {
     kv_u64("verdicts_posted", tel[5]);
     kv_u64("verdict_post_full", tel[6]);
     kv_u64("wait_sum_ms", tel[7]);
-    out += "}}";
+    out += "}";
+    // which worker answered, and each worker's own numbers: the totals
+    // above are their sums (depth_hwm, loop_gap_max_ms: their maxima)
+    kv_u64("workers", static_cast<uint64_t>(workers_));
+    kv_u64("answered_by", static_cast<uint64_t>(worker_));
+    out += ", \"";
+    out += "per_worker";  // apart: tools/check_metrics_schema.py greps the key
+    out += "\": [";
+    for (int w = 0; w < workers_; ++w) {
+      const WorkerSlot& s = per[w];
+      out += w ? ", {" : "{";
+      kv_u64("worker", static_cast<uint64_t>(w), true);
+      kv_u64("requests", s.stats.requests);
+      kv_u64("blocked", s.stats.blocked);
+      kv_u64("verdicts", s.stats.verdicts);
+      kv_u64("fail_open", s.stats.fail_open);
+      kv_u64("awaiting", s.awaiting);
+      kv_u64("connections", s.connections);
+      kv_u64("loop_gap_max_ms", s.release.loop_gap_max_ms);
+      out += ", \"ring\": {";
+      kv_u64("enqueued", s.tel[0], true);
+      kv_u64("depth", s.tel[3]);
+      kv_u64("depth_hwm", s.tel[4]);
+      kv_u64("wait_sum_ms", s.tel[7]);
+      out += "}}";
+    }
+    out += "]}";
     return out;
   }
 
   // Prometheus text exposition, metric names shared with the Python
   // plane (pingoo_tpu/obs/schema.py — the parity test's contract).
   std::string metrics_prometheus() {
-    uint64_t tel[PINGOO_TELEMETRY_WORDS];
-    pingoo_ring_telemetry_snapshot(ring_, tel);
-    size_t pooled = 0;
-    for (const auto& kv : upstream_pool_) pooled += kv.second.size();
+    std::vector<WorkerSlot> per;
+    int newest = 0;
+    const WorkerSlot sum = listener_totals(&per, &newest);
+    const Stats& st = sum.stats;  // the listener's, not this worker's
+    const Release& rel = sum.release;
+    const uint64_t* tel = sum.tel;
     const std::string plane = "{plane=\"native\"}";
     std::string out;
     auto metric = [&out, &plane](const char* type, const char* name,
@@ -2261,56 +2420,55 @@ class Server {
       out += plane;
       out += " " + std::to_string(v) + "\n";
     };
-    metric("counter", "pingoo_requests_total", stats_.requests);
-    metric("counter", "pingoo_blocked_total", stats_.blocked);
-    metric("counter", "pingoo_captcha_total", stats_.captcha);
-    metric("counter", "pingoo_fail_open_total", stats_.fail_open);
-    metric("counter", "pingoo_ua_rejected_total", stats_.ua_rejected);
-    metric("counter", "pingoo_no_service_total", stats_.no_service);
-    metric("counter", "pingoo_upstream_fail_total", stats_.upstream_fail);
+    metric("counter", "pingoo_requests_total", st.requests);
+    metric("counter", "pingoo_blocked_total", st.blocked);
+    metric("counter", "pingoo_captcha_total", st.captcha);
+    metric("counter", "pingoo_fail_open_total", st.fail_open);
+    metric("counter", "pingoo_ua_rejected_total", st.ua_rejected);
+    metric("counter", "pingoo_no_service_total", st.no_service);
+    metric("counter", "pingoo_upstream_fail_total", st.upstream_fail);
     metric("counter", "pingoo_upstream_tls_fail_total",
-           stats_.upstream_tls_fail);
-    metric("counter", "pingoo_verdicts_total", stats_.verdicts);
-    metric("gauge", "pingoo_connections", conns_.size());
-    metric("gauge", "pingoo_pooled_upstreams", pooled);
+           st.upstream_tls_fail);
+    metric("counter", "pingoo_verdicts_total", st.verdicts);
+    metric("gauge", "pingoo_connections", sum.connections);
+    metric("gauge", "pingoo_pooled_upstreams", sum.pooled_upstreams);
     // Sidecar supervision (ISSUE 10): sidecar_up stays 0 until a
     // heartbeat has ever landed, so "no sidecar yet" and "sidecar
     // died" alert the same way; epoch counts (re)attaches.
-    metric("gauge", "pingoo_sidecar_up",
-           (sidecar_seen_ && !degraded_) ? 1 : 0);
-    metric("gauge", "pingoo_degraded_mode", degraded_ ? 1 : 0);
-    metric("gauge", "pingoo_sidecar_epoch", sidecar_epoch_);
+    metric("gauge", "pingoo_sidecar_up", sum.sidecar_up);
+    metric("gauge", "pingoo_degraded_mode", sum.degraded);
+    metric("gauge", "pingoo_sidecar_epoch", sum.sidecar_epoch);
     metric("counter", "pingoo_degraded_entered_total",
-           stats_.degraded_entered);
+           st.degraded_entered);
     // Release witness (ISSUE 30): the fail-opens by cause; the tickets
     // add up to pingoo_fail_open_total.
     out += "# TYPE pingoo_release_events_total counter\n";
     for (int i = 0; i < kRelCauses; ++i)
       out += std::string("pingoo_release_events_total{plane=\"native\","
                          "cause=\"") + kReleaseCauseNames[i] + "\"} " +
-             std::to_string(release_.events[i]) + "\n";
+             std::to_string(rel.events[i]) + "\n";
     out += "# TYPE pingoo_released_total counter\n";
     for (int i = 0; i < kRelCauses; ++i)
       out += std::string("pingoo_released_total{plane=\"native\","
                          "cause=\"") + kReleaseCauseNames[i] + "\"} " +
-             std::to_string(release_.tickets[i]) + "\n";
+             std::to_string(rel.tickets[i]) + "\n";
     metric("gauge", "pingoo_sidecar_heartbeat_age_max_ms",
-           release_.heartbeat_age_max_ms);
+           rel.heartbeat_age_max_ms);
     metric("counter", "pingoo_sidecar_heartbeat_late_total",
-           release_.heartbeat_late);
+           rel.heartbeat_late);
     metric("gauge", "pingoo_native_loop_gap_max_ms",
-           release_.loop_gap_max_ms);
+           rel.loop_gap_max_ms);
     // Streaming body inspection (ISSUE 13, obs/schema.py BODY_METRICS;
     // the carry-depth histogram is scanner-side and lives on the
     // sidecar's exposition). Degrades carry the caller-side reasons.
-    metric("counter", "pingoo_body_windows_total", stats_.body_windows);
-    metric("counter", "pingoo_body_bytes_total", stats_.body_bytes);
-    metric("gauge", "pingoo_body_flows_active", body_awaiting_.size());
+    metric("counter", "pingoo_body_windows_total", st.body_windows);
+    metric("counter", "pingoo_body_bytes_total", st.body_bytes);
+    metric("gauge", "pingoo_body_flows_active", sum.body_awaiting);
     out += "# TYPE pingoo_body_degrade_total counter\n";
     out += "pingoo_body_degrade_total{plane=\"native\",reason=\"fail_open\"} " +
-           std::to_string(stats_.body_fail_open) + "\n";
+           std::to_string(st.body_fail_open) + "\n";
     out += "pingoo_body_degrade_total{plane=\"native\",reason=\"h2\"} " +
-           std::to_string(stats_.body_h2_skipped) + "\n";
+           std::to_string(st.body_h2_skipped) + "\n";
     metric("counter", "pingoo_ring_enqueued_total", tel[0]);
     metric("counter", "pingoo_ring_enqueue_full_total", tel[1]);
     metric("counter", "pingoo_ring_dequeued_total", tel[2]);
@@ -2323,9 +2481,9 @@ class Server {
     static const char* kLe[7] = {"1", "2", "5", "10", "50", "100", "1000"};
     out += "# TYPE pingoo_verdict_wait_ms histogram\n";
     uint64_t cum = 0, total = 0;
-    for (int i = 0; i < 8; ++i) total += stats_.wait_hist[i];
+    for (int i = 0; i < 8; ++i) total += st.wait_hist[i];
     for (int i = 0; i < 7; ++i) {
-      cum += stats_.wait_hist[i];
+      cum += st.wait_hist[i];
       out += "pingoo_verdict_wait_ms_bucket{plane=\"native\",le=\"";
       out += kLe[i];
       out += "\"} " + std::to_string(cum) + "\n";
@@ -2333,9 +2491,35 @@ class Server {
     out += "pingoo_verdict_wait_ms_bucket{plane=\"native\",le=\"+Inf\"} " +
            std::to_string(total) + "\n";
     out += "pingoo_verdict_wait_ms_sum" + plane + " " +
-           std::to_string(stats_.wait_sum_ms) + "\n";
+           std::to_string(st.wait_sum_ms) + "\n";
     out += "pingoo_verdict_wait_ms_count" + plane + " " +
            std::to_string(total) + "\n";
+    // Each worker's own numbers under names of their own (ISSUE 31):
+    // the series above are the listener's, so a sum over `plane` still
+    // counts a request once.
+    metric("gauge", "pingoo_native_workers", static_cast<uint64_t>(workers_));
+    metric("gauge", "pingoo_native_answered_by",
+           static_cast<uint64_t>(worker_));
+    auto by_worker = [&out, &per](const char* type, const char* name,
+                                  uint64_t (*get)(const WorkerSlot&)) {
+      out += std::string("# TYPE ") + name + " " + type + "\n";
+      for (size_t w = 0; w < per.size(); ++w)
+        out += std::string(name) + "{plane=\"native\",worker=\"" +
+               std::to_string(w) + "\"} " + std::to_string(get(per[w])) +
+               "\n";
+    };
+    by_worker("counter", "pingoo_worker_requests_total",
+              [](const WorkerSlot& s) { return s.stats.requests; });
+    by_worker("counter", "pingoo_worker_verdicts_total",
+              [](const WorkerSlot& s) { return s.stats.verdicts; });
+    by_worker("counter", "pingoo_worker_fail_open_total",
+              [](const WorkerSlot& s) { return s.stats.fail_open; });
+    by_worker("gauge", "pingoo_worker_ring_depth",
+              [](const WorkerSlot& s) { return s.tel[3]; });
+    by_worker("gauge", "pingoo_worker_ring_depth_hwm",
+              [](const WorkerSlot& s) { return s.tel[4]; });
+    by_worker("gauge", "pingoo_worker_loop_gap_max_ms",
+              [](const WorkerSlot& s) { return s.release.loop_gap_max_ms; });
     return out;
   }
 
@@ -2428,7 +2612,8 @@ class Server {
     uint64_t total = flight_next_;
     size_t live = total < kFlightN ? static_cast<size_t>(total) : kFlightN;
     uint64_t start = total - live;
-    std::string out = "{\"plane\": \"native\", \"capacity\": " +
+    std::string out = "{\"plane\": \"native\", \"answered_by\": " +
+                      std::to_string(worker_) + ", \"capacity\": " +
                       std::to_string(kFlightN) +
                       ", \"recorded_total\": " + std::to_string(total) +
                       ", \"entries\": [";
@@ -2476,7 +2661,8 @@ class Server {
     size_t live = total < kFlightN ? static_cast<size_t>(total) : kFlightN;
     uint64_t start = total - live;
     std::string out =
-        "{\"displayTimeUnit\": \"ms\", \"clock\": {\"unit\": "
+        "{\"displayTimeUnit\": \"ms\", \"answered_by\": " +
+        std::to_string(worker_) + ", \"clock\": {\"unit\": "
         "\"monotonic_us\", \"monotonic_now_us\": " +
         std::to_string(now_ms() * 1000) +
         ", \"wall_now_s\": " + std::to_string(::time(nullptr)) +
@@ -3545,6 +3731,10 @@ class Server {
     pingoo_ring_telemetry_snapshot(ring_, tel);
     uint64_t lv[5];
     pingoo_ring_liveness(ring_, lv);
+    // odd while last_* change: a sibling answering a scrape reads again
+    __atomic_store_n(&slot_->release_seq, slot_->release_seq + 1,
+                     __ATOMIC_RELAXED);
+    __atomic_thread_fence(__ATOMIC_RELEASE);
     r.last_cause = cause;
     r.last_tickets = tickets;
     r.last_oldest_age_ms = oldest_age_ms;
@@ -3557,6 +3747,8 @@ class Server {
     r.last_last_ticket = last_ticket;
     r.last_posted_floor = lv[2];
     r.last_req_tail = lv[3];
+    __atomic_store_n(&slot_->release_seq, slot_->release_seq + 1,
+                     __ATOMIC_RELEASE);
     if (now - r.log_window_ms >= 1000) {  // at most 8 lines a second
       r.log_window_ms = now;
       r.log_lines = 0;
@@ -3567,7 +3759,7 @@ class Server {
                  "(cause %s, oldest %llu ms, tickets %llu..%llu, sidecar "
                  "posted below %llu and dequeued below %llu, ring depth "
                  "%llu, awaiting %zu, heartbeat %llu ms old, loop gap max "
-                 "%llu ms, at %llu ms)\n",
+                 "%llu ms, at %llu ms, worker %d of %d)\n",
                  static_cast<unsigned long long>(tickets),
                  kReleaseCauseNames[cause],
                  static_cast<unsigned long long>(oldest_age_ms),
@@ -3578,7 +3770,7 @@ class Server {
                  static_cast<unsigned long long>(tel[3]), awaiting_.size(),
                  static_cast<unsigned long long>(r.last_heartbeat_age_ms),
                  static_cast<unsigned long long>(r.loop_gap_max_ms),
-                 static_cast<unsigned long long>(now));
+                 static_cast<unsigned long long>(now), worker_, workers_);
   }
 
   // A request that never got a ticket (run_policy said kFailOpenProxy).
@@ -5711,7 +5903,14 @@ class Server {
   std::vector<std::pair<Conn*, int32_t>> ssl_resume_;
   uint32_t rng_ = 0x9e3779b9;  // xorshift32 state for upstream choice
   std::unordered_map<uint64_t, std::vector<PooledUpstream>> upstream_pool_;
-  Stats stats_;
+  // The listener's worker slots (WorkerSlot above): this worker writes
+  // slots_[worker_], reads them all when it answers a scrape.
+  WorkerSlot own_slot_;  // the block when no --worker-stats-fd was given
+  WorkerSlot* slots_;
+  int workers_;
+  int worker_;
+  WorkerSlot* slot_;
+  Stats& stats_;
   std::unordered_set<Conn*> conns_;
   struct Awaiting {
     Conn* conn;
@@ -5727,7 +5926,7 @@ class Server {
   bool sidecar_seen_ = false;    // a sidecar heartbeat has ever landed
   uint64_t sidecar_epoch_ = 0;   // last epoch read from the ring header
   uint64_t last_deadline_sweep_ms_ = 0;
-  Release release_;              // the release witness (ISSUE 30)
+  Release& release_;             // the release witness (ISSUE 30)
   std::vector<uint64_t> expired_;  // sweep_verdict_deadlines scratch
   std::vector<SockRef*> doomed_refs_;  // per-stream refs freed after the batch
   std::unordered_map<SSL*, Conn*> ssl_conn_;
@@ -5838,7 +6037,8 @@ int main(int argc, char** argv) {
                  "<upstream-port> [--captcha-upstream host:port] "
                  "[--jwks path] [--tls-dir dir] [--alpn-dir dir] "
                  "[--services path] [--bind addr] [--upstream-ca pem] "
-                 "[--internal-token-file path] [--tcp-proxy]\n",
+                 "[--internal-token-file path] [--tcp-proxy] "
+                 "[--worker-stats-fd fd --workers N --worker i]\n",
                  argv[0]);
     return 2;
   }
@@ -5855,6 +6055,8 @@ int main(int argc, char** argv) {
   const char* bind_addr = nullptr;
   const char* upstream_ca = nullptr;
   const char* internal_token_file = nullptr;
+  int worker_stats_fd = -1;
+  int workers = 1, worker = 0;
   bool tcp_mode = false;
   sockaddr_in captcha_upstream{};
   bool has_captcha = false;
@@ -5885,6 +6087,34 @@ int main(int argc, char** argv) {
       upstream_ca = argv[i + 1];
     } else if (strcmp(argv[i], "--internal-token-file") == 0) {
       internal_token_file = argv[i + 1];
+    } else if (strcmp(argv[i], "--worker-stats-fd") == 0) {
+      worker_stats_fd = std::atoi(argv[i + 1]);
+    } else if (strcmp(argv[i], "--workers") == 0) {
+      workers = std::atoi(argv[i + 1]);
+    } else if (strcmp(argv[i], "--worker") == 0) {
+      worker = std::atoi(argv[i + 1]);
+    }
+  }
+  // The listener's shared counter block (Server::WorkerSlot): every
+  // worker sizes it alike and writes only its own slot.
+  void* worker_block = nullptr;
+  if (worker_stats_fd >= 0) {
+    if (workers < 1 || worker < 0 || worker >= workers) {
+      std::fprintf(stderr, "bad --worker %d of --workers %d\n", worker,
+                   workers);
+      return 2;
+    }
+    size_t bytes = Server::worker_block_bytes(workers);
+    if (ftruncate(worker_stats_fd, static_cast<off_t>(bytes)) != 0) {
+      std::perror("ftruncate --worker-stats-fd");
+      return 1;
+    }
+    worker_block = mmap(nullptr, bytes, PROT_READ | PROT_WRITE, MAP_SHARED,
+                        worker_stats_fd, 0);
+    close(worker_stats_fd);
+    if (worker_block == MAP_FAILED) {
+      std::perror("mmap --worker-stats-fd");
+      return 1;
     }
   }
   // Per-boot token authenticating this proxy to the loopback control
@@ -6025,7 +6255,7 @@ int main(int argc, char** argv) {
   Server server(ep, ring, upstream, has_captcha ? &captcha_upstream : nullptr,
                 &gate, tls_dir ? &tls_store : nullptr,
                 services_path ? &services : nullptr, up_ctx,
-                internal_token, tcp_mode);
+                internal_token, tcp_mode, worker_block, workers, worker);
   g_server = &server;
   // SIGTERM starts a graceful drain: stop accepting, finish in-flight
   // requests, exit when idle or after the 20 s cap (the reference's
@@ -6033,9 +6263,11 @@ int main(int argc, char** argv) {
   struct sigaction sa {};
   sa.sa_handler = [](int) { g_sigterm = 1; };
   sigaction(SIGTERM, &sa, nullptr);
-  std::printf("{\"listening\": %d, \"tls\": %s, \"services\": %s}\n",
+  std::printf("{\"listening\": %d, \"tls\": %s, \"services\": %s, "
+              "\"worker\": %d, \"workers\": %d}\n",
               listen_port, tls_dir ? "true" : "false",
-              services_path ? "true" : "false");
+              services_path ? "true" : "false", worker_block ? worker : 0,
+              worker_block ? workers : 1);
   std::fflush(stdout);
 
   constexpr time_t kDrainCapS = 20;
@@ -6059,6 +6291,7 @@ int main(int argc, char** argv) {
     // detection window, not a seconds-long stall.
     server.check_sidecar_liveness();
     server.sweep_verdict_deadlines();
+    server.publish_slot();  // once a ms: what a sibling's scrape reads
 
     if (g_sigterm && !draining) {
       draining = true;

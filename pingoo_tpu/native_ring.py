@@ -260,6 +260,7 @@ class Ring:
             # capacity would silently alias slots and corrupt the queue.
             raise ValueError(f"ring capacity must be a power of two, got {capacity}")
         self.lib = _load_lib()
+        self.path = path
         self.capacity = capacity
         nbytes = self.lib.pingoo_ring_bytes(capacity)
         flags = os.O_RDWR | (os.O_CREAT if create else 0)
@@ -758,6 +759,27 @@ class RingSidecar:
         from .obs import REGISTRY
 
         self._registry = REGISTRY
+        # The rings by name (ISSUE 31; the file's name, which says the
+        # listener and the worker): rows dequeued from each, how many
+        # rings gave rows to each batch, and each ring's depth as the
+        # loop last read it (once a launch: `_ring_depths`).
+        from .obs.schema import SIDECAR_RING_METRICS
+
+        names = [os.path.basename(r.path) for r in self.rings]
+        if len(set(names)) < len(names):
+            names = [str(i) for i in range(len(names))]
+        self.ring_names = names
+        self._ring_rows = [
+            REGISTRY.counter(
+                "pingoo_ring_rows_total",
+                SIDECAR_RING_METRICS["pingoo_ring_rows_total"],
+                labels={"plane": "sidecar", "ring": name})
+            for name in names]
+        self._batch_rings = REGISTRY.counter(
+            "pingoo_batch_rings_total",
+            SIDECAR_RING_METRICS["pingoo_batch_rings_total"],
+            labels={"plane": "sidecar"})
+        self._ring_depth_seen = [0] * len(names)
         # Pipeline executor substrate (ISSUE 9): the staging encoder's
         # rotating buffer sets must outlive every in-flight batch that
         # still reads its views (depth in flight + the one being
@@ -828,7 +850,7 @@ class RingSidecar:
         # `sidecar/<phase>` annotations — the profiler's trace. Only the
         # `sched` label is observed directly: it is an age, not a span.
         self._pipe.attach_loop(self._stage, self.sched.observe_stage_cost,
-                               self.max_batch, self._queued_depth)
+                               self.max_batch, self._ring_depths)
         # Compact staging (ISSUE 15): bytes staged to the device per
         # verdict batch, by PINGOO_STAGING arm — same series the Python
         # listener plane exports.
@@ -1392,25 +1414,33 @@ class RingSidecar:
                 pend_parts, pend_n, oldest_enq_ms, pend_buf = \
                     self._apply_swaps(inflight, pend_parts, pend_n,
                                       oldest_enq_ms, pend_buf)
-            # One merged dequeue pass across all worker rings. The
-            # start index rotates so a saturated ring cannot monopolize
-            # the budget and starve its siblings into the data plane's
-            # verdict timeout (which fails open).
+            # One merged dequeue pass across all worker rings, from a
+            # rotating start. Each ring first gets a fair share of the
+            # budget (what a shallow ring leaves of its share passes on
+            # to the next), so a ring deeper than a whole batch keeps no
+            # sibling's rows out of this one, let alone starves them
+            # into the data plane's verdict timeout (which fails open);
+            # a ring that filled its share gets a second turn at what
+            # is left once every ring has had its first.
             budget = self.max_batch - pend_n
             nrings = len(self.rings)
             self._ring_rr = (self._ring_rr + 1) % nrings
             got = 0
-            for i in range(nrings):
-                if budget <= 0:
-                    break
-                r = self.rings[(self._ring_rr + i) % nrings]
+            turns = [((self._ring_rr + i) % nrings, nrings - i)
+                     for i in range(nrings)]  # (ring, rings still to share)
+            while turns and budget > 0:
+                ri, sharers = turns.pop(0)
+                r = self.rings[ri]
+                share = -(-budget // sharers)
                 if pend_buf is not None:
                     fill = pend_n + got
                     k = r.dequeue_batch_into(
-                        pend_buf[fill:fill + budget])
+                        pend_buf[fill:fill + share])
                     s = pend_buf[fill:fill + k]
                 else:
-                    s = r.dequeue_batch(budget)
+                    s = r.dequeue_batch(share)
+                if len(s) == share and sharers > 1:
+                    turns.append((ri, 1))
                 if len(s):
                     if self.geoip is not None:
                         # Enrich IN the per-ring slot arrays (the
@@ -1423,6 +1453,7 @@ class RingSidecar:
                         # part) must see the same geo values.
                         self._enrich_slots(s)
                     pend_parts.append((r, s))
+                    self._ring_rows[ri].inc(len(s))
                     budget -= len(s)
                     got += len(s)
                     first = int(s["enq_ms"].min())
@@ -1571,17 +1602,26 @@ class RingSidecar:
         except IndexError:
             return np.zeros(self.max_batch, dtype=REQUEST_SLOT_DTYPE)
 
-    def _queued_depth(self) -> int:
-        """Requests still waiting across this sidecar's rings (the
-        pingoo_sched_queue_depth gauge; one telemetry snapshot per ring
-        per LAUNCH, not per request)."""
-        total = 0
-        for r in self.rings:
+    def _ring_depths(self) -> dict:
+        """Requests still waiting in each of this sidecar's rings, by
+        ring name (one telemetry snapshot per ring per LAUNCH, not per
+        request); `stats()` shows what this last read."""
+        for i, r in enumerate(self.rings):
             try:
-                total += int(r.telemetry()["depth"])
+                self._ring_depth_seen[i] = int(r.telemetry()["depth"])
             except Exception:
                 pass
-        return total
+        return dict(zip(self.ring_names, self._ring_depth_seen))
+
+    def _queued_depth(self) -> int:
+        """The pingoo_sched_queue_depth gauge: all rings together."""
+        return sum(self._ring_depths().values())
+
+    def _begin_batch(self, parts, n: int):
+        """The batch's span record; counts the rings that gave it rows."""
+        rings = len({id(r) for r, _ in parts})
+        self._batch_rings.inc(rings)
+        return self._pipe.begin(self.pipeline_mode, n, rings)
 
     def _dispatch(self, parts, n: int, oldest_enq_ms: Optional[int],
                   slot_buf=None):
@@ -1589,7 +1629,7 @@ class RingSidecar:
         returns the in-flight tuple `_complete` consumes."""
         from .engine.batch import RequestBatch, bucket_arrays, pad_batch
 
-        rec = self._pipe.begin(self.pipeline_mode, n)
+        rec = self._begin_batch(parts, n)
         with self._pipe.stage("encode", rec) as sp:
             self.chaos.stage("encode")
             batch = raw = None
@@ -1850,7 +1890,7 @@ class RingSidecar:
         buffer set is checked out again, nbuf-1 windows later)."""
         from .engine.batch import RequestBatch, bucket_arrays, pad_batch
 
-        spans = self._pipe.begin(self.pipeline_mode, n)
+        spans = self._begin_batch(parts, n)
         with self._pipe.stage("encode", spans):
             self.chaos.stage("encode")
             batch = None
@@ -2717,6 +2757,10 @@ class RingSidecar:
             "truncated_rows": self.truncated_rows,
             "spilled_rows": self.spilled_rows,
             "rings": len(self.rings),
+            "ring_depth": dict(zip(self.ring_names, self._ring_depth_seen)),
+            "ring_rows": {name: c.value for name, c in
+                          zip(self.ring_names, self._ring_rows)},
+            "batch_rings": self._batch_rings.value,
             "ring_telemetry": self.ring_telemetry(),
             "sched": self.sched.snapshot(),
             "mesh": self.mesh.describe(),

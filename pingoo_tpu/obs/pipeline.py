@@ -25,7 +25,7 @@ family on its plane label:
 The ring sidecar also records its drain loop's stage boundaries here,
 and nowhere else (docs/OBSERVABILITY.md "Spans and scopes"):
 `stage(name, rec)` is a context manager that enters a
-`jax.profiler.TraceAnnotation("sidecar/<phase>", batch=, rows=)` — so
+`jax.profiler.TraceAnnotation("sidecar/<phase>", batch=, rows=, rings=)` — so
 the span lands in the profiler's own file, on the device trace's clock —
 and on exit fans ONE pair of `time.monotonic()` stamps out to every
 sink: the `pingoo_verdict_stage_ms` histogram, the occupancy/overlap
@@ -94,13 +94,16 @@ class BatchSpans:
     `seq` is the loop's batch counter (the pipeline slot id, and the
     `batch` stat of every span the batch causes); `points` maps a phase
     to its (t_start, t_end) in time.monotonic() seconds; `k` is how many
-    batches share the device wait (a megastep window's slices)."""
+    batches share the device wait (a megastep window's slices); `rings`
+    is how many of the sidecar's rings gave the batch rows."""
 
-    __slots__ = ("seq", "rows", "k", "points", "tags", "compute_ms")
+    __slots__ = ("seq", "rows", "rings", "k", "points", "tags",
+                 "compute_ms")
 
-    def __init__(self, seq: int, rows: int):
+    def __init__(self, seq: int, rows: int, rings: int = 1):
         self.seq = seq
         self.rows = rows
+        self.rings = rings
         self.k = 1
         self.points: dict[str, tuple] = {}
         self.tags: dict = {}
@@ -303,7 +306,8 @@ class PipelineStats:
         """Wire the sinks a stage boundary fans out to: `stage_hist`
         {stage label: pingoo_verdict_stage_ms histogram}, `observe_cost`
         (the scheduler's observe_stage_cost) at `cost_size` rows, and
-        `depth_fn` (requests still queued, for the stall line)."""
+        `depth_fn` (requests still queued, by ring, for the stall
+        line)."""
         from jax.profiler import TraceAnnotation
 
         from . import schema
@@ -338,6 +342,7 @@ class PipelineStats:
         self._stack.clear()
         self._base = "poll"
         self._t_flush = now
+        self.loop_stamps = [now, None]  # the account's first and last
         self._open("poll", None, now)
 
     def loop_stop(self) -> None:
@@ -346,10 +351,11 @@ class PipelineStats:
             self._close(now)
             self._cur = None
             self._flush(now)
+            self.loop_stamps[1] = now
 
-    def begin(self, mode: str, rows: int) -> BatchSpans:
+    def begin(self, mode: str, rows: int, rings: int = 1) -> BatchSpans:
         """enter() for the drain loop: the batch's span record."""
-        return BatchSpans(self.enter(mode), rows)
+        return BatchSpans(self.enter(mode), rows, rings)
 
     def finish(self) -> None:
         """exit() for the drain loop; the phase account reaches the
@@ -391,7 +397,7 @@ class PipelineStats:
             ann = self._annotate(self._span_names[name])
         else:
             ann = self._annotate(self._span_names[name], batch=rec.seq,
-                                 rows=rec.rows)
+                                 rows=rec.rows, rings=rec.rings)
         ann.__enter__()
         self._cur, self._cur_rec, self._cur_t0, self._cur_ann = \
             name, rec, t, ann
@@ -472,10 +478,12 @@ class PipelineStats:
                 schema.PIPELINE_METRICS["pingoo_sidecar_stall_total"],
                 labels={"plane": self.plane, "phase": name})
         ctr.inc()
+        depths = self._depth_fn() if self._depth_fn else None
         _log.warning("drain loop stalled", extra={"fields": {
             "phase": name, "ms": round(ms, 1),
             "batch": rec.seq if rec is not None else None,
-            "ring_depth": self._depth_fn() if self._depth_fn else None}})
+            "ring_depth": sum(depths.values()) if depths else depths,
+            "ring_depths": depths}})
 
     def snapshot(self) -> dict:
         wall = max(time.monotonic() - self._t_boot, 1e-9)

@@ -204,6 +204,18 @@ RING_METRICS = {
     "pingoo_ring_depth_hwm": "high-water mark of queued request slots",
 }
 
+# The drain loop names its rings (ISSUE 31): one ring a native worker,
+# all of them drained by one sidecar in a merged pass. Sidecar-only.
+SIDECAR_RING_METRICS = {
+    "pingoo_ring_rows_total":
+        "sidecar counter {ring=<ring file's name>}: request rows the "
+        "drain loop dequeued from each of its rings",
+    "pingoo_batch_rings_total":
+        "sidecar counter: over the batches launched, how many rings "
+        "gave rows to each (over pingoo_pipeline_batches_total: the "
+        "mean rings a batch)",
+}
+
 # Sidecar supervision + degradation-ladder metrics (ISSUE 10,
 # docs/RESILIENCE.md). The liveness trio (sidecar_up / degraded_mode /
 # sidecar_epoch) is exported by BOTH planes from the same ring-header
@@ -380,6 +392,21 @@ NATIVE_METRICS = {
     "pingoo_verdicts_total": "verdict bytes applied",
     "pingoo_connections": "open client connections",
     "pingoo_pooled_upstreams": "idle pooled upstream connections",
+    # One counter surface for N workers (ISSUE 31): every native series
+    # above is the LISTENER's (the sum, or the maximum for a high-water
+    # mark, over its --native-workers processes, whichever of them
+    # answers the scrape); these carry each worker's own share.
+    "pingoo_native_workers": "httpd worker processes on this listener",
+    "pingoo_native_answered_by": "index of the worker that answered",
+    "pingoo_worker_requests_total": "{worker}: requests it parsed",
+    "pingoo_worker_verdicts_total": "{worker}: verdict bytes it applied",
+    "pingoo_worker_fail_open_total":
+        "{worker}: requests it proxied uninspected",
+    "pingoo_worker_ring_depth": "{worker}: its ring's queued slots",
+    "pingoo_worker_ring_depth_hwm":
+        "{worker}: its ring's high-water mark",
+    "pingoo_worker_loop_gap_max_ms":
+        "{worker}: its event loop's longest pass-to-pass gap",
 }
 
 # JSON back-compat keys (the pre-registry schemas, still served under
@@ -390,6 +417,9 @@ PYTHON_JSON_KEYS = {
     "captcha_served": "pingoo_captcha_total",
 }
 NATIVE_JSON_KEYS = {
+    "workers": "pingoo_native_workers",
+    "answered_by": "pingoo_native_answered_by",
+    "per_worker": "pingoo_worker_requests_total",
     "requests": "pingoo_requests_total",
     "blocked": "pingoo_blocked_total",
     "captcha": "pingoo_captcha_total",
@@ -400,6 +430,7 @@ NATIVE_JSON_KEYS = {
 
 def all_metric_names() -> set[str]:
     return (set(SHARED_METRICS) | set(RING_METRICS) | set(NATIVE_METRICS)
+            | set(SIDECAR_RING_METRICS)
             | set(PREFILTER_METRICS) | set(DFA_METRICS)
             | set(PROVENANCE_METRICS)
             | set(PARITY_METRICS) | set(SCHED_METRICS)

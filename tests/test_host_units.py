@@ -265,6 +265,20 @@ class TestBackendSelection:
         assert "bogus" in proc.stderr  # JAX's own message, verbatim
         assert "starting pingoo-tpu" not in proc.stderr
 
+    def test_a_required_capability_the_build_lacks_refuses_the_start(
+            self, capsys):
+        """`--require CAPABILITY` (ISSUE 31): a deployment's file names
+        what it needs of the build; one without it refuses the command
+        line (exit 2, no boot line) instead of serving without it, as a
+        build older than the flag refuses the flag."""
+        from pingoo_tpu.__main__ import CAPABILITIES, main
+
+        assert "listener-metrics" in CAPABILITIES
+        with pytest.raises(SystemExit) as exc:
+            main(["--require", "no-such-capability"])
+        assert exc.value.code == 2
+        assert "no-such-capability" in capsys.readouterr().err
+
     def test_no_device_pins_cpu_and_still_boots(self, tmp_path):
         """--no-device beats even an unusable ambient platform, states
         what it serves on in the boot line, and drains to exit 0."""
@@ -274,7 +288,8 @@ class TestBackendSelection:
 
         env = dict(os.environ, JAX_PLATFORMS="bogus",
                    JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
-        argv, port = self._argv(tmp_path, "--no-device")
+        argv, port = self._argv(tmp_path, "--no-device",
+                                "--require", "listener-metrics")
         proc = subprocess.Popen(argv, cwd=self.REPO, env=env,
                                 stderr=subprocess.PIPE, text=True)
         try:
@@ -288,6 +303,7 @@ class TestBackendSelection:
             assert boot["platform"] == "cpu" and boot["device"] is False
             assert boot["device_count"] >= 1 and boot["device_kind"]
             assert boot["compile_cache"] == str(tmp_path / "cache")
+            assert "listener-metrics" in boot["capabilities"]
             deadline = time.monotonic() + 60
             while True:  # listening == the signal handlers are in place
                 try:

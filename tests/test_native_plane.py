@@ -61,52 +61,83 @@ class _Upstream(http.server.BaseHTTPRequestHandler):
 
 
 class NativeStack:
-    """native httpd + ring sidecar + plain upstream (+ optional extras)."""
+    """native httpd + ring sidecar + plain upstream (+ optional extras).
+    With `workers` > 1: that many httpd processes on the one port, a
+    ring each, one sidecar over all the rings and one shared counter
+    block, as host/native_plane.py starts them (--native-workers)."""
 
     def __init__(self, tmp, rules, lists=None, jwks=None, captcha_port=None,
                  tls_dir=None, alpn_dir=None, routes=None, services=None,
-                 upstream_ca=None):
+                 upstream_ca=None, workers=1, env=None, max_batch=64):
         from pingoo_tpu.compiler import compile_ruleset
 
-        self.upstream = http.server.HTTPServer(("127.0.0.1", 0), _Upstream)
+        # several workers keep several upstream connections alive at once
+        server = http.server.ThreadingHTTPServer if workers > 1 \
+            else http.server.HTTPServer
+        self.upstream = server(("127.0.0.1", 0), _Upstream)
         threading.Thread(target=self.upstream.serve_forever,
                          daemon=True).start()
-        plan = compile_ruleset(rules, lists or {}, routes=routes)
+        self.plan = compile_ruleset(rules, lists or {}, routes=routes)
         self.ring_path = str(tmp / "ring")
-        self.ring = Ring(self.ring_path, capacity=1024, create=True)
+        self.ring_paths = [self.ring_path] + [
+            str(tmp / f"ring_{w}") for w in range(1, workers)]
+        self.rings = [Ring(path, capacity=1024, create=True)
+                      for path in self.ring_paths]
+        self.ring = self.rings[0]
         self.sidecar = RingSidecar(
-            self.ring, plan, lists or {}, max_batch=64,
+            self.rings if workers > 1 else self.ring, self.plan,
+            lists or {}, max_batch=max_batch,
             services=[name for name, _ in routes] if routes else None)
         threading.Thread(target=self.sidecar.run, daemon=True).start()
         self.port = _free_port()
-        argv = [HTTPD, str(self.port), self.ring_path, "127.0.0.1",
-                str(self.upstream.server_address[1])]
-        if jwks:
-            argv += ["--jwks", jwks]
-        if captcha_port:
-            argv += ["--captcha-upstream", f"127.0.0.1:{captcha_port}"]
-        if tls_dir:
-            argv += ["--tls-dir", tls_dir]
-        if alpn_dir:
-            argv += ["--alpn-dir", alpn_dir]
         self.services_path = None
         if services is not None:
             self.services_path = str(tmp / "services.tbl")
             native_ring.write_services_file(self.services_path, services)
-            argv += ["--services", self.services_path]
-        if upstream_ca:
-            argv += ["--upstream-ca", upstream_ca]
-        self.proc = subprocess.Popen(argv, stdout=subprocess.PIPE,
-                                     stderr=subprocess.PIPE)
-        line = self.proc.stdout.readline()
-        assert b"listening" in line, line
+        self.procs = []
+        self.stats_fd = os.memfd_create("workers")  # the counter block
+        for w, ring_path in enumerate(self.ring_paths):
+            argv = [HTTPD, str(self.port), ring_path, "127.0.0.1",
+                    str(self.upstream.server_address[1])]
+            if jwks:
+                argv += ["--jwks", jwks]
+            if captcha_port:
+                argv += ["--captcha-upstream", f"127.0.0.1:{captcha_port}"]
+            if tls_dir:
+                argv += ["--tls-dir", tls_dir]
+            if alpn_dir:
+                argv += ["--alpn-dir", alpn_dir]
+            if self.services_path:
+                argv += ["--services", self.services_path]
+            if upstream_ca:
+                argv += ["--upstream-ca", upstream_ca]
+            stderr = subprocess.PIPE
+            if workers > 1:
+                argv += ["--worker-stats-fd", str(self.stats_fd),
+                         "--workers", str(workers), "--worker", str(w)]
+                # a file: nobody reads N pipes, and a full one blocks
+                stderr = open(tmp / f"httpd_{w}.err", "wb")
+            proc = subprocess.Popen(
+                argv, stdout=subprocess.PIPE, stderr=stderr,
+                pass_fds=(self.stats_fd,),
+                env=None if env is None else dict(os.environ, **env))
+            if stderr is not subprocess.PIPE:
+                stderr.close()
+            line = proc.stdout.readline()
+            assert b"listening" in line, line
+            self.procs.append(proc)
+        self.proc = self.procs[0]
 
     def stop(self):
-        self.proc.kill()
-        self.proc.wait()
+        # self.proc, not procs[0]: some tests put their own httpd there
+        for proc in [self.proc] + self.procs[1:]:
+            proc.kill()
+            proc.wait()
         self.upstream.shutdown()
         self.sidecar.stop()
-        self.ring.close()
+        for ring in self.rings:
+            ring.close()
+        os.close(self.stats_fd)
 
 
 def recv_one_response(c):

@@ -62,8 +62,9 @@ def main() -> int:
                 f"host/httpd.py: missing legacy JSON key {key!r}")
 
     sidecar_src = _read("pingoo_tpu/native_ring.py")
-    for name in schema.RING_METRICS:
-        if name not in sidecar_src:
+    for name in list(schema.RING_METRICS) + list(
+            schema.SIDECAR_RING_METRICS):   # the second: the drain loop's
+        if name not in sidecar_src:         # rings by name (ISSUE 31)
             problems.append(f"native_ring.py: missing metric {name}")
 
     service_src = _read("pingoo_tpu/engine/service.py")
