@@ -29,7 +29,7 @@ from ..compiler.lowering import DEFAULT_FIELD_SPECS, NfaPred
 from ..compiler.plan import quantize_stage_cap
 from ..expr import Context, Ip
 from ..ops.cidr import ip_to_words
-from ..ops.live_columns import walked_columns
+from ..ops.live_columns import walked_columns, walked_rows
 
 STRING_FIELDS = ("host", "url", "path", "method", "user_agent", "country")
 
@@ -221,31 +221,54 @@ def scan_columns(arrays: Mapping[str, np.ndarray],
     return out
 
 
-class ScanColumnCounters:
-    """`pingoo_scan_columns_total{plane, field, kind}` (obs/schema.py):
-    per batch and field some contains/regex rule of the plan scans,
-    kind="staged" counts the field's staged width and kind="walked"
-    the columns the dfa/* and pf/* byte loops walk for that batch."""
+def scan_rows(arrays: Mapping[str, np.ndarray], fields: Iterable[str],
+              sharded: bool = False) -> dict[str, tuple[int, int]]:
+    """{field: (staged, walked)} rows of an encoded batch: the rows the
+    batch is padded to, and those the device's byte loops walk for the
+    field (ops/live_columns: the row tiles up to the last row with a
+    byte; every row of a batch `sharded` over a mesh)."""
+    out = {}
+    for field in fields:
+        rows = arrays[f"{field}_bytes"].shape[0]
+        out[field] = (rows, walked_rows(arrays[f"{field}_len"], rows,
+                                        sharded))
+    return out
 
-    def __init__(self, plane: str, plan):
+
+class ScanColumnCounters:
+    """`pingoo_scan_columns_total{plane, field, kind}` and
+    `pingoo_scan_rows_total{plane, field, kind}` (obs/schema.py): per
+    batch and field some contains/regex rule of the plan scans,
+    kind="staged" counts the field's staged width (the padded batch's
+    rows) and kind="walked" the columns (rows) the dfa/* and pf/* byte
+    loops walk for that batch. `rows_sharded`: the plane's mesh shards
+    batches (dp > 1), where the loops walk every row."""
+
+    def __init__(self, plane: str, plan, rows_sharded: bool = False):
         from ..obs import REGISTRY
         from ..obs.schema import STAGING_METRICS
 
         self.fields = tuple(sorted(
             {leaf.field for leaf in plan.leaves
              if isinstance(leaf, NfaPred)}))
+        self.rows_sharded = rows_sharded
         self._counters = {
-            (field, kind): REGISTRY.counter(
-                "pingoo_scan_columns_total",
-                STAGING_METRICS["pingoo_scan_columns_total"],
+            (name, field, kind): REGISTRY.counter(
+                name, STAGING_METRICS[name],
                 labels={"plane": plane, "field": field, "kind": kind})
+            for name in ("pingoo_scan_columns_total",
+                         "pingoo_scan_rows_total")
             for field in self.fields for kind in ("staged", "walked")}
 
     def note(self, arrays: Mapping[str, np.ndarray]) -> None:
-        for field, (staged, walked) in scan_columns(
-                arrays, self.fields).items():
-            self._counters[field, "staged"].inc(staged)
-            self._counters[field, "walked"].inc(walked)
+        for name, extents in (
+                ("pingoo_scan_columns_total",
+                 scan_columns(arrays, self.fields)),
+                ("pingoo_scan_rows_total",
+                 scan_rows(arrays, self.fields, self.rows_sharded))):
+            for field, (staged, walked) in extents.items():
+                self._counters[name, field, "staged"].inc(staged)
+                self._counters[name, field, "walked"].inc(walked)
 
 
 # -- Compact staging (ISSUE 15, docs/EXECUTOR.md "Compact staging") ----------

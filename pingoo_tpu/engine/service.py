@@ -464,7 +464,6 @@ class VerdictService:
             # slices; built only under PINGOO_STAGING=compact, so the
             # default path compiles nothing new.
             state["stage_caps"] = resolve_stage_caps(plan)
-            state["scan_columns"] = ScanColumnCounters("python", plan)
             state["packed_verdict_fn"] = None
             state["packed_pf_fn"] = None
             if state["stage_caps"] is not None:
@@ -485,6 +484,8 @@ class VerdictService:
                 tables = jax.device_put(tables, device)
             state["mesh"] = mesh
             state["tables"] = tables
+            state["scan_columns"] = ScanColumnCounters(
+                "python", plan, rows_sharded=mesh.dp > 1)
             state["staging"] = (self._make_staging(plan)
                                 if self.pipeline_mode == "on" else None)
             # Megastep window program (ISSUE 12): built only when

@@ -1237,7 +1237,8 @@ class RingSidecar:
         # the fields this plan's byte loops scan (ops/live_columns.py).
         from .engine.batch import ScanColumnCounters
 
-        self._scan_columns = ScanColumnCounters("sidecar", plan)
+        self._scan_columns = ScanColumnCounters(
+            "sidecar", plan, rows_sharded=self.mesh.dp > 1)
         self._plan_state = state
         if self._provenance_on:
             from .obs.flightrecorder import (FlightRecorder,

@@ -341,6 +341,12 @@ STAGING_METRICS = {
         "byte columns per batch and scanned field, by kind (staged = "
         "the field's staged width, walked = the columns the dfa/pf "
         "byte loops walk: the batch's longest row, rounded up to 8)",
+    # Live-row walk (ISSUE 32): the same loops' row axis.
+    "pingoo_scan_rows_total":
+        "batch rows per batch and scanned field, by kind (staged = the "
+        "padded batch's rows, walked = the rows the dfa/pf byte loops "
+        "walk: the row tiles up to the last row that has a byte; every "
+        "row on a mesh that shards batches)",
 }
 
 # Perf ledger + cross-plane timeline + durable cost ledger (ISSUE 17,

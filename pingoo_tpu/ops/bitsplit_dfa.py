@@ -17,7 +17,11 @@ ops/prefilter.py's structure:
                         batch's longest row, and the byte -> class
                         gather runs per block inside it
                         (`pingoo_scan_columns_total{kind="walked"}` is
-                        the same count on the host);
+                        the same count on the host). Nor do the
+                        gathers span the padded batch: the loop runs
+                        per ROW_TILE rows, over the tiles up to the
+                        last row with a byte left
+                        (`pingoo_scan_rows_total{kind="walked"}`);
   * `_fused_dfa`      — Pallas kernel keeping state + H in VMEM for the
                         whole byte loop (one-hot f32 matmul lookups,
                         exact for values < 2^16; same trick as
@@ -174,7 +178,9 @@ def dfa_scan_chunk(tables: DfaTables, data: jax.Array, lengths: jax.Array,
     deliberately NOT applied here — it reads the final state, which
     only `dfa_finalize` knows. The walk stops at the longest row's
     remainder, ceil(clip(max(lengths - t_offset), 0, Lc) / 8) blocks: a
-    chunk wholly past every row runs none."""
+    chunk wholly past every row runs none. It covers the row tiles up
+    to the last row with a remainder (ops/live_columns.py), so `step`
+    and `classes` see B or ROW_TILE rows: both are row-wise."""
     C = tables.num_classes
 
     def classes(block):
@@ -184,7 +190,7 @@ def dfa_scan_chunk(tables: DfaTables, data: jax.Array, lengths: jax.Array,
 
     def step(carry, c, live):
         state, H = carry
-        fire = jnp.take(tables.step_accept, state, axis=0)  # [B, Wh]
+        fire = jnp.take(tables.step_accept, state, axis=0)  # [rows, Wh]
         H = jnp.where(live[:, None], H | fire, H)
         nxt = jnp.take(tables.trans_flat, state * C + c)
         state = jnp.where(live, nxt, state)

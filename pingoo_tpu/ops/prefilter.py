@@ -36,7 +36,9 @@ at all: the loop (ops/live_columns.py) runs
 ceil(min(max(lengths), L) / 8) blocks of 8 steps, a bound read on the
 device from the batch's longest row, not the staged width L
 (`pingoo_scan_columns_total{kind="walked"}` is the same count on the
-host).
+host). Rows past the batch's last live row are not walked either: the
+loop runs per ROW_TILE rows over the tiles up to it
+(`pingoo_scan_rows_total{kind="walked"}`).
 
 `scan_numpy` is the pure-numpy oracle used by the differential property
 tests (tests/test_prefilter.py); `prefilter_scan` is the device op;
@@ -192,7 +194,9 @@ def prefilter_scan_chunk(tables: PrefilterTables, data: jax.Array,
     `lengths` is each row's TOTAL live byte count in global positions;
     `prefilter_scan` below is one chunk at offset 0. The walk stops at
     the longest row's remainder (ceil(clip(max(lengths - t_offset), 0,
-    Lc) / 8) blocks); carried-in H already holds carried-in S."""
+    Lc) / 8) blocks) and covers the row tiles up to the last row with a
+    remainder (ops/live_columns.py: `step` sees B or ROW_TILE rows);
+    carried-in H already holds carried-in S."""
     init = tables.init
     one = jnp.uint32(1)
 

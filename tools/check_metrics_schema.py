@@ -158,14 +158,16 @@ def main() -> int:
     # staged-bytes counter and the per-field cap gauge — the counter is
     # what makes the full-vs-compact byte savings visible per plane,
     # and the gauge publishes the adopted plan's staging widths.
-    # The scan-columns counter's literal lives in engine/batch.py
-    # (ScanColumnCounters, shared); both planes must construct one.
-    if "pingoo_scan_columns_total" not in _read(
-            "pingoo_tpu/engine/batch.py"):
-        problems.append(
-            "engine/batch.py: missing metric pingoo_scan_columns_total")
+    # The scan-columns and scan-rows counters' literals live in
+    # engine/batch.py (ScanColumnCounters, shared); both planes must
+    # construct one.
+    scan_extents = ("pingoo_scan_columns_total", "pingoo_scan_rows_total")
+    batch_src = _read("pingoo_tpu/engine/batch.py")
+    for name in scan_extents:
+        if name not in batch_src:
+            problems.append(f"engine/batch.py: missing metric {name}")
     for name in schema.STAGING_METRICS:
-        if name == "pingoo_scan_columns_total":
+        if name in scan_extents:
             name = "ScanColumnCounters"
         if name not in service_src:
             problems.append(f"engine/service.py: missing metric {name}")
@@ -343,6 +345,8 @@ def main() -> int:
     reg.gauge("pingoo_staging_field_cap", "", labels={
         "field": "url"}).set(256)
     reg.counter("pingoo_scan_columns_total", "", labels={
+        "plane": "audit", "field": "url", "kind": "walked"}).inc()
+    reg.counter("pingoo_scan_rows_total", "", labels={
         "plane": "audit", "field": "url", "kind": "walked"}).inc()
     reg.counter("pingoo_compile_total", "", labels={
         "plane": "audit", "fn": "verdict", "kind": "cold"}).inc()
