@@ -3,7 +3,7 @@
 PY ?= python
 
 .PHONY: all native test check bench bench-regress audit asan \
-	metrics-smoke mesh-smoke chaos-smoke megastep-smoke body-smoke \
+	metrics-smoke mesh-smoke chaos-smoke body-smoke \
 	staging-smoke timeline-smoke chip-smoke \
 	clean analyze analyze-abi analyze-lint analyze-tidy analyze-tsan \
 	fuzz prove ringcheck surface
@@ -22,7 +22,6 @@ check:
 	$(MAKE) analyze
 	$(MAKE) mesh-smoke
 	$(MAKE) chaos-smoke
-	$(MAKE) megastep-smoke
 	$(MAKE) body-smoke
 	$(MAKE) staging-smoke
 	$(MAKE) timeline-smoke
@@ -127,14 +126,6 @@ mesh-smoke:
 # when jax or the native toolchain is unavailable.
 chaos-smoke:
 	$(PY) tools/chaos_smoke.py
-
-# Device-resident megastep smoke (ISSUE 12, docs/EXECUTOR.md): prove
-# PINGOO_MEGASTEP=force is bit-identical to the per-batch oracle on
-# BOTH planes with real K>1 windows dispatched. Offline-safe: skips
-# when jax is unavailable; the sidecar half skips without the native
-# toolchain.
-megastep-smoke:
-	$(PY) tools/megastep_smoke.py
 
 # Compact-staging smoke (ISSUE 15, docs/EXECUTOR.md "Compact
 # staging"): prove PINGOO_STAGING=compact is bit-identical to the

@@ -688,32 +688,19 @@ def bench_pipeline() -> dict:
     """ISSUE 9 satellite: A/B the zero-copy pipelined executor
     (PINGOO_PIPELINE=off vs on, docs/EXECUTOR.md) by driving the same
     seeded traffic stream through a live ring + RingSidecar per mode in
-    a SUBPROCESS (fresh jit caches per run, on the ambient platform),
-    plus a third `mega` arm (ISSUE 12: PINGOO_PIPELINE=on
-    + PINGOO_MEGASTEP=force) that amortizes one dispatch over K batch
-    slices. Verdict checksums must be identical across all modes — the
-    pipeline and the megastep are scheduling changes, never semantic
-    ones. Writes BENCH_pipeline.json and returns flattened
-    `pipeline_*`/`megastep_*` keys for the result line;
-    tools/bench_regress.py tracks on-mode and megastep throughput and
+    a SUBPROCESS (fresh jit caches per run, on the ambient platform).
+    Verdict checksums must be identical across both modes — the
+    pipeline is a scheduling change, never a semantic one. Writes
+    BENCH_pipeline.json and returns flattened `pipeline_*` keys for the
+    result line; tools/bench_regress.py tracks on-mode throughput and
     p99."""
     child = _run_child("_pipeline_bench_child", timeout=1800)
     on = child["modes"].get("on", {})
     off = child["modes"].get("off", {})
-    mega = child["modes"].get("mega", {})
     child["checksum_match"] = (on.get("checksum") == off.get("checksum")
                                and on.get("checksum") is not None)
     if off.get("req_per_s") and on.get("req_per_s"):
         child["speedup"] = round(on["req_per_s"] / off["req_per_s"], 3)
-    # ISSUE 12 acceptance surface: the megastep arm must be checksum-
-    # identical to the per-batch oracle, and its win over the pipelined
-    # per-batch arm is the dispatch-amortization headline.
-    child["megastep_checksum_match"] = (
-        mega.get("checksum") == off.get("checksum")
-        and mega.get("checksum") is not None)
-    if mega.get("req_per_s") and on.get("req_per_s"):
-        child["megastep_speedup_vs_on"] = round(
-            mega["req_per_s"] / on["req_per_s"], 3)
     try:
         with open("BENCH_pipeline.json", "w") as f:
             json.dump({"metric": "pipelined_executor_modes", **child},
@@ -731,9 +718,6 @@ def bench_pipeline() -> dict:
     res["pipeline_on_req_per_s"] = on.get("req_per_s")
     res["pipeline_on_p99_ms"] = on.get("p99_wait_ms")
     res["pipeline_overlap_ratio"] = on.get("overlap_ratio")
-    res["megastep_req_per_s"] = mega.get("req_per_s")
-    res["megastep_checksum_match"] = child["megastep_checksum_match"]
-    res["megastep_speedup_vs_on"] = child.get("megastep_speedup_vs_on")
     return res
 
 
@@ -808,7 +792,7 @@ def _pipeline_bench_child() -> None:
             # per iteration drip-feeds the ring, so the sidecar's
             # dequeue pass drains it dry and every arm serves
             # artificial near-empty backlogs instead of the deep-queue
-            # regime the executor (and megastep windows) batch against.
+            # regime the executor batches against.
             burst = 0
             while i < len(stream) and burst < 64:
                 m, h, p, u, ua, ip, port, asn, cc = stream[i]
@@ -836,24 +820,11 @@ def _pipeline_bench_child() -> None:
                 bytes(actions[j] for j in sorted(actions)))
         return elapsed
 
-    # Third arm (ISSUE 12): pipelining on PLUS the device-resident
-    # megastep — one dispatch amortized over K batch slices. Same
-    # stream, so the checksum must match `off` bit-for-bit.
-    for mode in ("off", "on", "mega"):
-        os.environ["PINGOO_PIPELINE"] = "on" if mode == "mega" else mode
-        if mode == "mega":
-            os.environ["PINGOO_MEGASTEP"] = "force"
-            os.environ["PINGOO_MEGASTEP_K"] = os.environ.get(
-                "BENCH_MEGASTEP_K", "4")
-        else:
-            os.environ.pop("PINGOO_MEGASTEP", None)
-            os.environ.pop("PINGOO_MEGASTEP_K", None)
+    for mode in ("off", "on"):
+        os.environ["PINGOO_PIPELINE"] = mode
         tmp = tempfile.mkdtemp(prefix="pingoo-pipe-bench-")
-        # Capacity must hold a full megastep window's worth of backlog
-        # (K x max_batch) or K-deep windows can never fill from real
-        # queue pressure — 4096 capped the mega arm at 2 slices of
-        # B=2048. 16384 matches the e2e/dataplane benches; same for
-        # all three arms.
+        # 16384 matches the e2e/dataplane benches: several batches of
+        # backlog, so the executor's depth fills from real pressure.
         ring = Ring(os.path.join(tmp, "ring"), capacity=16384,
                     create=True)
         sidecar = RingSidecar(ring, plan, lists, max_batch=max_batch,
@@ -887,9 +858,6 @@ def _pipeline_bench_child() -> None:
         }
         if mode == "on":
             row["stage_ewma_ms"] = cost.get("stage_ewma_ms")
-        if mode == "mega":
-            row["megastep"] = snap.get("megastep")
-            row["megastep_ewma_ms"] = cost.get("megastep_ewma_ms")
         result["modes"][mode] = row
     print(json.dumps(result), flush=True)
 

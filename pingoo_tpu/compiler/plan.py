@@ -92,7 +92,7 @@ DFA_KIND = "dfa"
 # compile pass below derives, per field, the maximum byte position any
 # compiled scanner can depend on, and `PINGOO_STAGING=compact` stages
 # only that capped prefix. The cap is quantized to this pow2 rung
-# ladder (à la megastep K) so hot-swapping between tenants whose caps
+# ladder so hot-swapping between tenants whose caps
 # land on the same rung reuses the XLA compile.
 STAGING_RUNGS = (16, 32, 64, 128, 256, 512, 1024, 2048)
 

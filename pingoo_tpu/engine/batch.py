@@ -722,144 +722,6 @@ class StagingEncoder:
                             staged_bytes=P * W)
 
 
-class DeviceInputQueue:
-    """Double-buffered host->device input stacks for the megastep
-    (ISSUE 12, docs/EXECUTOR.md "Device-resident loop").
-
-    The megastep (engine/verdict.make_megastep_fn) consumes K batch
-    slices as ONE stacked pytree {field: [K, B, ...]} plus device-side
-    n_valid/epoch words per slice. This queue owns `nbuf` rotating
-    stack sets sized to the field CAPACITIES, fills slice rows IN
-    PLACE as batches arrive (strided copies out of the StagingEncoder's
-    views, so the staging buffers are free to rotate immediately), and
-    `device_stack` issues the ASYNC `jax.device_put` copy of the filled
-    window — trimmed to the used K, the window's row bucket, and each
-    byte field's window-max pow2 column width — into the *next* device
-    buffer while the current megastep computes. Short slices are MASKED
-    by their n_valid word, never re-shaped; each slice carries the
-    ruleset epoch it was encoded under, echoed back untouched by the
-    device program (the hot-swap megastep-boundary proof)."""
-
-    def __init__(self, k: int, max_batch: int,
-                 field_specs: Optional[Mapping[str, int]] = None,
-                 nbuf: int = 2):
-        specs = dict(field_specs or DEFAULT_FIELD_SPECS)
-        self.k = max(1, int(k))
-        self.max_batch = int(max_batch)
-        self.specs = specs
-        self.nbuf = max(2, int(nbuf))
-        self._bufs: list[dict] = []
-        self._widths: list[dict] = []
-        self._rows: list[int] = [0] * self.nbuf
-        for _ in range(self.nbuf):
-            stacks: dict = {}
-            for field in STRING_FIELDS:
-                cap = specs.get(field, 256)
-                stacks[f"{field}_bytes"] = np.zeros(
-                    (self.k, self.max_batch, cap), dtype=np.uint8)
-                stacks[f"{field}_len"] = np.zeros(
-                    (self.k, self.max_batch), dtype=np.int32)
-            stacks["ip"] = np.zeros(
-                (self.k, self.max_batch, 4), dtype=np.uint32)
-            stacks["asn"] = np.zeros(
-                (self.k, self.max_batch), dtype=np.int64)
-            stacks["remote_port"] = np.zeros(
-                (self.k, self.max_batch), dtype=np.int64)
-            stacks["n_valid"] = np.zeros(self.k, dtype=np.int32)
-            stacks["epoch"] = np.zeros(self.k, dtype=np.int32)
-            self._bufs.append(stacks)
-            self._widths.append({})
-        self._cursor = 0
-
-    def checkout(self) -> int:
-        """Claim the next stack set for a new megastep window. With
-        nbuf >= 2 the window being filled is never the one a still
-        in-flight megastep is computing over (double buffering)."""
-        i = self._cursor
-        self._cursor = (self._cursor + 1) % self.nbuf
-        self._bufs[i]["n_valid"][:] = 0
-        self._widths[i].clear()
-        self._rows[i] = 0
-        return i
-
-    def fill_slice(self, buf_id: int, j: int, arrays: Mapping,
-                   n_valid: int, epoch: int) -> None:
-        """Copy one encoded batch slice into stack row j (hot): strided
-        copies into the REUSED stacks; the source views (StagingEncoder
-        buffers) may rotate as soon as this returns. Byte columns may be
-        narrower than capacity (bucketed views) — the remainder up to
-        the running window width is zeroed so a previous window's bytes
-        cannot leak into this one."""
-        buf = self._bufs[buf_id]
-        widths = self._widths[buf_id]
-        rows = 0
-        for name, arr in arrays.items():
-            rows = arr.shape[0]
-            if name.endswith("_bytes"):
-                # Invariant: every filled slice is valid (data + zeros)
-                # out to the window width, so the shipped window-max
-                # trim can never expose a previous window's bytes.
-                w = arr.shape[1]
-                prev = widths.get(name, 0)
-                dst = buf[name][j, :rows]
-                dst[:, :w] = arr
-                if w < prev:
-                    dst[:, w:prev] = 0
-                elif w > prev:
-                    if prev and j:
-                        buf[name][:j, :rows, prev:w] = 0
-                    widths[name] = w
-            else:
-                buf[name][j, :rows] = arr
-        if self._rows[buf_id] and rows != self._rows[buf_id]:
-            raise ValueError(
-                f"megastep slices must share one row bucket: "
-                f"{rows} != {self._rows[buf_id]}")
-        self._rows[buf_id] = rows
-        buf["n_valid"][j] = n_valid
-        buf["epoch"][j] = epoch
-
-    def slice_view(self, buf_id: int, j: int, n: int) -> dict:
-        """Host views of slice j's first n rows (capacity-width) — the
-        resolve path's raw batch, stable until this buffer set is
-        checked out again (nbuf - 1 windows later)."""
-        buf = self._bufs[buf_id]
-        return {name: buf[name][j, :n]
-                for name in buf if name not in ("n_valid", "epoch")}
-
-    def device_stack(self, buf_id: int, k_used: int, pad_to: int = 0):
-        """Issue the ASYNC host->device copy of the filled window (hot):
-        (stacked arrays, n_valid, epoch) device values, trimmed to
-        `k_used` slices, the window's row bucket, and each byte field's
-        window-max pow2 column width. jax.device_put only ENQUEUES the
-        transfer — the caller overlaps it with the in-flight megastep's
-        compute before dispatching this window.
-
-        `pad_to` ships a LARGER leading dim than the filled count:
-        every distinct K is its own XLA compile of the scan, so callers
-        quantize short windows up to a pow2 rung instead of paying a
-        fresh multi-second compile per arbitrary length. The padded
-        slices carry whatever bytes the stacks held — checkout() zeroed
-        their n_valid words, so the device program masks them out."""
-        import jax
-
-        k_ship = min(self.k, max(k_used, pad_to))
-        buf = self._bufs[buf_id]
-        widths = self._widths[buf_id]
-        rows = self._rows[buf_id] or self.max_batch
-        stacked = {}
-        for name, stack in buf.items():
-            if name in ("n_valid", "epoch"):
-                continue
-            view = stack[:k_ship, :rows]
-            if name.endswith("_bytes"):
-                view = view[:, :, :widths.get(name, stack.shape[2])]
-            stacked[name] = view
-        return (jax.device_put(stacked),
-                jax.device_put(buf["n_valid"][:k_ship]),
-                jax.device_put(buf["epoch"][:k_ship]))
-
-
 def batch_to_contexts(
     batch: RequestBatch, lists: Mapping[str, list]
 ) -> list[Context]:

@@ -15,7 +15,6 @@ Rung order (cheapest first — the order callers demote in):
 
   ==========  =====================================================
   pipeline    staging encoder -> legacy per-batch encode chain
-  megastep    device-resident K-batch megastep -> per-batch dispatch
   dfa         lowered bitsplit DFAs -> exact NFA scan
   mesh        sharded serving mesh -> single-device executor
   device      XLA device programs -> host interpreter
@@ -58,15 +57,11 @@ from typing import Callable, Optional
 
 from ..logging_utils import get_logger
 
-RUNGS = ("pipeline", "megastep", "dfa", "mesh", "device", "body")
+RUNGS = ("pipeline", "dfa", "mesh", "device", "body")
 
 # What each rung falls back TO (log/snapshot surface only).
 FALLBACKS = {
     "pipeline": "legacy-encode",
-    # ISSUE 12: a failed K-slice megastep window demotes the plane to
-    # per-batch device dispatch (every slice still bit-identical);
-    # backoff probes re-promote once the device program recovers.
-    "megastep": "per-batch-dispatch",
     "dfa": "nfa-scan",
     "mesh": "single-device",
     "device": "host-interpreter",

@@ -32,9 +32,8 @@ from ..expr import execute_as_bool
 from ..obs.flightrecorder import (FlightRecorder, register_recorder,
                                   tuple_digest)
 from ..obs.perf import (batch_leading_dim, get_compile_ledger,
-                        instrument_jit, instrument_megastep,
-                        plan_fingerprint, set_dispatch_context,
-                        staging_widths)
+                        instrument_jit, plan_fingerprint,
+                        set_dispatch_context, staging_widths)
 from ..obs.pipeline import PipelineStats
 from ..obs.provenance import (ParityAuditor, PrefilterAttribution,
                               RuleAttribution, provenance_enabled)
@@ -42,7 +41,6 @@ from ..obs.timeline import get_timeline
 from ..sched import MeshExecutor, MeshUnavailable, Scheduler, SchedulerConfig
 from ..sched.scheduler import load_cost_ledger, save_cost_ledger
 from .batch import (
-    DeviceInputQueue,
     RequestBatch,
     RequestTuple,
     ScanColumnCounters,
@@ -56,11 +54,9 @@ from .batch import (
     stage_overflow_thresholds,
     tuple_to_context,
 )
-from .verdict import (_resolve_megastep_mode, action_lanes, finish_batch,
-                      finish_megastep, make_megastep_fn,
+from .verdict import (action_lanes, finish_batch,
                       make_packed_prefilter_fn, make_packed_verdict_fn,
-                      make_prefilter_fn, make_verdict_fn, megastep_k_cap,
-                      megastep_k_ladder)
+                      make_prefilter_fn, make_verdict_fn)
 
 # Per-stage slices of the PINGOO_DEADLINE_MS budget (ISSUE 9,
 # docs/EXECUTOR.md): cumulative launch-relative fractions a batch may
@@ -392,20 +388,6 @@ class VerdictService:
         from .hotswap import set_epoch_gauge
 
         set_epoch_gauge("python", 0)
-        # Device-resident megastep (ISSUE 12, docs/EXECUTOR.md): the
-        # matrix-kind K-slice program + double-buffered device input
-        # queue live in the engine state (rebuilt per swap/demotion);
-        # mega_echo_mismatch counts per-slice ruleset-epoch echoes that
-        # disagreed with the plan the window was staged under.
-        self._mega_fn = None
-        self._mega_queue: Optional[DeviceInputQueue] = None
-        self._mega_rungs = megastep_k_ladder(megastep_k_cap())
-        self.mega_echo_mismatch = 0
-        # Monotonic megastep window id (ISSUE 17 satellite): stamped
-        # into every flight row a window serves, so stranded-slice
-        # reconciliation after a mid-window SIGKILL is traceable per
-        # window instead of per anonymous batch.
-        self._mega_window_seq = 0
         if use_device:
             state = self._build_engine_state(plan, device)
             if state is None:
@@ -488,19 +470,6 @@ class VerdictService:
                 "python", plan, rows_sharded=mesh.dp > 1)
             state["staging"] = (self._make_staging(plan)
                                 if self.pipeline_mode == "on" else None)
-            # Megastep window program (ISSUE 12): built only when
-            # PINGOO_MEGASTEP is enabled at state-build time — `off`
-            # (the default, and the bit-exact parity oracle) leaves
-            # the per-batch dispatch path byte-for-byte untouched.
-            state["mega_fn"] = None
-            state["mega_queue"] = None
-            if _resolve_megastep_mode() != "off":
-                state["mega_fn"] = instrument_megastep(
-                    make_megastep_fn(plan, kind="matrix"),
-                    plane="python", fingerprint=fp, widths=widths)
-                state["mega_queue"] = DeviceInputQueue(
-                    megastep_k_cap(), self.max_batch,
-                    field_specs=plan.field_specs, nbuf=2)
             return state
         except Exception as exc:
             # Boot-time demotion is permanent for this service (no
@@ -521,8 +490,6 @@ class VerdictService:
         self._tables = state["tables"]
         if state.get("staging") is not None:
             self._staging = state["staging"]
-        self._mega_fn = state.get("mega_fn")
-        self._mega_queue = state.get("mega_queue")
         # Compact staging (ISSUE 15): the packed fns + caps flip with
         # the plan at the same batch boundary the staging encoder does,
         # so every batch is encoded AND decoded under one cap set.
@@ -597,13 +564,6 @@ class VerdictService:
                     self.plan, donate=donate_batch_buffers()),
                 "verdict", plane="python", fingerprint=fp,
                 widths=widths)
-        if self._mega_fn is not None:
-            # The megastep embeds the same DFA dispatch decision; keep
-            # it in lockstep with the per-batch program it must stay
-            # bit-identical to.
-            self._mega_fn = instrument_megastep(
-                make_megastep_fn(self.plan, kind="matrix"),
-                plane="python", fingerprint=fp, widths=widths)
 
     def _dfa_rung_tick(self) -> None:
         """Demoted-dfa probe: when the backoff window opens, restore
@@ -1115,9 +1075,6 @@ class VerdictService:
             # Cross-plane timeline (ISSUE 17): per-batch cost while
             # unsampled is the one add+compare inside sample().
             if self._timeline.sample():
-                tl_args = {"pipeline_slot": pipe_slot}
-                if "megastep_k" in stages:
-                    tl_args["megastep_k"] = stages["megastep_k"]
                 self._timeline.batch_python(
                     stages_ms=stages, t_launch=t_launch,
                     t_resolve=t_resolve, t_end=t_res_end,
@@ -1126,7 +1083,7 @@ class VerdictService:
                           for i in range(
                               min(len(pending),
                                   self._timeline.rows_per_batch))],
-                    args=tl_args)
+                    args={"pipeline_slot": pipe_slot})
         finally:
             self._pipe.exit()
 
@@ -1410,21 +1367,6 @@ class VerdictService:
                         RequestBatch(size=batch.size, arrays=arrays),
                         self._pow2_size(n))
                 self._scan_columns.note(fast.arrays)
-                # Megastep window (ISSUE 12): PINGOO_MEGASTEP=force —
-                # or `auto` with a backlog queued behind this batch —
-                # scans the batch as K row slices through ONE jitted
-                # dispatch instead of the per-batch program below.
-                # None = not engaged, or the window failed; either way
-                # the per-batch dispatch serves the same rows,
-                # bit-identically by construction (the slice body IS
-                # the function make_verdict_fn jits).
-                matched = self._evaluate_megastep(fast, n, stages,
-                                                  t_launch, pipe_slot)
-                if matched is not None:
-                    self._observe_dfa()
-                    self._note_device_success()
-                    return self._rewrite_overflow_rows(reqs, batch,
-                                                       matched[:n])
                 # The dispatch token serializes device issue across
                 # in-flight batches (program order stays deterministic)
                 # while leaving compute token-free: batch N+1 encodes
@@ -1538,126 +1480,6 @@ class VerdictService:
             # host interpreter evaluates too — slice them off.
             matched = self._evaluate_host(batch)[:n]
         return self._rewrite_overflow_rows(reqs, batch, matched)
-
-    def _evaluate_megastep(self, fast: RequestBatch, n: int,
-                           stages: Optional[dict] = None,
-                           t_launch: Optional[float] = None,
-                           pipe_slot: Optional[int] = None
-                           ) -> Optional[np.ndarray]:
-        """Device-resident megastep window (ISSUE 12, docs/EXECUTOR.md
-        "Device-resident loop"): split the shape-stable batch into K
-        contiguous row slices, stage them through the DeviceInputQueue's
-        double-buffered host stacks (one async device_put per window),
-        and run ONE jitted kind="matrix" scan over all K — one dispatch
-        wall amortized across the window. Returns the [P, R] matched
-        matrix (device slices overlaid on the host-rule interpretation
-        by finish_megastep) or None when the window is not engaged:
-        PINGOO_MEGASTEP=off / state built without it, `auto` with no
-        backlog queued behind this batch, an active mesh (the
-        dp-sharded per-batch path owns placement), K deadline-sized
-        down to 1 outside force mode, or the megastep rung demoted with
-        its probe window closed. A window that raises demotes the
-        megastep rung ONLY (the per-batch dispatch probes device health
-        itself) and the caller re-dispatches per batch."""
-        if self._mega_fn is None or self._mega_queue is None:
-            return None
-        mode = _resolve_megastep_mode()
-        if mode == "off":
-            return None
-        if self.mesh is not None and self.mesh.active:
-            return None
-        if mode != "force" and self._queue.qsize() <= 0:
-            return None
-        size = fast.size
-        k = 1
-        for rung in self._mega_rungs:
-            if rung <= size and size % rung == 0:
-                k = rung
-        if mode != "force":
-            # Deadline-sized K (auto only — force is the operator
-            # pinning the cap): the largest rung whose estimated
-            # window wall still fits this batch's remaining slack.
-            now = time.monotonic()
-            k = min(k, self.sched.size_megastep_k(
-                self._mega_rungs, size // k,
-                t_launch if t_launch is not None else now, now))
-            if k <= 1:
-                return None
-        rows = size // k
-        if rows > self.max_batch:
-            # Oversize direct evaluation (> max_batch rows/slice) —
-            # outside the queue's capacity contract; per-batch serves.
-            return None
-        if not self.ladder.try_rung("megastep"):
-            return None
-        self._mega_window_seq += 1
-        if stages is not None:
-            # Flight-row window traceability (ISSUE 17 satellite): the
-            # window id + staging mode ride the batch stage dict into
-            # every flight record this window serves (megastep slices
-            # always stage per-field arrays, never the packed buffer).
-            stages["megastep_window"] = self._mega_window_seq
-            stages["staging_mode"] = "full"
-        from contextlib import nullcontext
-        try:
-            buf = self._mega_queue.checkout()
-            for j in range(k):
-                off = j * rows
-                self._mega_queue.fill_slice(
-                    buf, j,
-                    {name: arr[off:off + rows]
-                     for name, arr in fast.arrays.items()},
-                    max(0, min(rows, n - off)), self.ruleset_epoch)
-            tok = (self._stage_tokens["dispatch"]
-                   if self._staging is not None else nullcontext())
-            td0 = time.monotonic()
-            with tok:
-                stacked, nv, ep = self._mega_queue.device_stack(buf, k)
-                set_dispatch_context(batch=rows, k=k)
-                dev_out = self._mega_fn.fn(self._tables, stacked, nv, ep)
-                self._batch_stage(
-                    "device_dispatch", (time.monotonic() - td0) * 1e3,
-                    stages)
-            td1 = time.monotonic()
-            if pipe_slot is not None:
-                self._pipe.note_stage(pipe_slot, "dispatch", td0, td1)
-            self._check_stage_budget("dispatch", t_launch)
-            slices = [(j * rows, max(0, min(rows, n - j * rows)))
-                      for j in range(k)]
-            matched = finish_megastep(
-                self.plan, dev_out[0], slices, fast, self.lists,
-                on_device_wait=lambda ms: self._batch_stage(
-                    "device_compute", ms, stages))
-            tc1 = time.monotonic()
-            # Pipeline compute window = dispatch-end -> results-ready,
-            # same convention as the per-batch path.
-            if pipe_slot is not None:
-                self._pipe.note_stage(pipe_slot, "compute", td1, tc1)
-            if stages is not None:
-                stages["compute_wall_ms"] = round((tc1 - td1) * 1e3, 3)
-                stages["megastep_k"] = k
-            # Per-slice ruleset-epoch echo (the round-trip proof the
-            # hot-swap tests assert on): a mismatch means a window
-            # crossed a swap boundary it should have drained at.
-            # pingoo: allow(sync-asarray-hot): i32[K], ready post-sync
-            ep_echo = np.asarray(dev_out[3])
-            self.mega_echo_mismatch += int(
-                (ep_echo != self.ruleset_epoch).sum())
-            if self._pf_fn is not None and self._mega_fn.aux_len:
-                # Stage-A aux lanes are per-slice row counts — additive
-                # across the window, observed once over all K*rows.
-                # pingoo: allow(sync-asarray-hot): aux ready post-sync
-                aux = np.asarray(dev_out[2])
-                self._observe_prefilter(aux.sum(axis=0), size)
-            self.sched.observe_megastep_cost(k, rows, (tc1 - td0) * 1e3)
-            self._pipe.note_megastep(k, mode)
-            self.ladder.note_success("megastep")
-            return matched
-        except _StageBudgetExceeded:
-            raise
-        except Exception as exc:
-            self.ladder.note_failure("megastep", exc)
-            return None
 
     def _observe_prefilter(self, pf_aux, batch_rows: int) -> None:
         """Fold the Stage-A aux lanes into the metrics surface

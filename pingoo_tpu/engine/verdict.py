@@ -880,10 +880,8 @@ def _make_prefilter_body(plan: RulesetPlan):
     """UNJITTED Stage-A body: (stage_a, gated, masked) or None.
 
     Shared by make_prefilter_fn (which jits `stage_a` as its own
-    dispatch so the stage is separately timeable) and make_megastep_fn
-    (which inlines it per slice inside the scanned device loop) — one
-    code path, so the megastep's inline prefilter is bit-identical to
-    the separately-dispatched Stage-A pass by construction."""
+    dispatch so the stage is separately timeable) and its compact-
+    staging twin make_packed_prefilter_fn."""
     pf = getattr(plan, "prefilter", None)
     if pf is None or not pf.fields or _resolve_pf_mode(plan) == "off":
         return None
@@ -1037,11 +1035,8 @@ def _make_lane_body(plan: RulesetPlan, groups: list[list[str]],
                     with_rule_hits: bool):
     """UNJITTED lane-reduction body: (tables, arrays, pf_hits, n_valid)
     -> stacked [3 + max(G, 1), B] i32 lanes (+ [C] rule_hits when
-    with_rule_hits). Shared by make_lane_fn (which jits it as the
-    per-batch dispatch) and make_megastep_fn (which scans it over K
-    slices in one device-resident program) — one code path, so the
-    megastep's per-slice lanes are bit-identical to the per-batch
-    dispatch by construction."""
+    with_rule_hits). Shared by make_lane_fn and its compact-staging
+    twin make_packed_lane_fn."""
     device_rules = [r for r in plan.rules if not r.host]
     orig_idx = np.array([r.index for r in device_rules], dtype=np.int32)
     first_kind = np.array(
@@ -1123,143 +1118,6 @@ def _make_lane_body(plan: RulesetPlan, groups: list[list[str]],
     return lanes
 
 
-# -- device-resident megastep (ISSUE 12) --------------------------------------
-#
-# Every per-batch perf layer (prefilter, DFA, pipelining) pushed compute
-# down until host->device dispatch became the wall: BENCH_pipeline.json
-# showed the dispatch stage at ~0.88 occupancy vs ~0.26 compute. The
-# megastep keeps the verdict program RESIDENT on device: one jitted
-# lax.scan over K stacked batch slices runs prefilter -> DFA/NFA ->
-# action lanes per slice and writes every slice's verdict words into one
-# stacked output, so ONE dispatch amortizes over K batches.
-#
-# PINGOO_MEGASTEP (read per decision point, like PINGOO_DFA):
-#   off   — per-batch dispatch, the bit-exact parity oracle.
-#   auto  — engage when the executor has >= 2 batches of backlog to
-#           amortize over (each plane supplies its own backlog signal).
-#   force — always take the megastep path (K may degenerate to 1).
-# PINGOO_MEGASTEP_K caps K (default 4); the executor sizes K down the
-# pow2 ladder against the oldest slice's deadline slack using the sched
-# CostModel's per-K megastep EWMAs (sched/scheduler.py).
-#
-# Masking, not re-shaping: every slice arrives padded to the SAME batch
-# bucket; a device-side n_valid word per slice masks short slices (the
-# attribution fold and the host resolve read only the valid prefix) and
-# an epoch word per slice rides through the program untouched, so the
-# host can assert which ruleset epoch each slice was computed under
-# (hot-swaps flip plans only at megastep boundaries — docs/EXECUTOR.md).
-
-
-MEGASTEP_K_DEFAULT = 4
-
-
-def _resolve_megastep_mode() -> str:
-    """PINGOO_MEGASTEP env knob (read per decision point so tests can
-    monkeypatch it): off | auto | force, default off."""
-    mode = _os.environ.get("PINGOO_MEGASTEP", "off")
-    return mode if mode in ("off", "auto", "force") else "off"
-
-
-def megastep_k_cap() -> int:
-    """PINGOO_MEGASTEP_K: the largest K a single megastep may cover."""
-    try:
-        return max(1, int(_os.environ.get("PINGOO_MEGASTEP_K",
-                                          str(MEGASTEP_K_DEFAULT))))
-    except ValueError:
-        return MEGASTEP_K_DEFAULT
-
-
-def megastep_k_ladder(k_max: int) -> list[int]:
-    """Static pow2 K rungs [1, 2, 4, ...] bounded by k_max — each rung
-    is one compiled megastep variant, so admission can shrink K against
-    the deadline budget without retracing."""
-    rungs = [1]
-    while rungs[-1] * 2 <= max(1, k_max):
-        rungs.append(rungs[-1] * 2)
-    return rungs
-
-
-class MegastepProgram(NamedTuple):
-    """make_megastep_fn's bundle: the jitted K-slice device loop plus
-    the static metadata its callers need to unpack the outputs."""
-
-    fn: Any          # (tables, stacked, n_valid, epoch) -> outs
-    kind: str        # "lanes" (sidecar) | "matrix" (python plane)
-    aux_len: int     # Stage-A aux lanes per slice (0: no prefilter)
-    with_rule_hits: bool
-
-
-def make_megastep_fn(plan: RulesetPlan, kind: str = "lanes",
-                     service_groups: list[list[str]] | None = None,
-                     with_rule_hits: bool = False,
-                     donate: bool = False) -> MegastepProgram:
-    """Jitted MULTI-BATCH megastep: (tables, stacked, n_valid, epoch) ->
-    (out, rule_hits, pf_aux, epoch_echo), one lax.scan iteration per
-    batch slice so the whole K-batch window is ONE XLA program and one
-    host dispatch.
-
-      stacked  {name: [K, B, ...]} — K batch slices, every slice padded
-               to the same bucket (DeviceInputQueue, engine/batch.py).
-      n_valid  [K] i32 — valid-row count per slice; short slices are
-               masked, never re-shaped.
-      epoch    [K] i32 — ruleset epoch stamped per slice at fill time,
-               echoed back untouched (hot-swap boundary proof).
-
-    `kind="lanes"` scans the sidecar's action-lane reduction
-    (_make_lane_body) per slice -> out [K, 3 + max(G, 1), B] i32;
-    `kind="matrix"` scans the python plane's match-matrix body
-    (_matched_cols) -> out [K, B, C] bool. Either way the per-slice
-    bodies are the SAME traced functions the per-batch dispatches jit,
-    with Stage A (_make_prefilter_body) inlined per slice under the
-    active PINGOO_PREFILTER mode — bit-identity with PINGOO_MEGASTEP=off
-    is by construction, and tests/test_pipeline.py proves it.
-
-    rule_hits is [K, C] (zeros-width when with_rule_hits is False),
-    pf_aux is [K, aux_len] in make_prefilter_fn's aux layout (width 2
-    zeros when the plan has no active prefilter), epoch_echo is [K].
-
-    `donate=True` donates the stacked request arrays (arg 1) so XLA can
-    recycle the K-slice upload in place (see donate_batch_buffers)."""
-    if kind not in ("lanes", "matrix"):
-        raise ValueError(f"bad megastep kind {kind!r}")
-    pf_body = _make_prefilter_body(plan)
-    aux_len = 2 + 2 * len(pf_body[2]) if pf_body is not None else 0
-    groups = service_groups or []
-    lane_body = (_make_lane_body(plan, groups, with_rule_hits)
-                 if kind == "lanes" else None)
-    n_hit_cols = (len([r for r in plan.rules if not r.host])
-                  if with_rule_hits else 0)
-
-    def slice_step(tables, arrays, nv, ep):
-        if pf_body is not None:
-            pf_hits, aux = pf_body[0](tables, arrays)
-        else:
-            pf_hits, aux = None, jnp.zeros((2,), dtype=jnp.int32)
-        if kind == "lanes":
-            out = lane_body(tables, arrays, pf_hits=pf_hits, n_valid=nv)
-            if with_rule_hits:
-                out, hits = out
-            else:
-                hits = jnp.zeros((n_hit_cols,), dtype=jnp.int32)
-        else:
-            out = _matched_cols(plan, tables, arrays, pf_hits=pf_hits)
-            hits = jnp.zeros((n_hit_cols,), dtype=jnp.int32)
-        return out, hits, aux, ep
-
-    def megastep(tables, stacked, n_valid, epoch):
-        def step(carry, xs):
-            arrays_k, nv, ep = xs
-            return carry, slice_step(tables, arrays_k, nv, ep)
-
-        _, outs = jax.lax.scan(step, jnp.int32(0),
-                               (stacked, n_valid, epoch))
-        return outs
-
-    return MegastepProgram(
-        fn=jax.jit(megastep, donate_argnums=(1,) if donate else ()),
-        kind=kind, aux_len=aux_len, with_rule_hits=with_rule_hits)
-
-
 def host_rule_lanes(plan: RulesetPlan, batch, lists):
     """Host-interpreted rules' contribution to the action lanes
     (same triple as make_lane_fn, original-index space)."""
@@ -1325,8 +1183,8 @@ def evaluate_batch(plan, verdict_fn, tables, batch, lists,
 
 def _host_matrix(plan, batch, lists) -> np.ndarray:
     """[B, R] bool with only the host-interpreted rules' columns filled
-    — the interpreter half shared by finish_batch / finish_megastep, run
-    FIRST so it overlaps the asynchronous device execution."""
+    — finish_batch's interpreter half, run FIRST so it overlaps the
+    asynchronous device execution."""
     R = len(plan.rules)
     B = batch.size
     out = np.zeros((B, R), dtype=bool)  # the per-batch result buffer
@@ -1370,25 +1228,6 @@ def finish_batch(plan, dev, batch, lists, on_device_wait=None) -> np.ndarray:
     dev = np.asarray(dev)  # sync point, AFTER the host-rule overlap
     for col, idx in enumerate(plan.device_rule_indices):
         out[:, idx] = dev[:, col]
-    return out
-
-
-def finish_megastep(plan, dev, slices, batch, lists,
-                    on_device_wait=None) -> np.ndarray:
-    """finish_batch for the python plane's megastep path: `dev` is the
-    [K, Bs, C] stacked match matrix from a kind="matrix" megastep and
-    `slices` maps each scanned slice j to its (row offset, n_valid)
-    span of `batch`. Host rules run FIRST (the same async-dispatch
-    overlap as finish_batch), then ONE sync unpacks every slice —
-    padding rows beyond each slice's n_valid are never read."""
-    out = _host_matrix(plan, batch, lists)
-    _await_device(dev, on_device_wait)
-    # pingoo: allow(sync-asarray-hot): the megastep's one deliberate
-    dev = np.asarray(dev)  # sync point, AFTER the host-rule overlap
-    for j, (off, nv) in enumerate(slices):
-        rows = dev[j, :nv]
-        for col, idx in enumerate(plan.device_rule_indices):
-            out[off:off + nv, idx] = rows[:, col]
     return out
 
 

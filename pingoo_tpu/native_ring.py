@@ -559,22 +559,6 @@ def write_services_file(path: str, services: list) -> None:
     os.replace(tmp, path)
 
 
-class _MegaSlice:
-    """One staged megastep slice's resolve metadata (ISSUE 12): the
-    per-batch state `_dispatch` would have threaded through its
-    in-flight tuple, parked until the window's single device sync."""
-
-    __slots__ = ("parts", "slots", "raw", "n", "skip_masks", "slot_buf",
-                 "spans", "epoch", "oldest_enq_ms")
-
-
-class _MegaWindow:
-    """One in-flight K-slice megastep window (ISSUE 12): the deque
-    entry `_complete_inflight` routes to `_complete_megastep`."""
-
-    __slots__ = ("slices", "k", "k_ship", "dev_out", "window_id")
-
-
 class RingSidecar:
     """Drain loop: ring batches -> jitted verdict -> verdict ring.
 
@@ -796,43 +780,9 @@ class RingSidecar:
         self._slot_pool: _deque = _deque()
         caps = dict(FIELD_CAPS)
         caps["country"] = 2
-        # Device-resident megastep (ISSUE 12, docs/EXECUTOR.md
-        # "Device-resident loop"): PINGOO_MEGASTEP=off|auto|force. In a
-        # megastep window the drain loop STAGES admitted batches into
-        # the DeviceInputQueue's double-buffered [K, B, ...] host
-        # stacks instead of dispatching each one, then runs ONE jitted
-        # lax.scan over all K slices — one dispatch wall amortized over
-        # K batches. `off` keeps the per-batch path (the bit-exact
-        # parity oracle), `auto` engages only with backlog queued
-        # behind the window, `force` megasteps every window (the bench
-        # arm). Short/stale slices are masked on device by their
-        # n_valid/epoch words, never re-shaped.
-        from .engine.batch import DeviceInputQueue
-        from .engine.verdict import (_resolve_megastep_mode,
-                                     megastep_k_cap, megastep_k_ladder)
-
-        self._mega_mode = _resolve_megastep_mode()
-        self._mega_k = megastep_k_cap()
-        self._mega_rungs = megastep_k_ladder(self._mega_k)
-        self._mega_queue = None
-        self._mega_staged: list = []
-        self._mega_buf_id = 0
-        self._mega_target = 1
-        self._mega_fn = None
-        self.mega_windows = 0
-        self.mega_echo_mismatch = 0  # device epoch echo != staged epoch
-        if self._mega_mode != "off":
-            self._mega_queue = DeviceInputQueue(
-                self._mega_k, max_batch, field_specs=caps, nbuf=2)
-        # Slot-buffer pool: one per in-flight batch plus the one being
-        # filled; a staged megastep window parks up to K slot buffers
-        # until its single resolve, so the pool covers whichever bound
-        # is larger.
-        pool_n = max(self.pipeline_depth,
-                     self._mega_k if self._mega_mode != "off" else 1) + 1
         if self._zero_copy:
             self._staging = self._make_staging(plan, caps)
-            for _ in range(pool_n):
+            for _ in range(self.pipeline_depth + 1):
                 self._slot_pool.append(
                     np.zeros(max_batch, dtype=REQUEST_SLOT_DTYPE))
         self._stage = {
@@ -1174,21 +1124,6 @@ class RingSidecar:
                     pf.masked, plane="sidecar")
         state["dev_cols"] = np.asarray(plan.device_rule_indices,
                                        dtype=np.int64)
-        # Megastep program (ISSUE 12): same unjitted prefilter/lane
-        # bodies as the per-batch programs above, scanned over K
-        # slices — bit-identical by construction. Built only when the
-        # mode can engage (the jit trace is per plan, like lane_fn).
-        state["mega_fn"] = None
-        if self._mega_mode != "off":
-            from .engine.verdict import make_megastep_fn
-            from .obs.perf import instrument_megastep
-
-            state["mega_fn"] = instrument_megastep(
-                make_megastep_fn(
-                    plan, kind="lanes",
-                    service_groups=self._groups or None,
-                    with_rule_hits=self._provenance_on),
-                plane="sidecar", fingerprint=fp, widths=widths)
         return state
 
     def _adopt_plan_state(self, plan, lists, state: dict) -> None:
@@ -1206,7 +1141,6 @@ class RingSidecar:
         self._pf_gated_banks = state["pf_gated_banks"]
         self._pf_attr = state["pf_attr"]
         self._dev_cols = state["dev_cols"]
-        self._mega_fn = state.get("mega_fn")
         self._dfa_mode0 = getattr(plan, "dfa_default_mode", "auto")
         self._dfa_probe = False
         # Compact staging (ISSUE 15): re-cap the staging encoder's
@@ -1299,31 +1233,14 @@ class RingSidecar:
         t0 = time.monotonic()
         with self._hb_busy(), self._pipe.stage("swap"):
             if pend_parts:
-                # Megastep boundary (ISSUE 12): pending slots join the
-                # OPEN window when one exists — launching them per-batch
-                # past staged (older) slices would post their tickets
-                # first and break the posted-floor prefix invariant.
-                if self._mega_staged \
-                        and len(self._mega_staged) < self._mega_k:
-                    self._stage_mega_slice(pend_parts, pend_n,
-                                           oldest_enq_ms,
-                                           slot_buf=pend_buf)
-                else:
-                    if self._mega_staged:
-                        inflight.append(self._launch_megastep())
-                    inflight.append(self._dispatch(pend_parts, pend_n,
-                                                   oldest_enq_ms,
-                                                   slot_buf=pend_buf))
+                inflight.append(self._dispatch(pend_parts, pend_n,
+                                               oldest_enq_ms,
+                                               slot_buf=pend_buf))
                 pend_parts, pend_n, oldest_enq_ms = [], 0, None
                 pend_buf = self._take_slot_buf() if self._zero_copy \
                     else None
-            if self._mega_staged:
-                # The flip happens only at a megastep boundary: every
-                # slice staged under the old epoch computes and posts
-                # on the old plan before the new one is adopted.
-                inflight.append(self._launch_megastep())
             while inflight:
-                self._complete_inflight(inflight.popleft())
+                self._complete(*inflight.popleft())
             while True:
                 with self._swap_lock:
                     if not self._swap_queue:
@@ -1472,62 +1389,34 @@ class RingSidecar:
                     launch = sched.should_launch(
                         pend_n, oldest_enq_ms / 1e3, now_ms / 1e3)
             if launch:
-                # Megastep drive (ISSUE 12): while a window is open
-                # every admitted batch STAGES into it (per-batch
-                # launches past staged slices would post younger
-                # tickets first and break the posted-floor prefix);
-                # _mega_begin decides whether a launch signal with no
-                # open window starts one.
-                if self._mega_staged or self._mega_begin(oldest_enq_ms):
-                    self._stage_mega_slice(pend_parts, pend_n,
-                                           oldest_enq_ms,
-                                           slot_buf=pend_buf)
-                else:
-                    inflight.append(self._dispatch(pend_parts, pend_n,
-                                                   oldest_enq_ms,
-                                                   slot_buf=pend_buf))
+                inflight.append(self._dispatch(pend_parts, pend_n,
+                                               oldest_enq_ms,
+                                               slot_buf=pend_buf))
                 pend_parts, pend_n, oldest_enq_ms = [], 0, None
                 if pend_buf is not None:
                     pend_buf = self._take_slot_buf()
-            if self._mega_staged and (got == 0 or self._mega_due()):
-                # Window full (K target reached), the oldest staged
-                # slice's deadline slack no longer covers the window
-                # estimate, or the rings went quiet: ship it. A partial
-                # window launches with k_used < K — masked, not
-                # re-shaped.
-                inflight.append(self._launch_megastep())
             if inflight and (len(inflight) >= self.pipeline_depth
                              or not launch):
-                self._complete_inflight(inflight.popleft())
-            if got == 0 and not launch and not inflight \
-                    and not self._mega_staged:
+                self._complete(*inflight.popleft())
+            if got == 0 and not launch and not inflight:
                 if not pend_parts and max_requests is not None \
                         and self.processed >= max_requests:
                     break
                 self._pipe.idle()
                 time.sleep(self.idle_sleep_s)
             if max_requests is not None and self.processed >= max_requests \
-                    and not inflight and not pend_parts \
-                    and not self._mega_staged:
+                    and not inflight and not pend_parts:
                 break
         # Flush: accumulated-but-unlaunched slots still get verdicts
         # (the data plane would otherwise eat a fail-open timeout).
         if pend_parts:
-            if self._mega_staged and len(self._mega_staged) < self._mega_k:
-                self._stage_mega_slice(pend_parts, pend_n,
-                                       oldest_enq_ms, slot_buf=pend_buf)
-            else:
-                if self._mega_staged:
-                    inflight.append(self._launch_megastep())
-                inflight.append(self._dispatch(pend_parts, pend_n,
-                                               oldest_enq_ms,
-                                               slot_buf=pend_buf))
+            inflight.append(self._dispatch(pend_parts, pend_n,
+                                           oldest_enq_ms,
+                                           slot_buf=pend_buf))
         elif pend_buf is not None:
             self._slot_pool.append(pend_buf)
-        if self._mega_staged:
-            inflight.append(self._launch_megastep())
         while inflight:
-            self._complete_inflight(inflight.popleft())
+            self._complete(*inflight.popleft())
         # Final body drain: FINAL windows already in the ring still get
         # verdicts (else their held requests eat the fail-open timeout).
         if self._body_scan is not None:
@@ -1825,256 +1714,6 @@ class RingSidecar:
             masks.append(~late)
         return masks
 
-    # -- device-resident megastep (ISSUE 12, docs/EXECUTOR.md) ----------------
-
-    def _mega_begin(self, oldest_enq_ms: Optional[int] = None) -> bool:
-        """Open a new megastep window? Called at a launch signal with
-        no window staged. `force` always megasteps (a K=1 window is
-        legal — masked, not re-shaped); `auto` engages only when more
-        traffic is already queued behind this batch (a lone batch would
-        pay window-fill latency for zero amortization); a demoted
-        megastep rung opens only backoff-probe windows (per-batch
-        dispatch serves meanwhile). The K target is sized down the pow2
-        ladder against the oldest row's remaining deadline slack
-        (sched.size_megastep_k) so a window never out-waits its own
-        budget. The serving mesh shards per-batch programs only —
-        mesh-active planes keep the per-batch path."""
-        if self._mega_fn is None or self.mesh.active:
-            return False
-        if self._mega_mode == "auto" and self._queued_depth() <= 0:
-            return False
-        if not self.ladder.try_rung("megastep"):
-            return False
-        self._mega_target = self._mega_k
-        if self._mega_mode != "force":
-            # Deadline-sized K (auto only — force is the operator
-            # pinning the cap for an oracle/bench arm).
-            now_ms = int(self.ring.lib.pingoo_ring_now_ms())
-            oldest = now_ms if oldest_enq_ms is None else oldest_enq_ms
-            self._mega_target = min(
-                self._mega_k, self.sched.size_megastep_k(
-                    self._mega_rungs, self.max_batch,
-                    oldest / 1e3, now_ms / 1e3))
-        self._mega_buf_id = self._mega_queue.checkout()
-        return True
-
-    def _mega_due(self) -> bool:
-        """Ship the open window now? Full to its K target, or the
-        oldest staged slice's remaining deadline slack no longer covers
-        the window's own cost estimate (waiting for more slices would
-        trade amortization for misses)."""
-        staged = self._mega_staged
-        if len(staged) >= self._mega_target:
-            return True
-        if self._mega_mode == "force":
-            # force pins the cap: only window-full (above) or an idle
-            # drain pass in the run loop ships a short window.
-            return False
-        oldest = min((s.oldest_enq_ms for s in staged
-                      if s.oldest_enq_ms is not None), default=None)
-        if oldest is None:
-            return True
-        now_ms = int(self.ring.lib.pingoo_ring_now_ms())
-        slack_ms = self.sched.config.deadline_ms - (now_ms - oldest)
-        return slack_ms <= self.sched.cost.estimate_megastep(
-            len(staged), self.max_batch)
-
-    def _stage_mega_slice(self, parts, n: int,
-                          oldest_enq_ms: Optional[int],
-                          slot_buf=None) -> None:
-        """Encode one admitted batch into the open window's next
-        DeviceInputQueue slice row. Mirrors `_dispatch`'s encode stage
-        exactly — same staging encoder, same ladder rung, same legacy
-        fallback — then copies into the queue's own stacks, so the
-        staging views are free to rotate immediately; the slice's
-        resolve-path raw views read the queue's copy (stable until this
-        buffer set is checked out again, nbuf-1 windows later)."""
-        from .engine.batch import RequestBatch, bucket_arrays, pad_batch
-
-        spans = self._begin_batch(parts, n)
-        with self._pipe.stage("encode", spans):
-            self.chaos.stage("encode")
-            batch = None
-            if slot_buf is not None:
-                slots = slot_buf[:n]
-                if self.ladder.try_rung("pipeline"):
-                    try:
-                        batch = self._staging.encode_slots(
-                            slots, pad_to=self.max_batch)
-                        self.ladder.note_success("pipeline")
-                    except Exception as exc:
-                        self.ladder.note_failure("pipeline", exc)
-                        batch = None
-            else:
-                slots = parts[0][1] if len(parts) == 1 else np.concatenate(
-                    [s for _, s in parts])
-            if batch is None:
-                batch = pad_batch(RequestBatch(
-                    size=n, arrays=bucket_arrays(slots_to_arrays(slots))),
-                    self.max_batch)
-            self._scan_columns.note(batch.arrays)
-            j = len(self._mega_staged)
-            self._mega_queue.fill_slice(self._mega_buf_id, j, batch.arrays,
-                                        n, self.ruleset_epoch)
-            # Compact staging (ISSUE 15): the capped views ride the
-            # existing fill_slice width logic; carry the encoder's depth-
-            # overflow flags so `_complete` re-serves those rows from the
-            # full slot view, same as the per-batch path.
-            raw = RequestBatch(size=n, arrays=self._mega_queue.slice_view(
-                self._mega_buf_id, j, n),
-                overflow=(batch.overflow[:n]
-                          if batch.overflow is not None else None))
-        # Staging IS this batch's admission: scheduler launch
-        # accounting and the fail-open sweep happen here, charging late
-        # rows the REMAINING cost — the whole window's estimate, since
-        # their verdicts land at its single sync.
-        now_ms = int(self.ring.lib.pingoo_ring_now_ms())
-        self.sched.note_launch(n, self._queued_depth())
-        if oldest_enq_ms is not None:
-            self._stage["sched"].observe(
-                max(0.0, float(now_ms - oldest_enq_ms)))
-        rec = _MegaSlice()
-        rec.parts = parts
-        rec.slots = slots
-        rec.raw = raw
-        rec.n = n
-        rec.skip_masks = None
-        if self.sched.config.failopen == "allow":
-            rec.skip_masks = self._failopen_late_rows(
-                parts, now_ms,
-                est_ms=self.sched.cost.estimate_megastep(
-                    self._mega_target, self.max_batch))
-        rec.slot_buf = slot_buf
-        rec.spans = spans
-        rec.epoch = self.ruleset_epoch
-        rec.oldest_enq_ms = oldest_enq_ms
-        self._mega_staged.append(rec)
-
-    def _launch_megastep(self) -> _MegaWindow:
-        """Ship the staged window's host stacks (one async device_put)
-        and dispatch ONE jitted megastep over its K slices (async);
-        returns the in-flight window record. A launch failure demotes
-        the megastep rung only — `_complete_megastep` serves the
-        window's slices from the interpreter, and per-batch dispatch
-        (which probes device health itself) takes over."""
-        staged, self._mega_staged = self._mega_staged, []
-        k = len(staged)
-        # Quantize the shipped leading dim to the NEXT pow2 rung >= k:
-        # each distinct K is its own XLA compile of the scan, so
-        # arbitrary short idle-drain windows would pay a fresh
-        # multi-second compile each. Padded slices ride along masked by
-        # their zeroed n_valid words — but padding still costs their
-        # scan iterations, so a short window ships at its own rung
-        # rather than the full cap (in force mode too: the pinned K
-        # caps the rung set, it does not inflate quiet windows).
-        k_ship = next((r for r in self._mega_rungs if r >= k),
-                      self._mega_k)
-        k_ship = max(k, min(k_ship, self._mega_k))
-        self.chaos.stage("dispatch")
-        self._dfa_rung_tick()
-        dev_out = None
-        # The window's dispatch and its one device wait are recorded on
-        # its first slice, whose compute cost is split over the k.
-        staged[0].spans.k = k
-        try:
-            self.chaos.maybe_xla_error(self.batches)
-            # Busy window: the first call per (K, widths) signature
-            # blocks in XLA for seconds; the watchdog heartbeats
-            # through it.
-            with self._hb_busy(), \
-                    self._pipe.stage("dispatch", staged[0].spans):
-                stacked, nv, ep = self._mega_queue.device_stack(
-                    self._mega_buf_id, k, pad_to=k_ship)
-                from .obs.perf import set_dispatch_context
-                set_dispatch_context(
-                    batch=next((int(a.shape[1]) for a in
-                                stacked.values()
-                                if getattr(a, "ndim", 0) == 3), None),
-                    k=k_ship)
-                dev_out = self._mega_fn.fn(self._tables, stacked,
-                                           nv, ep)  # async
-        except Exception as exc:
-            self.ladder.note_failure("megastep", exc)
-            dev_out = None
-        self._pipe.note_megastep(k, self._mega_mode)
-        self.mega_windows += 1
-        win = _MegaWindow()
-        win.slices = staged
-        win.k = k
-        win.k_ship = k_ship
-        win.dev_out = dev_out
-        # Window id (ISSUE 17 satellite): stamps every flight row this
-        # window serves, so stranded-slice reconciliation after a
-        # mid-window SIGKILL is traceable per window.
-        win.window_id = self.mega_windows
-        return win
-
-    def _complete_inflight(self, entry) -> None:
-        """Route one in-flight deque entry: a megastep window resolves
-        through its single-sync path, a per-batch tuple through
-        `_complete` as before."""
-        if isinstance(entry, _MegaWindow):
-            self._complete_megastep(entry)
-        else:
-            with self._pipe.stage("host_rules", entry[-1]) as sp:
-                self._complete(sp, *entry)
-
-    def _complete_megastep(self, win: _MegaWindow) -> None:
-        """Resolve one in-flight megastep window: host-rule lanes for
-        ALL K slices first (the device is still computing — same
-        overlap per-batch completion gets), then ONE device sync for
-        the whole window, then each slice resolves through `_complete`
-        handed its precomputed host+device lanes — every post/floor/
-        spill/route/provenance behavior is the shared code path, not a
-        clone. A sync failure demotes the megastep rung and serves the
-        window bit-identically from the interpreter."""
-        from .engine.verdict import host_rule_lanes
-
-        rec = win.slices[0].spans
-        lanes = hits = aux = ep_out = None
-        with self._pipe.stage("host_rules", rec) as sp:
-            hosts = [host_rule_lanes(self.plan, s.raw, self.lists)
-                     for s in win.slices]
-            sp.next("device_wait")
-            if win.dev_out is not None:
-                try:
-                    with self._hb_busy():  # one sync per K slices
-                        lanes = np.asarray(win.dev_out[0])
-                        hits = np.asarray(win.dev_out[1])
-                        aux = np.asarray(win.dev_out[2])
-                        ep_out = np.asarray(win.dev_out[3])
-                    self._note_device_success()
-                    self.ladder.note_success("megastep")
-                except Exception as exc:
-                    self.ladder.note_failure("megastep", exc)
-                    lanes = None
-        # The wait's exit fed the window wall (launch -> results), split
-        # per slice, to the compute-stage EWMA (admission slack) — never
-        # K near-zero syncs; the megastep EWMA (K sizing) is keyed by
-        # the SHIPPED K, the compiled shape that set the window's cost.
-        self.device_wait_s += rec.span_ms("device_wait", "device_wait") / 1e3
-        self.sched.observe_megastep_cost(win.k_ship, self.max_batch,
-                                         rec.compute_ms)
-        for j, s in enumerate(win.slices):
-            if ep_out is not None and int(ep_out[j]) != s.epoch:
-                # The device program echoes each slice's staged epoch
-                # untouched; a mismatch would mean a slice crossed a
-                # swap boundary (tests assert this stays 0).
-                self.mega_echo_mismatch += 1
-            s.spans.tags.update(megastep_window=win.window_id,
-                                megastep_k=win.k_ship, staging_mode="full")
-            with self._pipe.stage("resolve", s.spans) as sp:
-                self._complete(
-                    sp, s.parts, s.slots, s.raw, None,
-                    (hits[j] if lanes is not None and self._provenance_on
-                     else None),
-                    (aux[j] if lanes is not None
-                     and self._pf_fn is not None else None),
-                    s.n, s.skip_masks, s.slot_buf, s.spans,
-                    host=hosts[j], pre=True,
-                    dev_lanes=(lanes[j][:, :s.n] if lanes is not None
-                               else None))
-
     def _enrich_slots(self, slots: np.ndarray) -> None:
         """Fill asn/country in place for rows the producer enqueued with
         the unknown markers (asn 0 + country "XX"). GeoipDB caches both
@@ -2099,23 +1738,17 @@ class RingSidecar:
             if len(cc) == 2:
                 slots["country"][i] = cc
 
-    def _complete(self, sp, parts, slots, raw_batch, dev, rule_hits,
-                  pf_aux, n: int, skip_masks, slot_buf, rec, host=None,
-                  pre=False, dev_lanes=None) -> None:
-        """Resolve one batch inside the caller's stage block `sp`
-        (opened on `host_rules`, or on `resolve` for a megastep slice)."""
+    def _complete(self, parts, slots, raw_batch, dev, rule_hits,
+                  pf_aux, n: int, skip_masks, slot_buf, rec) -> None:
+        """Resolve the oldest in-flight batch (`_dispatch`'s tuple):
+        host rules, the device sync, merge, post, provenance."""
         from .engine.verdict import host_rule_lanes, merge_lanes
 
-        # Megastep slices (ISSUE 12) arrive with host AND device lanes
-        # already resolved by _complete_megastep's single window sync —
-        # `pre` skips the per-batch sync and its compute-cost feeds
-        # (the window attributed them once; K near-zero observations
-        # would drag the compute EWMA toward zero).
-        wait_s = 0.0
-        if not pre:
+        with self._pipe.stage("host_rules", rec) as sp:
             # Host-interpreted rules run on the UNPADDED batch while the
             # device lanes are still in flight (jax dispatch is async).
             host = host_rule_lanes(self.plan, raw_batch, self.lists)
+            dev_lanes = None
             sp.next("device_wait")
             if dev is not None:
                 try:
@@ -2140,224 +1773,225 @@ class RingSidecar:
             wait_s = sp.next("resolve")
             self.device_wait_s += wait_s
             self.sched.observe_cost(self.max_batch, rec.compute_ms)
-        if pf_aux is not None:
-            # Resolved long before the lane sync above; aux int32 lanes.
-            vals = np.asarray(pf_aux)
-            denom = self.max_batch * self._pf_gated_banks
-            if denom:
-                self._pf_rate_gauge.set(int(vals[0]) / denom)
-            self._pf_skip_counter.inc(int(vals[1]))
-            if self._pf_attr is not None:
-                self._pf_attr.observe(vals, self.max_batch)
-        from .engine.verdict import dfa_dispatch_counts
+            if pf_aux is not None:
+                # Resolved long before the lane sync above; aux int32 lanes.
+                vals = np.asarray(pf_aux)
+                denom = self.max_batch * self._pf_gated_banks
+                if denom:
+                    self._pf_rate_gauge.set(int(vals[0]) / denom)
+                self._pf_skip_counter.inc(int(vals[1]))
+                if self._pf_attr is not None:
+                    self._pf_attr.observe(vals, self.max_batch)
+            from .engine.verdict import dfa_dispatch_counts
 
-        dfa_mode, dfa_banks, dfa_rechecks = dfa_dispatch_counts(self.plan)
-        if dfa_banks:
-            ctr = self._dfa_banks_counter.get(dfa_mode)
-            if ctr is not None:
-                ctr.inc(dfa_banks)
-            if dfa_rechecks:
-                self._dfa_recheck_counter.inc(dfa_rechecks)
-        self.chaos.stage("resolve")
-        self.batches += 1
-        route = None
-        if dev_lanes is None:
-            # Ladder device-rung fallback: the host interpreter — the
-            # parity oracle every fast path is tested against — serves
-            # the whole batch, bit-identically, at host speed.
-            with self._hb_busy():  # host interpret blocks the loop
-                unverified, verified_block, route = self._interpret_batch(
-                    parts, raw_batch)
-        else:
-            unverified, verified_block = merge_lanes(dev_lanes, host)
-        # Rows the producer flagged as truncated (a field exceeded its
-        # 2048-byte slot cap) were matched on the slot view — the widest
-        # bytes this plane carries. Count them so the residual truncation
-        # window (>2048B fields) is observable; the Python plane
-        # re-evaluates such rows on fully untruncated strings
-        # (engine/service.py).
-        self.truncated_rows += int(
-            ((slots["flags"] & SLOT_FLAG_TRUNCATED) != 0).sum())
-        # Per-row route: each ring's rows read THEIR listener group's
-        # route lane (make_lane_fn stacks one lane per distinct service
-        # order at rows 3..3+G; the reference binds a service list per
-        # listener, config.rs:241-253). Rows from rings with no service
-        # group keep route 0 — their consumer never reads bits 3-7.
-        if self._groups and dev_lanes is not None:
-            route = np.zeros(n, dtype=np.int64)
-            group_rows: list[list] = [[] for _ in self._groups]
-            off = 0
-            for ring, part in parts:
-                gi = self._ring_group_of.get(id(ring))
-                m = len(part)
-                if gi is not None:
-                    route[off:off + m] = np.asarray(
-                        dev_lanes[3 + gi][off:off + m], dtype=np.int64)
-                    group_rows[gi].append(np.arange(off, off + m))
-                off += m
-            contexts = None
-            for gi, chunks in enumerate(group_rows):
-                if not self._host_routes[gi] or not chunks:
-                    continue
-                rows = np.concatenate(chunks)
-                from .engine.batch import batch_to_contexts
-                from .expr import execute_as_bool
-
-                for order, prog in self._host_routes[gi]:
-                    better = rows[route[rows] > order]
-                    if not len(better):
+            dfa_mode, dfa_banks, dfa_rechecks = dfa_dispatch_counts(self.plan)
+            if dfa_banks:
+                ctr = self._dfa_banks_counter.get(dfa_mode)
+                if ctr is not None:
+                    ctr.inc(dfa_banks)
+                if dfa_rechecks:
+                    self._dfa_recheck_counter.inc(dfa_rechecks)
+            self.chaos.stage("resolve")
+            self.batches += 1
+            route = None
+            if dev_lanes is None:
+                # Ladder device-rung fallback: the host interpreter — the
+                # parity oracle every fast path is tested against — serves
+                # the whole batch, bit-identically, at host speed.
+                with self._hb_busy():  # host interpret blocks the loop
+                    unverified, verified_block, route = self._interpret_batch(
+                        parts, raw_batch)
+            else:
+                unverified, verified_block = merge_lanes(dev_lanes, host)
+            # Rows the producer flagged as truncated (a field exceeded its
+            # 2048-byte slot cap) were matched on the slot view — the widest
+            # bytes this plane carries. Count them so the residual truncation
+            # window (>2048B fields) is observable; the Python plane
+            # re-evaluates such rows on fully untruncated strings
+            # (engine/service.py).
+            self.truncated_rows += int(
+                ((slots["flags"] & SLOT_FLAG_TRUNCATED) != 0).sum())
+            # Per-row route: each ring's rows read THEIR listener group's
+            # route lane (make_lane_fn stacks one lane per distinct service
+            # order at rows 3..3+G; the reference binds a service list per
+            # listener, config.rs:241-253). Rows from rings with no service
+            # group keep route 0 — their consumer never reads bits 3-7.
+            if self._groups and dev_lanes is not None:
+                route = np.zeros(n, dtype=np.int64)
+                group_rows: list[list] = [[] for _ in self._groups]
+                off = 0
+                for ring, part in parts:
+                    gi = self._ring_group_of.get(id(ring))
+                    m = len(part)
+                    if gi is not None:
+                        route[off:off + m] = np.asarray(
+                            dev_lanes[3 + gi][off:off + m], dtype=np.int64)
+                        group_rows[gi].append(np.arange(off, off + m))
+                    off += m
+                contexts = None
+                for gi, chunks in enumerate(group_rows):
+                    if not self._host_routes[gi] or not chunks:
                         continue
-                    if contexts is None:
-                        contexts = batch_to_contexts(raw_batch, self.lists)
-                    for i in better:
-                        try:
-                            hit = prog is None or execute_as_bool(
-                                prog, contexts[i])
-                        except Exception:
-                            hit = False  # route errors fail to no-match
-                        if hit:
-                            route[i] = order
-        # Rows whose url/path overflowed the slot caps carry their FULL
-        # strings in the owning ring's spill area: re-evaluate every
-        # lane for those rows through the host interpreter over the
-        # untruncated bytes — exact parity with the reference, which
-        # matches full strings (http_listener.rs:140-141). Rows flagged
-        # truncated WITHOUT a spill slot (pool exhausted / > 64 KiB)
-        # keep the slot-view verdict and remain visible in
-        # truncated_rows above.
-        off = 0
-        for ring, part in parts:
-            gi = self._ring_group_of.get(id(ring))
-            svcs = self._groups[gi] if gi is not None else None
-            spilled = np.nonzero(part["spill_idx"] != SPILL_NONE)[0]
-            for j in spilled:
-                idx = int(part["spill_idx"][j])
-                full = ring.spill_read(idx)
-                if full is not None:
-                    unv, vblk, rt = self._interpret_overflow_row(
-                        part[j], full[0], full[1], svcs)
-                    unverified[off + j] = unv
-                    verified_block[off + j] = vblk
-                    if route is not None and gi is not None:
-                        route[off + j] = rt
-                    self.spilled_rows += 1
-                ring.spill_release(idx)
-            off += len(part)
-        # Depth-capped rows (ISSUE 15, PINGOO_STAGING=compact with a
-        # PINGOO_STAGING_DEPTH clamp below a field's required depth):
-        # the device matched a plan-capped prefix narrower than the
-        # slot bytes, so re-serve every lane for those rows from the
-        # FULL slot view through the host interpreter — the same
-        # exactness contract as the spill loop above. Spilled rows
-        # already re-evaluated over their untruncated strings; with no
-        # clamp the encoder's thresholds equal the slot caps and this
-        # mask is empty by construction.
-        over = getattr(raw_batch, "overflow", None)
-        if over is not None and over[:n].any():
+                    rows = np.concatenate(chunks)
+                    from .engine.batch import batch_to_contexts
+                    from .expr import execute_as_bool
+
+                    for order, prog in self._host_routes[gi]:
+                        better = rows[route[rows] > order]
+                        if not len(better):
+                            continue
+                        if contexts is None:
+                            contexts = batch_to_contexts(raw_batch, self.lists)
+                        for i in better:
+                            try:
+                                hit = prog is None or execute_as_bool(
+                                    prog, contexts[i])
+                            except Exception:
+                                hit = False  # route errors fail to no-match
+                            if hit:
+                                route[i] = order
+            # Rows whose url/path overflowed the slot caps carry their FULL
+            # strings in the owning ring's spill area: re-evaluate every
+            # lane for those rows through the host interpreter over the
+            # untruncated bytes — exact parity with the reference, which
+            # matches full strings (http_listener.rs:140-141). Rows flagged
+            # truncated WITHOUT a spill slot (pool exhausted / > 64 KiB)
+            # keep the slot-view verdict and remain visible in
+            # truncated_rows above.
             off = 0
             for ring, part in parts:
                 gi = self._ring_group_of.get(id(ring))
                 svcs = self._groups[gi] if gi is not None else None
-                rows = np.nonzero(over[off:off + len(part)]
-                                  & (part["spill_idx"] == SPILL_NONE))[0]
-                for j in rows:
-                    s = part[j]
-                    unv, vblk, rt = self._interpret_overflow_row(
-                        s, bytes(s["url"][:int(s["url_len"])]),
-                        bytes(s["path"][:int(s["path_len"])]), svcs)
-                    unverified[off + j] = unv
-                    verified_block[off + j] = vblk
-                    if route is not None and gi is not None:
-                        route[off + j] = rt
-                    self.depth_overflow_rows += 1
+                spilled = np.nonzero(part["spill_idx"] != SPILL_NONE)[0]
+                for j in spilled:
+                    idx = int(part["spill_idx"][j])
+                    full = ring.spill_read(idx)
+                    if full is not None:
+                        unv, vblk, rt = self._interpret_overflow_row(
+                            part[j], full[0], full[1], svcs)
+                        unverified[off + j] = unv
+                        verified_block[off + j] = vblk
+                        if route is not None and gi is not None:
+                            route[off + j] = rt
+                        self.spilled_rows += 1
+                    ring.spill_release(idx)
                 off += len(part)
-        # Verdict byte carries BOTH client-state lanes (the reference
-        # action loop diverges for captcha-verified clients,
-        # http_listener.rs:251-264): bits 0-1 = unverified action
-        # (0 none / 1 block / 2 captcha), bit 2 = verified-block, and —
-        # when this sidecar routes for a native listener — bits 3-7 =
-        # the first matching service's order (31 = no service matched,
-        # reference service-selection loop http_listener.rs:266-270).
-        actions = unverified | (verified_block.astype(np.int32) << 2)
-        if route is not None:
-            actions = actions | (np.minimum(route, 31).astype(np.int32) << 3)
-        acts = actions[:n].astype(np.uint8)
-        off = 0
-        for pi, (ring, part) in enumerate(parts):  # scatter per ring
-            m = len(part)
-            # Rows the scheduler already failed open at launch
-            # (skip_masks, PINGOO_SCHED_FAILOPEN=allow) were posted
-            # then; posting again would hand their consumer a second
-            # verdict for the same ticket.
-            if skip_masks is not None and not skip_masks[pi].all():
-                keep = skip_masks[pi]
-                tickets = np.ascontiguousarray(part["ticket"][keep],
-                                               dtype=np.uint64)
-                pacts = np.ascontiguousarray(acts[off:off + m][keep])
-                waits = part["enq_ms"][keep]
-            else:
-                tickets = np.ascontiguousarray(part["ticket"],
-                                               dtype=np.uint64)
-                pacts = acts[off:off + m]
-                waits = part["enq_ms"]
-            k = len(tickets)
-            done = 0
-            while done < k:  # one FFI hop per batch, resume on a full ring
-                if self.chaos.verdict_full():  # injected full-ring stall
-                    time.sleep(self.idle_sleep_s)
-                    continue
-                done += ring.post_verdicts(tickets[done:], pacts[done:])
-                if done < k:
-                    if self._stop:  # a dead consumer must not wedge stop()
-                        self._pipe.finish()
-                        return
-                    time.sleep(self.idle_sleep_s)
-            # Telemetry: enqueue -> verdict-post wall time for this
-            # ring's rows lands in the shm wait histogram (one FFI hop).
-            ring.record_waits(waits)
-            # Posted-floor advance (ring v5, docs/RESILIENCE.md): every
-            # ticket of this part now has a verdict (skip-mask rows
-            # were posted at launch), and parts complete in FIFO order,
-            # so posted tickets form a prefix — a reattaching sidecar's
-            # orphan scan starts above this mark.
-            if m:
-                ring.set_posted_floor(int(part["ticket"].max()) + 1)
-            off += m
-        # Deadline accounting on the ring clock: rows posted after
-        # their PINGOO_DEADLINE_MS budget count as misses (one
-        # vectorized compare per batch).
-        post_ms = int(self.ring.lib.pingoo_ring_now_ms())
-        self.sched.note_misses(int(
-            ((post_ms - slots["enq_ms"].astype(np.int64))
-             > self.sched.config.deadline_ms).sum()))
-        sp.next("provenance")
-        if self._attribution is not None and dev_lanes is not None:
-            # Interpreter-served batches (device rung demoted) skip
-            # attribution/parity: the aux lane never ran, and auditing
-            # the oracle against itself proves nothing.
-            self._observe_provenance(slots, rule_hits, dev_lanes, host,
-                                     raw_batch, unverified,
-                                     verified_block, wait_s, n, rec)
-        # Cross-plane timeline (ISSUE 17): per-batch cost while
-        # unsampled is the one add+compare inside sample(). The rows'
-        # enq_ms stamps are the NATIVE producer's ring clock — same
-        # CLOCK_MONOTONIC timebase as the batch's recorded points, which
-        # is what joins the ring-wait span across planes.
-        if self._timeline.sample():
-            self._timeline.batch_sidecar(
-                points=rec.points,
-                rows=[(f"t-{int(slots['ticket'][i])}",
-                       int(slots["enq_ms"][i]))
-                      for i in range(
-                          min(n, self._timeline.rows_per_batch))],
-                args=rec.tags)
-        self.processed += n
-        # The batch is fully resolved: its accumulation buffer returns
-        # to the pool and its pipeline slot retires.
-        if slot_buf is not None:
-            self._slot_pool.append(slot_buf)
-        self._pipe.finish()
-        self.chaos.on_batch_done(self.batches)
+            # Depth-capped rows (ISSUE 15, PINGOO_STAGING=compact with a
+            # PINGOO_STAGING_DEPTH clamp below a field's required depth):
+            # the device matched a plan-capped prefix narrower than the
+            # slot bytes, so re-serve every lane for those rows from the
+            # FULL slot view through the host interpreter — the same
+            # exactness contract as the spill loop above. Spilled rows
+            # already re-evaluated over their untruncated strings; with no
+            # clamp the encoder's thresholds equal the slot caps and this
+            # mask is empty by construction.
+            over = getattr(raw_batch, "overflow", None)
+            if over is not None and over[:n].any():
+                off = 0
+                for ring, part in parts:
+                    gi = self._ring_group_of.get(id(ring))
+                    svcs = self._groups[gi] if gi is not None else None
+                    rows = np.nonzero(over[off:off + len(part)]
+                                      & (part["spill_idx"] == SPILL_NONE))[0]
+                    for j in rows:
+                        s = part[j]
+                        unv, vblk, rt = self._interpret_overflow_row(
+                            s, bytes(s["url"][:int(s["url_len"])]),
+                            bytes(s["path"][:int(s["path_len"])]), svcs)
+                        unverified[off + j] = unv
+                        verified_block[off + j] = vblk
+                        if route is not None and gi is not None:
+                            route[off + j] = rt
+                        self.depth_overflow_rows += 1
+                    off += len(part)
+            # Verdict byte carries BOTH client-state lanes (the reference
+            # action loop diverges for captcha-verified clients,
+            # http_listener.rs:251-264): bits 0-1 = unverified action
+            # (0 none / 1 block / 2 captcha), bit 2 = verified-block, and —
+            # when this sidecar routes for a native listener — bits 3-7 =
+            # the first matching service's order (31 = no service matched,
+            # reference service-selection loop http_listener.rs:266-270).
+            actions = unverified | (verified_block.astype(np.int32) << 2)
+            if route is not None:
+                actions = actions | (
+                    np.minimum(route, 31).astype(np.int32) << 3)
+            acts = actions[:n].astype(np.uint8)
+            off = 0
+            for pi, (ring, part) in enumerate(parts):  # scatter per ring
+                m = len(part)
+                # Rows the scheduler already failed open at launch
+                # (skip_masks, PINGOO_SCHED_FAILOPEN=allow) were posted
+                # then; posting again would hand their consumer a second
+                # verdict for the same ticket.
+                if skip_masks is not None and not skip_masks[pi].all():
+                    keep = skip_masks[pi]
+                    tickets = np.ascontiguousarray(part["ticket"][keep],
+                                                   dtype=np.uint64)
+                    pacts = np.ascontiguousarray(acts[off:off + m][keep])
+                    waits = part["enq_ms"][keep]
+                else:
+                    tickets = np.ascontiguousarray(part["ticket"],
+                                                   dtype=np.uint64)
+                    pacts = acts[off:off + m]
+                    waits = part["enq_ms"]
+                k = len(tickets)
+                done = 0
+                while done < k:  # one FFI hop per batch, resume on a full ring
+                    if self.chaos.verdict_full():  # injected full-ring stall
+                        time.sleep(self.idle_sleep_s)
+                        continue
+                    done += ring.post_verdicts(tickets[done:], pacts[done:])
+                    if done < k:
+                        if self._stop:  # a dead consumer must not wedge stop()
+                            self._pipe.finish()
+                            return
+                        time.sleep(self.idle_sleep_s)
+                # Telemetry: enqueue -> verdict-post wall time for this
+                # ring's rows lands in the shm wait histogram (one FFI hop).
+                ring.record_waits(waits)
+                # Posted-floor advance (ring v5, docs/RESILIENCE.md): every
+                # ticket of this part now has a verdict (skip-mask rows
+                # were posted at launch), and parts complete in FIFO order,
+                # so posted tickets form a prefix — a reattaching sidecar's
+                # orphan scan starts above this mark.
+                if m:
+                    ring.set_posted_floor(int(part["ticket"].max()) + 1)
+                off += m
+            # Deadline accounting on the ring clock: rows posted after
+            # their PINGOO_DEADLINE_MS budget count as misses (one
+            # vectorized compare per batch).
+            post_ms = int(self.ring.lib.pingoo_ring_now_ms())
+            self.sched.note_misses(int(
+                ((post_ms - slots["enq_ms"].astype(np.int64))
+                 > self.sched.config.deadline_ms).sum()))
+            sp.next("provenance")
+            if self._attribution is not None and dev_lanes is not None:
+                # Interpreter-served batches (device rung demoted) skip
+                # attribution/parity: the aux lane never ran, and auditing
+                # the oracle against itself proves nothing.
+                self._observe_provenance(slots, rule_hits, dev_lanes, host,
+                                         raw_batch, unverified,
+                                         verified_block, wait_s, n, rec)
+            # Cross-plane timeline (ISSUE 17): per-batch cost while
+            # unsampled is the one add+compare inside sample(). The rows'
+            # enq_ms stamps are the NATIVE producer's ring clock — same
+            # CLOCK_MONOTONIC timebase as the batch's recorded points, which
+            # is what joins the ring-wait span across planes.
+            if self._timeline.sample():
+                self._timeline.batch_sidecar(
+                    points=rec.points,
+                    rows=[(f"t-{int(slots['ticket'][i])}",
+                           int(slots["enq_ms"][i]))
+                          for i in range(
+                              min(n, self._timeline.rows_per_batch))],
+                    args=rec.tags)
+            self.processed += n
+            # The batch is fully resolved: its accumulation buffer returns
+            # to the pool and its pipeline slot retires.
+            if slot_buf is not None:
+                self._slot_pool.append(slot_buf)
+            self._pipe.finish()
+            self.chaos.on_batch_done(self.batches)
 
     def _observe_provenance(self, slots, rule_hits, dev_lanes, host,
                             raw_batch, unverified, verified_block,
@@ -2400,10 +2034,8 @@ class RingSidecar:
             }
             # Pipeline slot id (ISSUE 9): lines this record up against
             # the pingoo_pipeline_* series and the `batch` stat of the
-            # trace's sidecar/* spans. Window id + K rung + staging mode
-            # (ISSUE 17 satellite): without these, stranded-slice
-            # reconciliation after a mid-window SIGKILL cannot tell
-            # which window a row rode.
+            # trace's sidecar/* spans; the batch's tags (staging mode)
+            # ride along.
             stages["pipeline_slot"] = rec.seq
             stages.update(rec.tags)
             recorder.record(
@@ -2482,18 +2114,6 @@ class RingSidecar:
                 self.plan, service_groups=self._groups or None,
                 with_rule_hits=self._provenance_on,
                 donate=donate_batch_buffers()), "lanes",
-                plane="sidecar", fingerprint=fp, widths=widths)
-        if self._mega_fn is not None:
-            # The megastep embeds the same lane body — keep its DFA
-            # dispatch in lockstep with the per-batch program.
-            from .engine.verdict import make_megastep_fn
-            from .obs.perf import instrument_megastep
-
-            self._mega_fn = instrument_megastep(
-                make_megastep_fn(
-                    self.plan, kind="lanes",
-                    service_groups=self._groups or None,
-                    with_rule_hits=self._provenance_on),
                 plane="sidecar", fingerprint=fp, widths=widths)
 
     def _dfa_rung_tick(self) -> None:
@@ -2766,12 +2386,6 @@ class RingSidecar:
             "sched": self.sched.snapshot(),
             "mesh": self.mesh.describe(),
             "pipeline": self._pipe.snapshot(),
-            "megastep": {
-                "mode": self._mega_mode,
-                "k_cap": self._mega_k,
-                "windows": self.mega_windows,
-                "echo_mismatch": self.mega_echo_mismatch,
-            },
             "ladder": self.ladder.snapshot(),
             "supervision": {"epoch": self.epoch,
                             "reconciled": dict(self.reconciled)},

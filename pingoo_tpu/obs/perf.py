@@ -2,8 +2,8 @@
 and durable.
 
 The engine's latency cliffs are XLA compiles: the first call of every
-jitted program per abstract signature (pow2 batch bucket, megastep K
-rung, staging widths-tuple) blocks for seconds, and a *recompile storm*
+jitted program per abstract signature (pow2 batch bucket, staging
+widths-tuple) blocks for seconds, and a *recompile storm*
 — a plan swap or a bucket ladder walking shapes under live traffic —
 is the difference between a 2 ms p99 and a multi-second outage. The
 stage histograms can't see it (they attribute the stall to whatever
@@ -51,12 +51,12 @@ from collections import deque
 from typing import Any, Callable, Optional
 
 # The fn-kind label values the wrappers emit (verdict/lane/prefilter
-# programs, their packed-staging twins under the same label, the
-# megastep scan, and the bot-score program).
-COMPILE_FN_KINDS = ("verdict", "lanes", "prefilter", "megastep", "score")
+# programs, their packed-staging twins under the same label, and the
+# bot-score program).
+COMPILE_FN_KINDS = ("verdict", "lanes", "prefilter", "score")
 
 # pingoo_compile_ms histogram bounds: sub-ms cache refreshes up to the
-# multi-second cold megastep compiles BENCH_pipeline measured (~9.5 s).
+# multi-second cold compiles of a whole lane program.
 COMPILE_BUCKETS_MS = (1.0, 5.0, 25.0, 100.0, 250.0, 500.0, 1000.0,
                       2500.0, 5000.0, 10000.0, 30000.0)
 
@@ -121,17 +121,13 @@ def _arg_shapes(args) -> list:
     return shapes
 
 
-def _shape_context(shapes: list) -> tuple:
-    """(batch_bucket, k) best-effort from the compile-time arg shapes:
-    the batch bucket is the most common leading dim of the 2-D request
-    arrays; K is the leading dim of a 3-D stacked megastep input."""
+def _shape_context(shapes: list) -> Optional[int]:
+    """The batch bucket, best-effort from the compile-time arg shapes:
+    the most common leading dim of the 2-D request arrays."""
     from collections import Counter
 
     lead2 = Counter(s[0] for s in shapes if len(s) == 2)
-    bucket = lead2.most_common(1)[0][0] if lead2 else None
-    lead3 = Counter(s[0] for s in shapes if len(s) == 3)
-    k = lead3.most_common(1)[0][0] if lead3 else None
-    return bucket, k
+    return lead2.most_common(1)[0][0] if lead2 else None
 
 
 def load_compile_surface(path: str) -> Optional[dict]:
@@ -159,9 +155,6 @@ def event_in_surface(event: dict, surface: dict) -> Optional[str]:
     if bucket is not None and bucket not in surface.get(
             "batch_buckets", ()):
         return f"batch_bucket={bucket}"
-    k = event.get("k")
-    if k is not None and k not in surface.get("k_rungs", ()):
-        return f"k={k}"
     widths = [list(w) for w in event.get("widths") or ()]
     if widths and "widths" in surface and widths not in surface["widths"]:
         return "widths"
@@ -179,15 +172,12 @@ _SURFACE_UNSET = object()
 _DISPATCH_TLS = threading.local()
 
 
-def set_dispatch_context(batch: Optional[int] = None,
-                         k: Optional[int] = None) -> None:
+def set_dispatch_context(batch: Optional[int] = None) -> None:
     _DISPATCH_TLS.batch = batch
-    _DISPATCH_TLS.k = k
 
 
-def dispatch_context() -> tuple:
-    return (getattr(_DISPATCH_TLS, "batch", None),
-            getattr(_DISPATCH_TLS, "k", None))
+def dispatch_context() -> Optional[int]:
+    return getattr(_DISPATCH_TLS, "batch", None)
 
 
 def batch_leading_dim(arrays) -> Optional[int]:
@@ -289,15 +279,13 @@ class CompileLedger:
     def note(self, *, plane: str, fn: str, kind: str, wall_ms: float,
              fingerprint: str = "", widths: tuple = (),
              shapes: Optional[list] = None,
-             batch_bucket: Optional[int] = None,
-             k: Optional[int] = None) -> None:
+             batch_bucket: Optional[int] = None) -> None:
         """One trace/compile event (called from the compile branch of
-        an instrumented call — rare by construction). Explicit
-        batch_bucket/k (from set_dispatch_context) win over the
+        an instrumented call — rare by construction). An explicit
+        batch_bucket (from set_dispatch_context) wins over the
         arg-shape heuristic, which cannot see through packed blobs."""
-        h_bucket, h_k = _shape_context(shapes or [])
-        bucket = batch_bucket if batch_bucket is not None else h_bucket
-        k = k if k is not None else h_k
+        bucket = (batch_bucket if batch_bucket is not None
+                  else _shape_context(shapes or []))
         event = {
             "ts": round(time.time(), 3),
             "plane": plane,
@@ -305,7 +293,6 @@ class CompileLedger:
             "kind": kind,
             "wall_ms": round(wall_ms, 3),
             "batch_bucket": bucket,
-            "k": k,
             "widths": [list(w) for w in widths],
             "fingerprint": fingerprint,
             "shapes": [list(s) for s in (shapes or [])[:12]],
@@ -417,12 +404,11 @@ class _InstrumentedJit:
                 wall_ms = (time.monotonic() - t0) * 1e3
                 kind = "cold" if self._compiles == 0 else "warm"
                 self._compiles += 1
-                ctx_batch, ctx_k = dispatch_context()
                 self._ledger.note(
                     plane=self._plane, fn=self._name, kind=kind,
                     wall_ms=wall_ms, fingerprint=self._fingerprint,
                     widths=self._widths, shapes=_arg_shapes(args),
-                    batch_bucket=ctx_batch, k=ctx_k)
+                    batch_bucket=dispatch_context())
         return out
 
     def __getattr__(self, item):
@@ -443,31 +429,3 @@ def instrument_jit(fn, name: str, *, plane: str, fingerprint: str = "",
     if not ledger.enabled:
         return fn
     return _InstrumentedJit(fn, name, plane, fingerprint, widths, ledger)
-
-
-class _InstrumentedMegastep:
-    """Shape-preserving wrapper for make_megastep_fn's program record:
-    `.fn` is the instrumented callable, everything else delegates."""
-
-    __slots__ = ("_prog", "fn")
-
-    def __init__(self, prog, fn):
-        self._prog = prog
-        self.fn = fn
-
-    def __getattr__(self, item):
-        return getattr(self._prog, item)
-
-
-def instrument_megastep(prog, *, plane: str, fingerprint: str = "",
-                        widths: tuple = (), ledger=None):
-    """instrument_jit for the megastep program object (callable at
-    `.fn`, metadata like `.aux_len` preserved)."""
-    if prog is None:
-        return None
-    fn = instrument_jit(prog.fn, "megastep", plane=plane,
-                        fingerprint=fingerprint, widths=widths,
-                        ledger=ledger)
-    if fn is prog.fn:
-        return prog
-    return _InstrumentedMegastep(prog, fn)

@@ -93,18 +93,15 @@ class BatchSpans:
     recorded for it: rides the in-flight tuple from launch to resolve.
     `seq` is the loop's batch counter (the pipeline slot id, and the
     `batch` stat of every span the batch causes); `points` maps a phase
-    to its (t_start, t_end) in time.monotonic() seconds; `k` is how many
-    batches share the device wait (a megastep window's slices); `rings`
-    is how many of the sidecar's rings gave the batch rows."""
+    to its (t_start, t_end) in time.monotonic() seconds; `rings` is how
+    many of the sidecar's rings gave the batch rows."""
 
-    __slots__ = ("seq", "rows", "rings", "k", "points", "tags",
-                 "compute_ms")
+    __slots__ = ("seq", "rows", "rings", "points", "tags", "compute_ms")
 
     def __init__(self, seq: int, rows: int, rings: int = 1):
         self.seq = seq
         self.rows = rows
         self.rings = rings
-        self.k = 1
         self.points: dict[str, tuple] = {}
         self.tags: dict = {}
         self.compute_ms = 0.0
@@ -171,21 +168,6 @@ class PipelineStats:
                 schema.PIPELINE_METRICS["pingoo_pipeline_stage_occupancy"],
                 labels={"plane": plane, "stage": stage})
             for stage in PIPELINE_EXEC_STAGES}
-        # Device-resident megastep instruments (ISSUE 12): K of the
-        # latest window, slices served per PINGOO_MEGASTEP mode, and
-        # the EWMA dispatch-amortization factor (slices per device
-        # dispatch; 1.0 means the plane is back to per-batch dispatch).
-        self.megastep_k = registry.gauge(
-            "pingoo_megastep_k",
-            schema.PIPELINE_METRICS["pingoo_megastep_k"], labels=labels)
-        self.megastep_amortization = registry.gauge(
-            "pingoo_megastep_amortization",
-            schema.PIPELINE_METRICS["pingoo_megastep_amortization"],
-            labels=labels)
-        self._megastep_batches: dict[str, object] = {}
-        self._amort_ewma: float | None = None
-        self.megastep_windows = 0
-        self.megastep_slices = 0
         self._batches: dict[str, object] = {}
         self._slot_seq = 0
         self._t_boot = time.monotonic()
@@ -220,29 +202,6 @@ class PipelineStats:
 
     def exit(self) -> None:
         self.inflight.dec()
-
-    def note_megastep(self, k: int, mode: str) -> None:
-        """One K-slice megastep window launched under PINGOO_MEGASTEP
-        `mode` (hot; ISSUE 12): updates the K gauge, the per-mode slice
-        counter, and the EWMA dispatch-amortization factor."""
-        k = max(1, int(k))
-        self.megastep_k.set(k)
-        counter = self._megastep_batches.get(mode)
-        if counter is None:
-            from . import schema
-
-            counter = self._registry.counter(
-                "pingoo_megastep_batches_total",
-                schema.PIPELINE_METRICS["pingoo_megastep_batches_total"],
-                labels={"plane": self.plane, "mode": mode})
-            self._megastep_batches[mode] = counter
-        counter.inc(k)
-        self.megastep_windows += 1
-        self.megastep_slices += k
-        prev = self._amort_ewma
-        self._amort_ewma = (float(k) if prev is None
-                            else prev + _EWMA_ALPHA * (k - prev))
-        self.megastep_amortization.set(round(self._amort_ewma, 6))
 
     def note_stage(self, slot: int, stage: str, t_start: float,
                    t_end: float) -> None:
@@ -424,7 +383,7 @@ class PipelineStats:
         """The executor's view of a phase: occupancy/overlap stage and
         the scheduler's stage cost. `dispatch` runs from the prefilter's
         issue to the lane program's; `compute` from the launch to the
-        results, split over the k batches that shared the wait."""
+        results."""
         stage = _PHASE_EXEC.get(name)
         if stage is None:
             return
@@ -435,8 +394,7 @@ class PipelineStats:
             rec.compute_ms = (t - t0) * 1e3
         self.note_stage(rec.seq, stage, t0, t)
         if stage != "resolve":  # the cost model has no resolve stage
-            self._observe_cost(stage, self._cost_size, (t - t0) * 1e3
-                               / (rec.k if stage == "compute" else 1))
+            self._observe_cost(stage, self._cost_size, (t - t0) * 1e3)
 
     def _push(self, name: str, rec) -> None:
         t = time.monotonic()
@@ -501,15 +459,4 @@ class PipelineStats:
                 for stage in PIPELINE_EXEC_STAGES},
             "loop_ms": {p: round(c.value, 3)
                         for p, c in self._loop_ctr.items()},
-            "megastep": {
-                "k": self.megastep_k.value,
-                "windows": self.megastep_windows,
-                "slices": self.megastep_slices,
-                "amortization": (round(self._amort_ewma, 4)
-                                 if self._amort_ewma is not None
-                                 else None),
-                "slices_by_mode": {
-                    mode: c.value for mode, c in sorted(
-                        self._megastep_batches.items())},
-            },
         }

@@ -141,19 +141,6 @@ PIPELINE_METRICS = {
     "pingoo_pipeline_batches_total":
         "batches served by the executor, split by mode (on = staged "
         "overlap, off = legacy lockstep)",
-    # Device-resident megastep (ISSUE 12, docs/EXECUTOR.md
-    # "Device-resident loop"): one jitted lax.scan dispatch covering K
-    # batch slices. `batches_total` carries a `mode` label over the
-    # PINGOO_MEGASTEP arms that actually launch (auto / force).
-    "pingoo_megastep_k":
-        "K of the most recently launched megastep window (batch "
-        "slices per device dispatch)",
-    "pingoo_megastep_batches_total":
-        "batch slices served device-resident, split by PINGOO_MEGASTEP "
-        "mode (auto = backlog-engaged, force = pinned)",
-    "pingoo_megastep_amortization":
-        "EWMA batch slices amortized per device dispatch (1.0 = "
-        "per-batch dispatch, K = fully amortized megastep windows)",
     # The sidecar drain loop's phase account (obs/pipeline.LOOP_PHASES):
     # every instant of the drain thread is in exactly one `phase`, so
     # the phases' deltas add up to the wall time between two scrapes.
@@ -266,8 +253,8 @@ RESILIENCE_METRICS = {
         "by action (reeval = slot bytes intact, re-evaluated; "
         "failopen = bytes recycled, allow posted)",
     "pingoo_degrade_total":
-        "degradation-ladder demotions by rung (pipeline|megastep|dfa|"
-        "mesh|device|body; engine/ladder.py)",
+        "degradation-ladder demotions by rung (pipeline|dfa|mesh|"
+        "device|body; engine/ladder.py)",
     "pingoo_chaos_injected_total":
         "faults injected by the PINGOO_CHAOS harness, by fault "
         "(obs/chaos.py; absent in production)",
@@ -354,7 +341,7 @@ STAGING_METRICS = {
 # Exported by every plane that runs the batched verdict engine
 # (plane="python" listener service, plane="sidecar" ring drainer).
 # `pingoo_compile_total` carries {plane, fn, kind} — fn over
-# obs/perf.COMPILE_FN_KINDS (verdict|lanes|prefilter|megastep|score;
+# obs/perf.COMPILE_FN_KINDS (verdict|lanes|prefilter|score;
 # the packed-staging twins report under the same fn label), kind
 # cold|warm (warm = a retrace under live traffic, the recompile-storm
 # alert series); `pingoo_compile_ms` is a {plane, fn} histogram over

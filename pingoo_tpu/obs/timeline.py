@@ -200,8 +200,7 @@ class Timeline:
         `points`: phase -> (t_start, t_end), time.monotonic() seconds):
         encode, prefilter, device_dispatch as recorded, device_compute
         from the dispatch's end to the device wait's, resolve as
-        recorded. A megastep slice without dispatch points of its own
-        gets a batch span over its resolve.
+        recorded.
 
         `rows` entries: (trace_id, enq_ms) with enq_ms the NATIVE
         producer's ring-clock stamp — the ring-wait span is emitted
@@ -219,7 +218,7 @@ class Timeline:
             points.get("device_wait", none)
         resolve = points.get("resolve", none)
         t_end = resolve[1] or wait[1]
-        t0 = points.get("encode", none)[0] or resolve[0] or t_end
+        t0 = points["encode"][0]
         t0_us = t0 * 1e6
         t_end_us = t_end * 1e6
         self.add_span("sidecar", tid, "batch", t0_us,

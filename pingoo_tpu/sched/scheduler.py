@@ -136,7 +136,7 @@ def seed_stages_from_bench_history(
     {stage: {"<bucket>": ms}}. Returns {stage: {bucket:int -> ms}} or
     None. Best-effort like seed_from_bench_history — a missing/corrupt
     history leaves the affine STAGE_SEED_SPLIT fallback in charge, but
-    when history exists the very first megastep K-sizing runs against
+    when history exists the first launch decisions run against
     MEASURED dispatch/compute walls instead of the 1.5 ms seed."""
     import json
 
@@ -238,24 +238,14 @@ class CostModel:
         #
         # Boot-seeded from bench history (ISSUE 12 satellite, gated the
         # same way as the batch-cost seed: only when no explicit seed
-        # was pinned) so the first megastep K-sizing decisions run on
-        # measured dispatch/compute walls. Live observations EWMA-blend
+        # was pinned) so the first launch decisions run on measured
+        # dispatch/compute walls. Live observations EWMA-blend
         # over the seed from the first batch.
         self._stage_ewma: dict[str, dict[int, float]] = {}
         if seeded_from_env_or_arg is False:
             hist = seed_stages_from_bench_history()
             if hist:
                 self._stage_ewma = {s: dict(b) for s, b in hist.items()}
-        # Per-(K, bucket) megastep window EWMAs (ISSUE 12): the wall of
-        # ONE K-slice device-resident dispatch. Unobserved pairs fall
-        # back to the amortization model dispatch + K * compute.
-        self._mega_ewma: dict[tuple[int, int], float] = {}
-        # First observation per (K, bucket), tracked SEPARATELY (ISSUE
-        # 15 satellite): the first window of a new (K, rows) shape pays
-        # the cold XLA compile (BENCH_pipeline showed 4x2048 seeded at
-        # ~9.5 s), and letting it seed the EWMA meant `auto` could
-        # never size K up past the poisoned rung again.
-        self._mega_first: dict[tuple[int, int], float] = {}
         # Dispatch-stage EWMAs keyed by staged-BYTES bucket (ISSUE 15):
         # the dispatch wall is bytes-proportional host staging, so with
         # compact staging in play the pow2 row bucket alone conflates
@@ -330,41 +320,6 @@ class CostModel:
         else:
             stages[bucket] = prev + self.alpha * (ms - prev)
 
-    def estimate_megastep(self, k: int, batch_size: int) -> float:
-        """Expected wall (ms) of ONE K-slice megastep window (hot;
-        ISSUE 12) — the admission loop sizes K down the pow2 ladder
-        against the oldest slice's deadline slack with this. Unobserved
-        (K, bucket) pairs fall back to the amortization model that is
-        the megastep's whole point: one dispatch + K compute walls."""
-        k = max(1, int(k))
-        bucket = _pow2_bucket(max(1, batch_size), self.max_batch)
-        est = self._mega_ewma.get((k, bucket))
-        if est is not None:
-            return est
-        return (self.estimate_stage("dispatch", batch_size)
-                + k * self.estimate_stage("compute", batch_size))
-
-    def observe_megastep(self, k: int, batch_size: int,
-                         ms: float) -> None:
-        """EWMA update from one completed K-slice megastep window's
-        measured dispatch->sync wall (hot)."""
-        if ms < 0:
-            return
-        k = max(1, int(k))
-        bucket = _pow2_bucket(max(1, batch_size), self.max_batch)
-        key = (k, bucket)
-        if key not in self._mega_first:
-            # The first window of a (K, bucket) shape pays the cold XLA
-            # compile; absorb it here so estimate_megastep keeps using
-            # the amortization model until a STEADY window lands.
-            self._mega_first[key] = ms
-            return
-        prev = self._mega_ewma.get(key)
-        if prev is None:
-            self._mega_ewma[key] = ms
-        else:
-            self._mega_ewma[key] = prev + self.alpha * (ms - prev)
-
     def estimate_dispatch(self, batch_size: int,
                           staged_bytes: Optional[int] = None) -> float:
         """Expected dispatch-stage wall (ms), preferring the staged-
@@ -401,12 +356,6 @@ class CostModel:
                             for b, v in sorted(buckets.items())}
                     for stage, buckets in sorted(
                         self._stage_ewma.items())},
-                "megastep_ewma_ms": {
-                    f"{k}x{b}": round(v, 4)
-                    for (k, b), v in sorted(self._mega_ewma.items())},
-                "megastep_first_ms": {
-                    f"{k}x{b}": round(v, 4)
-                    for (k, b), v in sorted(self._mega_first.items())},
                 "dispatch_bytes_ewma_ms": {
                     f"{kb}kb": round(v, 4)
                     for kb, v in sorted(
@@ -415,10 +364,12 @@ class CostModel:
     def restore(self, snap: dict) -> bool:
         """Inverse of snapshot(): overwrite this model's state from a
         durable cost-ledger entry (ISSUE 17). Snapshot keys arrive
-        JSON-round-tripped — int bucket keys are strings, megastep keys
-        are "KxB", bytes keys "<kb>kb" — so each map is re-parsed;
-        unparseable entries are skipped, and the method returns True if
-        ANY state was restored. Overwrite (not blend) semantics: a
+        JSON-round-tripped — int bucket keys are strings, bytes keys
+        "<kb>kb" — so each map is re-parsed; unparseable entries are
+        skipped, and the method returns True if ANY state was restored.
+        Keys this model no longer keeps (`megastep_ewma_ms`,
+        `megastep_first_ms` of a ledger written before the K-window was
+        deleted) are ignored. Overwrite (not blend) semantics: a
         ledger measured on the actual backend beats both the static
         seed and the lossy BENCH_history p_batch_ms seeding this path
         replaces."""
@@ -459,31 +410,6 @@ class CostModel:
                 self._stage_ewma = stage
                 restored = True
 
-        def _mega(raw):
-            out = {}
-            if isinstance(raw, dict):
-                for key, v in raw.items():
-                    try:
-                        k_s, b_s = str(key).split("x", 1)
-                        out[(int(k_s), int(b_s))] = float(v)
-                    except (TypeError, ValueError):
-                        continue
-            return out
-
-        mega = _mega(snap.get("megastep_ewma_ms"))
-        if mega:
-            self._mega_ewma = mega
-            restored = True
-        # _mega_first travels too: it records which (K, bucket) shapes
-        # already paid their cold compile, and with the compilation
-        # cache cold on a fresh boot that absorption must happen AGAIN
-        # — but restoring the map preserves the prior run's measured
-        # cold walls for the compile ledger cross-check, and a reloaded
-        # steady EWMA above means estimate_megastep never consults it.
-        first = _mega(snap.get("megastep_first_ms"))
-        if first:
-            self._mega_first = first
-            restored = True
         disp_raw = snap.get("dispatch_bytes_ewma_ms")
         if isinstance(disp_raw, dict):
             disp = {}
@@ -745,34 +671,11 @@ class Scheduler:
         once stages overlap across in-flight batches."""
         self.cost.observe_stage(stage, batch_size, ms)
 
-    def observe_megastep_cost(self, k: int, batch_size: int,
-                              ms: float) -> None:
-        """One completed K-slice megastep window's measured wall
-        (hot; ISSUE 12)."""
-        self.cost.observe_megastep(k, batch_size, ms)
-
     def observe_dispatch_bytes(self, staged_bytes: int,
                                ms: float) -> None:
         """Dispatch-stage wall keyed by the batch's staged-bytes bucket
         (hot; ISSUE 15 compact staging)."""
         self.cost.observe_dispatch_bytes(staged_bytes, ms)
-
-    def size_megastep_k(self, k_ladder, batch_size: int,
-                        oldest_admit_s: float, now_s: float) -> int:
-        """Largest K rung whose estimated megastep window still fits
-        the OLDEST pending slice's remaining deadline slack (ISSUE 12).
-        Never below 1 — a megastep with a blown budget still launches
-        immediately at K=1 rather than stalling (the miss is counted at
-        resolve like every other late batch)."""
-        slack_ms = (oldest_admit_s + self.config.deadline_ms / 1e3
-                    - now_s) * 1e3
-        k = 1
-        for rung in k_ladder:
-            if rung == 1:
-                continue
-            if self.cost.estimate_megastep(rung, batch_size) <= slack_ms:
-                k = rung
-        return k
 
     def snapshot(self) -> dict:
         return {

@@ -133,8 +133,8 @@ class TestHotPathLinter:
         body = "def f(dev):\n    dev.block_until_ready()\n"
         assert [f.rule for f in _lint(body)] == ["sync-block"]
         # The same call inside the blessed _await_device (the one
-        # sanctioned sync primitive finish_batch / finish_megastep
-        # route through) is allowed.
+        # sanctioned sync primitive finish_batch routes through) is
+        # allowed.
         blessed = "def _await_device(dev):\n    dev.block_until_ready()\n"
         assert _lint(blessed, "pingoo_tpu/engine/verdict.py") == []
         # finish_batch itself is no longer blessed — a direct sync

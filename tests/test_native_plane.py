@@ -89,6 +89,17 @@ class NativeStack:
             lists or {}, max_batch=max_batch,
             services=[name for name, _ in routes] if routes else None)
         threading.Thread(target=self.sidecar.run, daemon=True).start()
+        # The sidecar's programs come up before any httpd exists: under
+        # load the first CPU compile of `lanes` outlasts httpd's 3 s
+        # verdict deadline, and the test's first request would be
+        # released uninspected. One row through the ring compiles them;
+        # its verdict is taken off here, so httpd never sees it.
+        assert self.ring.enqueue(host=b"warm.test",
+                                 user_agent=b"warm") is not None
+        deadline = time.monotonic() + 300
+        while self.ring.poll_verdict() is None:
+            assert time.monotonic() < deadline, "no verdict from the sidecar"
+            time.sleep(0.01)
         self.port = _free_port()
         self.services_path = None
         if services is not None:

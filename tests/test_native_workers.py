@@ -426,6 +426,7 @@ def test_one_worker_keeps_its_keys(tmp_path, flags):
             pass_fds=(stack.stats_fd,))
         assert b"listening" in stack.proc.stdout.readline()
     try:
+        m0 = _scrape(stack.port)  # holds NativeStack's warm-up row
         assert _exchange(stack.port, [("/ok", "ua"), ("/evil", "ua")]) \
             == [200, 403]
         m = _scrape(stack.port)
@@ -435,6 +436,7 @@ def test_one_worker_keeps_its_keys(tmp_path, flags):
     assert (m["workers"], m["answered_by"]) == (1, 0)
     assert (m["requests"], m["verdicts"], m["blocked"], m["fail_open"]) \
         == (2, 2, 1, 0)
-    assert m["ring"]["enqueued"] == m["ring"]["verdicts_posted"] == 2
+    assert [m["ring"][key] - m0["ring"][key]
+            for key in ("enqueued", "verdicts_posted")] == [2, 2]
     assert m["release"]["worker"] == 0
     _check_totals(m)

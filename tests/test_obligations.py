@@ -148,16 +148,14 @@ def test_event_in_surface_membership():
     from pingoo_tpu.obs.perf import event_in_surface
 
     surf = {"planes": ["python", "sidecar"], "fns": ["verdict", "score"],
-            "kinds": ["cold", "warm"], "batch_buckets": [8, 16],
-            "k_rungs": [1, 2, 4]}
+            "kinds": ["cold", "warm"], "batch_buckets": [8, 16]}
     assert event_in_surface(_event(), surf) is None
-    assert event_in_surface(_event(batch_bucket=16, k=2), surf) is None
+    assert event_in_surface(_event(batch_bucket=16), surf) is None
     assert "fn=" in event_in_surface(_event(fn="mystery"), surf)
     assert "plane=" in event_in_surface(_event(plane="gpu"), surf)
     assert "kind=" in event_in_surface(_event(kind="hot"), surf)
     assert event_in_surface(_event(batch_bucket=26), surf) \
         == "batch_bucket=26"
-    assert event_in_surface(_event(k=3), surf) == "k=3"
     # Widths gate only when the surface carries a widths key.
     assert event_in_surface(_event(widths=[[4, 8]]), surf) is None
     surf["widths"] = [[[4, 8]]]

@@ -25,8 +25,6 @@ def _seeded_cost() -> CostModel:
     cost.observe_stage("encode", 16, 0.8)
     cost.observe_stage("dispatch", 16, 0.4)
     cost.observe_stage("compute", 64, 6.0)
-    cost.observe_megastep(4, 16, 2.5)   # first obs -> absorbed cold
-    cost.observe_megastep(4, 16, 1.5)   # second -> steady EWMA
     cost.observe_dispatch_bytes(48 * 1024, 0.9)
     return cost
 
@@ -39,14 +37,10 @@ class TestCostModelPersistence:
         assert fresh.restore(snap) is True
         assert fresh.snapshot() == cost.snapshot()
         # The reloaded model estimates from the restored EWMAs (no
-        # BENCH_history re-seeding): stage + megastep estimates match.
+        # BENCH_history re-seeding): stage estimates match.
         for stage in ("encode", "dispatch", "compute"):
             assert fresh.estimate_stage(stage, 16) == pytest.approx(
                 cost.estimate_stage(stage, 16))
-        assert fresh.estimate_megastep(4, 16) == pytest.approx(
-            cost.estimate_megastep(4, 16))
-        # _mega_first (cold-compile absorption) travels too.
-        assert fresh._mega_first == cost._mega_first
 
     def test_restore_rejects_garbage(self):
         fresh = CostModel()
@@ -55,7 +49,7 @@ class TestCostModelPersistence:
         # Unparseable keys are skipped, parseable ones restore.
         ok = fresh.restore({"ewma_ms": {"16": 2.0, "what": 1.0},
                             "stage_ewma_ms": {"bogus_stage": {"8": 1.0}},
-                            "megastep_ewma_ms": {"nonsense": 3.0}})
+                            "dispatch_bytes_ewma_ms": {"nonsense": 3.0}})
         assert ok is True
         assert fresh._ewma == {16: 2.0}
         assert fresh._stage_ewma == {}
@@ -186,10 +180,9 @@ class TestCompileLedger:
         assert fn._cache_size() == 0  # __getattr__ delegation
 
     def test_shape_context(self):
-        bucket, k = perf._shape_context([(64, 128), (64, 16), (8, 64, 4)])
-        assert bucket == 64
-        assert k == 8
-        assert perf._shape_context([]) == (None, None)
+        assert perf._shape_context(
+            [(64, 128), (64, 16), (8, 64, 4)]) == 64
+        assert perf._shape_context([]) is None
 
     def test_path_gate(self, monkeypatch):
         monkeypatch.delenv("PINGOO_PERF_LEDGER", raising=False)
@@ -266,14 +259,6 @@ class TestTimeline:
         assert len(join) == 1
         # enq at 19.99 s, sidecar pickup at 20.0 s -> 10 ms wait.
         assert join[0][4] == pytest.approx(10_000.0)
-
-    def test_batch_sidecar_megastep_slice_fallback(self):
-        tl = self._timeline()
-        # No per-slice dispatch points: the batch span must cover the
-        # resolve window, not start at monotonic zero.
-        tl.batch_sidecar(points={"resolve": (30.0, 30.002)})
-        batch = [s for s in tl.spans if s[2] == "batch"][0]
-        assert batch[3] == pytest.approx(30.0e6)
 
     def test_chrome_trace_export(self):
         tl = self._timeline()

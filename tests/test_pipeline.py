@@ -642,472 +642,3 @@ class TestLintHotRegistry:
                                        "pingoo_tpu/engine/service.py")
         assert any(f.rule == "sync-asarray-hot" for f in findings), \
             findings
-
-
-# -- ISSUE 12: device-resident megastep -----------------------------------
-
-
-class TestDeviceInputQueue:
-    """Double-buffered device input stacks: the fill/trim/scrub
-    invariants that keep the shipped window bit-identical to the
-    batches that staged into it."""
-
-    def _batch(self, seed, n, pad):
-        return _legacy_encode(random_requests(random.Random(seed), n),
-                              None, pad)
-
-    def test_fill_and_device_stack_round_trip(self):
-        from pingoo_tpu.engine.batch import DeviceInputQueue
-
-        q = DeviceInputQueue(4, 32)
-        buf = q.checkout()
-        batches = [self._batch(s, 32, 32) for s in (0, 1, 2)]
-        for j, b in enumerate(batches):
-            q.fill_slice(buf, j, b.arrays, b.size, epoch=7)
-        stacked, nv, ep = q.device_stack(buf, 3)
-        assert np.asarray(nv).tolist() == [32, 32, 32]
-        assert np.asarray(ep).tolist() == [7, 7, 7]
-        for j, b in enumerate(batches):
-            for name, arr in b.arrays.items():
-                got = np.asarray(stacked[name])[j]
-                if name.endswith("_bytes"):
-                    w = arr.shape[1]
-                    assert np.array_equal(got[:, :w], arr), name
-                    # window-max trim beyond this slice's bucket must
-                    # be zeros, never another slice's bytes
-                    assert not got[:, w:].any(), name
-                else:
-                    assert np.array_equal(got, arr), name
-
-    def test_widen_scrub_never_leaks_previous_window(self):
-        """A wide window dirties the stacks; after the buffer set
-        rotates back, a window whose early slice is NARROW and late
-        slice WIDE must see zeros (not stale bytes) in the widened
-        columns of the early slice."""
-        from pingoo_tpu.engine.batch import DeviceInputQueue
-
-        def mk(path):
-            reqs = [RequestTuple(host="h.test", url="/u", path=path,
-                                 user_agent="ua", ip="10.0.0.1")] * 4
-            return _legacy_encode(reqs, None, 4)
-
-        q = DeviceInputQueue(2, 4, nbuf=2)
-        wide, narrow = mk("/" + "w" * 120), mk("/n")
-        b0 = q.checkout()
-        q.fill_slice(b0, 0, wide.arrays, 4, epoch=0)
-        q.fill_slice(b0, 1, wide.arrays, 4, epoch=0)
-        q.checkout()          # rotate to the other set ...
-        b2 = q.checkout()     # ... and back onto the dirtied one
-        assert b2 == b0
-        q.fill_slice(b2, 0, narrow.arrays, 4, epoch=1)
-        q.fill_slice(b2, 1, wide.arrays, 4, epoch=1)
-        stacked, _, _ = q.device_stack(b2, 2)
-        path = np.asarray(stacked["path_bytes"])
-        w_narrow = narrow.arrays["path_bytes"].shape[1]
-        assert path.shape[2] == wide.arrays["path_bytes"].shape[1]
-        assert not path[0, :, w_narrow:].any(), \
-            "stale wide-window bytes leaked into the narrow slice"
-
-    def test_mismatched_row_buckets_raise(self):
-        from pingoo_tpu.engine.batch import DeviceInputQueue
-
-        q = DeviceInputQueue(2, 32)
-        buf = q.checkout()
-        q.fill_slice(buf, 0, self._batch(1, 8, 8).arrays, 8, epoch=0)
-        with pytest.raises(ValueError, match="row bucket"):
-            q.fill_slice(buf, 1, self._batch(2, 16, 16).arrays, 16,
-                         epoch=0)
-
-    def test_pad_to_ships_rung_shape_with_masked_slices(self):
-        """pad_to quantizes the shipped leading dim (each distinct K is
-        its own XLA compile): the padded slices must arrive with
-        n_valid=0 — masked, whatever stale bytes the stacks held — and
-        the filled slices bit-identical to the unpadded ship."""
-        from pingoo_tpu.engine.batch import DeviceInputQueue
-
-        q = DeviceInputQueue(4, 16, nbuf=2)
-        stale = self._batch(9, 16, 16)
-        b0 = q.checkout()
-        for j in range(4):  # dirty all four slices of this buffer set
-            q.fill_slice(b0, j, stale.arrays, 16, epoch=0)
-        q.checkout()
-        b2 = q.checkout()
-        assert b2 == b0
-        fresh = self._batch(10, 16, 16)
-        q.fill_slice(b2, 0, fresh.arrays, 16, epoch=3)
-        stacked, nv, ep = q.device_stack(b2, 1, pad_to=4)
-        assert np.asarray(nv).tolist() == [16, 0, 0, 0]
-        assert int(np.asarray(ep)[0]) == 3
-        for name, arr in fresh.arrays.items():
-            got = np.asarray(stacked[name])[0]
-            if name.endswith("_bytes"):
-                assert np.array_equal(got[:, :arr.shape[1]], arr), name
-            else:
-                assert np.array_equal(got, arr), name
-        # pad_to never exceeds the queue's K and never trims below the
-        # filled count
-        assert np.asarray(q.device_stack(b2, 1, pad_to=9)[1]).shape == (4,)
-
-    def test_slice_view_stable_across_one_rotation(self):
-        """nbuf=3: a window's host views must survive the NEXT window's
-        checkout+fill (its batches are still resolving while the next
-        window stages) — the same contract the StagingEncoder holds."""
-        from pingoo_tpu.engine.batch import DeviceInputQueue
-
-        q = DeviceInputQueue(1, 16, nbuf=3)
-        a = self._batch(3, 16, 16)
-        b0 = q.checkout()
-        q.fill_slice(b0, 0, a.arrays, 16, epoch=0)
-        view = q.slice_view(b0, 0, 16)
-        want = {k: v.copy() for k, v in view.items()}
-        b1 = q.checkout()
-        q.fill_slice(b1, 0, self._batch(4, 16, 16).arrays, 16, epoch=0)
-        for name, arr in want.items():
-            assert np.array_equal(view[name], arr), name
-
-
-class TestMegastepKnobs:
-    """Mode/K env parsing + the scheduler's megastep cost model."""
-
-    def test_mode_resolution(self, monkeypatch):
-        from pingoo_tpu.engine.verdict import _resolve_megastep_mode
-
-        monkeypatch.delenv("PINGOO_MEGASTEP", raising=False)
-        assert _resolve_megastep_mode() == "off"
-        for mode in ("off", "auto", "force"):
-            monkeypatch.setenv("PINGOO_MEGASTEP", mode)
-            assert _resolve_megastep_mode() == mode
-        monkeypatch.setenv("PINGOO_MEGASTEP", "warp")
-        assert _resolve_megastep_mode() == "off"
-
-    def test_k_ladder_is_pow2_and_capped(self, monkeypatch):
-        from pingoo_tpu.engine.verdict import (megastep_k_cap,
-                                               megastep_k_ladder)
-
-        assert megastep_k_ladder(6) == [1, 2, 4]
-        assert megastep_k_ladder(1) == [1]
-        assert megastep_k_ladder(0) == [1]
-        monkeypatch.setenv("PINGOO_MEGASTEP_K", "8")
-        assert megastep_k_cap() == 8
-        monkeypatch.setenv("PINGOO_MEGASTEP_K", "bogus")
-        assert megastep_k_cap() >= 1
-
-    def test_estimate_falls_back_to_amortization_model(self):
-        cm = CostModel(max_batch=1024, seed_ms=8.0)
-        for _ in range(40):
-            cm.observe_stage("dispatch", 512, 2.0)
-            cm.observe_stage("compute", 512, 5.0)
-        # Unobserved (K, bucket): one dispatch + K compute walls.
-        assert cm.estimate_megastep(4, 512) == pytest.approx(
-            2.0 + 4 * 5.0, rel=0.05)
-        # Observed wall wins over the model.
-        for _ in range(40):
-            cm.observe_megastep(4, 512, 9.0)
-        assert cm.estimate_megastep(4, 512) == pytest.approx(
-            9.0, rel=0.05)
-        snap = cm.snapshot()
-        assert snap["megastep_ewma_ms"]["4x512"] == pytest.approx(
-            9.0, rel=0.05)
-
-    def test_size_megastep_k_fits_deadline_slack(self):
-        from pingoo_tpu.sched.scheduler import Scheduler, SchedulerConfig
-
-        cfg = SchedulerConfig(max_batch=128, deadline_ms=50.0)
-        s = Scheduler(cfg, plane="python")
-        for _ in range(40):
-            s.cost.observe_stage("dispatch", 128, 2.0)
-            s.cost.observe_stage("compute", 128, 10.0)
-        now = 100.0
-        # Fresh admit: 50ms slack fits 2 + 4*10 = 42ms but not
-        # 2 + 8*10 = 82ms.
-        assert s.size_megastep_k([1, 2, 4, 8], 128, now, now) == 4
-        # 25ms slack left: only K=2 (22ms) fits.
-        assert s.size_megastep_k([1, 2, 4, 8], 128, now - 0.025,
-                                 now) == 2
-        # Budget blown: never below 1 (launch now, count the miss).
-        assert s.size_megastep_k([1, 2, 4, 8], 128, now - 10.0,
-                                 now) == 1
-
-
-class TestMegastepProgramParity:
-    """make_megastep_fn vs the per-batch programs it amortizes: the
-    K-slice scan must be bit-identical to K separate dispatches,
-    including masked odd tails (n_valid < rows)."""
-
-    def test_matrix_kind_matches_per_batch_finish(self):
-        from pingoo_tpu.engine.batch import DeviceInputQueue
-        from pingoo_tpu.engine.verdict import (finish_batch,
-                                               finish_megastep,
-                                               make_megastep_fn,
-                                               make_verdict_fn)
-
-        plan = _make_plan()
-        verdict_fn = make_verdict_fn(plan)
-        mega = make_megastep_fn(plan, kind="matrix")
-        q = DeviceInputQueue(4, 16, field_specs=plan.field_specs)
-        # K=4 slices with odd tails: 16, 13, 16, 5 live rows.
-        ns = (16, 13, 16, 5)
-        batches = [
-            _legacy_encode(random_requests(random.Random(40 + j), n),
-                           plan.field_specs, 16)
-            for j, n in enumerate(ns)]
-        buf = q.checkout()
-        for j, (n, b) in enumerate(zip(ns, batches)):
-            q.fill_slice(buf, j, b.arrays, n, epoch=3)
-        stacked, nv, ep = q.device_stack(buf, 4)
-        out = mega.fn(plan.device_tables(), stacked, nv, ep)
-        assert np.asarray(out[3]).tolist() == [3, 3, 3, 3]
-        lists = dict(LISTS)
-        offsets, slices = [], []
-        off = 0
-        for n in ns:
-            slices.append((off, n))
-            offsets.append(off)
-            off += 16
-        stitched = RequestBatch(size=off, arrays={
-            name: np.concatenate(
-                [np.asarray(stacked[name])[j] for j in range(4)])
-            for name in stacked})
-        got = finish_megastep(plan, out[0], slices, stitched, lists)
-        for j, (n, b) in enumerate(zip(ns, batches)):
-            dev = verdict_fn(plan.device_tables(), b.arrays, None)
-            want = finish_batch(plan, dev, b, lists)
-            got_rows = got[offsets[j]:offsets[j] + n]
-            assert np.array_equal(got_rows, want[:n]), \
-                f"slice {j} (n={n}) diverged from per-batch dispatch"
-
-
-@pytest.mark.slow
-class TestMegastepPythonPlaneParity:
-    """PINGOO_MEGASTEP off|auto|force through the full service: `off`
-    is the oracle, and every mode must serve identical verdicts."""
-
-    def test_off_auto_force_bit_identity_and_telemetry(self):
-        plan = _make_plan()
-        reqs = random_requests(random.Random(77), 200)
-        base = {"PINGOO_PIPELINE": "on", "PINGOO_PIPELINE_DEPTH": "3",
-                "PINGOO_MEGASTEP_K": "4"}
-        v_off, _, _ = _drive_service(
-            plan, reqs, {**base, "PINGOO_MEGASTEP": "off"})
-        v_force, snap_f, cost_f = _drive_service(
-            plan, reqs, {**base, "PINGOO_MEGASTEP": "force"})
-        v_auto, _, _ = _drive_service(
-            plan, reqs, {**base, "PINGOO_MEGASTEP": "auto"})
-        assert len(v_off) == len(v_force) == len(v_auto) == len(reqs)
-        for a, b, c in zip(v_off, v_force, v_auto):
-            assert a.action == b.action == c.action
-            assert a.verified_block == b.verified_block \
-                == c.verified_block
-            assert np.array_equal(a.matched, b.matched)
-            assert np.array_equal(a.matched, c.matched)
-        # force actually ran megastep windows, and the telemetry
-        # satellite saw them: K gauge, per-mode slices, cost EWMAs.
-        mega = snap_f["megastep"]
-        assert mega["windows"] > 0
-        assert mega["slices"] >= mega["windows"]
-        assert mega["slices_by_mode"].get("force", 0) > 0
-        assert cost_f.get("megastep_ewma_ms")
-
-
-@needs_native
-@pytest.mark.slow
-class TestMegastepSidecarParity:
-    """off|force|auto through real shm rings: identical ticket-ordered
-    actions (n=300 over a 256-capacity ring covers wraparound), live
-    windows under force, and zero ruleset-epoch echo mismatches."""
-
-    def _drive(self, tmp_path, tag, env, n=300):
-        from pingoo_tpu.compiler import compile_ruleset
-        from pingoo_tpu.native_ring import Ring, RingSidecar
-
-        plan = compile_ruleset(make_rules(RULE_SOURCES[:23]), LISTS)
-        saved = {k: os.environ.get(k) for k in env}
-        os.environ.update(env)
-        try:
-            ring = Ring(str(tmp_path / f"mring-{tag}"), capacity=256,
-                        create=True)
-            sidecar = RingSidecar(ring, plan, LISTS, max_batch=32,
-                                  pipeline_depth=3)
-            th = threading.Thread(target=sidecar.run, daemon=True)
-            th.start()
-            rng = random.Random(23)
-            paths = [b"/admin/.env" if rng.random() < 0.3
-                     else f"/ok/{k}".encode() for k in range(n)]
-            actions = {}
-            sent = 0
-            t_deadline = time.time() + 120
-            while len(actions) < n and time.time() < t_deadline:
-                if sent < n:
-                    path = paths[sent]
-                    t = ring.enqueue(
-                        method=b"GET", host=b"h.test", path=path,
-                        url=path, user_agent=b"Mozilla/5.0 t",
-                        ip=b"\x00" * 10 + b"\xff\xff" + bytes(
-                            [172, 16, sent % 256, 9]),
-                        port=4000 + sent, asn=64496, country=b"FR")
-                    if t is not None:
-                        sent += 1
-                v = ring.poll_verdict()
-                while v is not None:
-                    ticket, action, _ = v
-                    actions[ticket] = action
-                    v = ring.poll_verdict()
-            stats = sidecar.stats()
-            sidecar.stop()
-            ring.close()
-            assert len(actions) == n, f"{tag}: {len(actions)}/{n}"
-            return [actions[t] for t in sorted(actions)], stats
-        finally:
-            for k, v in saved.items():
-                if v is None:
-                    os.environ.pop(k, None)
-                else:
-                    os.environ[k] = v
-
-    def test_off_force_auto_checksum_parity(self, tmp_path):
-        base = {"PINGOO_MEGASTEP_K": "4"}
-        off, _ = self._drive(
-            tmp_path, "off", {**base, "PINGOO_MEGASTEP": "off"})
-        force, st_f = self._drive(
-            tmp_path, "force", {**base, "PINGOO_MEGASTEP": "force"})
-        auto, st_a = self._drive(
-            tmp_path, "auto", {**base, "PINGOO_MEGASTEP": "auto"})
-        assert len(set(off)) > 1  # mixed allow/block stream
-        assert off == force
-        assert off == auto
-        assert st_f["megastep"]["mode"] == "force"
-        assert st_f["megastep"]["windows"] > 0
-        assert st_f["megastep"]["echo_mismatch"] == 0
-        assert st_a["megastep"]["echo_mismatch"] == 0
-
-
-def _prefix_plan(prefix):
-    """One-rule plan blocking paths under `prefix` — swapping between
-    two of these gives every ticket a phase-determined verdict."""
-    from pingoo_tpu.compiler import compile_ruleset
-    from pingoo_tpu.config.schema import Action, RuleConfig
-    from pingoo_tpu.expr import compile_expression
-
-    rules = [RuleConfig(
-        name="blk", actions=(Action.BLOCK,),
-        expression=compile_expression(
-            f'http_request.path.starts_with("{prefix}")'))]
-    return compile_ruleset(rules, {})
-
-
-@needs_native
-@pytest.mark.slow
-class TestMegastepHotSwapBoundary:
-    """ISSUE 11 x ISSUE 12: a hot-swap under PINGOO_MEGASTEP=force
-    flips ONLY at a megastep-window boundary — every slice verdicts
-    under the plan epoch it was staged with (zero epoch-echo
-    mismatches), and each phase is bit-exact under ITS plan."""
-
-    def test_swap_flips_at_window_boundary(self, tmp_path, monkeypatch):
-        from pingoo_tpu.native_ring import Ring, RingSidecar
-
-        monkeypatch.setenv("PINGOO_MEGASTEP", "force")
-        monkeypatch.setenv("PINGOO_MEGASTEP_K", "4")
-        ring = Ring(str(tmp_path / "ring-mswap"), capacity=256,
-                    create=True)
-        sidecar = RingSidecar(ring, _prefix_plan("/alpha"), {},
-                              max_batch=16)
-        n = 48
-
-        def enq(i, phase):
-            path = (b"/%s/%d" % (phase.encode(), i)) if i % 3 == 0 \
-                else b"/ok/%d" % i
-            return ring.enqueue(method=b"GET", host=b"r.test",
-                                path=path, url=path,
-                                user_agent=b"Mozilla/5.0")
-
-        def poll_all(need, timeout=120.0):
-            got: dict = {}
-            deadline = time.monotonic() + timeout
-            while sum(len(v) for v in got.values()) < need and \
-                    time.monotonic() < deadline:
-                v = ring.poll_verdict()
-                if v is None:
-                    time.sleep(0.002)
-                    continue
-                got.setdefault(v[0], []).append(v[1])
-            return got
-
-        try:
-            worker = threading.Thread(target=sidecar.run, daemon=True)
-            worker.start()
-            for i in range(n):
-                assert enq(i, "alpha") is not None
-            got_a = poll_all(n)
-
-            handle = sidecar.request_swap(_prefix_plan("/beta"))
-            assert handle.wait(120) and handle.result == "ok"
-            assert sidecar.ruleset_epoch >= 1
-
-            for i in range(n, 2 * n):
-                assert enq(i, "beta") is not None
-            got_b = poll_all(n)
-            stats = sidecar.stats()
-            sidecar.stop()
-            worker.join(30)
-
-            assert sorted(got_a) == list(range(n))
-            assert sorted(got_b) == list(range(n, 2 * n))
-            for got in (got_a, got_b):
-                assert all(len(a) == 1 for a in got.values())
-            # Each phase bit-exact under ITS plan epoch.
-            for i in range(n):
-                assert got_a[i][0] & 3 == (1 if i % 3 == 0 else 0), i
-            for i in range(n, 2 * n):
-                assert got_b[i][0] & 3 == (1 if i % 3 == 0 else 0), i
-            # Megastep windows ran on both sides of the flip, and no
-            # slice ever computed under a different epoch than it was
-            # staged with: the flip happened at a window boundary.
-            assert stats["megastep"]["windows"] > 0
-            assert stats["megastep"]["echo_mismatch"] == 0
-        finally:
-            sidecar.stop()
-            ring.close()
-
-
-class TestMegastepLintRegistry:
-    """ISSUE 12 satellite: the megastep hot path is registered, with a
-    mutation proof that a fresh allocation in the window stage/dispatch
-    path fails `make analyze`."""
-
-    def test_megastep_functions_registered(self):
-        from tools.analyze import lint_config
-
-        for fn in (
-            "pingoo_tpu/engine/batch.py::DeviceInputQueue.fill_slice",
-            "pingoo_tpu/engine/batch.py::DeviceInputQueue.device_stack",
-            "pingoo_tpu/engine/verdict.py::finish_megastep",
-            "pingoo_tpu/engine/service.py::"
-            "VerdictService._evaluate_megastep",
-            "pingoo_tpu/sched/scheduler.py::CostModel.observe_megastep",
-            "pingoo_tpu/sched/scheduler.py::CostModel.estimate_megastep",
-            "pingoo_tpu/obs/pipeline.py::PipelineStats.note_megastep",
-        ):
-            assert fn in lint_config.HOT_FUNCTIONS, fn
-        for fn in (
-            "pingoo_tpu/engine/verdict.py::make_megastep_fn.slice_step",
-            "pingoo_tpu/engine/verdict.py::make_megastep_fn.megastep",
-        ):
-            assert fn in lint_config.TRACED_FUNCTIONS, fn
-
-    def test_mutated_megastep_alloc_fails_lint(self):
-        """The window fill copies into REUSED queue stacks; a fresh
-        allocation inside _evaluate_megastep must fail the lint."""
-        from tools.analyze import REPO_ROOT, lint
-
-        with open(os.path.join(REPO_ROOT, "pingoo_tpu", "engine",
-                               "service.py")) as f:
-            src = f.read()
-        needle = "            buf = self._mega_queue.checkout()"
-        assert needle in src
-        mutated = src.replace(
-            needle,
-            "            scratch = np.zeros((64, 64))\n" + needle, 1)
-        findings, _ = lint.lint_source(mutated,
-                                       "pingoo_tpu/engine/service.py")
-        assert any(f.rule == "hot-alloc" for f in findings), findings

@@ -16,11 +16,9 @@ Covers the tentpole's cap-soundness contract and the satellites:
     batch shapes at the verdict-program level, plus the pinned
     last-dependent-byte-exactly-at-cap case.
   * Sidecar end-to-end: full|compact served-verdict checksums through
-    real shm rings (ring wraparound, spill slots, megastep windows)
-    and a mid-run hot-swap onto a plan with WIDER caps.
-  * The megastep CostModel compile-poisoning fix (first (K, bucket)
-    observation absorbed, never seeding the EWMA) and the
-    staged-bytes-bucketed dispatch EWMA.
+    real shm rings (ring wraparound, spill slots) and a mid-run
+    hot-swap onto a plan with WIDER caps.
+  * The staged-bytes-bucketed dispatch EWMA.
   * The analyze-lint hot registration of the packed encode path, with
     a mutation proof that a fresh per-batch allocation there fails
     `make analyze`.
@@ -361,9 +359,9 @@ class TestPackedEncoder:
 @pytest.mark.slow
 class TestSidecarStagingParity:
     """PINGOO_STAGING full|compact through real shm rings: identical
-    served actions over a stream that exercises ring wraparound, spill
-    slots (over-spec URLs) and — in the megastep arm — K-slice
-    windows, plus a mid-run hot-swap onto a plan with wider caps."""
+    served actions over a stream that exercises ring wraparound and
+    spill slots (over-spec URLs), plus a mid-run hot-swap onto a plan
+    with wider caps."""
 
     def _drive(self, tmp_path, tag, env, n=260):
         from pingoo_tpu.native_ring import Ring, RingSidecar
@@ -454,15 +452,6 @@ class TestSidecarStagingParity:
         assert full == compact
         assert over > 0  # the clamp actually rerouted deep rows
 
-    def test_compact_megastep_windows_identical(self, tmp_path):
-        base = {"PINGOO_PIPELINE": "on", "PINGOO_MEGASTEP": "force",
-                "PINGOO_MEGASTEP_K": "4"}
-        full, _, _, _ = self._drive(
-            tmp_path, "mfull", {**base, "PINGOO_STAGING": "full"})
-        compact, _, _, _ = self._drive(
-            tmp_path, "mcompact", {**base, "PINGOO_STAGING": "compact"})
-        assert full == compact
-
     def test_hot_swap_widens_caps_mid_run(self, tmp_path, monkeypatch):
         """Swap from a shallow-cap plan to one whose rules need wider
         staging: the encoder re-caps at the batch boundary and the
@@ -526,40 +515,10 @@ class TestSidecarStagingParity:
             ring.close()
 
 
-# -- CostModel: megastep compile absorption + dispatch-bytes EWMA ------------
+# -- CostModel: dispatch-bytes EWMA -------------------------------------------
 
 
 class TestCostModelStaging:
-    def test_first_megastep_observation_absorbed(self):
-        """Regression (ISSUE 15 satellite): the first (K, bucket)
-        window pays the cold XLA compile — seeding the EWMA with it
-        poisoned estimate_megastep for the whole run and starved K>1
-        admission. It must land in the first-observation absorber."""
-        cm = CostModel(max_batch=64)
-        cm.observe_stage("dispatch", 32, 1.0)
-        cm.observe_stage("compute", 32, 2.0)
-        amortized = cm.estimate_megastep(4, 32)
-        cm.observe_megastep(4, 32, 900.0)  # cold compile wall
-        # Still the amortization model, NOT 900ms.
-        assert cm.estimate_megastep(4, 32) == amortized
-        snap = cm.snapshot()
-        assert snap["megastep_first_ms"] == {"4x32": 900.0}
-        assert snap["megastep_ewma_ms"] == {}
-        # The first STEADY window seeds the EWMA.
-        cm.observe_megastep(4, 32, 8.0)
-        assert cm.estimate_megastep(4, 32) == 8.0
-        cm.observe_megastep(4, 32, 10.0)
-        assert amortized != 900.0
-        assert 8.0 < cm.estimate_megastep(4, 32) < 10.0
-
-    def test_absorption_is_per_shape(self):
-        cm = CostModel(max_batch=64)
-        cm.observe_megastep(4, 32, 500.0)
-        cm.observe_megastep(2, 32, 400.0)  # different K: own absorber
-        snap = cm.snapshot()
-        assert set(snap["megastep_first_ms"]) == {"4x32", "2x32"}
-        assert snap["megastep_ewma_ms"] == {}
-
     def test_dispatch_bytes_ewma_buckets(self):
         cm = CostModel(max_batch=64)
         assert _pow2_kb_bucket(40 * 1024) == _pow2_kb_bucket(60 * 1024)

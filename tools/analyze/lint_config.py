@@ -77,15 +77,6 @@ HOT_FUNCTIONS = frozenset({
     "pingoo_tpu/sched/scheduler.py::CostModel.estimate_stage",
     "pingoo_tpu/sched/scheduler.py::Scheduler.observe_stage_cost",
     "pingoo_tpu/obs/pipeline.py::PipelineStats.note_stage",
-    # Device-resident megastep (ISSUE 12): the double-buffered input
-    # queue's fill runs per slice on the drain path (strided copies
-    # into REUSED host stacks, never fresh allocations), device_stack
-    # issues the ASYNC device_put copy for the next buffer while the
-    # current megastep computes (it must never sync), the per-slice
-    # resolve unpacks one already-synced numpy stack, and the megastep
-    # cost EWMAs are pure float math on the admission path.
-    "pingoo_tpu/engine/batch.py::DeviceInputQueue.fill_slice",
-    "pingoo_tpu/engine/batch.py::DeviceInputQueue.device_stack",
     # Compact staging (ISSUE 15): the packed encoders fill the single
     # reused [B, width] staging buffer per batch (one strided copy per
     # field into REUSED memory, never a fresh matrix), and the meta
@@ -93,11 +84,6 @@ HOT_FUNCTIONS = frozenset({
     "pingoo_tpu/engine/batch.py::StagingEncoder._encode_requests_packed",
     "pingoo_tpu/engine/batch.py::StagingEncoder._encode_slots_packed",
     "pingoo_tpu/engine/batch.py::StagingEncoder._pack_meta",
-    "pingoo_tpu/engine/verdict.py::finish_megastep",
-    "pingoo_tpu/engine/service.py::VerdictService._evaluate_megastep",
-    "pingoo_tpu/sched/scheduler.py::CostModel.observe_megastep",
-    "pingoo_tpu/sched/scheduler.py::CostModel.estimate_megastep",
-    "pingoo_tpu/obs/pipeline.py::PipelineStats.note_megastep",
     # Perf ledger + timeline (ISSUE 17): the compile probe wraps EVERY
     # jitted dispatch (two O(1) cache-size calls per invocation; event
     # assembly only on the rare compile branch), the stride sampler is
@@ -151,18 +137,12 @@ TRACED_FUNCTIONS = frozenset({
     # The byte loops' shared driver (ISSUE 29): traced from both of the
     # above through their *_scan_chunk.
     "pingoo_tpu/ops/live_columns.py::scan_live_columns",
-    # Device-resident megastep driver (ISSUE 12): the K-slice lax.scan
-    # body and its per-slice step execute at trace time from
-    # make_megastep_fn's jit — captured host constants there re-stage
-    # on every retrace.
-    "pingoo_tpu/engine/verdict.py::make_megastep_fn.slice_step",
-    "pingoo_tpu/engine/verdict.py::make_megastep_fn.megastep",
 })
 
 # The explicit blessing list for block_until_ready: the ONE deliberate
 # device sync point per plane. Everything else must go through these.
-# (_await_device is the shared wait primitive finish_batch /
-# finish_megastep route their single sanctioned sync through.)
+# (_await_device is the wait primitive finish_batch routes its single
+# sanctioned sync through.)
 BLOCK_UNTIL_READY_ALLOW = frozenset({
     "pingoo_tpu/engine/verdict.py::_await_device",
 })
@@ -172,7 +152,7 @@ BLOCK_UNTIL_READY_ALLOW = frozenset({
 # blocking device round-trip per call (sync-scalar-cast).
 JITTED_DISPATCH_NAMES = frozenset({
     "_verdict_fn", "_score_fn", "_lane_fn", "_pf_fn", "verdict_fn",
-    "lane_fn", "_mega_fn", "mega_fn",
+    "lane_fn",
 })
 
 # Registered shape quantizers (unbounded-compile-axis): the ONLY
@@ -186,7 +166,6 @@ SHAPE_QUANTIZERS = frozenset({
     "bucket_arrays",     # engine/batch.py: bucket every field axis
     "pad_batch",         # engine/batch.py: pad batch axis to a rung
     "quantize_stage_cap",  # compiler/plan.py: staging-width rungs
-    "megastep_k_ladder",   # engine/verdict.py: pow2 megastep K rungs
     "_pow2_size",        # service wrapper over pow2_batch_size
 })
 

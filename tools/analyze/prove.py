@@ -13,10 +13,8 @@ One offline-safe entry point over the three ISSUE-18 pillars
                   artifact cache runs at compile time (cache.py v12);
                   running them here proves the prover itself still
                   discharges on the seed corpus in bounded wall time.
-  compile surface surface.py re-walks the jit entry points, refreshes
-                  the committed COMPILE_SURFACE.json, and cross-checks
-                  its jax-free K-rung mirror against the live
-                  engine ladder (megastep_k_ladder(megastep_k_cap())).
+  compile surface surface.py re-walks the jit entry points and
+                  refreshes the committed COMPILE_SURFACE.json.
   ring protocol   ringcheck.py explores every interleaving of the ring
                   + body models up to the bound; all properties hold.
 
@@ -139,8 +137,6 @@ def run(history: bool = False, mutations: bool = True) -> int:
     from pingoo_tpu.compiler import obligations as ob
     from pingoo_tpu.compiler.plan import compile_ruleset
     from pingoo_tpu.engine.bodyscan import compile_body_plan
-    from pingoo_tpu.engine.verdict import megastep_k_cap, \
-        megastep_k_ladder
     from pingoo_tpu.utils import crs
 
     failures: list = []
@@ -175,12 +171,6 @@ def run(history: bool = False, mutations: bool = True) -> int:
                      f"registered -> COMPILE_SURFACE.json", failures)
     except ValueError as exc:
         _check(False, f"compile surface: {exc}", failures)
-        surf = None
-    if surf is not None:
-        live = megastep_k_ladder(megastep_k_cap())
-        _check(list(surf["k_rungs"]) == list(live),
-               f"surface K rungs match the live engine ladder "
-               f"({surf['k_rungs']} vs {live})", failures)
 
     # -- pillar 3: ring-protocol model checker -------------------------
     _check(ringcheck.run(quiet=True) == 0,

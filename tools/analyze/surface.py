@@ -4,8 +4,7 @@ XLA compilation is the one unbounded latency hazard the serving path
 has: any NEW (fn, shape) pair that reaches a jitted dispatch stalls a
 live batch for seconds.  Every shape axis the engine exposes is
 deliberately rung-quantized — pow2 batch buckets (engine/batch.py
-pow2_batch_size, floor 8), pow2 megastep K rungs (engine/verdict.py
-megastep_k_ladder), quantized staging widths (compiler/plan.py
+pow2_batch_size, floor 8), quantized staging widths (compiler/plan.py
 STAGING_RUNGS), and the DFA mode ladder — so the set of admissible
 compilations per plan is CLOSED and statically enumerable.
 
@@ -44,7 +43,6 @@ MAKE_FN_LABELS = {
     "make_packed_prefilter_fn": "prefilter",
     "make_lane_fn": "lanes",
     "make_packed_lane_fn": "lanes",
-    "make_megastep_fn": "megastep",
 }
 
 PLANES = ("python", "sidecar")
@@ -61,17 +59,6 @@ def _pow2_ladder(lo: int, hi: int) -> list[int]:
         out.append(v)
         v *= 2
     return out
-
-
-def _k_ladder() -> list[int]:
-    """Mirror of engine/verdict.megastep_k_ladder(megastep_k_cap())
-    without importing jax; tools/analyze/prove.py cross-checks the two
-    whenever the engine is importable."""
-    try:
-        cap = max(1, int(os.environ.get("PINGOO_MEGASTEP_K", "4")))
-    except ValueError:
-        cap = 4
-    return _pow2_ladder(1, cap)
 
 
 def scan_entry_points(repo_root: str = REPO_ROOT):
@@ -121,13 +108,12 @@ def _scan_module(tree: ast.AST, rel: str, entries: list,
             callee = node.func
             cname = callee.attr if isinstance(callee, ast.Attribute) \
                 else getattr(callee, "id", "")
-            if cname not in ("instrument_jit", "instrument_megastep"):
+            if cname != "instrument_jit":
                 continue
             if rel.replace(os.sep, "/") == "pingoo_tpu/obs/perf.py":
                 continue  # the instrument layer itself
-            fn_label: Optional[str] = "megastep" \
-                if cname == "instrument_megastep" else None
-            if cname == "instrument_jit" and len(node.args) >= 2:
+            fn_label: Optional[str] = None
+            if len(node.args) >= 2:
                 arg = node.args[1]
                 if isinstance(arg, ast.Constant) and isinstance(
                         arg.value, str):
@@ -167,11 +153,10 @@ def build_surface(plan: Any = None, max_batch: int = 8192,
         "planes": list(PLANES),
         "fns": fns,
         "kinds": list(KINDS),
-        # pow2_batch_size floors direct batches at 8, but a megastep
-        # window's per-slice rows can be any pow2 below it (size/K), so
-        # the admissible bucket set is the full pow2 ladder.
+        # pow2_batch_size floors the python plane's batches at 8, but
+        # the sidecar pads to its own max_batch, which may be any pow2
+        # below that, so the admissible set is the full pow2 ladder.
         "batch_buckets": _pow2_ladder(1, max(8, max_batch)),
-        "k_rungs": _k_ladder(),
         "dfa_modes": list(DFA_MODES),
         "entry_points": entries,
     }
@@ -207,7 +192,6 @@ def run(out_path: str = DEFAULT_PATH) -> int:
     print(f"surface: OK — {factories} factories + {sites} instrumented "
           f"sites -> {os.path.relpath(out_path, REPO_ROOT)} "
           f"({len(surface['batch_buckets'])} buckets x "
-          f"{len(surface['k_rungs'])} K rungs x "
           f"{len(surface['fns'])} fns)")
     return 0
 

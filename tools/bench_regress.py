@@ -26,7 +26,6 @@ error. Tracked metrics and their directions:
     dfa_auto_req_per_s   higher is better (ISSUE 8 bitsplit-DFA arm)
     pipeline_on_req_per_s  higher is better (ISSUE 9 pipelined executor)
     pipeline_on_p99_ms     lower  is better
-    megastep_req_per_s   higher is better (ISSUE 12 megastep arm)
     swap_pause_p99_ms    lower  is better (ISSUE 11 hot-swap pause)
     body_stream_mb_per_s higher is better (ISSUE 13 streaming body scan)
     staging_compact_req_per_s higher is better (ISSUE 15 compact staging)
@@ -60,8 +59,6 @@ TRACKED = (
     # Zero-copy pipelined executor A/B (ISSUE 9, bench.py --pipeline).
     ("pipeline_on_req_per_s", True),
     ("pipeline_on_p99_ms", False),
-    # Device-resident megastep arm (ISSUE 12, bench.py --pipeline).
-    ("megastep_req_per_s", True),
     # Sidecar supervision chaos smoke (ISSUE 10, tools/chaos_smoke.py):
     # p99 enqueue->resolution during a sidecar outage must stay within
     # the degraded fail-open bound.

@@ -11,11 +11,6 @@ REAL failures, offline and in ~a minute:
     demand — zero lost tickets, zero double-posts, p99
     enqueue->resolution bounded through the outage
     (`degraded_failopen_p99_ms`);
-  * SIGKILL mid-megastep (PINGOO_MEGASTEP=force; ISSUE 12): the victim
-    dies with a K-slice device window in flight — more rows stranded
-    than one batch can hold — and reattach re-evaluates every orphaned
-    slice row exactly once while the new generation keeps serving in
-    megastep mode;
   * heartbeat freeze (PINGOO_CHAOS=heartbeat_freeze): the ring
     heartbeat goes stale within the detection window while the drain
     loop itself keeps serving — the liveness detector reads the
@@ -52,8 +47,6 @@ sys.path.insert(0, REPO)
 FAILURES: list = []
 
 N_KILL = 64        # scenario A requests
-N_MEGA = 64        # scenario A2 pre-kill requests (ISSUE 12)
-N_MEGA_EXTRA = 32  # scenario A2 post-reattach requests
 N_LADDER = 48      # scenario C requests
 N_SWAP = 96        # scenario D requests
 MAX_BATCH = 16
@@ -119,8 +112,7 @@ def parent() -> int:
     for k in ("PINGOO_CHAOS", "PINGOO_DFA", "PINGOO_MESH",
               "PINGOO_DEADLINE_MS", "PINGOO_SCHED_MODE",
               "PINGOO_SCHED_FAILOPEN", "PINGOO_PIPELINE",
-              "PINGOO_PIPELINE_DEPTH", "PINGOO_MEGASTEP",
-              "PINGOO_MEGASTEP_K"):
+              "PINGOO_PIPELINE_DEPTH"):
         env.pop(k, None)
     proc = subprocess.run(
         [sys.executable, os.path.abspath(__file__), "--child"],
@@ -248,118 +240,6 @@ def scenario_kill_reattach(tmp: str) -> dict:
     ring.close()
     return {"orphans": orphans, "reconciled": rec,
             "degraded_failopen_p99_ms": round(p99, 1)}
-
-
-def scenario_kill_mid_megastep(tmp: str) -> dict:
-    """SIGKILL with a K-slice megastep window in flight (ISSUE 12):
-    the chaos kill fires after the window's FIRST resolved slice, so
-    the victim dies holding K-1 computed-but-unposted slices. The
-    reattach must re-evaluate every stranded row exactly once, and the
-    new generation must resume serving IN megastep mode."""
-    import threading
-
-    from pingoo_tpu.native_ring import Ring, RingSidecar
-
-    print("-- scenario: SIGKILL mid-megastep window + crash-reattach --")
-    ring_path = os.path.join(tmp, "ring_mega")
-    ready_path = os.path.join(tmp, "ready_mega")
-    ring = Ring(ring_path, capacity=256, create=True)
-    enq_t = {}
-    # Enqueue the whole pre-kill stream BEFORE the victim attaches: its
-    # drain then fills a full K=4 window immediately, so the kill
-    # deterministically lands with multiple slices in flight instead of
-    # racing the enqueuer into a short idle-drain window.
-    for i in range(N_MEGA):
-        tk = ring.enqueue(**req_fields(i))
-        if tk is None:
-            check(False, f"enqueue {i} hit a full ring")
-            continue
-        enq_t[tk] = time.monotonic()
-    need_total = N_MEGA + N_MEGA_EXTRA
-    got: dict = {}
-    stop_poll = False
-    poll = threading.Thread(
-        target=_poller, args=(ring, got, lambda: stop_poll, need_total),
-        daemon=True)
-    poll.start()
-    env = dict(os.environ)
-    env["PINGOO_CHAOS"] = "kill:1"
-    env["PINGOO_MEGASTEP"] = "force"
-    env["PINGOO_MEGASTEP_K"] = "4"
-    proc = subprocess.Popen(
-        [sys.executable, os.path.abspath(__file__), "--sidecar",
-         ring_path, ready_path], env=env, cwd=REPO)
-    proc.wait(timeout=300)
-    check(proc.returncode == -9,
-          f"victim died by SIGKILL mid-window (rc={proc.returncode})")
-    lv = ring.liveness()
-    orphans = lv["req_tail"] - lv["posted_floor"]
-    check(lv["epoch"] == 1, f"epoch 1 before reattach ({lv['epoch']})")
-    # The proof the kill landed MID-window: more rows stranded than a
-    # single per-batch dispatch could ever hold in flight.
-    check(orphans > MAX_BATCH,
-          f"kill stranded multiple window slices ({orphans} rows > one "
-          f"{MAX_BATCH}-row batch)")
-
-    plan = make_plan()
-    os.environ["PINGOO_MEGASTEP"] = "force"
-    os.environ["PINGOO_MEGASTEP_K"] = "4"
-    try:
-        sidecar = RingSidecar(ring, plan, {}, max_batch=MAX_BATCH)
-    finally:
-        del os.environ["PINGOO_MEGASTEP"]
-        del os.environ["PINGOO_MEGASTEP_K"]
-    check(sidecar.epoch == 2, f"reattach bumped epoch ({sidecar.epoch})")
-    rec = dict(sidecar.reconciled)
-    check(rec["reeval"] == orphans,
-          f"every in-flight slice row re-evaluated exactly once "
-          f"({rec} vs {orphans} orphans)")
-    # Fresh load for the reattached generation: it must serve these
-    # through megastep windows, not fall back to per-batch dispatch.
-    for i in range(N_MEGA, need_total):
-        tk = ring.enqueue(**req_fields(i))
-        if tk is None:
-            check(False, f"post-reattach enqueue {i} hit a full ring")
-            continue
-        enq_t[tk] = time.monotonic()
-    remaining = need_total - lv["req_tail"]
-    worker = threading.Thread(target=sidecar.run,
-                              kwargs={"max_requests": remaining},
-                              daemon=True)
-    worker.start()
-    deadline = time.time() + 240
-    while time.time() < deadline and \
-            sum(len(v) for v in got.values()) < need_total:
-        time.sleep(0.01)
-    stop_poll = True
-    poll.join(timeout=5)
-    sidecar.stop()
-    worker.join(timeout=30)
-
-    lost = [t for t in enq_t if t not in got]
-    doubles = {t: [a for a, _ in v] for t, v in got.items()
-               if len(v) > 1}
-    check(not lost, f"zero lost tickets ({len(lost)} lost: {lost[:5]})")
-    check(not doubles, f"zero double-posted tickets ({doubles})")
-    wrong = [t for t, v in got.items()
-             if (v[0][0] & 3) != want_action(t)]
-    check(not wrong,
-          f"verdicts bit-exact across the mid-window crash ({wrong[:5]})")
-    mega = sidecar.stats()["megastep"]
-    check(mega["windows"] >= 1,
-          f"reattached generation resumed in megastep mode "
-          f"({mega['windows']} windows)")
-    check(mega["echo_mismatch"] == 0,
-          f"zero ruleset-epoch echo mismatches after reattach ({mega})")
-    lats = sorted((v[0][1] - enq_t[t]) * 1e3 for t, v in got.items()
-                  if t in enq_t)
-    p99 = lats[max(0, int(len(lats) * 0.99) - 1)] if lats else -1.0
-    check(0 < p99 < P99_BOUND_MS,
-          f"p99 enqueue->resolution bounded through the outage "
-          f"({p99:.0f}ms < {P99_BOUND_MS:.0f}ms)")
-    ring.close()
-    return {"megastep_orphans": orphans,
-            "megastep_windows_after_reattach": mega["windows"]}
 
 
 def scenario_heartbeat_freeze(tmp: str) -> dict:
@@ -544,7 +424,6 @@ def child() -> int:
     summary = {"backend": "chaos-cpu"}
     with tempfile.TemporaryDirectory() as tmp:
         summary.update(scenario_kill_reattach(tmp))
-        summary.update(scenario_kill_mid_megastep(tmp))
         summary.update(scenario_heartbeat_freeze(tmp))
         summary.update(scenario_ladder(tmp))
         summary.update(scenario_swap_storm(tmp))
@@ -557,8 +436,7 @@ def child() -> int:
     check(not problems, f"prometheus lint clean {problems[:3]}")
     for name in ("pingoo_sidecar_epoch", "pingoo_reattach_reconciled_total",
                  "pingoo_degrade_total", "pingoo_chaos_injected_total",
-                 "pingoo_ruleset_epoch", "pingoo_ruleset_swap_total",
-                 "pingoo_megastep_k"):
+                 "pingoo_ruleset_epoch", "pingoo_ruleset_swap_total"):
         check(name in text, f"scrape exposes {name}")
 
     if FAILURES:

@@ -17,7 +17,7 @@ BOTH planes:
     series export through the shared registry and the exposition
     passes the Prometheus lint.
 
-Offline-safe like megastep-smoke: when jax is unavailable the smoke
+Offline-safe like mesh-smoke: when jax is unavailable the smoke
 SKIPS WITH A WARNING (exit 0) instead of failing the gate. The work
 happens in a re-exec'd child under a controlled environment so a parent
 shell pinning PINGOO_STAGING cannot skew the A/B.
@@ -54,8 +54,7 @@ def parent() -> int:
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
     for k in ("PINGOO_STAGING", "PINGOO_STAGING_DEPTH", "PINGOO_PIPELINE",
-              "PINGOO_PIPELINE_DEPTH", "PINGOO_MEGASTEP",
-              "PINGOO_MEGASTEP_K", "PINGOO_MESH", "PINGOO_DFA",
+              "PINGOO_PIPELINE_DEPTH", "PINGOO_MESH", "PINGOO_DFA",
               "PINGOO_DEADLINE_MS", "PINGOO_SCHED_MODE",
               "PINGOO_SCHED_FAILOPEN", "PINGOO_CHAOS"):
         env.pop(k, None)

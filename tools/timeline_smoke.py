@@ -65,8 +65,8 @@ def parent() -> int:
         tmp, "COMPILE_SURFACE.json")
     for k in ("PINGOO_TIMELINE_N", "PINGOO_TIMELINE_ROWS",
               "PINGOO_PERF_LEDGER_N", "PINGOO_STAGING", "PINGOO_PIPELINE",
-              "PINGOO_MEGASTEP", "PINGOO_MESH", "PINGOO_CHAOS",
-              "PINGOO_PARITY_SAMPLE", "PINGOO_PROFILE_DIR"):
+              "PINGOO_MESH", "PINGOO_CHAOS", "PINGOO_PARITY_SAMPLE",
+              "PINGOO_PROFILE_DIR"):
         env.pop(k, None)
     proc = subprocess.run(
         [sys.executable, os.path.abspath(__file__), "--child"],
