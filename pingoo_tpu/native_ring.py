@@ -1240,7 +1240,7 @@ class RingSidecar:
                 pend_buf = self._take_slot_buf() if self._zero_copy \
                     else None
             while inflight:
-                self._complete(*inflight.popleft())
+                self._complete_oldest(inflight, "drain")
             while True:
                 with self._swap_lock:
                     if not self._swap_queue:
@@ -1267,11 +1267,18 @@ class RingSidecar:
     def run(self, max_requests: Optional[int] = None) -> int:
         """Blocking drain loop; returns requests processed.
 
-        Two-deep pipeline: batch N+1 is DISPATCHED (jax is async) and its
-        host-interpreted rules evaluated while batch N's device verdict
-        is still in flight — so per-batch wall time is the max of host
-        work and device occupancy, not their sum plus the transfer
-        round trip.
+        A pipeline of at most `pipeline_depth` batches: a batch is
+        DISPATCHED (jax is async) and the loop turns to the next dequeue
+        while the device works on it. The oldest batch in flight is
+        completed (host rules, lanes to the host, merge, post) as soon
+        as its lanes are ready on the device (looked at after each
+        dequeue pass and after each launch), and otherwise when
+        `pipeline_depth` batches are in flight (the loop blocks on the
+        oldest) or on a pass that launched nothing — always oldest
+        first. While the device is the pace nothing is ready early and
+        the depth decides: batch N+1's host work hides behind batch N's
+        device time. While the host is the pace a batch leaves once the
+        chip is done with it, not `pipeline_depth - 1` passes later.
 
         Admission (ISSUE 6): dequeued slots ACCUMULATE across drain
         cycles under the continuous-batching scheduler — a batch
@@ -1380,6 +1387,8 @@ class RingSidecar:
             pend_n += got
             if got:
                 self._pipe.wake()
+            # before this pass's rows are encoded
+            self._complete_ready(inflight)
             launch = False
             if pend_n:
                 if not continuous or pend_n >= self.max_batch:
@@ -1395,9 +1404,12 @@ class RingSidecar:
                 pend_parts, pend_n, oldest_enq_ms = [], 0, None
                 if pend_buf is not None:
                     pend_buf = self._take_slot_buf()
-            if inflight and (len(inflight) >= self.pipeline_depth
-                             or not launch):
-                self._complete(*inflight.popleft())
+                # what became ready while that batch was encoded
+                self._complete_ready(inflight)
+            if len(inflight) >= self.pipeline_depth:
+                self._complete_oldest(inflight, "depth")  # the bound
+            elif inflight and not launch:
+                self._complete_oldest(inflight, "drain")
             if got == 0 and not launch and not inflight:
                 if not pend_parts and max_requests is not None \
                         and self.processed >= max_requests:
@@ -1416,7 +1428,7 @@ class RingSidecar:
         elif pend_buf is not None:
             self._slot_pool.append(pend_buf)
         while inflight:
-            self._complete(*inflight.popleft())
+            self._complete_oldest(inflight, "drain")
         # Final body drain: FINAL windows already in the ring still get
         # verdicts (else their held requests eat the fail-open timeout).
         if self._body_scan is not None:
@@ -1436,6 +1448,28 @@ class RingSidecar:
                                error=RuntimeError("sidecar stopped"))
         self._pipe.loop_stop()
         return self.processed
+
+    def _complete_oldest(self, inflight, how: str) -> None:
+        """Complete the oldest batch in flight, counted by the rule that
+        chose it: `ready` (its lanes were there), `depth` (the in-flight
+        bound: `_complete` blocks on the device) or `drain` (a pass that
+        launched nothing, the flush, a swap boundary)."""
+        self._pipe.note_completion(how)
+        self._complete(*inflight.popleft())
+
+    def _complete_ready(self, inflight) -> None:
+        """Complete, oldest first, every batch in flight whose device
+        lanes are already ready; stop at the first that is not, so
+        completion stays FIFO and posted tickets stay a prefix
+        (`set_posted_floor`). `dev` None is a batch the interpreter
+        serves (device rung demoted): nothing to wait for. `rule_hits`
+        is an output of the same program and `pf_aux` of an earlier
+        one, so `dev` speaks for all three."""
+        while inflight:
+            dev = inflight[0][3]
+            if dev is not None and not dev.is_ready():
+                return
+            self._complete_oldest(inflight, "ready")
 
     def _drain_bodies(self) -> None:
         """Drain each ring's body-window ring through the streaming
@@ -2382,6 +2416,7 @@ class RingSidecar:
             "ring_rows": {name: c.value for name, c in
                           zip(self.ring_names, self._ring_rows)},
             "batch_rings": self._batch_rings.value,
+            "completions": dict(self._pipe.completions),
             "ring_telemetry": self.ring_telemetry(),
             "sched": self.sched.snapshot(),
             "mesh": self.mesh.describe(),

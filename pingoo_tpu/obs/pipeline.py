@@ -40,6 +40,8 @@ points (which the sampled Timeline reads) and the loop-phase account:
   * pingoo_sidecar_stall_total{plane,phase}: spans of a non-idle phase
     longer than STALL_MS, each logged once with its batch and the ring
     depth.
+  * pingoo_sidecar_completions_total{plane,how}: batches completed, by
+    the rule of `RingSidecar.run` that chose the moment (COMPLETIONS).
 
 Interval bookkeeping is host-side float math on the plane's own
 serial context (event loop / drain thread): no locks, no arrays, no
@@ -83,6 +85,10 @@ PHASE_STAGE = {"encode": "encode", "prefilter": "prefilter",
 _PHASE_EXEC = {"encode": "encode", "dispatch": "dispatch",
                "device_wait": "compute", "resolve": "resolve"}
 STALL_MS = 250.0
+# Why the drain loop completed a batch when it did: its device lanes
+# were ready, the in-flight bound was reached (the loop blocked on it),
+# or a pass launched nothing (also the flush and a swap boundary).
+COMPLETIONS = ("ready", "depth", "drain")
 _IDLE_FLUSH_S = 1.0
 
 _log = logging.getLogger(__name__)
@@ -286,6 +292,13 @@ class PipelineStats:
                 labels={"plane": self.plane, "phase": p})
             for p in LOOP_PHASES}
         self._stall_ctr: dict[str, object] = {}
+        self._completion_ctr = {
+            how: self._registry.counter(
+                "pingoo_sidecar_completions_total",
+                schema.PIPELINE_METRICS["pingoo_sidecar_completions_total"],
+                labels={"plane": self.plane, "how": how})
+            for how in COMPLETIONS}
+        self.completions = dict.fromkeys(COMPLETIONS, 0)  # this loop's own
         self._stack: list = []      # enclosing with-blocks: (name, rec)
         self._base = "poll"         # what the loop falls back to
         self._cur = None            # the open span: name, rec, t0, ann
@@ -315,6 +328,12 @@ class PipelineStats:
     def begin(self, mode: str, rows: int, rings: int = 1) -> BatchSpans:
         """enter() for the drain loop: the batch's span record."""
         return BatchSpans(self.enter(mode), rows, rings)
+
+    def note_completion(self, how: str) -> None:
+        """The drain loop is about to complete its oldest batch, by the
+        rule `how` (COMPLETIONS)."""
+        self._completion_ctr[how].inc()
+        self.completions[how] += 1
 
     def finish(self) -> None:
         """exit() for the drain loop; the phase account reaches the

@@ -151,6 +151,12 @@ PIPELINE_METRICS = {
     "pingoo_sidecar_stall_total":
         "spans of one non-idle drain-loop phase longer than 250 ms, by "
         "phase (each also logs its batch and the ring depth)",
+    "pingoo_sidecar_completions_total":
+        "batches the drain loop completed, by the rule that chose the "
+        "moment (how=ready: its device lanes were already there; depth: "
+        "PINGOO_PIPELINE_DEPTH batches in flight, the loop blocked on "
+        "the oldest; drain: a pass that launched nothing, the flush, a "
+        "swap boundary)",
 }
 
 # Continuous-batching scheduler + serving-mesh metrics (ISSUE 6,

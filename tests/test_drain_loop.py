@@ -2,12 +2,15 @@
 RingSidecar, a small plan, no httpd.
 
 `RingSidecar.run` has one way from dequeue to posted verdict: dequeue,
-`should_launch`, `_dispatch`, `_complete` on the oldest when the
-pipeline is full or nothing launched, idle. What that loop owes its
-callers, whatever `pipeline_depth` is:
+`should_launch`, `_dispatch`, `_complete` on the oldest when its lanes
+are ready, when the pipeline is full or when nothing launched, idle.
+What that loop owes its callers, whatever `pipeline_depth` is:
 
   * every dequeued row gets exactly one verdict, the interpreter's;
   * batches in flight never exceed `pipeline_depth`;
+  * a batch whose lanes are ready is completed at once, oldest first
+    (ISSUE 34); lanes that are never ready early are held to the depth,
+    launch for launch and completion for completion as before;
   * rows held under the launch threshold are posted by the flush;
   * the posted floor never passes an unposted ticket;
   * a hot swap requested with batches in flight flips between batches;
@@ -18,6 +21,9 @@ callers, whatever `pipeline_depth` is:
 
 Every drive enqueues its whole burst BEFORE the loop starts, so each
 pass finds a full batch and the launches are the same on every run.
+When a batch's lanes are ready is the device's to say, so the drives
+that test the completion rule put a stand-in in their place (`_Lanes`)
+whose `is_ready()` the test decides.
 
 The last two tests are the knob census: docs/configuration.md against
 what the program reads.
@@ -34,6 +40,7 @@ import re
 import threading
 import time
 
+import numpy as np
 import pytest
 
 from pingoo_tpu import native_ring
@@ -117,11 +124,74 @@ def _run_to_end(sidecar, n):
     assert not worker.is_alive(), "the drain loop never finished the burst"
 
 
+class _Lanes:
+    """A batch's device lanes with the readiness a test gives them (as
+    tests/test_resilience.py's `_NeverReady`); the sync still gets the
+    program's own lanes."""
+
+    def __init__(self, dev, ready: bool):
+        self._dev, self._ready = dev, ready
+
+    def is_ready(self) -> bool:
+        return self._ready
+
+    def __array__(self, dtype=None, copy=None):
+        return np.asarray(self._dev)
+
+
+# what a drive's batches find when the loop asks `is_ready()`, by the
+# batch's place in the launch order (1, 2, ...); "real" leaves the
+# device's own arrays in, "none" is a batch with no device lanes at all
+# (the ladder's device rung demoted: the interpreter serves it)
+LANES = {
+    "ready": lambda seq: True,
+    "never": lambda seq: False,
+    "even": lambda seq: seq % 2 == 0,  # ready ones BEHIND unready ones
+}
+
+
+def _stand_in_lanes(sidecar, lanes: str, events=None):
+    """Wrap `_dispatch` (and `_complete`, for the order) of a sidecar."""
+    dispatch, complete = sidecar._dispatch, sidecar._complete
+    events = [] if events is None else events
+
+    def staged_dispatch(*args, **kwargs):
+        entry = dispatch(*args, **kwargs)
+        seq = entry[-1].seq
+        events.append(("launch", seq))
+        if lanes == "none":
+            entry = entry[:3] + (None, None) + entry[5:]
+        elif lanes != "real":
+            entry = entry[:3] + (_Lanes(entry[3], LANES[lanes](seq)),) \
+                + entry[4:]
+        return entry
+
+    def watched_complete(*entry):
+        events.append(("complete", entry[-1].seq))
+        return complete(*entry)
+
+    sidecar._dispatch = staged_dispatch
+    sidecar._complete = watched_complete
+
+
+def _parents_order(batches: int, depth: int) -> list:
+    """What the loop did before ISSUE 34 with a full batch on every
+    pass: a launch a pass, the oldest completed once `depth` are in
+    flight, the rest by the passes that launch nothing."""
+    events, inflight = [], []
+    for seq in range(1, batches + 1):
+        events.append(("launch", seq))
+        inflight.append(seq)
+        if len(inflight) >= depth:
+            events.append(("complete", inflight.pop(0)))
+    return events + [("complete", seq) for seq in inflight]
+
+
 class Drive:
     """One burst through one sidecar, with what the loop itself counts
     and calls recorded on the way."""
 
-    def __init__(self, tmp, depth: int, n_rings: int):
+    def __init__(self, tmp, depth: int, n_rings: int, lanes: str = "real"):
         from pingoo_tpu.native_ring import Ring, RingSidecar
 
         self.plan = _plan()
@@ -134,6 +204,9 @@ class Drive:
         sidecar = RingSidecar(
             self.rings if n_rings > 1 else self.rings[0], self.plan, {},
             max_batch=MAX_BATCH, pipeline_depth=depth)
+        # launches and completions in the order the loop made them
+        self.events: list = []
+        _stand_in_lanes(sidecar, lanes, self.events)
         # pingoo_pipeline_inflight as each launch leaves it
         self.inflight_seen: list = []
         dispatch = sidecar._dispatch
@@ -152,6 +225,7 @@ class Drive:
         try:
             _run_to_end(sidecar, BURST)
             self.batches = sidecar.batches
+            self.completions = sidecar.stats()["completions"]
             self.got = [_verdicts(ring) for ring in self.rings]
         finally:
             sidecar.stop()
@@ -181,17 +255,17 @@ class Drive:
 
 @pytest.fixture(scope="module")
 def drive(tmp_path_factory):
-    """(depth, rings) -> the Drive, made once a module."""
+    """(depth, rings, lanes) -> the Drive, made once a module."""
     made: dict = {}
 
-    def get(depth, n_rings=1):
-        key = (depth, n_rings)
+    def get(depth, n_rings=1, lanes="real"):
+        key = (depth, n_rings, lanes)
         if key not in made:
             saved = {k: os.environ.pop(k, None) for k in KNOBS}
             try:
                 made[key] = Drive(
-                    tmp_path_factory.mktemp(f"d{depth}r{n_rings}"),
-                    depth, n_rings)
+                    tmp_path_factory.mktemp(f"d{depth}r{n_rings}{lanes}"),
+                    depth, n_rings, lanes)
             finally:
                 os.environ.update(
                     {k: v for k, v in saved.items() if v is not None})
@@ -214,20 +288,101 @@ def test_every_row_gets_the_interpreters_verdict_once(drive, depth):
     assert d.batches == -(-BURST // MAX_BATCH)   # the burst spanned them
 
 
+def _assert_served_right(d):
+    """Each ring's tickets answered once, with the interpreter's action,
+    floors never ahead of a post, and every batch counted by one rule."""
+    for got, sent in zip(d.got, d.sent):
+        assert sorted(got) == sorted(sent)
+        assert all(len(v) == 1 for v in got.values())
+        assert {t: v[0] & 3 for t, v in got.items()} == \
+            {t: _want(d.plan, tup) for t, tup in sent.items()}
+    assert d.floor_passed_unposted == []
+    assert d.floors == [len(sent) for sent in d.sent]
+    assert sorted(d.completions) == ["depth", "drain", "ready"]
+    assert sum(d.completions.values()) == d.batches == -(-BURST // MAX_BATCH)
+    # completed in the order launched, whichever rule chose the moment
+    done = [seq for what, seq in d.events if what == "complete"]
+    assert done == list(range(1, d.batches + 1))
+
+
 @needs_native
 @pytest.mark.parametrize("depth", [1, 2, 3])
-def test_batches_in_flight_stay_within_the_depth(drive, depth):
-    d = drive(depth)
+@pytest.mark.parametrize("lanes", ["real", "never"])
+def test_batches_in_flight_stay_within_the_depth(drive, depth, lanes):
+    d = drive(depth, lanes=lanes)
     assert len(d.inflight_seen) == d.batches
-    # never more, and with batches queued behind it the pipeline fills
-    assert max(d.inflight_seen) == depth
+    assert max(d.inflight_seen) <= depth             # never more
+    if lanes == "never":
+        # with batches queued behind it and a device that is the pace,
+        # the pipeline fills
+        assert max(d.inflight_seen) == depth
+    _assert_served_right(d)
+
+
+@needs_native
+@pytest.mark.parametrize("depth", [1, 2, 3])
+def test_ready_lanes_are_completed_in_the_pass_that_launched_them(
+        drive, depth):
+    d = drive(depth, lanes="ready")
+    assert d.completions == {"ready": d.batches, "depth": 0, "drain": 0}
+    assert d.events == [(what, seq) for seq in range(1, d.batches + 1)
+                        for what in ("launch", "complete")]
+    assert max(d.inflight_seen) == 1
+    _assert_served_right(d)
+
+
+@needs_native
+@pytest.mark.parametrize("depth", [1, 2, 3])
+def test_lanes_never_ready_early_are_held_to_the_depth_as_before(
+        drive, depth):
+    """The device-paced case loses nothing: launch for launch and
+    completion for completion what the loop did before ISSUE 34."""
+    d = drive(depth, lanes="never")
+    assert d.events == _parents_order(d.batches, depth)
+    assert d.completions == {"ready": 0, "depth": d.batches - (depth - 1),
+                             "drain": depth - 1}
+    _assert_served_right(d)
+
+
+@needs_native
+@pytest.mark.parametrize("depth", [1, 2, 3])
+def test_a_ready_batch_behind_an_unready_one_waits_its_turn(drive, depth):
+    d = drive(depth, lanes="even")
+    _assert_served_right(d)     # FIFO, and no floor ahead of a post
+    odd = -(-d.batches // 2)    # batches 1, 3, 5: never ready early
+    if depth == 1:
+        # the bound leaves nothing in flight for the ready rule to find
+        # but the batch just launched
+        assert d.completions == {"ready": d.batches - odd, "depth": odd,
+                                 "drain": 0}
+    else:
+        # an unready batch leaves by the bound or the drain, and only
+        # then the ready one behind it by the ready rule
+        assert d.completions["ready"] == d.batches - odd
+        assert d.completions["depth"] + d.completions["drain"] == odd
+        for seq in range(2, d.batches + 1, 2):
+            assert d.events.index(("complete", seq - 1)) \
+                < d.events.index(("complete", seq))
+            assert d.events.index(("launch", seq)) \
+                < d.events.index(("complete", seq - 1))
+
+
+@needs_native
+@pytest.mark.parametrize("depth", [1, 2, 3])
+def test_a_batch_the_interpreter_serves_counts_as_ready(drive, depth):
+    d = drive(depth, lanes="none")      # `dev is None`: nothing to wait for
+    assert d.completions == {"ready": d.batches, "depth": 0, "drain": 0}
+    _assert_served_right(d)
 
 
 @needs_native
 @pytest.mark.parametrize("n_rings", [1, 4])
-def test_posted_floor_never_passes_an_unposted_ticket(drive, n_rings):
-    d = drive(3, n_rings)
-    assert max(d.inflight_seen) == 3
+@pytest.mark.parametrize("lanes", ["real", "never"])
+def test_posted_floor_never_passes_an_unposted_ticket(drive, n_rings, lanes):
+    d = drive(3, n_rings, lanes)
+    assert max(d.inflight_seen) <= 3
+    if lanes == "never":
+        assert max(d.inflight_seen) == 3
     assert d.floor_passed_unposted == []
     # ... and it ends above every ticket of every ring
     assert d.floors == [len(sent) for sent in d.sent]
@@ -275,7 +430,8 @@ def test_swap_with_batches_in_flight_flips_between_batches(
         tmp_path, monkeypatch):
     """tests/test_hotswap.py swaps a quiet sidecar (every phase-A
     verdict polled first); here the swap is requested from inside the
-    third launch, with two batches in flight and three more queued."""
+    third launch, with two batches in flight and three more queued
+    (lanes that are not ready early keep them in flight)."""
     from pingoo_tpu.native_ring import Ring, RingSidecar
 
     for k in KNOBS:
@@ -289,6 +445,7 @@ def test_swap_with_batches_in_flight_flips_between_batches(
     launched_in: dict = {}       # ticket -> ruleset epoch at its launch
     inflight_at_request: list = []
     handles: list = []
+    _stand_in_lanes(sidecar, "never")
     dispatch = sidecar._dispatch
 
     def watched_dispatch(parts, *args, **kwargs):
