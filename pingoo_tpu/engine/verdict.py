@@ -242,6 +242,48 @@ def _pf_compact_sizes(B: int) -> list[int]:
     return sizes
 
 
+CASCADE_STATS = ("candidate", "candidate_bucket", "recheck",
+                 "recheck_bucket")
+
+
+def _bank_gated(pf, key: str) -> bool:
+    """Does Stage A gate bank `key`: a gated bank with a factor mask
+    over a field the prefilter scans."""
+    return (bool(pf.bank_gated.get(key)) and key in pf.bank_masks
+            and pf.bank_field.get(key) in pf.fields)
+
+
+def cascade_banks(plan: RulesetPlan) -> tuple[str, ...]:
+    """Host-static: the banks whose rows the cascade counts, in the
+    order the lanes program reports them (`CASCADE_STATS` each): every
+    bank Stage A gates (`make_prefilter_fn(...).gated`'s test) and
+    every bank behind an APPROXIMATE DFA, whose recheck ladder runs
+    gated or not. Read under the env the trace will see
+    (PINGOO_PREFILTER / PINGOO_DFA)."""
+    pf = getattr(plan, "prefilter", None)
+    gate = pf is not None and _resolve_pf_mode(plan) != "off"
+    dfa_mode = _resolve_dfa_mode(plan)
+    banks: list[str] = []
+
+    def gated(key):
+        return gate and _bank_gated(pf, key)
+
+    for key, entry in getattr(plan, "scan_plans", {}).items():
+        approx = (_dfa_bank_active(plan, entry, dfa_mode)
+                  and not plan.np_tables[entry.dfa_key].exact)
+        banks.extend(k for k in (entry.split or (key,))
+                     if gated(k) or approx)
+    wins = {b.table_key for b in plan.bindings.values()
+            if b.kind == "window"}
+    for key in sorted(wins):
+        dkey = getattr(plan, "win_dfa", {}).get(key)
+        approx = (_dfa_win_active(plan, key, dfa_mode)
+                  and not plan.np_tables[dkey].exact)
+        if gated(key) or approx:
+            banks.append(key)
+    return tuple(banks)
+
+
 # -- numeric IR evaluation ---------------------------------------------------
 
 
@@ -305,8 +347,14 @@ _CMP = {
 # -- leaf evaluation ---------------------------------------------------------
 
 
-def _eval_leaves(plan: RulesetPlan, tables, arrays, B, pf_hits=None):
+def _eval_leaves(plan: RulesetPlan, tables, arrays, B, pf_hits=None,
+                 cascade=None):
     """Compute every leaf's ([B] val, [B] err) with shared group ops.
+
+    `cascade`, a dict, receives per bank the row counts the cascade
+    already holds as traced int32 scalars ({bank: {stat: scalar}},
+    stats of CASCADE_STATS): what each ladder was asked to hold and the
+    bucket it took.
 
     `pf_hits` optionally carries precomputed Stage-A prefilter hit maps
     ({field: [B, F] bool} from make_prefilter_fn — the service path
@@ -404,7 +452,9 @@ def _eval_leaves(plan: RulesetPlan, tables, arrays, B, pf_hits=None):
         """Gather candidate rows into the smallest ladder bucket that
         holds them, scan the compacted rows, scatter hits back over the
         skipped-bank base. Every branch has static shapes (lax.switch);
-        the last branch is the empty-candidate full skip."""
+        the last branch is the empty-candidate full skip. Returns
+        (hits, candidate count, rows of the bucket taken: 0 for the
+        skip)."""
         Bsz = data.shape[0]
         sizes = _pf_compact_sizes(Bsz)
         count = cand.sum(dtype=jnp.int32)
@@ -428,7 +478,13 @@ def _eval_leaves(plan: RulesetPlan, tables, arrays, B, pf_hits=None):
         else:
             lev = jnp.int32(0)
         lev = jnp.where(count == 0, jnp.int32(len(branches) - 1), lev)
-        return jax.lax.switch(lev, branches)
+        bucket = jnp.take(jnp.asarray(sizes + [0], dtype=jnp.int32), lev)
+        return jax.lax.switch(lev, branches), count, bucket
+
+    def note_cascade(key, ladder, count, bucket):
+        if cascade is not None:
+            cascade.setdefault(key, {}).update(
+                {ladder: count, f"{ladder}_bucket": bucket})
 
     def gated_scan(key, data, lens, scan_rows, base_fn):
         """Run one bank through the cascade: unconditional when the bank
@@ -436,13 +492,22 @@ def _eval_leaves(plan: RulesetPlan, tables, arrays, B, pf_hits=None):
         mode."""
         cand = bank_candidates(key, data.shape[0])
         if cand is None:
+            # Ungated: every row is scanned. -1 = "all live rows" (the
+            # host knows how many those are; the program does not).
+            note_cascade(key, "candidate", jnp.int32(-1),
+                         jnp.int32(data.shape[0]))
             return scan_rows(data, lens)
         if pf_mode == "compact":
-            return compact_rows(scan_rows, base_fn, data, lens, cand)
-        return jax.lax.cond(
-            jnp.any(cand),
-            lambda: scan_rows(data, lens),
-            base_fn)
+            hits, count, bucket = compact_rows(scan_rows, base_fn, data,
+                                               lens, cand)
+        else:
+            count = cand.sum(dtype=jnp.int32)
+            bucket = jnp.where(count > 0, jnp.int32(data.shape[0]), 0)
+            hits = jax.lax.cond(count > 0,
+                                lambda: scan_rows(data, lens),
+                                base_fn)
+        note_cascade(key, "candidate", count, bucket)
+        return hits
 
     def gated_bank_hits(key, bank, strat, data, lens):
         with _bank_scope("nfa", key):
@@ -452,7 +517,7 @@ def _eval_leaves(plan: RulesetPlan, tables, arrays, B, pf_hits=None):
                 lambda: bank_skip_result(bank, lens))
 
     def dfa_cascade_hits(key, dtab, data, lens, recheck_rows,
-                         recheck_base):
+                         recheck_base, recheck_scope):
         """One lowered bank's [B, P] hits via its bitsplit DFA.
 
         Exact DFA: a drop-in replacement for the bank's scan that rides
@@ -465,8 +530,11 @@ def _eval_leaves(plan: RulesetPlan, tables, arrays, B, pf_hits=None):
         non-candidates), then rows with any non-trivial hit are
         rechecked through the bank's EXACT scan (NFA tables / window
         conv) via a second, smaller compact ladder; pruned rows take
-        the exact skip base. Either way the verdict is bit-identical to
-        PINGOO_DFA=off (tests/test_bitsplit_dfa)."""
+        the exact skip base. The recheck runs under `recheck_scope`
+        (`nfa/<bank>`, nested in the caller's `dfa/<bank>`), so a device
+        trace tells the exact re-scan from the DFA's own ladder. Either
+        way the verdict is bit-identical to PINGOO_DFA=off
+        (tests/test_bitsplit_dfa)."""
         dfa_rows = lambda d, l: dfa_scan(dtab, d, l)
         dfa_base = lambda: dfa_skip_hits(dtab, lens)
         if dtab.exact:
@@ -476,8 +544,11 @@ def _eval_leaves(plan: RulesetPlan, tables, arrays, B, pf_hits=None):
         pf_cand = bank_candidates(key, data.shape[0])
         if pf_cand is not None:
             cand = cand & pf_cand
-        return compact_rows(recheck_rows, recheck_base, data, lens,
-                            cand)
+        with recheck_scope:
+            hits, count, bucket = compact_rows(recheck_rows, recheck_base,
+                                               data, lens, cand)
+        note_cascade(key, "recheck", count, bucket)
+        return hits
 
     def dfa_bank_hits(key, entry, bank, data, lens):
         strat = _resolve_strategy(entry.strategy)
@@ -485,7 +556,8 @@ def _eval_leaves(plan: RulesetPlan, tables, arrays, B, pf_hits=None):
             return dfa_cascade_hits(
                 key, tables[entry.dfa_key], data, lens,
                 lambda d, l: bank_hits(bank, strat, d, l),
-                lambda: bank_skip_result(bank, lens))
+                lambda: bank_skip_result(bank, lens),
+                _bank_scope("nfa", key))
 
     def gated_window_hits(key, field):
         """The window bank under the same cascade: a gated win bank's
@@ -512,7 +584,8 @@ def _eval_leaves(plan: RulesetPlan, tables, arrays, B, pf_hits=None):
                 and tables[dkey].num_slots == P:
             with _bank_scope("dfa", key):
                 return dfa_cascade_hits(key, tables[dkey], data, lens,
-                                        win_rows, win_base)
+                                        win_rows, win_base,
+                                        jax.named_scope(f"win/{field}"))
         with jax.named_scope(f"win/{field}"):
             if pf is None or key not in pf.slot_codes:
                 return win_rows(data, lens)
@@ -730,9 +803,11 @@ def _eval_bool(ir, leaves, B):
 
 
 @jax.named_scope("bool")
-def _matched_cols(plan: RulesetPlan, tables, arrays, pf_hits=None):
+def _matched_cols(plan: RulesetPlan, tables, arrays, pf_hits=None,
+                  cascade=None):
     """Traced body shared by the verdict/lane functions:
     (tables, arrays) -> [B, R_dev] bool in device_rule_indices order.
+    `cascade`: _eval_leaves' collector of the cascade's row counts.
 
     Rules whose IR is a single leaf (the common WAF shape — one
     predicate per rule) read their column straight out of the stacked
@@ -742,7 +817,8 @@ def _matched_cols(plan: RulesetPlan, tables, arrays, pf_hits=None):
     device_rules = [r for r in plan.rules if not r.host]
     n_leaves = len(plan.leaves)
     B = arrays["asn"].shape[0]
-    leaves = _eval_leaves(plan, tables, arrays, B, pf_hits=pf_hits)
+    leaves = _eval_leaves(plan, tables, arrays, B, pf_hits=pf_hits,
+                          cascade=cascade)
     # Effective per-leaf match columns (+ const true / false).
     eff = [None] * n_leaves
     for leaf_id, (v, e) in leaves.items():
@@ -891,9 +967,7 @@ def _make_prefilter_body(plan: RulesetPlan):
     for key, entry in plan.scan_plans.items():
         scanned.extend(entry.split if entry.split else (key,))
     scanned.extend(k for k in pf.bank_masks if k.startswith("win_"))
-    gated = [k for k in scanned
-             if pf.bank_gated.get(k) and k in pf.bank_masks
-             and pf.bank_field.get(k) in pf.fields]
+    gated = [k for k in scanned if _bank_gated(pf, k)]
     # Hoisted device constants (analyze-lint recompile-const-upload).
     masks = {k: jnp.asarray(pf.bank_masks[k]) for k in gated
              if pf.bank_masks[k].any()}
@@ -1034,9 +1108,13 @@ def make_packed_lane_fn(plan: RulesetPlan,
 def _make_lane_body(plan: RulesetPlan, groups: list[list[str]],
                     with_rule_hits: bool):
     """UNJITTED lane-reduction body: (tables, arrays, pf_hits, n_valid)
-    -> stacked [3 + max(G, 1), B] i32 lanes (+ [C] rule_hits when
+    -> stacked [3 + max(G, 1) + X, B] i32 lanes (+ [C] rule_hits when
     with_rule_hits). Shared by make_lane_fn and its compact-staging
-    twin make_packed_lane_fn."""
+    twin make_packed_lane_fn. The last X rows (`cascade_lane_rows`; 0
+    for a plan with no gated or approximate-DFA bank) carry the
+    cascade's row counts, `CASCADE_STATS` for each of
+    `cascade_banks(plan)`, flat from the first of those rows: they ride
+    the lanes' one device->host copy (`cascade_counts` reads them)."""
     device_rules = [r for r in plan.rules if not r.host]
     orig_idx = np.array([r.index for r in device_rules], dtype=np.int32)
     first_kind = np.array(
@@ -1068,11 +1146,25 @@ def _make_lane_body(plan: RulesetPlan, groups: list[list[str]],
          jnp.asarray([o for o, _ in dev_route], dtype=jnp.int32))
         if dev_route else None
         for dev_route in group_routes]
+    banks = cascade_banks(plan)
 
     @jax.named_scope("act")
     def lanes(tables, arrays, pf_hits=None, n_valid=None):
-        matched = _matched_cols(plan, tables, arrays, pf_hits)  # [B, C]
+        cascade: dict = {}
+        matched = _matched_cols(plan, tables, arrays, pf_hits,
+                                cascade)  # [B, C]
         B = arrays["asn"].shape[0]
+
+        def cascade_rows():
+            """[X, B]: the banks' counts, zero-padded to whole rows."""
+            if not banks:
+                return []
+            zero = jnp.int32(0)
+            flat = jnp.stack([cascade.get(k, {}).get(stat, zero)
+                              for k in banks for stat in CASCADE_STATS])
+            x = cascade_lane_rows(len(banks), B)
+            return list(jnp.pad(flat, (0, x * B - flat.shape[0]))
+                        .reshape(x, B))
 
         def rule_hits():
             # Attribution fold ON DEVICE: padded batch rows are inert
@@ -1090,7 +1182,7 @@ def _make_lane_body(plan: RulesetPlan, groups: list[list[str]],
         n_route = max(len(groups), 1)
         if matched.shape[1] == 0:
             return pack(jnp.stack([none, jnp.zeros((B,), jnp.int32), none]
-                                  + [none] * n_route))
+                                  + [none] * n_route + cascade_rows()))
         act_idx = jnp.where(matched & has_act_row, idx_row, LANE_NONE)
         first_act_idx = jnp.min(act_idx, axis=1)
         arg = jnp.argmin(act_idx, axis=1)
@@ -1113,9 +1205,27 @@ def _make_lane_body(plan: RulesetPlan, groups: list[list[str]],
         # One stacked [3 + G, B] array = ONE device->host transfer
         # (plus the [C] attribution lane when with_rule_hits).
         return pack(jnp.stack([first_act_idx, kind, first_block_idx]
-                              + route_lanes))
+                              + route_lanes + cascade_rows()))
 
     return lanes
+
+
+def cascade_lane_rows(n_banks: int, B: int) -> int:
+    """Lane rows of B int32 that hold n_banks x CASCADE_STATS counts."""
+    return -(-n_banks * len(CASCADE_STATS) // B)
+
+
+def cascade_counts(dev_lanes: np.ndarray, n_banks: int) -> list:
+    """[n_banks][CASCADE_STATS] host ints off the last rows of the
+    lanes program's stacked output, already on the host (full width:
+    the padding columns included)."""
+    if not n_banks:
+        return []
+    k = len(CASCADE_STATS)
+    x = cascade_lane_rows(n_banks, dev_lanes.shape[1])
+    counts = dev_lanes[-x:].reshape(-1)[:n_banks * k].reshape(n_banks, k)
+    # pingoo: allow(sync-tolist): host numpy already, four ints a bank
+    return counts.tolist()
 
 
 def host_rule_lanes(plan: RulesetPlan, batch, lists):

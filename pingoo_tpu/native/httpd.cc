@@ -1877,6 +1877,7 @@ class Server {
   TlsStore* tls() { return tls_; }
 
   void add_client(int cfd, const sockaddr_in& peer, SSL_CTX* base_ctx) {
+    stats_.accepted++;
     Conn* c = new Conn();
     c->fd = cfd;
     c->last_active = now_;
@@ -2067,6 +2068,10 @@ class Server {
     uint64_t upstream_tls_fail = 0;  // client handshake/verify failures
     uint64_t verdicts = 0;        // verdict bytes applied
     uint64_t degraded_entered = 0;  // degraded-mode transitions (enter)
+    // The accept and block-and-reconnect path (ISSUE 35): one plain
+    // store a connection each.
+    uint64_t accepted = 0;        // connections accepted on the listener
+    uint64_t closed_after_block = 0;  // h1 connections closed behind a 403
     // Streaming body inspection (ISSUE 13, PINGOO_BODY_INSPECT=on).
     uint64_t body_flows = 0;      // h1 cycles armed for inspection
     uint64_t body_windows = 0;    // body windows enqueued to the ring
@@ -2328,6 +2333,8 @@ class Server {
     kv_u64("upstream_fail", st.upstream_fail);
     kv_u64("upstream_tls_fail", st.upstream_tls_fail);
     kv_u64("verdicts", st.verdicts);
+    kv_u64("accepted", st.accepted);
+    kv_u64("closed_after_block", st.closed_after_block);
     out += ", \"verdict_wait_ms_hist\": {";
     static const char* kHistKeys[6] = {"le1",  "le2",  "le5",
                                        "le10", "le50", "le100"};
@@ -2384,6 +2391,8 @@ class Server {
       kv_u64("blocked", s.stats.blocked);
       kv_u64("verdicts", s.stats.verdicts);
       kv_u64("fail_open", s.stats.fail_open);
+      kv_u64("accepted", s.stats.accepted);
+      kv_u64("closed_after_block", s.stats.closed_after_block);
       kv_u64("awaiting", s.awaiting);
       kv_u64("connections", s.connections);
       kv_u64("loop_gap_max_ms", s.release.loop_gap_max_ms);
@@ -2430,6 +2439,9 @@ class Server {
     metric("counter", "pingoo_upstream_tls_fail_total",
            st.upstream_tls_fail);
     metric("counter", "pingoo_verdicts_total", st.verdicts);
+    metric("counter", "pingoo_accepted_total", st.accepted);
+    metric("counter", "pingoo_closed_after_block_total",
+           st.closed_after_block);
     metric("gauge", "pingoo_connections", sum.connections);
     metric("gauge", "pingoo_pooled_upstreams", sum.pooled_upstreams);
     // Sidecar supervision (ISSUE 10): sidecar_up stays 0 until a
@@ -2514,6 +2526,8 @@ class Server {
               [](const WorkerSlot& s) { return s.stats.verdicts; });
     by_worker("counter", "pingoo_worker_fail_open_total",
               [](const WorkerSlot& s) { return s.stats.fail_open; });
+    by_worker("counter", "pingoo_worker_accepted_total",
+              [](const WorkerSlot& s) { return s.stats.accepted; });
     by_worker("gauge", "pingoo_worker_ring_depth",
               [](const WorkerSlot& s) { return s.tel[3]; });
     by_worker("gauge", "pingoo_worker_ring_depth_hwm",
@@ -3967,6 +3981,7 @@ class Server {
     flight_record(c->req, ticket, c->enq_ms, action, decided);
     if (decided == 1) {
       stats_.blocked++;
+      stats_.closed_after_block++;
       respond_close(c, k403);
     } else if (decided == 2) {
       stats_.captcha++;
@@ -4143,6 +4158,7 @@ class Server {
     Policy outcome = run_policy(c);
     switch (outcome) {
       case Policy::kBlock:
+        stats_.closed_after_block++;
         respond_close(c, k403);
         return;
       case Policy::kCaptchaRedirect:

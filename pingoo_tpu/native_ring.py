@@ -1041,8 +1041,8 @@ class RingSidecar:
         then pointer assignment at a batch boundary, never compilation."""
         from .engine.batch import (resolve_stage_caps,
                                    stage_overflow_thresholds)
-        from .engine.verdict import (donate_batch_buffers, make_lane_fn,
-                                     make_packed_lane_fn,
+        from .engine.verdict import (cascade_banks, donate_batch_buffers,
+                                     make_lane_fn, make_packed_lane_fn,
                                      make_packed_prefilter_fn,
                                      make_prefilter_fn)
         from .obs.perf import (instrument_jit, plan_fingerprint,
@@ -1064,6 +1064,7 @@ class RingSidecar:
             plan, service_groups=self._groups or None,
             with_rule_hits=self._provenance_on,
             donate=donate_batch_buffers()), "lanes")
+        state["cascade_banks"] = cascade_banks(plan)
         # Compact staging (ISSUE 15): the packed twins decode the
         # one-copy buffer on device; built only under
         # PINGOO_STAGING=compact (the default full arm traces nothing
@@ -1173,6 +1174,11 @@ class RingSidecar:
 
         self._scan_columns = ScanColumnCounters(
             "sidecar", plan, rows_sharded=self.mesh.dp > 1)
+        # pingoo_cascade_rows_total / _bucket_rows_total: what the lanes
+        # program counted of its own cascade, folded in `_complete`.
+        from .obs.pipeline import CascadeCounters
+
+        self._cascade = CascadeCounters("sidecar", state["cascade_banks"])
         self._plan_state = state
         if self._provenance_on:
             from .obs.flightrecorder import (FlightRecorder,
@@ -1615,6 +1621,7 @@ class RingSidecar:
             rule_hits = None
             dev = None
             self._dfa_rung_tick()
+            rec.cascade = self._cascade
             # Ladder device rung: while demoted, skip the dispatch
             # entirely (the interpreter serves in `_complete`) except for
             # backoff probes; a dispatch-time exception demotes — it no
@@ -1776,18 +1783,29 @@ class RingSidecar:
                   pf_aux, n: int, skip_masks, slot_buf, rec) -> None:
         """Resolve the oldest in-flight batch (`_dispatch`'s tuple):
         host rules, the device sync, merge, post, provenance."""
-        from .engine.verdict import host_rule_lanes, merge_lanes
+        from .engine.verdict import (cascade_counts, host_rule_lanes,
+                                     merge_lanes)
 
         with self._pipe.stage("host_rules", rec) as sp:
             # Host-interpreted rules run on the UNPADDED batch while the
             # device lanes are still in flight (jax dispatch is async).
             host = host_rule_lanes(self.plan, raw_batch, self.lists)
-            dev_lanes = None
+            dev_lanes = cascade = None
             sp.next("device_wait")
             if dev is not None:
                 try:
                     with self._hb_busy(sync=(dev,)):  # can block ms-s
-                        dev_lanes = np.asarray(dev)[:, :n]  # drop padding
+                        full = np.asarray(dev)
+                    dev_lanes = full[:, :n]  # drop padding
+                    # The cascade's own row counts ride the same copy
+                    # (the lanes' last rows); the spans from here on
+                    # say what the batch cost: rows the device lanes
+                    # block, rows rechecked.
+                    cascade = cascade_counts(full,
+                                             len(rec.cascade.banks))
+                    rec.stats = {
+                        "blocked": int((dev_lanes[1] == 1).sum()),
+                        "recheck": sum(c[2] for c in cascade)}
                     self._note_device_success()
                 except Exception as exc:
                     # jax dispatch is async — a device/runtime error
@@ -1807,6 +1825,8 @@ class RingSidecar:
             wait_s = sp.next("resolve")
             self.device_wait_s += wait_s
             self.sched.observe_cost(self.max_batch, rec.compute_ms)
+            if cascade:
+                rec.cascade.fold(cascade, n)
             if pf_aux is not None:
                 # Resolved long before the lane sync above; aux int32 lanes.
                 vals = np.asarray(pf_aux)
@@ -2127,11 +2147,16 @@ class RingSidecar:
         when PINGOO_DFA is unset, so the demotion is per-plan, not
         process-global. The next dispatch pays one re-jit (a bounded
         stall during an already-degraded event)."""
-        from .engine.verdict import donate_batch_buffers, make_lane_fn
+        from .engine.verdict import (cascade_banks, donate_batch_buffers,
+                                     make_lane_fn)
         from .obs.perf import (instrument_jit, plan_fingerprint,
                                staging_widths)
+        from .obs.pipeline import CascadeCounters
 
         self.plan.dfa_default_mode = "off" if dfa_off else self._dfa_mode0
+        # The banks that recheck change with the DFAs: batches in flight
+        # keep the counters of the program that launched them.
+        self._cascade = CascadeCounters("sidecar", cascade_banks(self.plan))
         fp = plan_fingerprint(self.plan)
         widths = staging_widths(self.plan)
         self._lane_fn = instrument_jit(make_lane_fn(
@@ -2416,6 +2441,7 @@ class RingSidecar:
             "ring_rows": {name: c.value for name, c in
                           zip(self.ring_names, self._ring_rows)},
             "batch_rings": self._batch_rings.value,
+            "cascade": self._cascade.snapshot(),
             "completions": dict(self._pipe.completions),
             "ring_telemetry": self.ring_telemetry(),
             "sched": self.sched.snapshot(),

@@ -94,20 +94,75 @@ _IDLE_FLUSH_S = 1.0
 _log = logging.getLogger(__name__)
 
 
+CASCADE_STAGES = ("live", "candidate", "recheck")
+CASCADE_LADDERS = ("candidate", "recheck")
+
+
+class CascadeCounters:
+    """`pingoo_cascade_rows_total{plane, bank, stage}` and
+    `pingoo_cascade_bucket_rows_total{plane, bank, ladder}`
+    (obs/schema.py): what the lanes program counted of its own cascade
+    (engine/verdict.cascade_counts, already on the host with the
+    batch's lanes), folded once a batch where the batch resolves."""
+
+    def __init__(self, plane: str, banks: tuple):
+        from . import REGISTRY as registry, schema
+
+        self.banks = tuple(b.removeprefix("nfa_") for b in banks)
+        self._rows = [
+            [registry.counter(
+                "pingoo_cascade_rows_total",
+                schema.CASCADE_METRICS["pingoo_cascade_rows_total"],
+                labels={"plane": plane, "bank": bank, "stage": stage})
+             for stage in CASCADE_STAGES] for bank in self.banks]
+        self._buckets = [
+            [registry.counter(
+                "pingoo_cascade_bucket_rows_total",
+                schema.CASCADE_METRICS[
+                    "pingoo_cascade_bucket_rows_total"],
+                labels={"plane": plane, "bank": bank, "ladder": ladder})
+             for ladder in CASCADE_LADDERS] for bank in self.banks]
+
+    def fold(self, counts: list, n: int) -> None:
+        """One batch of `n` requests; `counts` is [banks][(candidate,
+        candidate_bucket, recheck, recheck_bucket)] host ints, a
+        candidate of -1 meaning an ungated bank (every live row)."""
+        for rows, buckets, (cand, cand_b, re, re_b) in zip(
+                self._rows, self._buckets, counts):
+            rows[0].inc(n)
+            rows[1].inc(n if cand < 0 else cand)
+            rows[2].inc(re)
+            buckets[0].inc(cand_b)
+            buckets[1].inc(re_b)
+
+    def snapshot(self) -> dict:
+        return {bank: {
+            **{stage: c.value for stage, c in zip(CASCADE_STAGES, rows)},
+            **{f"{ladder}_bucket": c.value
+               for ladder, c in zip(CASCADE_LADDERS, buckets)}}
+            for bank, rows, buckets in zip(self.banks, self._rows,
+                                           self._buckets)}
+
+
 class BatchSpans:
     """One batch's identity in the drain loop, and the phase boundaries
     recorded for it: rides the in-flight tuple from launch to resolve.
     `seq` is the loop's batch counter (the pipeline slot id, and the
     `batch` stat of every span the batch causes); `points` maps a phase
     to its (t_start, t_end) in time.monotonic() seconds; `rings` is how
-    many of the sidecar's rings gave the batch rows."""
+    many of the sidecar's rings gave the batch rows; `stats` is what
+    the spans opened from then on carry besides (`blocked`, `recheck`:
+    set once the batch's lanes are on the host)."""
 
-    __slots__ = ("seq", "rows", "rings", "points", "tags", "compute_ms")
+    __slots__ = ("seq", "rows", "rings", "stats", "cascade", "points",
+                 "tags", "compute_ms")
 
     def __init__(self, seq: int, rows: int, rings: int = 1):
         self.seq = seq
         self.rows = rows
         self.rings = rings
+        self.stats: dict = {}
+        self.cascade = None     # the CascadeCounters of its lanes program
         self.points: dict[str, tuple] = {}
         self.tags: dict = {}
         self.compute_ms = 0.0
@@ -375,7 +430,8 @@ class PipelineStats:
             ann = self._annotate(self._span_names[name])
         else:
             ann = self._annotate(self._span_names[name], batch=rec.seq,
-                                 rows=rec.rows, rings=rec.rings)
+                                 rows=rec.rows, rings=rec.rings,
+                                 **rec.stats)
         ann.__enter__()
         self._cur, self._cur_rec, self._cur_t0, self._cur_ann = \
             name, rec, t, ann

@@ -63,6 +63,24 @@ PREFILTER_METRICS = {
         "any of the bank's necessary literal factors",
 }
 
+# The cascade's second half, row by row (ISSUE 35, docs/PREFILTER.md
+# "What the cascade counts"): counted by the lanes program itself
+# (engine/verdict.cascade_counts: they ride the lanes' one device->host
+# copy) and folded by the sidecar where the batch resolves
+# (obs/pipeline.CascadeCounters), per Stage-A-gated or approximate-DFA
+# bank. plane="sidecar" only: the Python listener plane runs the
+# verdict matrix, not the lanes program.
+CASCADE_METRICS = {
+    "pingoo_cascade_rows_total":
+        "{bank, stage}: rows a bank saw: live (the batch's requests), "
+        "candidate (rows Stage A left it), recheck (rows its "
+        "approximate DFA flagged, re-scanned by the exact bank)",
+    "pingoo_cascade_bucket_rows_total":
+        "{bank, ladder}: rows of the gather-ladder bucket taken, "
+        "summed: candidate (the Stage-A compaction) and recheck (the "
+        "exact re-scan); over the stage's rows it is the padding paid",
+}
+
 # Bitsplit-DFA lowering metrics (ISSUE 8, docs/DFA.md): exported by
 # every plane that runs the batched verdict engine (plane="python"
 # listener service, plane="sidecar" ring drainer). Both are host-static
@@ -389,6 +407,10 @@ NATIVE_METRICS = {
     "pingoo_upstream_tls_fail_total":
         "upstream TLS handshake/verify failures",
     "pingoo_verdicts_total": "verdict bytes applied",
+    # The accept and block-and-reconnect path (ISSUE 35).
+    "pingoo_accepted_total": "connections accepted on the listener",
+    "pingoo_closed_after_block_total":
+        "HTTP/1 connections closed because httpd answered 403",
     "pingoo_connections": "open client connections",
     "pingoo_pooled_upstreams": "idle pooled upstream connections",
     # One counter surface for N workers (ISSUE 31): every native series
@@ -401,6 +423,7 @@ NATIVE_METRICS = {
     "pingoo_worker_verdicts_total": "{worker}: verdict bytes it applied",
     "pingoo_worker_fail_open_total":
         "{worker}: requests it proxied uninspected",
+    "pingoo_worker_accepted_total": "{worker}: connections it accepted",
     "pingoo_worker_ring_depth": "{worker}: its ring's queued slots",
     "pingoo_worker_ring_depth_hwm":
         "{worker}: its ring's high-water mark",
@@ -423,6 +446,8 @@ NATIVE_JSON_KEYS = {
     "blocked": "pingoo_blocked_total",
     "captcha": "pingoo_captcha_total",
     "fail_open": "pingoo_fail_open_total",
+    "accepted": "pingoo_accepted_total",
+    "closed_after_block": "pingoo_closed_after_block_total",
     "verdict_wait_ms_hist": "pingoo_verdict_wait_ms",
 }
 
@@ -430,7 +455,8 @@ NATIVE_JSON_KEYS = {
 def all_metric_names() -> set[str]:
     return (set(SHARED_METRICS) | set(RING_METRICS) | set(NATIVE_METRICS)
             | set(SIDECAR_RING_METRICS)
-            | set(PREFILTER_METRICS) | set(DFA_METRICS)
+            | set(PREFILTER_METRICS) | set(CASCADE_METRICS)
+            | set(DFA_METRICS)
             | set(PROVENANCE_METRICS)
             | set(PARITY_METRICS) | set(SCHED_METRICS)
             | set(PIPELINE_METRICS) | set(RESILIENCE_METRICS)

@@ -1,0 +1,282 @@
+"""The cascade's second half under attack traffic (ISSUE 35): Stage-A
+candidates -> the approximate DFA's flags -> the exact recheck, and the
+list lane at a size that decides verdicts.
+
+  * at candidate shares from none to all, with benign near-misses among
+    the rows, the lanes equal the interpreter's first-match action row
+    for row in both prefilter modes, and the row counts the lanes
+    program reports of its own cascade equal a numpy twin computed from
+    Stage A's hits and the DFA's flags: recheck <= candidate <= live,
+    every bucket the smallest of its ladder that holds its count;
+  * live rows scattered over a batch of two row tiles, the candidates
+    all in the LAST tile: the row bound of the byte loops and the
+    compaction agree with the packed batch, row for row and count for
+    count;
+  * a 65,536-entry IPv4 list in the benchmark generator's shape: the
+    device lookup, the interpreter and the benchmark's plain reference
+    agree on members, members of listed networks, their neighbours and
+    random addresses.
+"""
+
+from __future__ import annotations
+
+import ipaddress
+import os
+import random
+import sys
+
+import numpy as np
+import pytest
+
+from pingoo_tpu.compiler import compile_ruleset
+from pingoo_tpu.engine.batch import (RequestBatch, RequestTuple,
+                                     batch_to_contexts, encode_requests)
+from pingoo_tpu.engine.verdict import (_pf_compact_sizes,
+                                       action_lanes, cascade_banks,
+                                       cascade_counts, host_rule_lanes,
+                                       interpret_rules_row, make_lane_fn,
+                                       make_prefilter_fn, merge_lanes)
+from pingoo_tpu.utils.crs import generate_ruleset, generate_traffic
+
+SIZES = dict(num_rules=60, seed=20260728, list_sizes=(64, 16))
+WIDTH = 256          # one staged width a string field: one program a mode
+# ISSUE 35's near-misses: benign strings that share a literal factor
+# with a url rule (a Stage-A candidate that matches nothing)
+NEAR_MISSES = ("q=union+selection+committee", "page=selected-items-from-cart",
+               "view=onloading-screen", "sort=group-by-having-fun",
+               "u=wget-http-guide", "q=information-schema-design")
+
+
+@pytest.fixture(scope="module")
+def crs():
+    rules, lists = generate_ruleset(**SIZES)
+    plan = compile_ruleset(rules, lists)
+    approx = [k for k, e in plan.scan_plans.items()
+              if e.dfa_key and e.dfa_auto
+              and not plan.np_tables[e.dfa_key].exact]
+    assert "nfa_url" in approx, "the url DFA must be a merged one"
+    assert not plan.host_rules
+    return lists, plan, plan.device_tables()
+
+
+@pytest.fixture(scope="module")
+def programs(crs):
+    """One lanes program a (mode, batch rows) and one Stage A a mode:
+    the shapes are fixed, so each compiles once for the module."""
+    _, plan, _ = crs
+    made: dict = {}
+
+    def get(kind, mode):
+        os.environ["PINGOO_PREFILTER"] = mode   # read at every trace
+        if (kind, mode) not in made:
+            made[kind, mode] = (make_lane_fn(plan) if kind == "lanes"
+                                else make_prefilter_fn(plan).fn)
+        return made[kind, mode]
+
+    yield get
+    os.environ.pop("PINGOO_PREFILTER", None)
+
+
+def _requests(share: float, n: int, lists, seed: int) -> list:
+    reqs = [r for r in generate_traffic(n * 2, lists=lists, seed=seed,
+                                        attack_fraction=share)
+            if len(r.url) < WIDTH and len(r.user_agent) < WIDTH][:n]
+    reqs += [RequestTuple(host="shop.example.com", url=f"/search?{q}",
+                          path="/search", user_agent="Mozilla/5.0",
+                          ip="198.51.100.7") for q in NEAR_MISSES]
+    return reqs
+
+
+def _arrays(reqs: list, rows: int, at=None) -> dict:
+    """The requests staged at fixed widths into a batch of `rows` rows,
+    packed in front or at the row indices `at`; the rest are the inert
+    rows of a padded batch."""
+    packed = encode_requests(reqs).arrays
+    at = np.arange(len(reqs)) if at is None else np.asarray(at)
+    out = {}
+    for name, a in packed.items():
+        if name.endswith("_bytes"):
+            assert not a[:, WIDTH:].any(), name
+            a = a[:, :WIDTH]
+        full = np.zeros((rows,) + a.shape[1:], dtype=a.dtype)
+        full[at] = a
+        out[name] = full
+    return out
+
+
+def _interpreter_actions(plan, lists, arrays) -> np.ndarray:
+    batch = RequestBatch(size=len(arrays["asn"]), arrays=arrays)
+    matrix = np.stack([interpret_rules_row(plan, ctx)
+                       for ctx in batch_to_contexts(batch, lists)])
+    return action_lanes(plan, matrix)
+
+
+def _twin(plan, tables, arrays, pf_hits, key) -> tuple:
+    """(candidate rows, rechecked rows) of an approximate-DFA bank as
+    numpy booleans, from Stage A's hit map and the DFA's own flags."""
+    from pingoo_tpu.ops.bitsplit_dfa import dfa_row_candidates, dfa_scan
+
+    pf = plan.prefilter
+    field = pf.bank_field[key]
+    cand = (np.asarray(pf_hits[field]) & pf.bank_masks[key][None, :]).any(1)
+    dtab = tables[plan.scan_plans[key].dfa_key]
+    data, lens = arrays[f"{field}_bytes"], arrays[f"{field}_len"]
+    flags = np.asarray(dfa_row_candidates(dtab, dfa_scan(dtab, data, lens),
+                                          lens))
+    return cand, flags & cand
+
+
+def _smallest_bucket(ladder: list, count: int) -> int:
+    return min((s for s in ladder if s >= count), default=0) if count else 0
+
+
+def _check_counts(plan, tables, arrays, n_live, mode, lanes, pf_hits):
+    """The lanes' cascade rows against the numpy twin; -> the counts by
+    bank."""
+    banks = cascade_banks(plan)
+    rows = len(arrays["asn"])
+    counts = dict(zip(banks, cascade_counts(lanes, len(banks))))
+    ladder = _pf_compact_sizes(rows)
+    for key, got in counts.items():
+        cand, cand_b, re, re_b = got
+        assert 0 <= re <= cand <= n_live, (key, got)
+        want_b = (_smallest_bucket(ladder, cand) if mode == "compact"
+                  else rows * (cand > 0))
+        assert cand_b == want_b, (key, got, mode)
+        assert re_b == _smallest_bucket(ladder, re), (key, got)
+        if key in plan.scan_plans and plan.scan_plans[key].dfa_key and \
+                not plan.np_tables[plan.scan_plans[key].dfa_key].exact:
+            t_cand, t_re = _twin(plan, tables, arrays, pf_hits, key)
+            assert (cand, re) == (int(t_cand.sum()), int(t_re.sum())), key
+        else:
+            assert re == re_b == 0, (key, got)   # nothing to recheck
+    return counts
+
+
+@pytest.mark.parametrize("mode", ["banks", "compact"])
+@pytest.mark.parametrize("share", [0.0, 0.05, 0.3, 1.0])
+def test_lanes_and_counts_at_every_candidate_share(crs, programs, share,
+                                                   mode):
+    lists, plan, tables = crs
+    reqs = _requests(share, 96, lists, seed=int(share * 100) + 7)
+    arrays = _arrays(reqs, 128)
+    want = _interpreter_actions(plan, lists, arrays)
+    pf_hits, aux = programs("stage_a", mode)(tables, arrays)
+    lanes = np.asarray(programs("lanes", mode)(tables, arrays, pf_hits))
+    batch = RequestBatch(size=128, arrays=arrays)
+    got = merge_lanes(lanes, host_rule_lanes(plan, batch, lists))
+    np.testing.assert_array_equal(got[0], want[0])
+    np.testing.assert_array_equal(got[1], want[1])
+    # the near-misses are passed, though some are url candidates (five
+    # of them on crs500's 500 rules, fewer on these 60)
+    assert not got[0][len(reqs) - len(NEAR_MISSES):len(reqs)].any()
+    counts = _check_counts(plan, tables, arrays, len(reqs), mode, lanes,
+                           pf_hits)
+    assert counts["nfa_url"][0] >= 1
+    if share >= 0.3:
+        assert counts["nfa_url"][2] > 0, "no row was rechecked"
+    # Stage A's own aux vector holds the same candidate counts
+    masked = make_prefilter_fn(plan).masked
+    aux = np.asarray(aux)
+    for i, key in enumerate(masked):
+        if key in counts:
+            assert aux[2 + i] == counts[key][0], key
+
+
+@pytest.mark.parametrize("mode", ["banks", "compact"])
+def test_scattered_rows_and_candidates_in_the_last_tile(crs, programs, mode):
+    """Two 256-row tiles: the benign rows scattered over the first, every
+    candidate in the second. The byte loops walk both tiles, the
+    compaction brings the candidates forward, and nothing differs from
+    the packed batch."""
+    lists, plan, tables = crs
+    reqs = _requests(0.3, 96, lists, seed=23)
+    packed = _arrays(reqs, 128)
+    pf_hits, _ = programs("stage_a", mode)(tables, packed)
+    any_cand = np.zeros(128, bool)
+    for key in ("nfa_url", "nfa_path"):
+        any_cand |= _twin(plan, tables, packed, pf_hits, key)[0]
+    for field, hits in pf_hits.items():      # the window banks' too
+        any_cand |= np.asarray(hits).any(1)
+    any_cand = any_cand[:len(reqs)]
+    assert 8 < any_cand.sum() < len(reqs) - 8
+    rng = np.random.default_rng(5)
+    at = np.empty(len(reqs), np.int64)
+    at[~any_cand] = rng.choice(256, int((~any_cand).sum()), replace=False)
+    at[any_cand] = 256 + rng.choice(256, int(any_cand.sum()), replace=False)
+    arrays = _arrays(reqs, 512, at)
+
+    packed_lanes = np.asarray(
+        programs("lanes", mode)(tables, packed, pf_hits))
+    wide_hits, _ = programs("stage_a", mode)(tables, arrays)
+    lanes = np.asarray(programs("lanes", mode)(tables, arrays, wide_hits))
+    want = _interpreter_actions(plan, lists, arrays)
+    got = merge_lanes(lanes, host_rule_lanes(
+        plan, RequestBatch(size=512, arrays=arrays), lists))
+    np.testing.assert_array_equal(got[0], want[0])
+    np.testing.assert_array_equal(got[1], want[1])
+    # row for row the packed batch's lanes (the action lanes: 0..2)
+    np.testing.assert_array_equal(lanes[:3, at], packed_lanes[:3, :len(reqs)])
+    wide = _check_counts(plan, tables, arrays, len(reqs), mode, lanes,
+                         wide_hits)
+    narrow = _check_counts(plan, tables, packed, len(reqs), mode,
+                           packed_lanes, pf_hits)
+    for key in wide:     # the same rows counted, whatever their places
+        assert (wide[key][0], wide[key][2]) == \
+            (narrow[key][0], narrow[key][2]), key
+    assert wide["nfa_url"][2] > 0
+
+
+# -- the list lane at a size that decides -----------------------------------------
+
+
+def _bench_lib():
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from lib import reference, rules
+    return reference, rules
+
+
+def test_a_65536_entry_list_agrees_three_ways():
+    import jax.numpy as jnp
+
+    from pingoo_tpu.expr import (Context, Ip, compile_expression,
+                                 execute_as_bool)
+    from pingoo_tpu.ops.cidr import build_v4_buckets, v4_buckets_contains
+
+    reference, rules = _bench_lib()
+    items = rules._random_ip_list(random.Random(35), 65536)
+    nets = [ipaddress.ip_network(i) for i in items]
+    singles = [int(n.network_address) for n in nets if n.prefixlen == 32]
+    listed = [n for n in nets if n.prefixlen == 24]
+    assert len(listed) == 65536 // 16
+    rng = random.Random(36)
+    probes = set(rng.sample(singles, 512))                 # members
+    probes |= {a + d for a in rng.sample(singles, 512) for d in (-1, 1)}
+    for n in rng.sample(listed, 256):
+        first = int(n.network_address)
+        probes |= {first, first + rng.randrange(1, 255), first + 255,
+                   first - 1, first + 256}     # inside, and both neighbours
+    probes |= {rng.randrange(1 << 24, 224 << 24) for _ in range(4096)}
+    probes = sorted(probes)
+
+    ref_list = reference.IpList(items)
+    want = np.array([str(ipaddress.ip_address(p)) in ref_list
+                     for p in probes])
+    assert 700 < want.sum() < len(probes) - 4000
+
+    entries = [Ip(i) for i in items]
+    prog = compile_expression('lists["blocked_ips"].contains(client.ip)')
+    interp = np.array([execute_as_bool(prog, Context(variables={
+        "client": {"ip": Ip(str(ipaddress.ip_address(p)))},
+        "lists": {"blocked_ips": entries}})) for p in probes[:600]])
+    np.testing.assert_array_equal(interp, want[:600])
+
+    words = np.zeros((len(probes), 4), np.uint32)
+    words[:, 2], words[:, 3] = 0xFFFF, np.array(probes, np.uint32)
+    got = np.asarray(v4_buckets_contains(build_v4_buckets(entries),
+                                         jnp.asarray(words)))
+    np.testing.assert_array_equal(got, want)
+

@@ -1234,7 +1234,10 @@ class TestNativeRouting:
         try:
             out = self._get_until(stack.port, "/api/v1", b"svc-a")
             assert b"svc-a:/api/v1" in out, out[:200]
-            out = self._get(stack.port, "/index.html")
+            # `_get_until` here too: a request released by the 3 s
+            # verdict deadline (a starved sidecar under `-n 6`) goes to
+            # service 0, which is `api`, and routing is what is tested
+            out = self._get_until(stack.port, "/index.html", b"svc-b")
             assert b"svc-b:/index.html" in out, out[:200]
             # hot swap: the registry repoints api at svc-c; the C++ plane
             # reloads the table on mtime change without restarting
@@ -1245,7 +1248,7 @@ class TestNativeRouting:
             out = self._get_until(stack.port, "/api/v2", b"svc-c")
             assert b"svc-c:/api/v2" in out, out[:200]
             # web unaffected by the swap
-            out = self._get(stack.port, "/w")
+            out = self._get_until(stack.port, "/w", b"svc-b")
             assert b"svc-b:/w" in out, out[:200]
         finally:
             stack.stop()

@@ -432,8 +432,11 @@ def test_one_worker_keeps_its_keys(tmp_path, flags):
         m = _scrape(stack.port)
     finally:
         stack.stop()
-    assert set(m) == KEYS_BEFORE | {"workers", "answered_by", "per_worker"}
+    assert set(m) == KEYS_BEFORE | {"workers", "answered_by", "per_worker",
+                                    "accepted", "closed_after_block"}
     assert (m["workers"], m["answered_by"]) == (1, 0)
+    # two scrapes and the exchange's one connection, which the 403 closed
+    assert (m["accepted"], m["closed_after_block"]) == (3, 1)
     assert (m["requests"], m["verdicts"], m["blocked"], m["fail_open"]) \
         == (2, 2, 1, 0)
     assert [m["ring"][key] - m0["ring"][key]

@@ -114,6 +114,10 @@ HOT_FUNCTIONS = frozenset({
     "pingoo_tpu/obs/pipeline.py::PipelineStats._switch",
     "pingoo_tpu/obs/pipeline.py::PipelineStats._pop",
     "pingoo_tpu/obs/pipeline.py::PipelineStats._flush",
+    # The cascade's row counters (ISSUE 35): folded once a batch where
+    # the batch resolves, from host ints that came with the lanes' own
+    # copy — counter adds, never an array, never a device sync.
+    "pingoo_tpu/obs/pipeline.py::CascadeCounters.fold",
 })
 
 # Functions traced by jax.jit that the AST cannot see are jitted (they

@@ -84,6 +84,16 @@ def main() -> int:
         if name not in sidecar_src:
             problems.append(f"native_ring.py: missing metric {name}")
 
+    # The cascade's row counters (ISSUE 35): the name literals live with
+    # their fold, obs/pipeline.CascadeCounters; the sidecar wires it.
+    pipeline_src = _read("pingoo_tpu/obs/pipeline.py")
+    for name in schema.CASCADE_METRICS:
+        if name not in pipeline_src:
+            problems.append(f"obs/pipeline.py: missing metric {name}")
+    if "CascadeCounters" not in sidecar_src:
+        problems.append("native_ring.py: cascade wiring missing "
+                        "CascadeCounters")
+
     # Bitsplit-DFA dispatch metrics (ISSUE 8): like the prefilter
     # family, both engine planes must export the documented names (the
     # counts themselves are host-static, engine/verdict
