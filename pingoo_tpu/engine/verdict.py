@@ -952,12 +952,10 @@ class PrefilterProgram(NamedTuple):
     masked: tuple[str, ...]
 
 
-def _make_prefilter_body(plan: RulesetPlan):
-    """UNJITTED Stage-A body: (stage_a, gated, masked) or None.
-
-    Shared by make_prefilter_fn (which jits `stage_a` as its own
-    dispatch so the stage is separately timeable) and its compact-
-    staging twin make_packed_prefilter_fn."""
+def _stage_a_banks(plan: RulesetPlan):
+    """Host-static: (prefilter, gated bank keys, the gated keys with a
+    non-empty factor mask) of the plan's Stage A, or None when the plan
+    has no prefilter or the mode is off."""
     pf = getattr(plan, "prefilter", None)
     if pf is None or not pf.fields or _resolve_pf_mode(plan) == "off":
         return None
@@ -967,10 +965,22 @@ def _make_prefilter_body(plan: RulesetPlan):
     for key, entry in plan.scan_plans.items():
         scanned.extend(entry.split if entry.split else (key,))
     scanned.extend(k for k in pf.bank_masks if k.startswith("win_"))
-    gated = [k for k in scanned if _bank_gated(pf, k)]
+    gated = tuple(k for k in scanned if _bank_gated(pf, k))
+    return pf, gated, tuple(k for k in gated if pf.bank_masks[k].any())
+
+
+def _make_prefilter_body(plan: RulesetPlan):
+    """UNJITTED Stage-A body: (stage_a, gated, masked) or None.
+
+    Shared by make_prefilter_fn (which jits `stage_a` as its own
+    dispatch so the stage is separately timeable) and its compact-
+    staging twin make_packed_prefilter_fn."""
+    banks = _stage_a_banks(plan)
+    if banks is None:
+        return None
+    pf, gated, masked = banks
     # Hoisted device constants (analyze-lint recompile-const-upload).
-    masks = {k: jnp.asarray(pf.bank_masks[k]) for k in gated
-             if pf.bank_masks[k].any()}
+    masks = {k: jnp.asarray(pf.bank_masks[k]) for k in masked}
 
     def stage_a(tables, arrays):
         hits = {}
@@ -996,7 +1006,7 @@ def _make_prefilter_body(plan: RulesetPlan):
         return hits, jnp.stack([cand_rows, skipped]
                                + bank_cands + bank_skips)
 
-    return stage_a, tuple(gated), tuple(masks)
+    return stage_a, gated, masked
 
 
 def make_prefilter_fn(plan: RulesetPlan):
@@ -1040,10 +1050,12 @@ LANE_NONE = np.int32(2**30)  # "no rule": sorts after every real index
 def make_lane_fn(plan: RulesetPlan, services: list[str] | None = None,
                  service_groups: list[list[str]] | None = None,
                  with_rule_hits: bool = False, donate: bool = False):
-    """Jitted device ACTION-LANE reduction: (tables, arrays) ->
-    [3 + max(G, 1), B] i32 rows (first_act_idx, first_act_kind,
-    first_block_idx, route lane(s)), indices in ORIGINAL rule-index
-    space.
+    """Jitted device ACTION-LANE reduction: (tables, arrays, pf_hits,
+    n_valid, pf_aux) -> ONE stacked [rows, B] i32 array: first_act_idx,
+    first_act_kind, first_block_idx, the route lane(s), indices in
+    ORIGINAL rule-index space, then what `lane_rows` says rides the same
+    device->host copy (the cascade's counts, the attribution lane,
+    Stage A's aux vector).
 
     This is the transfer-thin form of the verdict for the ring sidecar:
     instead of shipping the [B, R_dev] match matrix off the device
@@ -1062,13 +1074,12 @@ def make_lane_fn(plan: RulesetPlan, services: list[str] | None = None,
     lane by the ring it came from. Services whose route predicate fell
     back to host interpretation are merged by the sidecar afterwards.
 
-    `with_rule_hits` adds the PER-RULE ATTRIBUTION aux lane (ISSUE 5):
-    the [C] int32 per-column hit counts, batch rows folded ON DEVICE
-    with padding rows masked by the traced `n_valid` argument, ride the
-    same dispatch as the lanes — C extra int32s per batch, so
-    provenance costs no extra transfer round trip. The fn then returns
-    (lanes, rule_hits); columns map to original rule indices via
-    plan.device_rule_indices.
+    `with_rule_hits` adds the PER-RULE ATTRIBUTION lane (ISSUE 5): the
+    [C] int32 per-column hit counts, batch rows folded ON DEVICE with
+    padding rows masked by the traced `n_valid` argument, in whole rows
+    of the same stacked array (`rule_hit_counts` reads them), so
+    provenance costs no transfer of its own; columns map to original
+    rule indices via plan.device_rule_indices.
 
     `donate=True` marks the request arrays (arg 1) as donated buffers
     (ISSUE 9; see donate_batch_buffers for the backend gating)."""
@@ -1086,8 +1097,8 @@ def make_packed_lane_fn(plan: RulesetPlan,
                         with_rule_hits: bool = False,
                         donate: bool = False):
     """Compact-staging twin of make_lane_fn (ISSUE 15): the jitted lane
-    reduction takes (tables, packed, layout, pf_hits, n_valid) with
-    `layout` static and decodes the one-copy packed buffer on device
+    reduction takes (tables, packed, layout, pf_hits, n_valid, pf_aux)
+    with `layout` static and decodes the one-copy packed buffer on device
     via unpack_staged. The traced body is the SAME _make_lane_body
     closure make_lane_fn jits, so per-batch lanes are bit-identical
     across staging modes by construction."""
@@ -1097,9 +1108,10 @@ def make_packed_lane_fn(plan: RulesetPlan,
               else ([services] if services else []))
     lanes = _make_lane_body(plan, groups, with_rule_hits)
 
-    def lanes_packed(tables, packed, layout, pf_hits=None, n_valid=None):
+    def lanes_packed(tables, packed, layout, pf_hits=None, n_valid=None,
+                     pf_aux=None):
         return lanes(tables, unpack_staged(packed, layout),
-                     pf_hits=pf_hits, n_valid=n_valid)
+                     pf_hits=pf_hits, n_valid=n_valid, pf_aux=pf_aux)
 
     return jax.jit(lanes_packed, static_argnums=(2,),
                    donate_argnums=(1,) if donate else ())
@@ -1107,14 +1119,14 @@ def make_packed_lane_fn(plan: RulesetPlan,
 
 def _make_lane_body(plan: RulesetPlan, groups: list[list[str]],
                     with_rule_hits: bool):
-    """UNJITTED lane-reduction body: (tables, arrays, pf_hits, n_valid)
-    -> stacked [3 + max(G, 1) + X, B] i32 lanes (+ [C] rule_hits when
-    with_rule_hits). Shared by make_lane_fn and its compact-staging
-    twin make_packed_lane_fn. The last X rows (`cascade_lane_rows`; 0
-    for a plan with no gated or approximate-DFA bank) carry the
-    cascade's row counts, `CASCADE_STATS` for each of
-    `cascade_banks(plan)`, flat from the first of those rows: they ride
-    the lanes' one device->host copy (`cascade_counts` reads them)."""
+    """UNJITTED lane-reduction body: (tables, arrays, pf_hits, n_valid,
+    pf_aux) -> ONE stacked [rows, B] i32 array. Shared by make_lane_fn
+    and its compact-staging twin make_packed_lane_fn. Under the verdict
+    and route lanes come the rows `lane_rows(plan, ...)` names: the
+    cascade's row counts, the attribution lane and Stage A's aux vector
+    (`pf_aux`, the second output of the Stage-A program, handed over as
+    the device array it is; zeros where a caller hands none), so that a
+    batch's results reach the host in one copy."""
     device_rules = [r for r in plan.rules if not r.host]
     orig_idx = np.array([r.index for r in device_rules], dtype=np.int32)
     first_kind = np.array(
@@ -1147,24 +1159,15 @@ def _make_lane_body(plan: RulesetPlan, groups: list[list[str]],
         if dev_route else None
         for dev_route in group_routes]
     banks = cascade_banks(plan)
+    rows = lane_rows(plan, len(groups), with_rule_hits)
 
     @jax.named_scope("act")
-    def lanes(tables, arrays, pf_hits=None, n_valid=None):
+    def lanes(tables, arrays, pf_hits=None, n_valid=None, pf_aux=None):
         cascade: dict = {}
         matched = _matched_cols(plan, tables, arrays, pf_hits,
                                 cascade)  # [B, C]
         B = arrays["asn"].shape[0]
-
-        def cascade_rows():
-            """[X, B]: the banks' counts, zero-padded to whole rows."""
-            if not banks:
-                return []
-            zero = jnp.int32(0)
-            flat = jnp.stack([cascade.get(k, {}).get(stat, zero)
-                              for k in banks for stat in CASCADE_STATS])
-            x = cascade_lane_rows(len(banks), B)
-            return list(jnp.pad(flat, (0, x * B - flat.shape[0]))
-                        .reshape(x, B))
+        zero = jnp.int32(0)
 
         def rule_hits():
             # Attribution fold ON DEVICE: padded batch rows are inert
@@ -1175,14 +1178,20 @@ def _make_lane_body(plan: RulesetPlan, groups: list[list[str]],
                 m = m & (jnp.arange(B) < n_valid)[:, None]
             return m.sum(axis=0, dtype=jnp.int32)
 
-        def pack(stack):
-            return (stack, rule_hits()) if with_rule_hits else stack
+        def stack(lane_list):
+            # ONE stacked array = ONE device->host transfer a batch
+            return rows.stack(lane_list, {
+                "cascade": lambda: jnp.stack(
+                    [cascade.get(k, {}).get(stat, zero)
+                     for k in banks for stat in CASCADE_STATS]),
+                "rule_hits": rule_hits,
+                "stage_a": lambda: (jnp.zeros(rows.stage_a, jnp.int32)
+                                    if pf_aux is None else pf_aux)})
 
         none = jnp.full((B,), LANE_NONE, dtype=jnp.int32)
-        n_route = max(len(groups), 1)
         if matched.shape[1] == 0:
-            return pack(jnp.stack([none, jnp.zeros((B,), jnp.int32), none]
-                                  + [none] * n_route + cascade_rows()))
+            return stack([none, jnp.zeros((B,), jnp.int32), none]
+                         + [none] * rows.n_route)
         act_idx = jnp.where(matched & has_act_row, idx_row, LANE_NONE)
         first_act_idx = jnp.min(act_idx, axis=1)
         arg = jnp.argmin(act_idx, axis=1)
@@ -1202,30 +1211,84 @@ def _make_lane_body(plan: RulesetPlan, groups: list[list[str]],
                 route_lanes.append(none)
         if not route_lanes:
             route_lanes.append(none)
-        # One stacked [3 + G, B] array = ONE device->host transfer
-        # (plus the [C] attribution lane when with_rule_hits).
-        return pack(jnp.stack([first_act_idx, kind, first_block_idx]
-                              + route_lanes + cascade_rows()))
+        return stack([first_act_idx, kind, first_block_idx] + route_lanes)
 
     return lanes
 
 
-def cascade_lane_rows(n_banks: int, B: int) -> int:
-    """Lane rows of B int32 that hold n_banks x CASCADE_STATS counts."""
-    return -(-n_banks * len(CASCADE_STATS) // B)
+LANE_SEGMENTS = ("cascade", "rule_hits", "stage_a")
 
 
-def cascade_counts(dev_lanes: np.ndarray, n_banks: int) -> list:
-    """[n_banks][CASCADE_STATS] host ints off the last rows of the
-    lanes program's stacked output, already on the host (full width:
-    the padding columns included)."""
-    if not n_banks:
-        return []
-    k = len(CASCADE_STATS)
-    x = cascade_lane_rows(n_banks, dev_lanes.shape[1])
-    counts = dev_lanes[-x:].reshape(-1)[:n_banks * k].reshape(n_banks, k)
+class LaneRows(NamedTuple):
+    """The row layout of the lanes program's stacked [rows, B] int32
+    output: 3 verdict lanes | `n_route` route lanes | then each of
+    LANE_SEGMENTS in that order, a flat run of int32 zero-padded to
+    whole rows of B (none for a count of 0). The program stacks by it
+    (`stack`) and the host readers below slice by it (`read`); nothing
+    else knows an offset."""
+
+    n_route: int    # route lanes (one a listener group, at least one)
+    cascade: int    # CASCADE_STATS for each of cascade_banks(plan)
+    rule_hits: int  # a hit count a device column; 0 without attribution
+    stage_a: int    # Stage A's aux vector; 0 for a plan with no prefilter
+
+    def stack(self, lanes: list, segments: dict):
+        """The program's side: the 3 + `n_route` lanes ([B] int32 each),
+        then every segment that has a count, from `segments[name]()`
+        (a flat int32 of that count), zero-padded to whole rows."""
+        B = lanes[0].shape[0]
+        tail = []
+        for name in LANE_SEGMENTS:
+            ints = getattr(self, name)
+            if ints:
+                x = -(-ints // B)
+                tail.extend(jnp.pad(segments[name](), (0, x * B - ints))
+                            .reshape(x, B))
+        return jnp.stack(lanes + tail)
+
+    def read(self, full: np.ndarray, segment: str) -> np.ndarray:
+        """`segment`'s int32 off the stacked output, already on the
+        host at full width (the padding columns included); a view."""
+        first = 3 + self.n_route
+        B = full.shape[1]
+        for name in LANE_SEGMENTS:
+            if name == segment:
+                break
+            first += -(-getattr(self, name) // B)
+        return full[first:].reshape(-1)[:getattr(self, segment)]
+
+
+def lane_rows(plan: RulesetPlan, n_groups: int,
+              with_rule_hits: bool) -> LaneRows:
+    """Host-static: what the lanes program built for `n_groups` listener
+    groups stacks under its lanes. Read off the plan (its cascade banks,
+    device columns and Stage A), under the env the trace will see
+    (PINGOO_PREFILTER / PINGOO_DFA)."""
+    stage_a = _stage_a_banks(plan)
+    return LaneRows(
+        n_route=max(n_groups, 1),
+        cascade=len(cascade_banks(plan)) * len(CASCADE_STATS),
+        rule_hits=len(plan.device_rule_indices) if with_rule_hits else 0,
+        # [candidate rows, banks skipped, *per-bank candidates, *skips]
+        stage_a=0 if stage_a is None else 2 + 2 * len(stage_a[2]))
+
+
+def cascade_counts(full: np.ndarray, rows: LaneRows) -> list:
+    """[banks][CASCADE_STATS] host ints off the lanes' stacked output."""
     # pingoo: allow(sync-tolist): host numpy already, four ints a bank
-    return counts.tolist()
+    return rows.read(full, "cascade").reshape(-1, len(CASCADE_STATS)).tolist()
+
+
+def rule_hit_counts(full: np.ndarray, rows: LaneRows) -> np.ndarray:
+    """The [C] attribution lane off the lanes' stacked output, in device-
+    column order (plan.device_rule_indices maps it to rule indices)."""
+    return rows.read(full, "rule_hits")
+
+
+def stage_a_counts(full: np.ndarray, rows: LaneRows) -> np.ndarray:
+    """Stage A's aux vector (make_prefilter_fn's layout) off the lanes'
+    stacked output."""
+    return rows.read(full, "stage_a")
 
 
 def host_rule_lanes(plan: RulesetPlan, batch, lists):

@@ -684,8 +684,8 @@ class RingSidecar:
         self._ring_group_of = {id(r): gi for r, gi in
                                zip(self.rings, self._ring_group)}
         # Verdict provenance (ISSUE 5): the per-rule attribution fold
-        # rides the lane dispatch as an aux output (with_rule_hits) —
-        # the match matrix itself still never leaves the device.
+        # rides the lanes' own stacked output (with_rule_hits) — the
+        # match matrix itself still never leaves the device.
         from .obs.provenance import provenance_enabled
 
         self._provenance_on = provenance_enabled()
@@ -1042,7 +1042,8 @@ class RingSidecar:
         from .engine.batch import (resolve_stage_caps,
                                    stage_overflow_thresholds)
         from .engine.verdict import (cascade_banks, donate_batch_buffers,
-                                     make_lane_fn, make_packed_lane_fn,
+                                     lane_rows, make_lane_fn,
+                                     make_packed_lane_fn,
                                      make_packed_prefilter_fn,
                                      make_prefilter_fn)
         from .obs.perf import (instrument_jit, plan_fingerprint,
@@ -1065,6 +1066,8 @@ class RingSidecar:
             with_rule_hits=self._provenance_on,
             donate=donate_batch_buffers()), "lanes")
         state["cascade_banks"] = cascade_banks(plan)
+        state["lane_rows"] = lane_rows(plan, len(self._groups),
+                                       self._provenance_on)
         # Compact staging (ISSUE 15): the packed twins decode the
         # one-copy buffer on device; built only under
         # PINGOO_STAGING=compact (the default full arm traces nothing
@@ -1179,6 +1182,7 @@ class RingSidecar:
         from .obs.pipeline import CascadeCounters
 
         self._cascade = CascadeCounters("sidecar", state["cascade_banks"])
+        self._lane_rows = state["lane_rows"]
         self._plan_state = state
         if self._provenance_on:
             from .obs.flightrecorder import (FlightRecorder,
@@ -1468,9 +1472,7 @@ class RingSidecar:
         lanes are already ready; stop at the first that is not, so
         completion stays FIFO and posted tickets stay a prefix
         (`set_posted_floor`). `dev` None is a batch the interpreter
-        serves (device rung demoted): nothing to wait for. `rule_hits`
-        is an output of the same program and `pf_aux` of an earlier
-        one, so `dev` speaks for all three."""
+        serves (device rung demoted): nothing to wait for."""
         while inflight:
             dev = inflight[0][3]
             if dev is not None and not dev.is_ready():
@@ -1618,10 +1620,9 @@ class RingSidecar:
             sp.next("prefilter")
             self.chaos.stage("dispatch")
             pf_hits = pf_aux = None
-            rule_hits = None
             dev = None
             self._dfa_rung_tick()
-            rec.cascade = self._cascade
+            rec.cascade, rec.lane_rows = self._cascade, self._lane_rows
             # Ladder device rung: while demoted, skip the dispatch
             # entirely (the interpreter serves in `_complete`) except for
             # backoff probes; a dispatch-time exception demotes — it no
@@ -1657,32 +1658,27 @@ class RingSidecar:
                                     self._tables, dev_packed,
                                     batch.layout)  # async
                             sp.next("dispatch")
-                            if self._provenance_on:
-                                dev, rule_hits = self._packed_lane_fn(
-                                    self._tables, dev_packed, batch.layout,
-                                    pf_hits, np.int32(n))  # async
-                            else:
-                                dev = self._packed_lane_fn(
-                                    self._tables, dev_packed, batch.layout,
-                                    pf_hits)  # async
+                            dev = self._packed_lane_fn(
+                                self._tables, dev_packed, batch.layout,
+                                pf_hits, np.int32(n), pf_aux)  # async
                         else:
                             if self._pf_fn is not None:
                                 pf_hits, pf_aux = self._pf_fn(
                                     self._tables, arrays)  # async
                             sp.next("dispatch")
-                            if self._provenance_on:
-                                # Attribution aux lane rides the SAME
-                                # dispatch; the traced n masks
-                                # batch-padding rows on device.
-                                dev, rule_hits = self._lane_fn(
-                                    self._tables, arrays, pf_hits,
-                                    np.int32(n))  # async
-                            else:
-                                dev = self._lane_fn(self._tables, arrays,
-                                                    pf_hits)  # async
+                            # The traced n masks batch-padding rows out
+                            # of the attribution lane on device; Stage
+                            # A's aux goes in as the device array it is
+                            # and comes back in the lanes' own rows.
+                            dev = self._lane_fn(
+                                self._tables, arrays, pf_hits,
+                                np.int32(n), pf_aux)  # async
+                        # Queue the batch's ONE device->host copy behind
+                        # the program: `_complete` finds the bytes there.
+                        dev.copy_to_host_async()
                 except Exception as exc:
                     self._note_device_failure(exc)
-                    pf_hits = pf_aux = rule_hits = dev = None
+                    dev = None
             sp.next("dispatch")  # no-op when a branch above got there
         # Staged-bytes accounting (ISSUE 15): the transfer volume
         # behind this dispatch window, on the metrics surface AND into
@@ -1719,8 +1715,7 @@ class RingSidecar:
         # the staging mode lands in every flight row.
         rec.tags["staging_mode"] = ("compact" if batch.packed is not None
                                     else "full")
-        return (parts, slots, raw, dev, rule_hits, pf_aux, n, skip_masks,
-                slot_buf, rec)
+        return (parts, slots, raw, dev, n, skip_masks, slot_buf, rec)
 
     def _failopen_late_rows(self, parts, now_ms: int,
                             est_ms: Optional[float] = None) -> list:
@@ -1779,30 +1774,43 @@ class RingSidecar:
             if len(cc) == 2:
                 slots["country"][i] = cc
 
-    def _complete(self, parts, slots, raw_batch, dev, rule_hits,
-                  pf_aux, n: int, skip_masks, slot_buf, rec) -> None:
+    def _to_host(self, dev) -> np.ndarray:
+        """Materialise a device array on the host, counted: a batch owes
+        `pingoo_sidecar_host_copies_total` exactly one."""
+        self._pipe.host_copies.inc()
+        return np.asarray(dev)
+
+    def _complete(self, parts, slots, raw_batch, dev, n: int, skip_masks,
+                  slot_buf, rec) -> None:
         """Resolve the oldest in-flight batch (`_dispatch`'s tuple):
-        host rules, the device sync, merge, post, provenance."""
+        host rules, the device sync (the batch's one device->host copy:
+        lanes, cascade counts, attribution lane and Stage A's counts in
+        one stacked array), merge, post, provenance."""
         from .engine.verdict import (cascade_counts, host_rule_lanes,
-                                     merge_lanes)
+                                     merge_lanes, rule_hit_counts,
+                                     stage_a_counts)
 
         with self._pipe.stage("host_rules", rec) as sp:
             # Host-interpreted rules run on the UNPADDED batch while the
             # device lanes are still in flight (jax dispatch is async).
             host = host_rule_lanes(self.plan, raw_batch, self.lists)
-            dev_lanes = cascade = None
+            dev_lanes = cascade = rule_hits = pf_aux = None
             sp.next("device_wait")
             if dev is not None:
                 try:
                     with self._hb_busy(sync=(dev,)):  # can block ms-s
-                        full = np.asarray(dev)
+                        full = self._to_host(dev)
                     dev_lanes = full[:, :n]  # drop padding
-                    # The cascade's own row counts ride the same copy
-                    # (the lanes' last rows); the spans from here on
-                    # say what the batch cost: rows the device lanes
-                    # block, rows rechecked.
-                    cascade = cascade_counts(full,
-                                             len(rec.cascade.banks))
+                    # The cascade's own row counts, the attribution
+                    # lane and Stage A's counts ride the same copy
+                    # (`rec.lane_rows`: the rows under the lanes); the
+                    # spans from here on say what the batch cost: rows
+                    # the device lanes block, rows rechecked.
+                    cascade = cascade_counts(full, rec.lane_rows)
+                    if rec.lane_rows.rule_hits:
+                        rule_hits = rule_hit_counts(full, rec.lane_rows)
+                    if rec.lane_rows.stage_a:
+                        pf_aux = stage_a_counts(full, rec.lane_rows)
                     rec.stats = {
                         "blocked": int((dev_lanes[1] == 1).sum()),
                         "recheck": sum(c[2] for c in cascade)}
@@ -1828,14 +1836,12 @@ class RingSidecar:
             if cascade:
                 rec.cascade.fold(cascade, n)
             if pf_aux is not None:
-                # Resolved long before the lane sync above; aux int32 lanes.
-                vals = np.asarray(pf_aux)
                 denom = self.max_batch * self._pf_gated_banks
                 if denom:
-                    self._pf_rate_gauge.set(int(vals[0]) / denom)
-                self._pf_skip_counter.inc(int(vals[1]))
+                    self._pf_rate_gauge.set(int(pf_aux[0]) / denom)
+                self._pf_skip_counter.inc(int(pf_aux[1]))
                 if self._pf_attr is not None:
-                    self._pf_attr.observe(vals, self.max_batch)
+                    self._pf_attr.observe(pf_aux, self.max_batch)
             from .engine.verdict import dfa_dispatch_counts
 
             dfa_mode, dfa_banks, dfa_rechecks = dfa_dispatch_counts(self.plan)
@@ -2051,11 +2057,10 @@ class RingSidecar:
                             raw_batch, unverified, verified_block,
                             device_wait_s, n: int, rec) -> None:
         """Sidecar-plane provenance (ISSUE 5): fold the on-device
-        attribution aux lane, flight-record the batch, and hand the
-        FINAL served lanes (spill rewrites included) to the parity
-        sampler. Registered hot in the analyze-lint registries — the
-        aux lane resolved with the batch's lane sync, so nothing here
-        may wait on the device. Lane-plane attribution covers the
+        attribution lane (`rule_hits`: a host slice of the batch's one
+        copy), flight-record the batch, and hand the FINAL served lanes
+        (spill rewrites included) to the parity sampler. Nothing here
+        touches the device. Lane-plane attribution covers the
         DEVICE-resident rules (the match matrix never leaves the chip);
         host-fallback rules are attributed on the Python plane, where
         the full matrix exists."""
@@ -2148,15 +2153,18 @@ class RingSidecar:
         process-global. The next dispatch pays one re-jit (a bounded
         stall during an already-degraded event)."""
         from .engine.verdict import (cascade_banks, donate_batch_buffers,
-                                     make_lane_fn)
+                                     lane_rows, make_lane_fn)
         from .obs.perf import (instrument_jit, plan_fingerprint,
                                staging_widths)
         from .obs.pipeline import CascadeCounters
 
         self.plan.dfa_default_mode = "off" if dfa_off else self._dfa_mode0
-        # The banks that recheck change with the DFAs: batches in flight
-        # keep the counters of the program that launched them.
+        # The banks that recheck change with the DFAs, and the lanes'
+        # rows with them: batches in flight keep the counters and the
+        # row layout of the program that launched them.
         self._cascade = CascadeCounters("sidecar", cascade_banks(self.plan))
+        self._lane_rows = lane_rows(self.plan, len(self._groups),
+                                    self._provenance_on)
         fp = plan_fingerprint(self.plan)
         widths = staging_widths(self.plan)
         self._lane_fn = instrument_jit(make_lane_fn(
@@ -2443,6 +2451,7 @@ class RingSidecar:
             "batch_rings": self._batch_rings.value,
             "cascade": self._cascade.snapshot(),
             "completions": dict(self._pipe.completions),
+            "host_copies": self._pipe.host_copies.value,
             "ring_telemetry": self.ring_telemetry(),
             "sched": self.sched.snapshot(),
             "mesh": self.mesh.describe(),

@@ -42,6 +42,8 @@ points (which the sampled Timeline reads) and the loop-phase account:
     depth.
   * pingoo_sidecar_completions_total{plane,how}: batches completed, by
     the rule of `RingSidecar.run` that chose the moment (COMPLETIONS).
+  * pingoo_sidecar_host_copies_total{plane}: device arrays the loop
+    materialised on the host (`RingSidecar._to_host`): one a batch.
 
 Interval bookkeeping is host-side float math on the plane's own
 serial context (event loop / drain thread): no locks, no arrays, no
@@ -154,8 +156,8 @@ class BatchSpans:
     the spans opened from then on carry besides (`blocked`, `recheck`:
     set once the batch's lanes are on the host)."""
 
-    __slots__ = ("seq", "rows", "rings", "stats", "cascade", "points",
-                 "tags", "compute_ms")
+    __slots__ = ("seq", "rows", "rings", "stats", "cascade", "lane_rows",
+                 "points", "tags", "compute_ms")
 
     def __init__(self, seq: int, rows: int, rings: int = 1):
         self.seq = seq
@@ -163,6 +165,7 @@ class BatchSpans:
         self.rings = rings
         self.stats: dict = {}
         self.cascade = None     # the CascadeCounters of its lanes program
+        self.lane_rows = None   # and that program's row layout (LaneRows)
         self.points: dict[str, tuple] = {}
         self.tags: dict = {}
         self.compute_ms = 0.0
@@ -354,6 +357,10 @@ class PipelineStats:
                 labels={"plane": self.plane, "how": how})
             for how in COMPLETIONS}
         self.completions = dict.fromkeys(COMPLETIONS, 0)  # this loop's own
+        self.host_copies = self._registry.counter(
+            "pingoo_sidecar_host_copies_total",
+            schema.PIPELINE_METRICS["pingoo_sidecar_host_copies_total"],
+            labels={"plane": self.plane})
         self._stack: list = []      # enclosing with-blocks: (name, rec)
         self._base = "poll"         # what the loop falls back to
         self._cur = None            # the open span: name, rec, t0, ann

@@ -8,13 +8,13 @@ plane, plane="sidecar"):
   * `RuleAttribution` — cardinality-bounded per-rule hit counters. The
     fold input is either the host-side match matrix sum (the Python
     plane already ships the [B, R] matrix back for finish_batch, so the
-    fold is one vector add) or the on-device [R_dev] hit-count aux lane
-    that rides the sidecar's existing lane dispatch (engine/verdict.py
-    make_lane_fn(with_rule_hits=True) — no extra transfer beyond R_dev
-    int32s). Exposition is bounded: the top-K rules by cumulative hits
-    get labelled `pingoo_rule_hits_total{rule=...}` series, everything
-    else folds into one `rule="_overflow"` series, so a 500-rule plan
-    costs K+1 series, not 500.
+    fold is one vector add) or the on-device [R_dev] hit-count lane
+    that rides the sidecar's lanes in their one stacked output
+    (engine/verdict.py make_lane_fn(with_rule_hits=True) — no transfer
+    of its own). Exposition is bounded: the top-K rules by cumulative
+    hits get labelled `pingoo_rule_hits_total{rule=...}` series,
+    everything else folds into one `rule="_overflow"` series, so a
+    500-rule plan costs K+1 series, not 500.
 
   * `PrefilterAttribution` — per-gated-bank candidate rates and skip
     counters from the Stage-A aux vector (engine/verdict.py
@@ -115,17 +115,15 @@ class RuleAttribution:
 
     def fold_batch(self, hit_counts, indices=None) -> None:
         """Fold one batch's per-rule hit counts (hot path: one vector
-        add). `hit_counts` is [R] int (original-index order) or — on the
-        lane plane — the device aux lane in device-column order with
-        `indices` mapping columns to original rule indices; the
-        materialization below lands AFTER the batch's lane sync, so it
-        never blocks on the device."""
-        # pingoo: allow(sync-asarray-hot): aux lane resolved with the batch's lane sync
-        vals = np.asarray(hit_counts, dtype=np.int64)
+        add). `hit_counts` is a HOST int array: [R] in original-index
+        order or, on the lane plane, the attribution lane in device-
+        column order (engine/verdict.rule_hit_counts: a slice of the
+        batch's one device->host copy) with `indices` mapping columns
+        to original rule indices."""
         if indices is not None:
-            np.add.at(self._counts, indices, vals)
+            np.add.at(self._counts, indices, hit_counts)
         else:
-            self._counts += vals
+            self._counts += hit_counts
 
     @property
     def total_hits(self) -> int:

@@ -175,6 +175,11 @@ PIPELINE_METRICS = {
         "PINGOO_PIPELINE_DEPTH batches in flight, the loop blocked on "
         "the oldest; drain: a pass that launched nothing, the flush, a "
         "swap boundary)",
+    "pingoo_sidecar_host_copies_total":
+        "device arrays the drain loop materialised on the host to "
+        "complete its batches: one a batch (lanes, cascade counts, "
+        "attribution lane and Stage-A counts in one stacked array), "
+        "none for a batch the interpreter served",
 }
 
 # Continuous-batching scheduler + serving-mesh metrics (ISSUE 6,

@@ -12,6 +12,13 @@ list lane at a size that decides verdicts.
     all in the LAST tile: the row bound of the byte loops and the
     compaction agree with the packed batch, row for row and count for
     count;
+  * the lanes program's ONE stacked output (ISSUE 37): the attribution
+    lane and Stage A's counts decoded from the rows `lane_rows` names
+    equal the per-rule hit counts and the aux vector computed apart, for
+    plans with and without a prefilter, cascade banks and device rules,
+    with counts that take several rows and with padding rows in the
+    batch; the verdict, route and cascade rows are those of the program
+    built without them;
   * a 65,536-entry IPv4 list in the benchmark generator's shape: the
     device lookup, the interpreter and the benchmark's plain reference
     agree on members, members of listed networks, their neighbours and
@@ -31,11 +38,13 @@ import pytest
 from pingoo_tpu.compiler import compile_ruleset
 from pingoo_tpu.engine.batch import (RequestBatch, RequestTuple,
                                      batch_to_contexts, encode_requests)
-from pingoo_tpu.engine.verdict import (_pf_compact_sizes,
+from pingoo_tpu.engine.verdict import (CASCADE_STATS, _pf_compact_sizes,
                                        action_lanes, cascade_banks,
                                        cascade_counts, host_rule_lanes,
-                                       interpret_rules_row, make_lane_fn,
-                                       make_prefilter_fn, merge_lanes)
+                                       interpret_rules_row, lane_rows,
+                                       make_lane_fn, make_prefilter_fn,
+                                       merge_lanes, rule_hit_counts,
+                                       stage_a_counts)
 from pingoo_tpu.utils.crs import generate_ruleset, generate_traffic
 
 SIZES = dict(num_rules=60, seed=20260728, list_sizes=(64, 16))
@@ -132,10 +141,12 @@ def _smallest_bucket(ladder: list, count: int) -> int:
 
 def _check_counts(plan, tables, arrays, n_live, mode, lanes, pf_hits):
     """The lanes' cascade rows against the numpy twin; -> the counts by
-    bank."""
+    bank. `lanes` is a program's stacked output with no route group
+    (the cascade rows come before whatever else it stacks)."""
     banks = cascade_banks(plan)
     rows = len(arrays["asn"])
-    counts = dict(zip(banks, cascade_counts(lanes, len(banks))))
+    counts = dict(zip(banks, cascade_counts(lanes,
+                                            lane_rows(plan, 0, False))))
     ladder = _pf_compact_sizes(rows)
     for key, got in counts.items():
         cand, cand_b, re, re_b = got
@@ -225,6 +236,153 @@ def test_scattered_rows_and_candidates_in_the_last_tile(crs, programs, mode):
         assert (wide[key][0], wide[key][2]) == \
             (narrow[key][0], narrow[key][2]), key
     assert wide["nfa_url"][2] > 0
+
+
+# -- one stacked array out of the lanes program ------------------------------
+
+
+def _prefix_plan():
+    """Prefix, equality and list rules only: no literal factor, so no
+    Stage A and no cascade bank; two services, so route lanes."""
+    from pingoo_tpu.config.schema import Action, RuleConfig
+    from pingoo_tpu.expr import compile_expression
+
+    rules = [RuleConfig(name=f"r{i}", actions=(Action.BLOCK,),
+                        expression=compile_expression(src))
+             for i, src in enumerate((
+                 'http_request.path.starts_with("/.env")',
+                 'http_request.path.starts_with("/search")',
+                 'http_request.host == "shop.example.com"',
+                 'http_request.method == "TRACE"'))]
+    routes = [("search", compile_expression(
+        'http_request.path.starts_with("/search")')), ("web", None)]
+    return compile_ruleset(rules, {}, routes=routes), \
+        [["search", "web"], ["web"]]
+
+
+# (plan, PINGOO_PREFILTER, PINGOO_DFA, batch rows): what each case's
+# lanes program must stack under its lanes
+STACKED = {
+    # every segment in one row each; the batch a fifth padding
+    "crs": ("crs", "banks", None, 128),
+    # 16-row batch: the cascade's 20 counts take 2 rows, the 60 device
+    # columns 4 (C > B), Stage A's 12 counts one
+    "crs-several-rows": ("crs", "banks", None, 16),
+    # no Stage A: no Stage-A row and no gated bank, but the approximate
+    # DFAs still recheck, so the cascade rows stay
+    "crs-prefilter-off": ("crs", "off", None, 16),
+    # no DFA: the gated banks alone are counted, nothing rechecks
+    "crs-dfa-off": ("crs", "banks", "off", 16),
+    "no-prefilter-no-cascade": ("prefix", None, None, 16),
+    "no-device-rule": ("empty", None, None, 16),          # C = 0
+}
+
+
+@pytest.mark.parametrize("case", sorted(STACKED))
+def test_the_stacked_output_holds_what_three_copies_held(crs, case,
+                                                         monkeypatch):
+    which, pf_mode, dfa_mode, B = STACKED[case]
+    for name, value in (("PINGOO_PREFILTER", pf_mode),
+                        ("PINGOO_DFA", dfa_mode)):
+        if value is None:
+            monkeypatch.delenv(name, raising=False)
+        else:
+            monkeypatch.setenv(name, value)
+    lists, plan, tables = crs
+    groups = None
+    if which == "prefix":
+        (plan, groups), lists = _prefix_plan(), {}
+        tables = plan.device_tables()
+    elif which == "empty":
+        plan, lists = compile_ruleset([], {}), {}
+        tables = plan.device_tables()
+    # attack rows and near-misses in front, the rest of the batch padding
+    reqs = _requests(1.0, 96 if B == 128 else 8, lists if which == "crs"
+                     else crs[0], seed=11)
+    n = len(reqs)
+    assert n < B
+    arrays = _arrays(reqs, B)
+    # always-match columns would count padding rows too: give the
+    # padding a path a prefix rule matches, where the plan has one
+    if which == "prefix":
+        arrays["path_bytes"][n:, :5] = np.frombuffer(b"/.env", np.uint8)
+        arrays["path_len"][n:] = 5
+
+    pf = make_prefilter_fn(plan)
+    pf_hits, aux = pf.fn(tables, arrays) if pf is not None else (None, None)
+    full = np.asarray(make_lane_fn(
+        plan, service_groups=groups, with_rule_hits=True)(
+            tables, arrays, pf_hits, np.int32(n), aux))
+    bare = np.asarray(make_lane_fn(plan, service_groups=groups)(
+        tables, arrays, pf_hits))
+    rows, bare_rows = (lane_rows(plan, len(groups or ()), hits)
+                       for hits in (True, False))
+
+    # the layout is read off the plan
+    dev_cols = plan.device_rule_indices
+    banks = cascade_banks(plan)
+    assert rows == bare_rows._replace(rule_hits=len(dev_cols))
+    assert rows.n_route == max(len(groups or ()), 1)
+    assert rows.cascade == len(banks) * len(CASCADE_STATS)
+    assert rows.stage_a == (0 if pf is None else 2 + 2 * len(pf.masked))
+    assert (pf is None) == (pf_mode in (None, "off"))
+    assert bool(banks) == (which == "crs")
+    lanes_end = 3 + rows.n_route
+    want_rows = lanes_end + sum(-(-ints // B) for ints in rows[1:])
+    assert full.shape == (want_rows, B) and full.dtype == np.int32
+    assert bare.shape == (want_rows - -(-rows.rule_hits // B), B)
+    if case == "crs-several-rows":
+        assert [-(-ints // B) for ints in rows[1:]] == [2, 4, 1]
+
+    # the attribution lane: the interpreter's per-rule hits over the
+    # LIVE rows (the padding counts nothing), device columns only
+    batch = RequestBatch(size=B, arrays=arrays)
+    matrix = np.stack([interpret_rules_row(plan, ctx)
+                       for ctx in batch_to_contexts(batch, lists)])
+    hits = rule_hit_counts(full, rows)
+    assert hits.shape == (len(dev_cols),)
+    np.testing.assert_array_equal(hits, matrix[:n, dev_cols].sum(axis=0))
+    if which == "prefix":
+        assert matrix[n:, 0].all() and hits[0] < matrix[:, 0].sum()
+    if which == "crs":
+        assert hits.sum() > 0
+    # Stage A's counts: the Stage-A program's own second output
+    if pf is not None:
+        np.testing.assert_array_equal(stage_a_counts(full, rows),
+                                      np.asarray(aux))
+        assert stage_a_counts(full, rows)[0] > 0      # candidates
+        # not handed in (the bare program): the row is there, zero
+        assert not stage_a_counts(bare, bare_rows).any()
+    else:
+        assert stage_a_counts(full, rows).shape == (0,)
+    # the cascade's counts, from their new place
+    counts = cascade_counts(full, rows)
+    assert len(counts) == len(banks)
+    assert counts == cascade_counts(bare, bare_rows)
+    if (which, pf_mode, dfa_mode) == ("crs", "banks", None):
+        _check_counts(plan, tables, arrays, n, pf_mode, full, pf_hits)
+    if pf_mode == "off":                 # ungated: every live row
+        assert all(c[0] == -1 for c in counts)
+    if dfa_mode == "off":                # nothing approximate to recheck
+        assert all(c[2] == c[3] == 0 for c in counts)
+    # verdict, route and cascade rows: bit for bit the program's that
+    # stacks nothing else, and the interpreter's actions
+    top = lanes_end + -(-rows.cascade // B)
+    np.testing.assert_array_equal(full[:top], bare[:top])
+    got = merge_lanes(full[:, :n], host_rule_lanes(
+        plan, RequestBatch(size=n, arrays={k: v[:n] for k, v in
+                                           arrays.items()}), lists))
+    want = (action_lanes(plan, matrix) if plan.rules   # no rule: no action
+            else np.zeros((2, B), np.int32))
+    np.testing.assert_array_equal(got[0], want[0][:n])
+    np.testing.assert_array_equal(got[1], want[1][:n])
+    # what pads a segment to whole rows is zero
+    flat = full[lanes_end:].reshape(-1)
+    at = 0
+    for ints in rows[1:]:
+        whole = -(-ints // B) * B
+        assert not flat[at + ints:at + whole].any()
+        at += whole
 
 
 # -- the list lane at a size that decides -----------------------------------------

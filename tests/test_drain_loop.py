@@ -13,6 +13,9 @@ What that loop owes its callers, whatever `pipeline_depth` is:
     launch for launch and completion for completion as before;
   * rows held under the launch threshold are posted by the flush;
   * the posted floor never passes an unposted ticket;
+  * a batch's results reach the host in ONE device->host copy, whatever
+    the lanes program stacks for the plan (ISSUE 37), and the counters
+    fed from it read what separate copies would have;
   * a hot swap requested with batches in flight flips between batches;
   * the legacy encode chain under the ladder's `pipeline` rung serves
     the same verdicts;
@@ -45,7 +48,8 @@ import pytest
 
 from pingoo_tpu import native_ring
 from pingoo_tpu.engine.batch import RequestTuple, tuple_to_context
-from pingoo_tpu.engine.verdict import action_lanes, interpret_rules_row
+from pingoo_tpu.engine.verdict import (action_lanes, interpret_rules_row,
+                                       make_prefilter_fn)
 from pingoo_tpu.sched.scheduler import CostModel
 
 needs_native = pytest.mark.skipif(not native_ring.ensure_built(),
@@ -160,7 +164,7 @@ def _stand_in_lanes(sidecar, lanes: str, events=None):
         seq = entry[-1].seq
         events.append(("launch", seq))
         if lanes == "none":
-            entry = entry[:3] + (None, None) + entry[5:]
+            entry = entry[:3] + (None,) + entry[4:]
         elif lanes != "real":
             entry = entry[:3] + (_Lanes(entry[3], LANES[lanes](seq)),) \
                 + entry[4:]
@@ -389,6 +393,162 @@ def test_posted_floor_never_passes_an_unposted_ticket(drive, n_rings, lanes):
     for got, sent in zip(d.got, d.sent):
         assert sorted(got) == sorted(sent)
         assert all(len(v) == 1 for v in got.values())
+
+
+# what the lanes program stacks under its lanes, by the environment the
+# sidecar is built in; "none" is the device rung demoted (no device lanes)
+COPIES = {
+    "provenance-and-prefilter": ({}, "ready"),
+    "provenance-off": ({"PINGOO_PROVENANCE": "0"}, "ready"),
+    "prefilter-off": ({"PINGOO_PREFILTER": "off"}, "ready"),
+    "both-off": ({"PINGOO_PROVENANCE": "0", "PINGOO_PREFILTER": "off"},
+                 "ready"),
+    "device-demoted": ({}, "none"),
+}
+
+
+@needs_native
+@pytest.mark.parametrize("case", sorted(COPIES))
+def test_a_batch_comes_to_the_host_in_one_copy(tmp_path, monkeypatch, case):
+    """`_complete` materialises exactly one device array a batch: the
+    stand-in's `__array__` is asked once, numpy is handed no other
+    device array while a batch completes, and
+    `pingoo_sidecar_host_copies_total` says the same. The attribution
+    table, Stage A's gauges and counters and the cascade's counters read
+    what Stage A's own output and the interpreter give for the same
+    batches, computed apart."""
+    import jax
+
+    from pingoo_tpu.native_ring import Ring, RingSidecar
+
+    env, lanes = COPIES[case]
+    for k in KNOBS + ("PINGOO_PROVENANCE", "PINGOO_PREFILTER"):
+        monkeypatch.delenv(k, raising=False)
+    for k, v in env.items():
+        monkeypatch.setenv(k, v)
+    plan = _plan()
+    ring = Ring(str(tmp_path / "ring"), capacity=256, create=True)
+    reqs = _requests(BURST)
+    sent = {_enqueue(ring, tup): tup for tup in reqs}
+    sidecar = RingSidecar(ring, plan, {}, max_batch=MAX_BATCH,
+                          pipeline_depth=2)
+    batches = -(-BURST // MAX_BATCH)
+    device_batches = 0 if lanes == "none" else batches
+
+    asked: list = []        # the stand-in's __array__, by batch
+    handed: list = []       # device arrays numpy was handed in _complete
+    completing: list = []
+    stage_a: list = []      # Stage A's own second output, a batch each
+
+    class Counted(_Lanes):
+        def __array__(self, dtype=None, copy=None):
+            asked.append(completing[-1])
+            return super().__array__(dtype, copy)
+
+    dispatch, complete = sidecar._dispatch, sidecar._complete
+
+    def staged_dispatch(*args, **kwargs):
+        entry = dispatch(*args, **kwargs)
+        # nothing of the device rides the tuple but the lanes
+        assert not any(isinstance(x, jax.Array) for x in entry[4:])
+        dev = None if lanes == "none" else Counted(entry[3], True)
+        return entry[:3] + (dev,) + entry[4:]
+
+    def watched_complete(*entry):
+        completing.append(entry[-1].seq)
+        try:
+            return complete(*entry)
+        finally:
+            completing.pop()
+
+    sidecar._dispatch, sidecar._complete = staged_dispatch, watched_complete
+    for name in ("_pf_fn", "_packed_pf_fn"):
+        fn = getattr(sidecar, name)
+        if fn is not None:
+            def recording(*args, _fn=fn):
+                hits, aux = _fn(*args)
+                stage_a.append(aux)
+                return hits, aux
+            setattr(sidecar, name, recording)
+    asarray = np.asarray
+
+    def watched_asarray(a, *args, **kwargs):
+        if completing and isinstance(a, jax.Array):
+            handed.append(completing[-1])
+        return asarray(a, *args, **kwargs)
+
+    monkeypatch.setattr(np, "asarray", watched_asarray)
+    copies0 = sidecar._pipe.host_copies.value
+    skipped0 = sidecar._pf_skip_counter.value
+    cascade0 = sidecar._cascade.snapshot()
+    pf_attr = sidecar._pf_attr
+    bank_skips0 = [c.value for c in pf_attr._skip_counters] if pf_attr \
+        else []
+    try:
+        _run_to_end(sidecar, BURST)
+        got = _verdicts(ring)
+        stats = sidecar.stats()
+    finally:
+        sidecar.stop()
+        ring.close()
+    monkeypatch.setattr(np, "asarray", asarray)
+
+    # every row served right, whichever way the batch came home
+    assert {t: [v & 3 for v in vs] for t, vs in got.items()} == \
+        {t: [_want(plan, tup)] for t, tup in sent.items()}
+    assert sidecar.batches == batches
+    # ONE copy a batch
+    want_seqs = list(range(1, device_batches + 1))
+    assert asked == want_seqs
+    assert handed == want_seqs          # the stand-in's own, nothing else
+    assert sidecar._pipe.host_copies.value - copies0 == device_batches
+    assert stats["host_copies"] == sidecar._pipe.host_copies.value
+
+    # the attribution table: the interpreter's hits a rule
+    rows = sidecar._lane_rows
+    provenance = "PINGOO_PROVENANCE" not in env
+    assert (rows.rule_hits > 0) == provenance
+    if provenance:
+        want_hits = np.stack([interpret_rules_row(
+            plan, tuple_to_context(tup, {})) for tup in reqs]).sum(axis=0)
+        if lanes == "none":
+            want_hits[:] = 0    # the attribution lane never ran
+        np.testing.assert_array_equal(sidecar._attribution._counts,
+                                      want_hits)
+    else:
+        assert sidecar._attribution is None
+    # Stage A's gauges and counters: its own output, copied apart
+    prefilter = "PINGOO_PREFILTER" not in env
+    assert (rows.stage_a > 0) == prefilter
+    assert len(stage_a) == (batches if prefilter else 0)
+    # (a batch the interpreter serves observes none of it)
+    aux = np.stack([asarray(a) for a in stage_a]) \
+        if stage_a and lanes != "none" else None
+    if aux is not None:
+        m = len(pf_attr.masked_keys) if pf_attr else 0
+        assert aux.shape == (batches, rows.stage_a)
+        assert sidecar._pf_skip_counter.value - skipped0 == aux[:, 1].sum()
+        assert sidecar._pf_rate_gauge.value == pytest.approx(
+            aux[-1, 0] / (MAX_BATCH * sidecar._pf_gated_banks))
+        if provenance:
+            assert m and [c.value - c0 for c, c0 in zip(
+                pf_attr._skip_counters, bank_skips0)] == \
+                list(aux[:, 2 + m:].sum(axis=0))
+            assert [g.value for g in pf_attr._rate_gauges] == \
+                [round(int(c) / MAX_BATCH, 4) for c in aux[-1, 2:2 + m]]
+        # the cascade's own counts of the same candidates
+        cascade = stats["cascade"]
+        masked = make_prefilter_fn(plan).masked
+        assert sorted(cascade) == sorted(
+            k.removeprefix("nfa_") for k in masked)
+        for i, key in enumerate(masked):
+            bank = key.removeprefix("nfa_")
+            assert cascade[bank]["live"] - cascade0[bank]["live"] == BURST
+            assert cascade[bank]["candidate"] \
+                - cascade0[bank]["candidate"] == aux[:, 2 + i].sum()
+    else:
+        assert sidecar._pf_skip_counter.value == skipped0
+        assert stats["cascade"] == cascade0
 
 
 @needs_native

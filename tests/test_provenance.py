@@ -31,8 +31,9 @@ from pingoo_tpu.engine import RequestTuple, encode_requests, evaluate_batch, \
 from pingoo_tpu.engine.batch import (RequestBatch, bucket_arrays, pad_batch,
                                      tuple_to_context)
 from pingoo_tpu.engine.service import VerdictService
-from pingoo_tpu.engine.verdict import (interpret_rules_row, make_lane_fn,
-                                       make_prefilter_fn)
+from pingoo_tpu.engine.verdict import (interpret_rules_row, lane_rows,
+                                       make_lane_fn, make_prefilter_fn,
+                                       rule_hit_counts)
 from pingoo_tpu.expr import compile_expression
 from pingoo_tpu.obs import schema
 from pingoo_tpu.obs.flightrecorder import (FlightRecorder, dump_all,
@@ -168,9 +169,9 @@ class TestAttributionParityProperty:
                           arrays=bucket_arrays(batch.arrays))
         padded = pad_batch(b2, 128)
         tables = plan.device_tables()
-        lanes, hits = make_lane_fn(plan, with_rule_hits=True)(
-            tables, padded.arrays, None, np.int32(n))
-        hits = np.asarray(hits)
+        full = np.asarray(make_lane_fn(plan, with_rule_hits=True)(
+            tables, padded.arrays, None, np.int32(n)))
+        hits = rule_hit_counts(full, lane_rows(plan, 0, True))
         matched = evaluate_batch(plan, make_verdict_fn(plan), tables,
                                  b2, lists)
         dev_cols = plan.device_rule_indices
@@ -525,17 +526,17 @@ class TestLintMutations:
             return f.read()
 
     def test_bare_sync_in_attribution_fold_fails_lint(self):
-        """ISSUE 5 satellite: strip the fold's sanctioned suppression
-        and the hot-path lint must fail on the bare host sync."""
+        """The fold takes a HOST array (ISSUE 37: the attribution lane
+        comes in the batch's one copy), so it carries no sanctioned
+        sync any more; putting a materialization back fails the lint."""
         from tools.analyze import lint
 
         src = self._source()
-        marker = ("# pingoo: allow(sync-asarray-hot): aux lane "
-                  "resolved with the batch's lane sync\n")
-        assert marker.replace("\n", "") in src.replace("\n", "")
-        mutated = "\n".join(
-            ln for ln in src.splitlines()
-            if "allow(sync-asarray-hot)" not in ln)
+        assert "allow(sync-asarray-hot)" not in src
+        marker = "        if indices is not None:\n            np.add.at("
+        assert src.count(marker) == 1
+        mutated = src.replace(
+            marker, "        hit_counts = np.asarray(hit_counts)\n" + marker)
         findings, _ = lint.lint_source(mutated,
                                        "pingoo_tpu/obs/provenance.py")
         assert any(f.rule == "sync-asarray-hot"

@@ -81,9 +81,12 @@ def main(argv=None) -> int:
                            donate=donate_batch_buffers())
 
     def lanes(arrays: dict, n: int):
-        pf_hits = pf.fn(tables, arrays)[0] if pf is not None else None
-        dev, _hits = lane_fn(tables, arrays, pf_hits, np.int32(n))
-        return np.asarray(jax.block_until_ready(dev))
+        pf_hits, pf_aux = (pf.fn(tables, arrays) if pf is not None
+                           else (None, None))
+        dev = lane_fn(tables, arrays, pf_hits, np.int32(n), pf_aux)
+        # the verdict and route lanes; the rows under them are counts
+        # over the whole batch, not a row's verdict
+        return np.asarray(jax.block_until_ready(dev))[:3 + 1]
 
     reqs = generate_traffic(MAX_BATCH, attack_fraction=0.05,
                             seed=args.seed + 2, lists=lists)
