@@ -47,6 +47,12 @@ def main(argv: list[str] | None = None) -> int:
     parser.add_argument("--native-workers", type=int, default=1,
                         help="SO_REUSEPORT httpd workers per listener "
                              "(one verdict ring each)")
+    parser.add_argument("--replicas", type=int, default=1,
+                        help="chips the native plane's drain loop launches "
+                             "whole batches on, each holding a copy of "
+                             "the rules and lists (the first N local "
+                             "devices; not with a PINGOO_MESH over "
+                             "several)")
     parser.add_argument("--require", action="append", default=[],
                         choices=CAPABILITIES, metavar="CAPABILITY",
                         help="refuse to start unless this build has the "
@@ -85,6 +91,17 @@ def main(argv: list[str] | None = None) -> int:
     except RuntimeError as exc:
         log.error(f"jax backend initialisation failed: {exc}")
         return 1
+    if args.replicas != 1:
+        from .native_ring import replica_devices
+
+        try:
+            if not args.native_plane or args.no_device:
+                raise ValueError(f"--replicas {args.replicas} needs "
+                                 "--native-plane and the device")
+            replica_devices(args.replicas)
+        except ValueError as exc:
+            log.error(str(exc))
+            return 2
 
     child = None
     if config.child_process is not None:
@@ -100,6 +117,7 @@ def main(argv: list[str] | None = None) -> int:
         "rules": len(config.rules),
         "device": not args.no_device,
         "native_plane": args.native_plane,
+        "replicas": args.replicas,
         "capabilities": list(CAPABILITIES),
         **backend,
         "compile_cache": compile_cache,
@@ -111,6 +129,7 @@ def main(argv: list[str] | None = None) -> int:
             asyncio.run(run_native(
                 config, state_dir=args.state_dir,
                 workers=args.native_workers,
+                replicas=args.replicas,
                 upstream_ca=args.upstream_ca,
                 use_device=not args.no_device,
                 enable_docker=not args.no_docker,

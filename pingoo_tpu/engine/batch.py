@@ -394,7 +394,9 @@ class StagingEncoder:
     Double-buffered: `nbuf` rotating buffer sets, so batch N+1's host
     fill cannot overwrite buffers a still-in-flight batch N hands to
     the device or reads at resolve time. Planes size `nbuf` to their
-    executor depth + 1.
+    executor depth + 1; `checkouts` counts the sets handed out, so a
+    plane that may complete out of order can tell which batch holds
+    the set the next encode takes (the one `nbuf` checkouts back).
 
     Two fill paths:
       * `encode_requests` — RequestTuple list (Python listener plane);
@@ -416,6 +418,7 @@ class StagingEncoder:
         self.specs = specs
         self.nbuf = max(1, int(nbuf))
         self._cursor = 0
+        self.checkouts = 0
         self._bufs: list[dict] = []
         for _ in range(self.nbuf):
             bufs: dict = {}
@@ -470,6 +473,7 @@ class StagingEncoder:
     def _checkout(self) -> dict:
         buf = self._bufs[self._cursor]
         self._cursor = (self._cursor + 1) % self.nbuf
+        self.checkouts += 1
         return buf
 
     def encode_requests(

@@ -6,7 +6,10 @@ is compiled AHEAD of the switch (through the artifact cache, off the
 serving path) and each engine plane flips to it atomically at a batch
 boundary: in-flight batches finish on the old plan, new admissions use
 the new one, and every verdict is attributable to exactly one epoch
-(`pingoo_ruleset_epoch`). The swap pause — drain-of-inflight + pointer
+(`pingoo_ruleset_epoch`). A sidecar that serves from several chips
+(`--replicas`) builds the new plan's tables on every one of them with
+the rest of its state, off the loop, so that no chip is left on the
+old plan after the flip. The swap pause — drain-of-inflight + pointer
 flip, compile excluded by construction — is the number the
 PINGOO_DEADLINE_MS budget must absorb (tracked as swap_pause_p99_ms in
 bench_regress).

@@ -87,13 +87,14 @@ class NativePlane:
 
     def __init__(self, config: Config, state_dir: str,
                  use_device: bool = True, workers: int = 1,
-                 httpd_bin: Optional[str] = None,
+                 replicas: int = 1, httpd_bin: Optional[str] = None,
                  upstream_ca: Optional[str] = None, **server_kwargs):
         from .. import native_ring
 
         self.config = config
         self.state_dir = state_dir
         self.workers = max(1, workers)
+        self.replicas = replicas  # chips the sidecar launches batches on
         # Trust anchor for TLS upstream hops: system roots by default,
         # an explicit bundle for private-CA deployments (and tests).
         self.upstream_ca = upstream_ca or os.environ.get(
@@ -188,7 +189,10 @@ class NativePlane:
         self.sidecar = RingSidecar(
             self.rings, self.server.plan, self.server.lists,
             max_batch=1024, ring_services=ring_services,
-            geoip=self.server.geoip)
+            geoip=self.server.geoip, replicas=self.replicas)
+        # Every chip compiles and runs its program pair before a worker
+        # takes a request (a no-op on one chip).
+        await asyncio.to_thread(self.sidecar.warm_replicas)
         self._sidecar_thread = threading.Thread(
             target=self.sidecar.run, daemon=True)
         self._sidecar_thread.start()
@@ -256,6 +260,7 @@ class NativePlane:
                 "address": f"{listener.host}:{listener.port}",
                 "tls": listener.protocol.is_tls,
                 "workers": self.workers,
+                "replicas": self.replicas,
                 "rings": [os.path.basename(ring_paths[(listener.name, w)])
                           for w in range(self.workers)],
                 "fail_open": f"127.0.0.1:{fail_open_port}",

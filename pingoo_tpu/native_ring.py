@@ -559,6 +559,45 @@ def write_services_file(path: str, services: list) -> None:
     os.replace(tmp, path)
 
 
+def replica_devices(n: int) -> list:
+    """The chips `--replicas n` launches batches on: [None] for one,
+    which leaves every placement to JAX's default device as before,
+    else the first n local devices. Raises ValueError where the host
+    has fewer (no code stands in for an absent chip), and beside a
+    PINGOO_MESH that spans devices (that shards one batch over them
+    instead: a batch goes whole to one chip or over the mesh, not
+    both)."""
+    n = int(n)
+    if n < 1:
+        raise ValueError(f"--replicas {n}: at least 1")
+    if n == 1:
+        return [None]
+    import jax
+
+    from .sched import mesh_env_spec
+
+    local = jax.local_devices()
+    if n > len(local):
+        raise ValueError(f"--replicas {n}: this host has {len(local)} "
+                         "local device(s)")
+    dp, tp, sp = mesh_env_spec()
+    if dp * tp * sp > 1:
+        raise ValueError(f"--replicas {n} with PINGOO_MESH={dp}x{tp}x{sp}: "
+                         "a batch goes whole to one chip or is sharded "
+                         "over the mesh, not both")
+    return list(local[:n])
+
+
+def _place_on(tables, device):
+    """A copy of a plan's device tables committed to `device`: every
+    device array of the pytree, the rest (static leaves) as it is."""
+    import jax
+
+    return jax.tree.map(
+        lambda leaf: jax.device_put(leaf, device)
+        if isinstance(leaf, jax.Array) else leaf, tables)
+
+
 class RingSidecar:
     """Drain loop: ring batches -> jitted verdict -> verdict ring.
 
@@ -568,12 +607,21 @@ class RingSidecar:
     verdict queue is MPMC, so co-consumers would steal each other's
     tickets). The sidecar drains all rings into ONE merged device batch
     per cycle and scatters the verdicts back per ring.
+
+    `replicas` N > 1 (`--replicas`) keeps a whole copy of the plan's
+    device tables on each of the first N local devices and launches
+    every batch whole on one of them: the chip with the fewest batches
+    in flight, ties broken round-robin. `pipeline_depth` bounds each
+    chip's batches in flight; a chip's batches complete in launch
+    order, the chips' in whichever order their lanes are ready, and a
+    pass with nothing to launch polls the chips rather than block on
+    the oldest batch.
     """
 
     def __init__(self, ring, plan, lists, max_batch: int = 1024,
                  idle_sleep_s: float = 0.0002, pipeline_depth: int = 3,
                  services: Optional[list] = None, geoip=None,
-                 ring_services: Optional[list] = None):
+                 ring_services: Optional[list] = None, replicas: int = 1):
         self.rings: list[Ring] = list(ring) if isinstance(
             ring, (list, tuple)) else [ring]
         self.ring = self.rings[0]  # single-ring callers' view
@@ -601,6 +649,15 @@ class RingSidecar:
                 "PINGOO_PIPELINE_DEPTH", str(self.pipeline_depth))))
         except ValueError:
             pass
+        # Replicas (`--replicas`): the chips a batch may launch on.
+        self._replica_devices = replica_devices(replicas)
+        self.replicas = len(self._replica_devices)
+        # every batch the loop may hold between dequeue and post: each
+        # chip's in-flight bound, and the one being filled
+        self._max_inflight = self.replicas * self.pipeline_depth
+        self._replica_inflight = [0] * self.replicas
+        self._replica_rr = -1   # the last chip chosen (ties go round)
+        self._posted_high: dict = {}  # ring id -> highest ticket+1 posted
         self._zero_copy = self.pipeline_mode == "on"
         # Continuous-batching admission scheduler (ISSUE 6, docs/
         # SCHEDULER.md): replaces the fixed drain window (dispatch
@@ -782,7 +839,7 @@ class RingSidecar:
         caps["country"] = 2
         if self._zero_copy:
             self._staging = self._make_staging(plan, caps)
-            for _ in range(self.pipeline_depth + 1):
+            for _ in range(self._max_inflight + 1):
                 self._slot_pool.append(
                     np.zeros(max_batch, dtype=REQUEST_SLOT_DTYPE))
         self._stage = {
@@ -801,6 +858,7 @@ class RingSidecar:
         # `sched` label is observed directly: it is an age, not a span.
         self._pipe.attach_loop(self._stage, self.sched.observe_stage_cost,
                                self.max_batch, self._ring_depths)
+        self._pipe.replicas(self.replicas)
         # Compact staging (ISSUE 15): bytes staged to the device per
         # verdict batch, by PINGOO_STAGING arm — same series the Python
         # listener plane exports.
@@ -903,11 +961,13 @@ class RingSidecar:
         # meanwhile).
         import threading as _threading
 
-        self._busy_since: Optional[float] = None
-        # The device arrays the loop is blocked on, when the busy window
-        # is a device->host sync: what the watchdog probes when the
-        # sync is overdue (`_probe_overdue_sync`), and what it found.
-        self._busy_sync: Optional[tuple] = None
+        # The open busy window as ONE tuple, so the watchdog never pairs
+        # one window's start with the next one's arrays: (its start in
+        # monotonic s, the device arrays the loop is blocked on when the
+        # window is a device->host sync, else None, and the chip they
+        # live on). An overdue sync is what the watchdog probes
+        # (`_probe_overdue_sync`); `_sync_probes` is what it found.
+        self._busy: Optional[tuple] = None
         self._sync_probes: _deque = _deque(maxlen=8)
         self._sync_overdue_ctr = {
             ready: REGISTRY.counter(
@@ -935,62 +995,63 @@ class RingSidecar:
     _SYNC_PROBE_AT_S = (0.25, 1.0, 2.0)
 
     @contextlib.contextmanager
-    def _hb_busy(self, sync: Optional[tuple] = None):
+    def _hb_busy(self, sync: Optional[tuple] = None, device: int = 0):
         """Declare a known-blocking drain-loop window (XLA compile,
         device sync, interpreter fallback, reattach reconciliation):
         the heartbeat watchdog stamps only inside these. `sync` names
-        the device arrays a sync window waits for."""
-        self._busy_sync = sync
-        self._busy_since = time.monotonic()
+        the device arrays a sync window waits for, `device` the chip
+        (replica index) they are on."""
+        self._busy = (time.monotonic(), sync, device)
         try:
             yield
         finally:
-            self._busy_since = None
-            self._busy_sync = None
+            self._busy = None
 
     def _heartbeat_watchdog(self) -> None:
         import threading as _threading
 
         probed = (None, 0)  # (the busy window, probes made of it)
         while not self._stop:
-            busy = self._busy_since
-            if busy is not None \
-                    and time.monotonic() - busy < self._HB_BUSY_GRACE_S \
+            window = self._busy
+            if window is not None \
+                    and time.monotonic() - window[0] < self._HB_BUSY_GRACE_S \
                     and not self.chaos.heartbeat_frozen():
                 for r in self.rings:
                     r.heartbeat()
-                sync = self._busy_sync
-                n = probed[1] if probed[0] == busy else 0
-                if sync is not None and n < len(self._SYNC_PROBE_AT_S) \
-                        and time.monotonic() - busy \
+                n = probed[1] if probed[0] is window else 0
+                if window[1] is not None \
+                        and n < len(self._SYNC_PROBE_AT_S) \
+                        and time.monotonic() - window[0] \
                         >= self._SYNC_PROBE_AT_S[n]:
-                    probed = (busy, n + 1)
+                    probed = (window, n + 1)
                     # A thread of its own: if the runtime is stuck the
                     # probe blocks too, and the heartbeat must go on.
                     _threading.Thread(
                         target=self._probe_overdue_sync,
-                        args=(busy, sync, n), name="pingoo-sync-probe",
+                        args=(window, n), name="pingoo-sync-probe",
                         daemon=True).start()
             time.sleep(0.1)
 
-    def _probe_overdue_sync(self, busy: float, arrays: tuple,
-                            n: int) -> None:
+    def _probe_overdue_sync(self, window: tuple, n: int) -> None:
         """The drain loop has been blocked in one device->host sync
-        since `busy` (monotonic s). Record whether the arrays it waits
-        for are ready on the device, then send the device a round trip
-        of this thread's own (one word up, the same word back) and time
-        it: a runtime that answers here while the loop stays blocked
-        has lost that sync's completion, one that does not answer is
-        stuck as a whole. The round trip is also new device traffic,
-        which is what a lost completion would be waiting for."""
+        (`window`: its start in monotonic s, the arrays, their chip).
+        Record whether the arrays it waits for are ready on the device,
+        then send that chip a round trip of this thread's own (one word
+        up, the same word back) and time it: a runtime that answers
+        here while the loop stays blocked has lost that sync's
+        completion, one that does not answer is stuck as a whole. The
+        round trip is also new device traffic, which is what a lost
+        completion would be waiting for."""
         import jax
 
-        rec = {"probe": n, "overdue_ms": round(
+        busy, arrays, device = window
+        rec = {"probe": n, "device": device, "overdue_ms": round(
             (time.monotonic() - busy) * 1e3, 1)}
         try:
             rec["ready"] = [bool(a.is_ready()) for a in arrays]
             t0 = time.monotonic()
-            word = jax.device_put(np.zeros(1, dtype=np.int32))
+            word = jax.device_put(np.zeros(1, dtype=np.int32),
+                                  self._replica_devices[device])
             word.block_until_ready()
             t1 = time.monotonic()
             np.asarray(word)
@@ -1002,7 +1063,7 @@ class RingSidecar:
             # (None: still blocked half a second later)
             rec["loop_back_ms"] = None
             while time.monotonic() - t2 < 0.5:
-                if self._busy_since != busy:
+                if self._busy is not window:
                     rec["loop_back_ms"] = round(
                         (time.monotonic() - t2) * 1e3, 1)
                     break
@@ -1028,10 +1089,10 @@ class RingSidecar:
         scaps = resolve_stage_caps(plan)
         if scaps is None:
             return StagingEncoder(self.max_batch, field_specs=caps,
-                                  nbuf=self.pipeline_depth + 1)
+                                  nbuf=self._max_inflight + 1)
         return StagingEncoder(
             self.max_batch, field_specs=caps,
-            nbuf=self.pipeline_depth + 1, stage_caps=scaps,
+            nbuf=self._max_inflight + 1, stage_caps=scaps,
             overflow_thresholds=stage_overflow_thresholds(plan, scaps))
 
     def _build_plan_state(self, plan) -> dict:
@@ -1114,6 +1175,12 @@ class RingSidecar:
         tables = plan.device_tables()
         state["tables"] = (mesh.place_tables(tables)
                            if mesh.active else tables)
+        # One whole copy a chip (`replicas`): the first stays where JAX
+        # put it, so chip 0 runs the one-chip program; a swap builds
+        # every copy anew here, off the loop.
+        state["replica_tables"] = [state["tables"]] + [
+            _place_on(state["tables"], device)
+            for device in self._replica_devices[1:]]
         state["pf_fn"] = None
         state["pf_gated_banks"] = 0
         state["pf_attr"] = None
@@ -1141,6 +1208,7 @@ class RingSidecar:
         self._host_routes = state["host_routes"]
         self.mesh = state["mesh"]
         self._tables = state["tables"]
+        self._replica_tables = state["replica_tables"]
         self._pf_fn = state["pf_fn"]
         self._pf_gated_banks = state["pf_gated_banks"]
         self._pf_attr = state["pf_attr"]
@@ -1243,9 +1311,8 @@ class RingSidecar:
         t0 = time.monotonic()
         with self._hb_busy(), self._pipe.stage("swap"):
             if pend_parts:
-                inflight.append(self._dispatch(pend_parts, pend_n,
-                                               oldest_enq_ms,
-                                               slot_buf=pend_buf))
+                self._launch(inflight, pend_parts, pend_n, oldest_enq_ms,
+                             pend_buf)
                 pend_parts, pend_n, oldest_enq_ms = [], 0, None
                 pend_buf = self._take_slot_buf() if self._zero_copy \
                     else None
@@ -1408,22 +1475,29 @@ class RingSidecar:
                     launch = sched.should_launch(
                         pend_n, oldest_enq_ms / 1e3, now_ms / 1e3)
             if launch:
-                inflight.append(self._dispatch(pend_parts, pend_n,
-                                               oldest_enq_ms,
-                                               slot_buf=pend_buf))
+                self._launch(inflight, pend_parts, pend_n, oldest_enq_ms,
+                             pend_buf)
                 pend_parts, pend_n, oldest_enq_ms = [], 0, None
                 if pend_buf is not None:
                     pend_buf = self._take_slot_buf()
                 # what became ready while that batch was encoded
                 self._complete_ready(inflight)
-            if len(inflight) >= self.pipeline_depth:
+            if min(self._replica_inflight) >= self.pipeline_depth:
                 self._complete_oldest(inflight, "depth")  # the bound
-            elif inflight and not launch:
+            elif inflight and not launch and self.replicas == 1:
                 self._complete_oldest(inflight, "drain")
             if got == 0 and not launch and not inflight:
                 if not pend_parts and max_requests is not None \
                         and self.processed >= max_requests:
                     break
+                self._pipe.idle()
+                time.sleep(self.idle_sleep_s)
+            elif got == 0 and not launch and self.replicas > 1:
+                # Several chips: nothing to launch, none of the batches
+                # in flight ready. Blocking on the oldest (the drain
+                # rule) would hold a batch that another chip finishes
+                # meanwhile, and keep new rows off the chips that are
+                # free; poll them all again instead.
                 self._pipe.idle()
                 time.sleep(self.idle_sleep_s)
             if max_requests is not None and self.processed >= max_requests \
@@ -1432,9 +1506,8 @@ class RingSidecar:
         # Flush: accumulated-but-unlaunched slots still get verdicts
         # (the data plane would otherwise eat a fail-open timeout).
         if pend_parts:
-            inflight.append(self._dispatch(pend_parts, pend_n,
-                                           oldest_enq_ms,
-                                           slot_buf=pend_buf))
+            self._launch(inflight, pend_parts, pend_n, oldest_enq_ms,
+                         pend_buf)
         elif pend_buf is not None:
             self._slot_pool.append(pend_buf)
         while inflight:
@@ -1459,25 +1532,63 @@ class RingSidecar:
         self._pipe.loop_stop()
         return self.processed
 
+    def _launch(self, inflight, parts, n: int,
+                oldest_enq_ms: Optional[int], slot_buf) -> None:
+        """Dispatch one batch into `inflight`. Its staging views are the
+        StagingEncoder's next buffer set, of `nbuf` it hands out in
+        turn, and `_complete` reads a batch's views (host rules, the
+        overflow mask, provenance) until the batch is done: so first
+        complete, in launch order, the batch still holding that set
+        (`staging`). On one chip none does (at most the depth in flight
+        of depth + 1 sets, completed in launch order); on several, a
+        batch on a slow chip can outlast `nbuf` launches on the
+        others."""
+        if self._staging is not None:
+            reuse = self._staging.checkouts + 1 - self._staging.nbuf
+            while any(e[-1].staged is not None and e[-1].staged <= reuse
+                      for e in inflight):
+                self._complete_at(inflight, 0, "staging")
+        inflight.append(self._dispatch(parts, n, oldest_enq_ms,
+                                       slot_buf=slot_buf))
+
     def _complete_oldest(self, inflight, how: str) -> None:
         """Complete the oldest batch in flight, counted by the rule that
-        chose it: `ready` (its lanes were there), `depth` (the in-flight
-        bound: `_complete` blocks on the device) or `drain` (a pass that
-        launched nothing, the flush, a swap boundary)."""
+        chose it: `ready` (its lanes were there), `depth` (every chip
+        holds its in-flight bound: `_complete` blocks on the device) or
+        `drain` (one chip: a pass that launched nothing; the flush, a
+        swap boundary)."""
+        self._complete_at(inflight, 0, how)
+
+    def _complete_at(self, inflight, i: int, how: str) -> None:
+        """Take the `i`-th batch in launch order out of flight and
+        complete it, counted by `how`."""
+        entry = inflight[i]
+        del inflight[i]
+        self._replica_inflight[entry[-1].device] -= 1
         self._pipe.note_completion(how)
-        self._complete(*inflight.popleft())
+        self._complete(*entry, inflight=inflight)
 
     def _complete_ready(self, inflight) -> None:
-        """Complete, oldest first, every batch in flight whose device
-        lanes are already ready; stop at the first that is not, so
-        completion stays FIFO and posted tickets stay a prefix
-        (`set_posted_floor`). `dev` None is a batch the interpreter
-        serves (device rung demoted): nothing to wait for."""
-        while inflight:
-            dev = inflight[0][3]
-            if dev is not None and not dev.is_ready():
-                return
-            self._complete_oldest(inflight, "ready")
+        """Complete, in launch order, every batch in flight whose device
+        lanes are already ready, but none behind an unready batch of its
+        own chip: a chip runs its batches in order, so its batches
+        complete FIFO; the chips' batches in whichever order their
+        lanes come, so a chip that is done never waits on a slower one.
+        On one chip that is: stop at the first batch not ready. `dev`
+        None is a batch the interpreter serves (device rung demoted):
+        nothing to wait for."""
+        i, waiting = 0, set()
+        while i < len(inflight):
+            chip = inflight[i][-1].device
+            if chip not in waiting:
+                dev = inflight[i][3]
+                if dev is None or dev.is_ready():
+                    self._complete_at(inflight, i, "ready")
+                    continue
+                waiting.add(chip)
+                if len(waiting) == self.replicas:
+                    return
+            i += 1
 
     def _drain_bodies(self) -> None:
         """Drain each ring's body-window ring through the streaming
@@ -1550,10 +1661,23 @@ class RingSidecar:
         return sum(self._ring_depths().values())
 
     def _begin_batch(self, parts, n: int):
-        """The batch's span record; counts the rings that gave it rows."""
+        """The batch's span record; counts the rings that gave it rows
+        and puts it on a chip: the one with the fewest batches in
+        flight, the tie to the next after the last chosen."""
         rings = len({id(r) for r, _ in parts})
         self._batch_rings.inc(rings)
-        return self._pipe.begin(self.pipeline_mode, n, rings)
+        rec = self._pipe.begin(self.pipeline_mode, n, rings)
+        load = self._replica_inflight
+        low = min(load)
+        start = self._replica_rr + 1
+        chip = next(c % self.replicas
+                    for c in range(start, start + self.replicas)
+                    if load[c % self.replicas] == low)
+        self._replica_rr = chip
+        load[chip] += 1
+        self._pipe.note_launch(chip, sum(load))
+        rec.device = chip
+        return rec
 
     def _dispatch(self, parts, n: int, oldest_enq_ms: Optional[int],
                   slot_buf=None):
@@ -1581,6 +1705,7 @@ class RingSidecar:
                     try:
                         batch = self._staging.encode_slots(
                             slots, pad_to=self.max_batch)
+                        rec.staged = self._staging.checkouts
                         raw = RequestBatch(
                             size=n,
                             arrays={k: v[:n]
@@ -1619,7 +1744,6 @@ class RingSidecar:
                 arrays = self.mesh.shard_batch(arrays)
             sp.next("prefilter")
             self.chaos.stage("dispatch")
-            pf_hits = pf_aux = None
             dev = None
             self._dfa_rung_tick()
             rec.cascade, rec.lane_rows = self._cascade, self._lane_rows
@@ -1640,39 +1764,8 @@ class RingSidecar:
                     # blocks in XLA for seconds — the watchdog heartbeats
                     # through it so the data plane doesn't flip degraded.
                     with self._hb_busy():
-                        # Compact staging (ISSUE 15): ONE device_put of
-                        # the packed buffer replaces the per-field
-                        # transfers; the packed twins slice the fields
-                        # back out on device. Mesh stays on the per-field
-                        # path (the shard plan addresses named arrays).
-                        use_packed = (
-                            batch.packed is not None
-                            and self._packed_lane_fn is not None
-                            and not self.mesh.active)
-                        if use_packed:
-                            import jax
-
-                            dev_packed = jax.device_put(batch.packed)
-                            if self._packed_pf_fn is not None:
-                                pf_hits, pf_aux = self._packed_pf_fn(
-                                    self._tables, dev_packed,
-                                    batch.layout)  # async
-                            sp.next("dispatch")
-                            dev = self._packed_lane_fn(
-                                self._tables, dev_packed, batch.layout,
-                                pf_hits, np.int32(n), pf_aux)  # async
-                        else:
-                            if self._pf_fn is not None:
-                                pf_hits, pf_aux = self._pf_fn(
-                                    self._tables, arrays)  # async
-                            sp.next("dispatch")
-                            # The traced n masks batch-padding rows out
-                            # of the attribution lane on device; Stage
-                            # A's aux goes in as the device array it is
-                            # and comes back in the lanes' own rows.
-                            dev = self._lane_fn(
-                                self._tables, arrays, pf_hits,
-                                np.int32(n), pf_aux)  # async
+                        dev = self._run_lanes(batch, arrays, n,
+                                              rec.device, sp)
                         # Queue the batch's ONE device->host copy behind
                         # the program: `_complete` finds the bytes there.
                         dev.copy_to_host_async()
@@ -1716,6 +1809,86 @@ class RingSidecar:
         rec.tags["staging_mode"] = ("compact" if batch.packed is not None
                                     else "full")
         return (parts, slots, raw, dev, n, skip_masks, slot_buf, rec)
+
+    def _run_lanes(self, batch, arrays, n: int, chip: int, sp=None):
+        """Launch one encoded batch's device work on `chip` (async):
+        Stage A, then the lanes program, over that chip's tables;
+        returns the lanes' stacked output. `sp` is the batch's open
+        stage, moved to `dispatch` between the two programs."""
+        tables = self._replica_tables[chip]
+        pf_hits = pf_aux = None
+        # Compact staging (ISSUE 15): ONE device_put of the packed
+        # buffer replaces the per-field transfers; the packed twins
+        # slice the fields back out on device. Mesh stays on the
+        # per-field path (the shard plan addresses named arrays).
+        if batch.packed is not None and self._packed_lane_fn is not None \
+                and not self.mesh.active:
+            import jax
+
+            dev_packed = jax.device_put(batch.packed,
+                                        self._replica_devices[chip])
+            if self._packed_pf_fn is not None:
+                pf_hits, pf_aux = self._packed_pf_fn(
+                    tables, dev_packed, batch.layout)  # async
+            if sp is not None:
+                sp.next("dispatch")
+            return self._packed_lane_fn(
+                tables, dev_packed, batch.layout, pf_hits, np.int32(n),
+                pf_aux)  # async
+        if self._pf_fn is not None:
+            pf_hits, pf_aux = self._pf_fn(tables, arrays)  # async
+        if sp is not None:
+            sp.next("dispatch")
+        # The traced n masks batch-padding rows out of the attribution
+        # lane on device; Stage A's aux goes in as the device array it
+        # is and comes back in the lanes' own rows.
+        return self._lane_fn(tables, arrays, pf_hits, np.int32(n),
+                             pf_aux)  # async
+
+    def warm_replicas(self) -> None:
+        """`replicas` > 1: before the listener takes traffic, compile
+        and run the program pair once on every chip, over one empty
+        request encoded as the loop encodes a batch, so that no batch
+        of the served traffic meets a compile. Chip 0 compiles first;
+        the others, which reuse its trace, compile side by side. One
+        chip: nothing (its first batch compiles, as it always has)."""
+        if self.replicas == 1:
+            return
+        import threading as _threading
+
+        from .engine.batch import RequestBatch, bucket_arrays, pad_batch
+        from .obs.perf import batch_leading_dim, set_dispatch_context
+
+        slots = np.zeros(1, dtype=REQUEST_SLOT_DTYPE)
+        if self._staging is not None:
+            batch = self._staging.encode_slots(slots, pad_to=self.max_batch)
+        else:
+            batch = pad_batch(RequestBatch(size=1, arrays=bucket_arrays(
+                slots_to_arrays(slots))), self.max_batch)
+        failed: list = []
+
+        def warm(chip):
+            try:
+                set_dispatch_context(batch=batch_leading_dim(batch.arrays))
+                np.asarray(self._run_lanes(batch, batch.arrays, 1, chip))
+            except Exception as exc:
+                failed.append((chip, exc))
+
+        warm(0)
+        threads = [_threading.Thread(target=warm, args=(chip,),
+                                     name=f"pingoo-warm-{chip}")
+                   for chip in range(1, self.replicas)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+        if failed:
+            chip, exc = failed[0]
+            raise RuntimeError(f"warming chip {chip} failed: {exc!r}") \
+                from exc
+        _log.info("replicas warm", extra={"fields": {
+            "replicas": self.replicas,
+            "devices": [str(d) for d in self._replica_devices]}})
 
     def _failopen_late_rows(self, parts, now_ms: int,
                             est_ms: Optional[float] = None) -> list:
@@ -1781,11 +1954,12 @@ class RingSidecar:
         return np.asarray(dev)
 
     def _complete(self, parts, slots, raw_batch, dev, n: int, skip_masks,
-                  slot_buf, rec) -> None:
-        """Resolve the oldest in-flight batch (`_dispatch`'s tuple):
-        host rules, the device sync (the batch's one device->host copy:
-        lanes, cascade counts, attribution lane and Stage A's counts in
-        one stacked array), merge, post, provenance."""
+                  slot_buf, rec, inflight=()) -> None:
+        """Resolve an in-flight batch (`_dispatch`'s tuple): host rules,
+        the device sync (the batch's one device->host copy: lanes,
+        cascade counts, attribution lane and Stage A's counts in one
+        stacked array), merge, post, provenance. `inflight` is what
+        stays in flight, which the posted floor stays under."""
         from .engine.verdict import (cascade_counts, host_rule_lanes,
                                      merge_lanes, rule_hit_counts,
                                      stage_a_counts)
@@ -1798,7 +1972,7 @@ class RingSidecar:
             sp.next("device_wait")
             if dev is not None:
                 try:
-                    with self._hb_busy(sync=(dev,)):  # can block ms-s
+                    with self._hb_busy(sync=(dev,), device=rec.device):
                         full = self._to_host(dev)
                     dev_lanes = full[:, :n]  # drop padding
                     # The cascade's own row counts, the attribution
@@ -2011,11 +2185,19 @@ class RingSidecar:
                 ring.record_waits(waits)
                 # Posted-floor advance (ring v5, docs/RESILIENCE.md): every
                 # ticket of this part now has a verdict (skip-mask rows
-                # were posted at launch), and parts complete in FIFO order,
-                # so posted tickets form a prefix — a reattaching sidecar's
-                # orphan scan starts above this mark.
+                # were posted at launch), and on one chip parts complete
+                # in FIFO order, so posted tickets form a prefix — a
+                # reattaching sidecar's orphan scan starts above this
+                # mark. On several chips a batch may complete before an
+                # older one: the floor then stays at the oldest ticket
+                # still in flight (a ticket posted twice on reattach is
+                # dropped by its consumer's unknown-ticket check).
                 if m:
-                    ring.set_posted_floor(int(part["ticket"].max()) + 1)
+                    floor = int(part["ticket"].max()) + 1
+                    if self.replicas > 1:
+                        floor = self._floor_under_inflight(ring, floor,
+                                                           inflight)
+                    ring.set_posted_floor(floor)
                 off += m
             # Deadline accounting on the ring clock: rows posted after
             # their PINGOO_DEADLINE_MS budget count as misses (one
@@ -2052,6 +2234,19 @@ class RingSidecar:
                 self._slot_pool.append(slot_buf)
             self._pipe.finish()
             self.chaos.on_batch_done(self.batches)
+
+    def _floor_under_inflight(self, ring, floor: int, inflight) -> int:
+        """`ring`'s posted floor once a part below `floor` is posted
+        with the batches `inflight` still out: the highest ticket+1
+        posted so far, but no higher than the oldest ticket of that ring
+        still in flight."""
+        high = self._posted_high[id(ring)] = max(
+            self._posted_high.get(id(ring), 0), floor)
+        for entry in inflight:
+            for r, part in entry[0]:
+                if r is ring and len(part):
+                    high = min(high, int(part["ticket"].min()))
+        return high
 
     def _observe_provenance(self, slots, rule_hits, dev_lanes, host,
                             raw_batch, unverified, verified_block,
@@ -2452,6 +2647,11 @@ class RingSidecar:
             "cascade": self._cascade.snapshot(),
             "completions": dict(self._pipe.completions),
             "host_copies": self._pipe.host_copies.value,
+            "replicas": self.replicas,
+            "replica_batches": {str(d): c.value for d, c in
+                                enumerate(self._pipe.replica_batches)},
+            "replica_inflight": list(self._replica_inflight),
+            "inflight_at_launch": self._pipe.inflight_at_launch.value,
             "ring_telemetry": self.ring_telemetry(),
             "sched": self.sched.snapshot(),
             "mesh": self.mesh.describe(),

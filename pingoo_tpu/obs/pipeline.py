@@ -25,7 +25,8 @@ family on its plane label:
 The ring sidecar also records its drain loop's stage boundaries here,
 and nowhere else (docs/OBSERVABILITY.md "Spans and scopes"):
 `stage(name, rec)` is a context manager that enters a
-`jax.profiler.TraceAnnotation("sidecar/<phase>", batch=, rows=, rings=)` — so
+`jax.profiler.TraceAnnotation("sidecar/<phase>", batch=, rows=, rings=,
+device=)` — so
 the span lands in the profiler's own file, on the device trace's clock —
 and on exit fans ONE pair of `time.monotonic()` stamps out to every
 sink: the `pingoo_verdict_stage_ms` histogram, the occupancy/overlap
@@ -44,6 +45,11 @@ points (which the sampled Timeline reads) and the loop-phase account:
     the rule of `RingSidecar.run` that chose the moment (COMPLETIONS).
   * pingoo_sidecar_host_copies_total{plane}: device arrays the loop
     materialised on the host (`RingSidecar._to_host`): one a batch.
+  * pingoo_sidecar_replica_batches_total{plane,device},
+    pingoo_sidecar_inflight_at_launch_total{plane} and the
+    pingoo_sidecar_replicas{plane} gauge: batches launched on each chip
+    (`--replicas`), and the batches in flight on all of them summed at
+    each launch (`note_launch`).
 
 Interval bookkeeping is host-side float math on the plane's own
 serial context (event loop / drain thread): no locks, no arrays, no
@@ -89,8 +95,10 @@ _PHASE_EXEC = {"encode": "encode", "dispatch": "dispatch",
 STALL_MS = 250.0
 # Why the drain loop completed a batch when it did: its device lanes
 # were ready, the in-flight bound was reached (the loop blocked on it),
-# or a pass launched nothing (also the flush and a swap boundary).
-COMPLETIONS = ("ready", "depth", "drain")
+# a pass launched nothing (also the flush and a swap boundary), or it
+# held the staging buffers the next batch is encoded into (several
+# chips: a batch on a slow chip outlasted the encoder's rotation).
+COMPLETIONS = ("ready", "depth", "drain", "staging")
 _IDLE_FLUSH_S = 1.0
 
 _log = logging.getLogger(__name__)
@@ -152,17 +160,21 @@ class BatchSpans:
     `seq` is the loop's batch counter (the pipeline slot id, and the
     `batch` stat of every span the batch causes); `points` maps a phase
     to its (t_start, t_end) in time.monotonic() seconds; `rings` is how
-    many of the sidecar's rings gave the batch rows; `stats` is what
-    the spans opened from then on carry besides (`blocked`, `recheck`:
-    set once the batch's lanes are on the host)."""
+    many of the sidecar's rings gave the batch rows; `device` the chip
+    it runs on (its `--replicas` index); `staged` the StagingEncoder
+    checkout its views are in (None off the staging encoder); `stats`
+    is what the spans opened from then on carry besides (`blocked`,
+    `recheck`: set once the batch's lanes are on the host)."""
 
-    __slots__ = ("seq", "rows", "rings", "stats", "cascade", "lane_rows",
-                 "points", "tags", "compute_ms")
+    __slots__ = ("seq", "rows", "rings", "device", "staged", "stats",
+                 "cascade", "lane_rows", "points", "tags", "compute_ms")
 
     def __init__(self, seq: int, rows: int, rings: int = 1):
         self.seq = seq
         self.rows = rows
         self.rings = rings
+        self.device = 0
+        self.staged = None      # its StagingEncoder checkout, if any
         self.stats: dict = {}
         self.cascade = None     # the CascadeCounters of its lanes program
         self.lane_rows = None   # and that program's row layout (LaneRows)
@@ -361,6 +373,7 @@ class PipelineStats:
             "pingoo_sidecar_host_copies_total",
             schema.PIPELINE_METRICS["pingoo_sidecar_host_copies_total"],
             labels={"plane": self.plane})
+        self.replica_batches: list = []  # one counter a chip: replicas()
         self._stack: list = []      # enclosing with-blocks: (name, rec)
         self._base = "poll"         # what the loop falls back to
         self._cur = None            # the open span: name, rec, t0, ann
@@ -368,6 +381,35 @@ class PipelineStats:
         self._cur_t0 = 0.0
         self._cur_ann = None
         self._t_flush = 0.0
+
+    def replicas(self, n: int) -> None:
+        """The drain loop launches on `n` chips: one batch counter each
+        (`device` = the chip's index), the in-flight-at-launch sum and
+        the gauge."""
+        from . import schema
+
+        labels = {"plane": self.plane}
+        self.replica_batches = [
+            self._registry.counter(
+                "pingoo_sidecar_replica_batches_total",
+                schema.PIPELINE_METRICS[
+                    "pingoo_sidecar_replica_batches_total"],
+                labels={**labels, "device": str(chip)})
+            for chip in range(n)]
+        self.inflight_at_launch = self._registry.counter(
+            "pingoo_sidecar_inflight_at_launch_total",
+            schema.PIPELINE_METRICS[
+                "pingoo_sidecar_inflight_at_launch_total"], labels=labels)
+        self._registry.gauge(
+            "pingoo_sidecar_replicas",
+            schema.PIPELINE_METRICS["pingoo_sidecar_replicas"],
+            labels=labels).set(n)
+
+    def note_launch(self, chip: int, inflight: int) -> None:
+        """A batch launched on `chip`, `inflight` batches now in flight
+        on all chips (this one included)."""
+        self.replica_batches[chip].inc()
+        self.inflight_at_launch.inc(inflight)
 
     def loop_start(self) -> None:
         """The drain loop begins: everything until loop_stop() is
@@ -410,8 +452,9 @@ class PipelineStats:
         return _Stage(self, name, rec)
 
     def idle(self) -> None:
-        """An empty pass with nothing in flight (the loop sleeps next):
-        one clock read and a compare while already idle."""
+        """An empty pass with nothing in flight, or on several chips
+        nothing in flight ready (the loop sleeps next): one clock read
+        and a compare while already idle."""
         now = time.monotonic()
         if self._cur != "idle":
             self._close(now)
@@ -438,7 +481,7 @@ class PipelineStats:
         else:
             ann = self._annotate(self._span_names[name], batch=rec.seq,
                                  rows=rec.rows, rings=rec.rings,
-                                 **rec.stats)
+                                 device=rec.device, **rec.stats)
         ann.__enter__()
         self._cur, self._cur_rec, self._cur_t0, self._cur_ann = \
             name, rec, t, ann

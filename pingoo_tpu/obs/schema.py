@@ -174,12 +174,25 @@ PIPELINE_METRICS = {
         "moment (how=ready: its device lanes were already there; depth: "
         "PINGOO_PIPELINE_DEPTH batches in flight, the loop blocked on "
         "the oldest; drain: a pass that launched nothing, the flush, a "
-        "swap boundary)",
+        "swap boundary; staging: it held the staging buffers the next "
+        "batch is encoded into)",
     "pingoo_sidecar_host_copies_total":
         "device arrays the drain loop materialised on the host to "
         "complete its batches: one a batch (lanes, cascade counts, "
         "attribution lane and Stage-A counts in one stacked array), "
         "none for a batch the interpreter served",
+    "pingoo_sidecar_replica_batches_total":
+        "batches the drain loop launched on each chip (device = the "
+        "chip's index among the --replicas local devices; 0 alone on "
+        "one chip)",
+    "pingoo_sidecar_inflight_at_launch_total":
+        "batches in flight on all chips, the one launched included, "
+        "summed over launches: over pingoo_pipeline_batches_total it is "
+        "the mean in flight at a launch (at most --replicas x "
+        "PINGOO_PIPELINE_DEPTH)",
+    "pingoo_sidecar_replicas":
+        "chips the drain loop launches batches on (--replicas), each "
+        "holding a whole copy of the plan's device tables",
 }
 
 # Continuous-batching scheduler + serving-mesh metrics (ISSUE 6,

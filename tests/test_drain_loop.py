@@ -170,9 +170,9 @@ def _stand_in_lanes(sidecar, lanes: str, events=None):
                 + entry[4:]
         return entry
 
-    def watched_complete(*entry):
+    def watched_complete(*entry, **kwargs):
         events.append(("complete", entry[-1].seq))
-        return complete(*entry)
+        return complete(*entry, **kwargs)
 
     sidecar._dispatch = staged_dispatch
     sidecar._complete = watched_complete
@@ -302,7 +302,10 @@ def _assert_served_right(d):
             {t: _want(d.plan, tup) for t, tup in sent.items()}
     assert d.floor_passed_unposted == []
     assert d.floors == [len(sent) for sent in d.sent]
-    assert sorted(d.completions) == ["depth", "drain", "ready"]
+    assert sorted(d.completions) == ["depth", "drain", "ready", "staging"]
+    # one chip completes in launch order: no batch outlasts the staging
+    # encoder's rotation
+    assert d.completions["staging"] == 0
     assert sum(d.completions.values()) == d.batches == -(-BURST // MAX_BATCH)
     # completed in the order launched, whichever rule chose the moment
     done = [seq for what, seq in d.events if what == "complete"]
@@ -328,7 +331,8 @@ def test_batches_in_flight_stay_within_the_depth(drive, depth, lanes):
 def test_ready_lanes_are_completed_in_the_pass_that_launched_them(
         drive, depth):
     d = drive(depth, lanes="ready")
-    assert d.completions == {"ready": d.batches, "depth": 0, "drain": 0}
+    assert d.completions == {"ready": d.batches, "depth": 0, "drain": 0,
+                             "staging": 0}
     assert d.events == [(what, seq) for seq in range(1, d.batches + 1)
                         for what in ("launch", "complete")]
     assert max(d.inflight_seen) == 1
@@ -344,7 +348,7 @@ def test_lanes_never_ready_early_are_held_to_the_depth_as_before(
     d = drive(depth, lanes="never")
     assert d.events == _parents_order(d.batches, depth)
     assert d.completions == {"ready": 0, "depth": d.batches - (depth - 1),
-                             "drain": depth - 1}
+                             "drain": depth - 1, "staging": 0}
     _assert_served_right(d)
 
 
@@ -358,7 +362,7 @@ def test_a_ready_batch_behind_an_unready_one_waits_its_turn(drive, depth):
         # the bound leaves nothing in flight for the ready rule to find
         # but the batch just launched
         assert d.completions == {"ready": d.batches - odd, "depth": odd,
-                                 "drain": 0}
+                                 "drain": 0, "staging": 0}
     else:
         # an unready batch leaves by the bound or the drain, and only
         # then the ready one behind it by the ready rule
@@ -375,7 +379,8 @@ def test_a_ready_batch_behind_an_unready_one_waits_its_turn(drive, depth):
 @pytest.mark.parametrize("depth", [1, 2, 3])
 def test_a_batch_the_interpreter_serves_counts_as_ready(drive, depth):
     d = drive(depth, lanes="none")      # `dev is None`: nothing to wait for
-    assert d.completions == {"ready": d.batches, "depth": 0, "drain": 0}
+    assert d.completions == {"ready": d.batches, "depth": 0, "drain": 0,
+                             "staging": 0}
     _assert_served_right(d)
 
 
@@ -454,10 +459,10 @@ def test_a_batch_comes_to_the_host_in_one_copy(tmp_path, monkeypatch, case):
         dev = None if lanes == "none" else Counted(entry[3], True)
         return entry[:3] + (dev,) + entry[4:]
 
-    def watched_complete(*entry):
+    def watched_complete(*entry, **kwargs):
         completing.append(entry[-1].seq)
         try:
-            return complete(*entry)
+            return complete(*entry, **kwargs)
         finally:
             completing.pop()
 

@@ -306,13 +306,28 @@ class TestOverdueSyncProbe:
         def is_ready(self):
             return False
 
+    @pytest.mark.parametrize("replicas, chip", [(1, 0), (2, 1)])
     def test_overdue_sync_is_probed_and_other_windows_are_not(
-            self, tmp_path, plan, caplog):
+            self, tmp_path, plan, caplog, replicas, chip):
+        """Counted by difference (the counter is the process's), and
+        with the sync window held open until the probe has started, so
+        a loaded machine neither misses the mark nor the probe the
+        window's close. The probe names the chip it probed and makes
+        its round trip there."""
+        import jax
+
         from pingoo_tpu.obs import REGISTRY
 
+        def overdue():
+            return REGISTRY.counter(
+                "pingoo_sidecar_sync_overdue_total", "",
+                labels={"plane": "sidecar", "ready": "false"}).value
+
         ring = Ring(str(tmp_path / "ring"), capacity=64, create=True)
-        sidecar = RingSidecar(ring, plan, {}, max_batch=16)
+        sidecar = RingSidecar(ring, plan, {}, max_batch=16,
+                              replicas=replicas)
         sidecar._SYNC_PROBE_AT_S = (0.05,)
+        before = overdue()
         try:
             hb0 = ring.liveness()["heartbeat_ms"]
             # a compile window names no arrays: stamped, never probed
@@ -321,23 +336,32 @@ class TestOverdueSyncProbe:
             assert not sidecar._sync_probes
             assert ring.liveness()["heartbeat_ms"] > hb0
             with caplog.at_level("WARNING", logger="pingoo_tpu.native_ring"):
-                with sidecar._hb_busy(sync=(self._NeverReady(),)):
-                    time.sleep(0.3)
-                deadline = time.monotonic() + 5
+                with sidecar._hb_busy(sync=(self._NeverReady(),),
+                                      device=chip):
+                    deadline = time.monotonic() + 10
+                    while not any(t.name == "pingoo-sync-probe"
+                                  for t in threading.enumerate()) \
+                            and not sidecar._sync_probes:
+                        assert time.monotonic() < deadline
+                        time.sleep(0.01)
+                    time.sleep(0.05)
+                deadline = time.monotonic() + 10
                 while not sidecar._sync_probes \
                         and time.monotonic() < deadline:
                     time.sleep(0.01)
             (rec,) = sidecar._sync_probes
             assert rec["probe"] == 0 and rec["overdue_ms"] >= 50
+            assert rec["device"] == chip
             assert rec["ready"] == [False] == rec["ready_after"]
             # the runtime answered the probe while the loop was blocked,
             # and the loop's own sync came back (the window closed)
             assert rec["up_ms"] >= 0 and rec["back_ms"] >= 0
             assert rec["loop_back_ms"] is not None
             assert "device sync overdue" in caplog.text
-            text = REGISTRY.prometheus_text()
-            assert ('pingoo_sidecar_sync_overdue_total{plane="sidecar",'
-                    'ready="false"} 1') in text
+            assert overdue() - before == 1
+            # ... whose round trip went where the chip's batches go
+            assert sidecar._replica_devices == (
+                [None] if replicas == 1 else jax.local_devices()[:2])
         finally:
             sidecar.stop()
             ring.close()
