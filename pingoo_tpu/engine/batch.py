@@ -29,9 +29,23 @@ from ..compiler.lowering import DEFAULT_FIELD_SPECS, NfaPred
 from ..compiler.plan import quantize_stage_cap
 from ..expr import Context, Ip
 from ..ops.cidr import ip_to_words
-from ..ops.live_columns import walked_columns, walked_rows
+from ..ops.live_columns import ROW_TILE, walked_columns, walked_rows
 
 STRING_FIELDS = ("host", "url", "path", "method", "user_agent", "country")
+
+# Upload heights of a packed batch (docs/EXECUTOR.md "Compact staging"):
+# the host ships the first H rows, the smallest rung that holds every
+# live row, and the chip pads them to the batch; past the last rung the
+# whole batch ships. One pad program a rung (engine/verdict.make_pad_fn).
+UPLOAD_ROWS = (64, ROW_TILE)
+
+
+def upload_rows(n: int, batch: int) -> int:
+    """Rows of a `batch`-row packed buffer to ship for `n` live rows."""
+    for rung in UPLOAD_ROWS:
+        if n <= rung < batch:
+            return rung
+    return batch
 
 
 @dataclass
@@ -74,6 +88,9 @@ class RequestBatch:
     packed: Optional[np.ndarray] = None
     layout: Optional["PackedLayout"] = None
     staged_bytes: int = 0  # host->device bytes this batch stages
+    # Rows of `packed` shipped to the device (`upload_rows`); the chip
+    # pads them to `size` with zero rows. 0 off the packed path.
+    upload_rows: int = 0
 
     def __getitem__(self, key: str):
         return self.arrays[key]
@@ -436,7 +453,12 @@ class StagingEncoder:
         # Compact staging (ISSUE 15): flat packed rows, FULL-spec-sized
         # once at boot so a hot-swap that widens caps never reallocates
         # — per batch only the current layout's [P, width] prefix is
-        # touched and shipped.
+        # touched and shipped. `packed_dirty` is how far into its flat
+        # buffer a set may hold a byte other than 0: a slot encode
+        # zeroes from its own live rows' end up to there, not the whole
+        # [P, width] (everything past the mark is zero already). In
+        # bytes of the flat buffer, not rows: a swap may change the row
+        # stride. `set_stage_caps` marks every set wholly dirty.
         self.stage_caps: Optional[dict[str, int]] = None
         self._thresholds: dict[str, int] = dict(specs)
         self._layout: Optional[PackedLayout] = None
@@ -458,10 +480,10 @@ class StagingEncoder:
         if "packed" not in self._bufs[0]:
             raise ValueError(
                 "encoder was built without packed staging buffers")
-        self.stage_caps = {f: min(int(stage_caps.get(
-            f, self.specs.get(f, 256))), self.specs.get(f, 256))
-            for f in STRING_FIELDS}
+        self.stage_caps = self._clamp_caps(stage_caps)
         self._layout = build_packed_layout(self.stage_caps)
+        for bufs in self._bufs:
+            bufs["packed_dirty"] = bufs["packed"].size
         self._thresholds = dict(self.specs)
         if overflow_thresholds is not None:
             for f in STRING_FIELDS:
@@ -469,6 +491,14 @@ class StagingEncoder:
                     int(overflow_thresholds.get(
                         f, self.specs.get(f, 256))),
                     self.specs.get(f, 256))
+
+    def _clamp_caps(self, stage_caps: Mapping[str, int]) -> dict[str, int]:
+        return {f: min(int(stage_caps.get(f, self.specs.get(f, 256))),
+                       self.specs.get(f, 256)) for f in STRING_FIELDS}
+
+    def packed_width(self, stage_caps: Mapping[str, int]) -> int:
+        """The packed row stride `set_stage_caps(stage_caps)` gives."""
+        return build_packed_layout(self._clamp_caps(stage_caps)).width
 
     def _checkout(self) -> dict:
         buf = self._bufs[self._cursor]
@@ -551,6 +581,8 @@ class StagingEncoder:
         W = layout.width
         pk = buf["packed"][: P * W].reshape(P, W)
         pk[:] = 0
+        # a slot encode into this set next clears all of it
+        buf["packed_dirty"] = buf["packed"].size
         arrays: dict = {}
         overflow = buf["overflow"][:P]
         overflow[:] = False
@@ -591,24 +623,25 @@ class StagingEncoder:
         self._pack_meta(pk, P, buf, layout)
         return RequestBatch(size=P, arrays=arrays, overflow=overflow,
                             packed=pk, layout=layout,
-                            staged_bytes=P * W)
+                            staged_bytes=P * W, upload_rows=P)
 
-    def _pack_meta(self, pk: np.ndarray, P: int, buf: dict,
+    def _pack_meta(self, pk: np.ndarray, rows: int, buf: dict,
                    layout: PackedLayout) -> None:
-        """Write the metadata tail of every packed row from the side
-        arrays (hot): u16-LE lens columns, big-endian IP bytes, i64-LE
-        asn/port bytes. The side arrays stay authoritative for host
-        consumers; the tail is what the device decodes."""
+        """Write the metadata tail of the first `rows` packed rows from
+        the side arrays (hot): u16-LE lens columns, big-endian IP bytes,
+        i64-LE asn/port bytes. The side arrays stay authoritative for
+        host consumers; the tail is what the device decodes."""
+        pk = pk[:rows]
         for field, off in layout.lens:
-            lens = buf[f"{field}_len"][:P]
+            lens = buf[f"{field}_len"][:rows]
             pk[:, off] = lens & 0xFF
             pk[:, off + 1] = (lens >> 8) & 0xFF
         pk[:, layout.ip_off:layout.ip_off + 16] = \
-            buf["ip"][:P].astype(">u4").view(np.uint8)
+            buf["ip"][:rows].astype(">u4").view(np.uint8)
         pk[:, layout.asn_off:layout.asn_off + 8] = \
-            buf["asn"][:P].view(np.uint8).reshape(P, 8)
+            buf["asn"][:rows].view(np.uint8).reshape(rows, 8)
         pk[:, layout.port_off:layout.port_off + 8] = \
-            buf["remote_port"][:P].view(np.uint8).reshape(P, 8)
+            buf["remote_port"][:rows].view(np.uint8).reshape(rows, 8)
 
     def encode_slots(self, slots: np.ndarray,
                      pad_to: Optional[int] = None) -> RequestBatch:
@@ -678,11 +711,20 @@ class StagingEncoder:
         (true slot length beyond a clamped cap) are flagged for the
         sidecar's interpreter backstop; with unclamped plan caps the
         thresholds equal the specs and no slot row can exceed them
-        (over-spec requests already ride the TRUNCATED/spill flags)."""
+        (over-spec requests already ride the TRUNCATED/spill flags).
+
+        Only the bytes an earlier batch left past this batch's `n` rows
+        are zeroed (the set's `packed_dirty` mark): the `n` rows are
+        overwritten whole, field regions and tail, and the rest of the
+        [P, width] view is zero, as a full clear would leave it. The
+        batch ships its first `upload_rows(n, P)` rows."""
         layout = self._layout
         W = layout.width
         pk = buf["packed"][: P * W].reshape(P, W)
-        pk[:] = 0
+        live, dirty = n * W, buf["packed_dirty"]
+        if dirty > live:
+            buf["packed"][live:dirty] = 0
+        buf["packed_dirty"] = live
         arrays: dict = {}
         overflow = buf["overflow"][:P]
         overflow[:] = False
@@ -720,10 +762,11 @@ class StagingEncoder:
         port[:n] = slots["remote_port"]
         port[n:] = 0
         arrays["remote_port"] = port
-        self._pack_meta(pk, P, buf, layout)
+        self._pack_meta(pk, n, buf, layout)
+        rows = upload_rows(n, P)
         return RequestBatch(size=P, arrays=arrays, overflow=overflow,
                             packed=pk, layout=layout,
-                            staged_bytes=P * W)
+                            staged_bytes=rows * W, upload_rows=rows)
 
 
 def batch_to_contexts(

@@ -1044,6 +1044,20 @@ def make_packed_prefilter_fn(plan: RulesetPlan):
                             gated=gated, masked=masked)
 
 
+def make_pad_fn(batch: int):
+    """Jitted row pad of compact staging: the first H rows of a packed
+    batch, as shipped ([H, width] uint8) -> the [batch, width] buffer
+    the packed program pair takes, zero rows below, on the chip the
+    rows are on. The pair's input is byte for byte the full upload it
+    replaces, so its programs and compile-cache entries stay as they
+    are. One instance a rung (engine/batch.UPLOAD_ROWS); each compiles
+    once per row stride and chip."""
+    def pad_rows(packed):
+        return jnp.pad(packed, ((0, batch - packed.shape[0]), (0, 0)))
+
+    return jax.jit(pad_rows)
+
+
 LANE_NONE = np.int32(2**30)  # "no rule": sorts after every real index
 
 

@@ -870,6 +870,14 @@ class RingSidecar:
                 STAGING_METRICS["pingoo_staged_bytes_total"],
                 labels={"plane": "sidecar", "mode": mode})
             for mode in ("full", "compact")}
+        # and the rows a packed batch ships against the rows the chip
+        # pads them to (`_run_lanes`).
+        self._staged_rows_counter = {
+            kind: REGISTRY.counter(
+                "pingoo_staged_rows_total",
+                STAGING_METRICS["pingoo_staged_rows_total"],
+                labels={"plane": "sidecar", "kind": kind})
+            for kind in ("uploaded", "padded")}
         # Stage-A literal prefilter (docs/PREFILTER.md): the sidecar is
         # the native plane's verdict engine, so it exports the same
         # candidate-rate/skip metrics the Python listener plane does.
@@ -1181,6 +1189,26 @@ class RingSidecar:
         state["replica_tables"] = [state["tables"]] + [
             _place_on(state["tables"], device)
             for device in self._replica_devices[1:]]
+        # The packed batch's row pad, one program a rung, compiled and
+        # run here on every chip at the row stride the encoder will use
+        # (boot, or a swap's compile-ahead), so that no served batch
+        # meets its compile.
+        state["pad_fns"] = {}
+        if state["packed_lane_fn"] is not None and not mesh.active \
+                and self._staging is not None:
+            import jax
+
+            from .engine.batch import UPLOAD_ROWS
+            from .engine.verdict import make_pad_fn
+
+            width = self._staging.packed_width(state["stage_caps"])
+            for rows in UPLOAD_ROWS:
+                if rows < self.max_batch:
+                    pad = _wrap(make_pad_fn(self.max_batch), "pad")
+                    for device in self._replica_devices:
+                        np.asarray(pad(jax.device_put(
+                            np.zeros((rows, width), np.uint8), device)))
+                    state["pad_fns"][rows] = pad
         state["pf_fn"] = None
         state["pf_gated_banks"] = 0
         state["pf_attr"] = None
@@ -1222,6 +1250,7 @@ class RingSidecar:
         self._stage_caps = state.get("stage_caps")
         self._packed_lane_fn = state.get("packed_lane_fn")
         self._packed_pf_fn = state.get("packed_pf_fn")
+        self._pad_fns = state.get("pad_fns") or {}
         if self._staging is not None and self._stage_caps is not None:
             try:
                 self._staging.set_stage_caps(
@@ -1742,6 +1771,8 @@ class RingSidecar:
             arrays = batch.arrays
             if self.mesh.active:
                 arrays = self.mesh.shard_batch(arrays)
+            if batch.upload_rows:
+                rec.stats["upload_rows"] = batch.upload_rows
             sp.next("prefilter")
             self.chaos.stage("dispatch")
             dev = None
@@ -1782,6 +1813,9 @@ class RingSidecar:
                 else "full"].inc(batch.staged_bytes)
             self.sched.observe_dispatch_bytes(
                 batch.staged_bytes, rec.span_ms("prefilter", "dispatch"))
+        if batch.packed is not None:
+            self._staged_rows_counter["uploaded"].inc(batch.upload_rows)
+            self._staged_rows_counter["padded"].inc(batch.size)
         self._scan_columns.note(batch.arrays)
         # Scheduler accounting at launch: occupancy + queue depth, the
         # sidecar's `sched` stage (oldest enqueue -> launch hold on the
@@ -1825,8 +1859,13 @@ class RingSidecar:
                 and not self.mesh.active:
             import jax
 
-            dev_packed = jax.device_put(batch.packed,
+            # Ship the batch's upload height and pad it to the batch on
+            # the chip: the pair reads the bytes a full upload would.
+            rows = batch.upload_rows
+            dev_packed = jax.device_put(batch.packed[:rows],
                                         self._replica_devices[chip])
+            if rows < batch.size:
+                dev_packed = self._pad_fns[rows](dev_packed)  # async
             if self._packed_pf_fn is not None:
                 pf_hits, pf_aux = self._packed_pf_fn(
                     tables, dev_packed, batch.layout)  # async
@@ -2647,6 +2686,10 @@ class RingSidecar:
             "cascade": self._cascade.snapshot(),
             "completions": dict(self._pipe.completions),
             "host_copies": self._pipe.host_copies.value,
+            "staged_bytes": {mode: c.value for mode, c in
+                             self._staged_bytes_counter.items()},
+            "staged_rows": {kind: c.value for kind, c in
+                            self._staged_rows_counter.items()},
             "replicas": self.replicas,
             "replica_batches": {str(d): c.value for d, c in
                                 enumerate(self._pipe.replica_batches)},

@@ -51,9 +51,9 @@ from collections import deque
 from typing import Any, Callable, Optional
 
 # The fn-kind label values the wrappers emit (verdict/lane/prefilter
-# programs, their packed-staging twins under the same label, and the
-# bot-score program).
-COMPILE_FN_KINDS = ("verdict", "lanes", "prefilter", "score")
+# programs, their packed-staging twins under the same label, the
+# packed batch's row pad and the bot-score program).
+COMPILE_FN_KINDS = ("verdict", "lanes", "prefilter", "pad", "score")
 
 # pingoo_compile_ms histogram bounds: sub-ms cache refreshes up to the
 # multi-second cold compiles of a whole lane program.

@@ -359,8 +359,14 @@ BODY_METRICS = {
 # under PINGOO_STAGING=full or when the ruleset pins the field).
 STAGING_METRICS = {
     "pingoo_staged_bytes_total":
-        "request bytes staged to the device for verdict batches, by "
+        "request bytes shipped to the device for verdict batches, by "
         "mode (full = per-field arrays, compact = packed buffer)",
+    # Upload height (docs/EXECUTOR.md "Compact staging"), sidecar only:
+    # per packed batch, the rows shipped and the rows the chip padded
+    # them to.
+    "pingoo_staged_rows_total":
+        "packed batch rows, by kind (uploaded = the rows shipped to the "
+        "device, padded = the batch rows the device padded them to)",
     "pingoo_staging_field_cap":
         "per-field staging width in bytes under the adopted plan "
         "(plan-derived cap, quantized to the pow2 rung ladder)",
@@ -383,7 +389,7 @@ STAGING_METRICS = {
 # Exported by every plane that runs the batched verdict engine
 # (plane="python" listener service, plane="sidecar" ring drainer).
 # `pingoo_compile_total` carries {plane, fn, kind} — fn over
-# obs/perf.COMPILE_FN_KINDS (verdict|lanes|prefilter|score;
+# obs/perf.COMPILE_FN_KINDS (verdict|lanes|prefilter|pad|score;
 # the packed-staging twins report under the same fn label), kind
 # cold|warm (warm = a retrace under live traffic, the recompile-storm
 # alert series); `pingoo_compile_ms` is a {plane, fn} histogram over

@@ -21,6 +21,8 @@ What `--replicas` owes its callers:
     however many batches the other chips run meanwhile;
   * each chip's copy of the tables, and each batch's lanes, live on
     that chip, and warming leaves no compile for the served batches;
+  * a batch that ships fewer rows than it holds is padded on its own
+    chip, by a program the boot compiled there;
   * a hot swap reaches every chip;
   * the boot refuses more chips than the host has, and a mesh beside.
 """
@@ -460,6 +462,100 @@ def test_a_hot_swap_reaches_every_chip(tmp_path, monkeypatch):
     # ... on its own copy of plan B's tables
     assert len(sidecar._replica_tables) == 4
     assert sidecar.plan is plan_b
+
+
+@needs_native
+def test_each_chip_pads_the_rows_shipped_to_it(tmp_path, monkeypatch):
+    """At a 512-row batch, a batch of up to 64 or 256 live rows ships
+    that many and its chip pads them: each pad's input and output live
+    on the batch's chip, `pingoo_staged_rows_total` and
+    `pingoo_staged_bytes_total` add up to what was shipped, every row
+    gets the interpreter's verdict, and after the boot's warm-up no
+    batch at any rung adds to `pingoo_compile_total{plane="sidecar"}`."""
+    from pingoo_tpu.engine.batch import upload_rows
+    from pingoo_tpu.native_ring import Ring, RingSidecar
+    from pingoo_tpu.obs import REGISTRY, schema
+    from pingoo_tpu.obs.perf import (COMPILE_FN_KINDS,
+                                     reset_compile_ledger_for_tests)
+
+    for k in KNOBS:
+        monkeypatch.delenv(k, raising=False)
+    for k, v in STAGING.items():
+        monkeypatch.setenv(k, v)
+    monkeypatch.setenv("PINGOO_PERF_LEDGER", str(tmp_path / "ledger"))
+    reset_compile_ledger_for_tests()
+
+    def compiles():
+        return sum(REGISTRY.counter(
+            "pingoo_compile_total",
+            schema.PERF_METRICS["pingoo_compile_total"],
+            labels={"plane": "sidecar", "fn": fn, "kind": kind}).value
+            for fn in COMPILE_FN_KINDS for kind in ("cold", "warm"))
+
+    batch, waves = 512, (5, 60, 100, 200, 300)
+    _, lists, traffic = _corpus()
+    plan = _plan()
+    rings = [Ring(str(tmp_path / f"ring_{i}"), capacity=1024, create=True)
+             for i in range(N_RINGS)]
+    try:
+        sidecar = RingSidecar(rings, plan, lists, max_batch=batch,
+                              pipeline_depth=DEPTH, replicas=4)
+        sidecar.warm_replicas()
+        assert sorted(sidecar._pad_fns) == [64, 256]
+        pads: list = []     # (rows, input devices, output devices)
+        for rows, pad in list(sidecar._pad_fns.items()):
+            def watched(x, pad=pad, rows=rows):
+                out = pad(x)
+                pads.append((rows, x.devices(), out.devices()))
+                return out
+            sidecar._pad_fns[rows] = watched
+        launched: list = []  # (live rows, chip)
+        dispatch = sidecar._dispatch
+
+        def watched_dispatch(parts, n, *args, **kwargs):
+            entry = dispatch(parts, n, *args, **kwargs)
+            launched.append((n, entry[-1].device))
+            return entry
+
+        sidecar._dispatch = watched_dispatch
+        compiled, st0 = compiles(), sidecar.stats()
+        sent = [{} for _ in rings]
+        total = 0
+        for size in waves:
+            for i in range(total, total + size):
+                r = i % N_RINGS
+                sent[r][_enqueue(rings[r], traffic[i % len(traffic)])] = \
+                    traffic[i % len(traffic)]
+            total += size
+            sidecar.run(max_requests=total)
+        st1 = sidecar.stats()
+        got = [_verdicts(ring) for ring in rings]
+    finally:
+        sidecar.stop()
+        for ring in rings:
+            ring.close()
+        reset_compile_ledger_for_tests()
+    for r in range(N_RINGS):
+        assert {t: v for t, v in got[r].items()} == \
+            {t: [_want(plan, tup)] for t, tup in sent[r].items()}
+    assert compiled > 0 and compiles() == compiled  # the boot's alone
+    shipped = [upload_rows(n, batch) for n, _ in launched]
+    assert {64, 256, batch} <= set(shipped)
+    local = sidecar._replica_devices
+    padded_on = [chip for (n, chip), rows in zip(launched, shipped)
+                 if rows < batch]
+    assert [rows for rows in shipped if rows < batch] == \
+        [rows for rows, _, _ in pads]
+    assert set(padded_on) == {0, 1, 2, 3}
+    for chip, (_, into, out) in zip(padded_on, pads):
+        assert into == out == {local[chip]}
+    width = sidecar._staging.packed_width(sidecar._stage_caps)
+    rows_delta = {k: st1["staged_rows"][k] - st0["staged_rows"][k]
+                  for k in ("uploaded", "padded")}
+    assert rows_delta == {"uploaded": sum(shipped),
+                          "padded": batch * len(launched)}
+    assert st1["staged_bytes"]["compact"] - st0["staged_bytes"]["compact"] \
+        == sum(shipped) * width
 
 
 def _swap_request(i):

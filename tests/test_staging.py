@@ -18,6 +18,9 @@ Covers the tentpole's cap-soundness contract and the satellites:
   * Sidecar end-to-end: full|compact served-verdict checksums through
     real shm rings (ring wraparound, spill slots) and a mid-run
     hot-swap onto a plan with WIDER caps.
+  * The upload height: a slot batch ships its first 64, 256 or all of
+    its rows, the chip pads them, and what the program pair reads is
+    the full upload byte for byte, whatever the set held before.
   * The staged-bytes-bucketed dispatch EWMA.
   * The analyze-lint hot registration of the packed encode path, with
     a mutation proof that a fresh per-batch allocation there fails
@@ -45,6 +48,7 @@ from pingoo_tpu.engine.batch import (
     resolve_stage_caps,
     resolve_staging_mode,
     stage_overflow_thresholds,
+    upload_rows,
 )
 from pingoo_tpu.sched.scheduler import CostModel, _pow2_kb_bucket
 from test_parity import LISTS, RULE_SOURCES, make_rules, random_requests
@@ -585,3 +589,145 @@ class TestStagingLintRegistry:
         findings, _ = lint.lint_source(mutated,
                                        "pingoo_tpu/engine/batch.py")
         assert any(f.rule == "hot-alloc" for f in findings), findings
+
+
+# -- the upload height: ship the live rows, pad them on the chip -----------
+
+UPLOAD_BATCH = 1024
+# (live rows, rows shipped): each side of each rung
+UPLOAD_CASES = [(1, 64), (7, 64), (63, 64), (64, 64), (65, 256),
+                (255, 256), (256, 256), (257, 1024), (1024, 1024)]
+
+
+def _slots_of(reqs):
+    """The shm slot rows the native plane enqueues for `reqs`."""
+    import ipaddress
+
+    from pingoo_tpu.engine.batch import SLOT_LEN_KEYS
+
+    out = np.zeros(len(reqs), dtype=native_ring.REQUEST_SLOT_DTYPE)
+    for i, req in enumerate(reqs):
+        for field, key in SLOT_LEN_KEYS.items():
+            raw = getattr(req, field).encode("latin-1")[
+                :native_ring.FIELD_CAPS[field]]
+            out[field][i, :len(raw)] = np.frombuffer(raw, dtype=np.uint8)
+            out[key][i] = len(raw)
+        ip = ipaddress.ip_address(req.ip)
+        if ip.version == 4:
+            ip = ipaddress.IPv6Address(f"::ffff:{ip}")
+        out["ip"][i] = np.frombuffer(ip.packed, dtype=np.uint8)
+        out["asn"][i] = req.asn
+        out["remote_port"][i] = req.remote_port
+        out["country"][i] = req.country.encode()
+    return out
+
+
+class _UploadRig:
+    """A plan with routes, its packed program pair (attribution lane
+    on) and a pad program a rung, built once: the sidecar's encoder
+    and programs at the served batch of 1,024 rows."""
+
+    def __init__(self):
+        import jax
+
+        from pingoo_tpu.engine.batch import UPLOAD_ROWS
+        from pingoo_tpu.engine.verdict import (make_packed_lane_fn,
+                                               make_packed_prefilter_fn,
+                                               make_pad_fn)
+        from pingoo_tpu.expr import compile_expression
+
+        saved = os.environ.get("PINGOO_STAGING")
+        os.environ["PINGOO_STAGING"] = "compact"
+        try:
+            self.plan = compile_ruleset(
+                make_rules(RULE_SOURCES), LISTS,
+                routes=[("api", compile_expression(
+                    'http_request.host == "api.example.com"')),
+                    ("app", None)])
+            self.caps = resolve_stage_caps(self.plan)
+        finally:
+            if saved is None:
+                os.environ.pop("PINGOO_STAGING", None)
+            else:
+                os.environ["PINGOO_STAGING"] = saved
+        self.thresholds = stage_overflow_thresholds(self.plan, self.caps)
+        self.tables = jax.device_put(self.plan.device_tables())
+        self.pf = make_packed_prefilter_fn(self.plan)
+        self.lane_fn = make_packed_lane_fn(
+            self.plan, service_groups=[["api", "app"]], with_rule_hits=True)
+        self.pad = {rows: make_pad_fn(UPLOAD_BATCH) for rows in UPLOAD_ROWS}
+
+    def encoder(self):
+        specs = dict(native_ring.FIELD_CAPS, country=2)
+        return StagingEncoder(UPLOAD_BATCH, field_specs=specs, nbuf=1,
+                              stage_caps=self.caps,
+                              overflow_thresholds=self.thresholds)
+
+    def lanes(self, dev_packed, layout, n):
+        pf_hits = pf_aux = None
+        if self.pf is not None:
+            pf_hits, pf_aux = self.pf.fn(self.tables, dev_packed, layout)
+        return np.asarray(self.lane_fn(self.tables, dev_packed, layout,
+                                       pf_hits, np.int32(n), pf_aux))
+
+
+@pytest.fixture(scope="module")
+def upload_rig():
+    return _UploadRig()
+
+
+class TestUploadHeight:
+    @pytest.mark.parametrize("n,rows", UPLOAD_CASES)
+    def test_the_padded_upload_is_the_full_upload(self, upload_rig, n,
+                                                  rows):
+        """A slot batch of `n` rows, encoded into a set a full batch of
+        longer rows dirtied, ships its first `rows` rows: padded on the
+        device they equal the full upload of a freshly zeroed set byte
+        for byte, the host views equal that set's, every byte past the
+        live rows is zero, and the program pair reads the same verdict,
+        route, cascade and attribution rows from both."""
+        import jax
+
+        rig = upload_rig
+        slots = _slots_of(random_requests(random.Random(n), n))
+        enc = rig.encoder()
+        enc.encode_slots(_slots_of(random_requests(
+            random.Random(41), UPLOAD_BATCH)), pad_to=UPLOAD_BATCH)
+        batch = enc.encode_slots(slots, pad_to=UPLOAD_BATCH)
+        ref = rig.encoder().encode_slots(slots, pad_to=UPLOAD_BATCH)
+        width = batch.layout.width
+        assert upload_rows(n, UPLOAD_BATCH) == batch.upload_rows == rows
+        assert batch.staged_bytes == rows * width
+        assert np.array_equal(batch.packed, ref.packed)
+        for key, arr in ref.arrays.items():
+            assert np.array_equal(batch.arrays[key], arr), key
+        assert not enc._bufs[0]["packed"][n * width:].any()
+
+        shipped = jax.device_put(batch.packed[:rows])
+        assert shipped.shape == (rows, width)
+        padded = rig.pad[rows](shipped) if rows < UPLOAD_BATCH else shipped
+        full = jax.device_put(ref.packed)
+        assert (padded.shape, padded.dtype) == (full.shape, full.dtype)
+        assert np.array_equal(np.asarray(padded), np.asarray(full))
+        got = rig.lanes(padded, batch.layout, n)
+        want = rig.lanes(full, ref.layout, n)
+        assert got.shape[0] > 4  # verdict, route, cascade, attribution
+        assert np.array_equal(got, want)
+
+    def test_a_swap_of_caps_clears_what_the_old_stride_left(self,
+                                                            upload_rig):
+        """The set's mark is in bytes of its flat buffer: rows written
+        under one row stride leave no byte behind under another."""
+        rig = upload_rig
+        enc, fresh = rig.encoder(), rig.encoder()
+        narrow = {f: 16 for f in STRING_FIELDS}
+        fresh.set_stage_caps(narrow)
+        enc.encode_slots(_slots_of(random_requests(random.Random(3), 300)),
+                         pad_to=UPLOAD_BATCH)
+        enc.set_stage_caps(narrow)
+        slots = _slots_of(random_requests(random.Random(4), 5))
+        batch = enc.encode_slots(slots, pad_to=UPLOAD_BATCH)
+        ref = fresh.encode_slots(slots, pad_to=UPLOAD_BATCH)
+        assert batch.layout.width < build_packed_layout(rig.caps).width
+        assert np.array_equal(batch.packed, ref.packed)
+        assert not enc._bufs[0]["packed"][5 * batch.layout.width:].any()

@@ -170,6 +170,7 @@ SHAPE_QUANTIZERS = frozenset({
     "bucket_arrays",     # engine/batch.py: bucket every field axis
     "pad_batch",         # engine/batch.py: pad batch axis to a rung
     "quantize_stage_cap",  # compiler/plan.py: staging-width rungs
+    "upload_rows",       # engine/batch.py: packed upload-height rungs
     "_pow2_size",        # service wrapper over pow2_batch_size
 })
 

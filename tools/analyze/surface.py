@@ -43,6 +43,7 @@ MAKE_FN_LABELS = {
     "make_packed_prefilter_fn": "prefilter",
     "make_lane_fn": "lanes",
     "make_packed_lane_fn": "lanes",
+    "make_pad_fn": "pad",
 }
 
 PLANES = ("python", "sidecar")
