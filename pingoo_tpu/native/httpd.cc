@@ -76,6 +76,7 @@
 #include <cstdio>
 #include <cstdlib>
 #include <cstring>
+#include <memory>
 #include <new>
 #include <string>
 #include <unordered_map>
@@ -763,6 +764,17 @@ struct UpTarget {
   std::string sni;
 };
 
+// A request's chain (Server's loop clock, µs; 0 = not reached): the
+// first byte read, enqueued on the ring or decided before it, verdict
+// applied, upstream response whole. It ends at the last byte written.
+struct Chain {
+  bool live = false;  // counted in `requests`, not ended yet
+  uint64_t first_us = 0, enq_us = 0, applied_us = 0, up_us = 0;
+  uint64_t ticket = UINT64_MAX;  // the ring ticket: joins the sidecar's
+  uint8_t verdict = 0;           // raw verdict byte
+  uint8_t decided = 3;           // 0 proxy 1 block 2 captcha 3 fail-open
+};
+
 // One multiplexed HTTP/2 request in flight on a connection.
 struct SockRef;
 
@@ -814,6 +826,7 @@ struct H2Stream {
   uint64_t enq_ms = 0;
   time_t verdict_at = 0;
   SockRef* up_ref = nullptr;  // heap ref handed to epoll (deferred free)
+  Chain ch;
 };
 
 std::string strip_host_port(const std::string& value);
@@ -1273,6 +1286,7 @@ struct Conn {
   // client flow-control stall can defer DATA past the next stream).
   std::unordered_map<int32_t, std::pair<std::string, size_t>> h2_send;
   time_t verdict_at = 0;           // when the active ticket was enqueued
+  Chain ch;                        // the h1 request cycle's
 };
 
 class Server;
@@ -1493,6 +1507,18 @@ class Server {
         slot_(new (&slots_[worker_]) WorkerSlot()),  // a restart counts anew
         stats_(slot_->stats),
         release_(slot_->release) {
+    if (worker_block != nullptr) {
+      rings_ = reinterpret_cast<PassRing*>(static_cast<char*>(worker_block) +
+                                           sizeof(WorkerSlot) * workers_);
+    } else {
+      own_ring_.reset(new PassRing());
+      rings_ = own_ring_.get();
+    }
+    pass_ring_ = new (&rings_[worker_]) PassRing();
+    open_proc_readings();
+    mark_us_ = now_us_ = last_pass_us_ = entry_start_us_ = mono_us();
+    wall_off_s_ = ::time(nullptr) - static_cast<time_t>(now_us_ / 1000000);
+    now_ = wall_now();
     if (captcha_upstream) {
       captcha_upstream_ = *captcha_upstream;
       has_captcha_upstream_ = true;
@@ -1501,7 +1527,8 @@ class Server {
 
   // Bytes of the shared block for `workers` slots (main() maps it).
   static size_t worker_block_bytes(int workers) {
-    return sizeof(WorkerSlot) * static_cast<size_t>(workers);
+    return (sizeof(WorkerSlot) + sizeof(PassRing)) *
+           static_cast<size_t>(workers);
   }
 
   // -- service routing -------------------------------------------------------
@@ -1732,11 +1759,16 @@ class Server {
       return;
     }
     if (!keep) {
-      c->state = ConnState::kClosing;
-      if (c->outbuf.empty()) mark_close(c);
-      else update_client_events(c);
+      c->state = ConnState::kClosing;  // its chain ends when outbuf drains
+      if (c->outbuf.empty()) {
+        chain_end(c->ch, c->req);
+        mark_close(c);
+      } else {
+        update_client_events(c);
+      }
       return;
     }
+    chain_end(c->ch, c->req);  // what is left of outbuf drains meanwhile
     begin_request_cycle(c);
   }
 
@@ -1987,7 +2019,9 @@ class Server {
     doomed_.push_back(c);
   }
 
-  void flush_doomed() {
+  // -> whether anything was closed (the loop account's `close`).
+  bool flush_doomed() {
+    if (doomed_.empty() && doomed_refs_.empty()) return false;
     for (Conn* c : doomed_) {
       if (c->h2 != nullptr) {
         nghttp2_session_del(c->h2);
@@ -2018,9 +2052,8 @@ class Server {
       delete r;
     }
     doomed_refs_.clear();
+    return true;
   }
-
-  void set_now(time_t t) { now_ = t; }
 
   void queue_ssl_resume(Conn* c, int32_t sid) {
     for (const auto& e : ssl_resume_)
@@ -2031,8 +2064,9 @@ class Server {
   // Deliver reads for data already decrypted inside SSL objects: epoll
   // cannot signal it (nothing is on the fd), so update_*_events queues
   // the link and the main loop drains the queue after each batch.
-  void process_ssl_resume() {
-    if (ssl_resume_.empty()) return;
+  // -> whether a link was resumed (the loop account's `tls`).
+  bool process_ssl_resume() {
+    if (ssl_resume_.empty()) return false;
     std::vector<std::pair<Conn*, int32_t>> work;
     work.swap(ssl_resume_);
     for (const auto& e : work) {
@@ -2045,6 +2079,7 @@ class Server {
         h2_stream_upstream_event(c, e.second, EPOLLIN);
       }
     }
+    return true;
   }
 
   bool awaiting_verdicts() const {
@@ -2056,6 +2091,38 @@ class Server {
   // (SURVEY §5 calls the metrics surface a build requirement): counters
   // + a verdict-wait histogram, served at /__pingoo/metrics on both
   // protocols. The reference ships no metrics endpoint at all.
+
+  // The worker's own clock (docs/OBSERVABILITY.md "Workers"). Every pass
+  // of the event loop is cut at its phase boundaries, one
+  // CLOCK_MONOTONIC stamp each, and each phase's time is added to
+  // loop_us[phase]: the phases partition the worker's wall time. A pass
+  // that handles no event, applies no verdict and sweeps nothing goes
+  // whole to `wait` (the busy-poll spin) and takes one stamp.
+  enum LoopPhase {
+    kPhWait,       // inside epoll_wait, and the empty passes
+    kPhVerdicts,   // drain_verdicts
+    kPhSupervise,  // liveness check, verdict deadlines, publish_slot
+    kPhAccept,     // the accept loop and add_client
+    kPhClient,     // handle() on a client socket
+    kPhUpstream,   // handle() on an upstream socket
+    kPhTls,        // process_ssl_resume
+    kPhClose,      // flush_doomed
+    kPhSweep,      // the once-a-second sweeps and maybe_reload
+    kPhases
+  };
+  static constexpr const char* kPhaseNames[kPhases] = {
+      "wait", "verdicts", "supervise", "accept", "client",
+      "upstream", "tls", "close", "sweep"};
+  static constexpr const char* kPhaseSpans[kPhases] = {
+      "httpd/wait", "httpd/verdicts", "httpd/supervise", "httpd/accept",
+      "httpd/client", "httpd/upstream", "httpd/tls", "httpd/close",
+      "httpd/sweep"};
+  // A request's chain: its stamps (first byte read, enqueued or decided
+  // before the ring, verdict applied, upstream response whole, last
+  // byte written) cut its residence into these segments.
+  enum ChainSeg { kSegRead, kSegVerdict, kSegUpstream, kSegReply, kSegs };
+  static constexpr const char* kSegNames[kSegs] = {"read", "verdict",
+                                                   "upstream", "reply"};
 
   struct Stats {
     uint64_t requests = 0;        // parsed requests (h1 cycles + h2 streams)
@@ -2087,6 +2154,16 @@ class Server {
     // surface folds the last two into its legacy "inf" key.
     uint64_t wait_hist[8] = {0, 0, 0, 0, 0, 0, 0, 0};
     uint64_t wait_sum_ms = 0;     // for the histogram _sum series
+    // The loop account and the request chain (µs on CLOCK_MONOTONIC).
+    uint64_t loop_us[kPhases] = {};
+    uint64_t passes = 0;          // event-loop passes
+    uint64_t empty_passes = 0;    // of them, all `wait`
+    uint64_t chain_us[kSegs] = {};
+    uint64_t chain_requests = 0;  // requests whose chain ended (answered)
+    uint64_t chain_upstream_requests = 0;  // of them, with an upstream leg
+    // this worker's run-queue wait (/proc/thread-self/schedstat, second
+    // field), read once a second; published only where the file exists
+    uint64_t runqueue_us = 0;
   };
 
   // Release witness (ISSUE 30, docs/OBSERVABILITY.md "Release
@@ -2127,9 +2204,15 @@ class Server {
     uint64_t heartbeat_late = 0;
     bool heartbeat_is_late = false;
     uint64_t loop_gap_max_ms = 0;
-    uint64_t last_pass_ms = 0;
     uint64_t log_window_ms = 0, log_lines = 0;
+    // The wedge's witness: a pass gap of kWedgeMs or more reads, over
+    // the gap, this worker's run-queue wait (a descheduled vCPU) and
+    // its delayacct_blkio_ticks (/proc/thread-self/stat field 42:
+    // writeback); the newest such gap is kept here and logged.
+    uint64_t wedges = 0, wedge_gap_ms = 0, wedge_at_ms = 0,
+             wedge_runqueue_us = 0, wedge_blkio_ticks = 0;
   };
+  static constexpr uint64_t kWedgeMs = 250;
 
   // One counter surface for N workers (ISSUE 31, docs/OBSERVABILITY.md
   // "Workers"). --native-workers N runs N of these processes on one
@@ -2151,6 +2234,23 @@ class Server {
     uint64_t awaiting = 0, body_awaiting = 0, connections = 0,
              pooled_upstreams = 0, degraded = 0, sidecar_up = 0,
              sidecar_epoch = 0, published_ms = 0;
+    uint64_t has_runqueue = 0;  // stats.runqueue_us was read
+    uint64_t loop_at_us = 0;    // the stamp loop_us accounts up to
+  };
+
+  // Each worker's recent passes, in the shared block after the slots
+  // (any worker renders all of them at /__pingoo/timeline): a pass that
+  // did work is one entry, its `wait` the time since the previous
+  // entry, so consecutive empty passes merge into the next entry.
+  static constexpr size_t kPassRingN = 4096;
+  struct PassEntry {
+    uint64_t start_us = 0;        // CLOCK_MONOTONIC µs: its wait begins
+    uint32_t us[kPhases] = {};    // each phase's time, µs
+    uint32_t passes = 0;          // loop passes merged into it
+  };
+  struct alignas(64) PassRing {
+    uint64_t head = 0;  // entries written; entry i is at e[i % kPassRingN]
+    PassEntry e[kPassRingN];
   };
 
   // A sibling's slot as it stands: word by word (its owner is writing),
@@ -2175,8 +2275,10 @@ class Server {
   // This worker's ring telemetry and gauges into its slot: once a
   // millisecond from the event loop, and before it answers a scrape.
   void publish_slot(bool force = false) {
-    uint64_t now = now_ms();
+    uint64_t now = pass_ms();
     if (!force && now == slot_->published_ms) return;
+    if (!pass_worked_) account(kPhWait, now_us_);  // an idle worker's too
+    slot_->loop_at_us = mark_us_;
     pingoo_ring_telemetry_snapshot(ring_, slot_->tel);
     size_t pooled = 0;
     for (const auto& kv : upstream_pool_) pooled += kv.second.size();
@@ -2209,7 +2311,7 @@ class Server {
     }
     Release& r = sum.release;
     r.heartbeat_late = r.oldest_age_max_ms = r.heartbeat_age_max_ms =
-        r.loop_gap_max_ms = 0;
+        r.loop_gap_max_ms = r.wedges = r.wedge_at_ms = 0;
     std::memset(r.events, 0, sizeof(r.events));
     std::memset(r.tickets, 0, sizeof(r.tickets));
     auto add = [](uint64_t* into, const uint64_t* from, size_t words) {
@@ -2227,6 +2329,13 @@ class Server {
       larger(&r.oldest_age_max_ms, s.release.oldest_age_max_ms);
       larger(&r.heartbeat_age_max_ms, s.release.heartbeat_age_max_ms);
       larger(&r.loop_gap_max_ms, s.release.loop_gap_max_ms);
+      r.wedges += s.release.wedges;
+      if (s.release.wedge_at_ms > r.wedge_at_ms) {  // the newest wedge
+        r.wedge_at_ms = s.release.wedge_at_ms;
+        r.wedge_gap_ms = s.release.wedge_gap_ms;
+        r.wedge_runqueue_us = s.release.wedge_runqueue_us;
+        r.wedge_blkio_ticks = s.release.wedge_blkio_ticks;
+      }
       if (w == 0) continue;  // `sum` began as worker 0's slot
       add(reinterpret_cast<uint64_t*>(&sum.stats),
           reinterpret_cast<const uint64_t*>(&s.stats),
@@ -2241,15 +2350,178 @@ class Server {
       larger(&sum.degraded, s.degraded);
       if (s.sidecar_up < sum.sidecar_up) sum.sidecar_up = s.sidecar_up;
       larger(&sum.sidecar_epoch, s.sidecar_epoch);
+      larger(&sum.has_runqueue, s.has_runqueue);
     }
     return sum;
   }
 
-  static uint64_t now_ms() {
+  // -- the loop clock ----------------------------------------------------------
+  // main() calls pass_begin() when epoll_wait returns (the one stamp an
+  // empty pass takes), phase_done(p) after each piece of work of phase
+  // p (a stamp each), and pass_end(). Every time the pass needs
+  // (time(), the ms clock of the deadlines, the liveness check, the
+  // slot's publication, a request's chain) reads now_us_, the stamp of
+  // the running phase's start.
+
+  static uint64_t mono_us() {
     timespec ts;
     clock_gettime(CLOCK_MONOTONIC, &ts);
-    return static_cast<uint64_t>(ts.tv_sec) * 1000 +
-           static_cast<uint64_t>(ts.tv_nsec) / 1000000;
+    return static_cast<uint64_t>(ts.tv_sec) * 1000000 +
+           static_cast<uint64_t>(ts.tv_nsec) / 1000;
+  }
+
+  uint64_t now_us() const { return now_us_; }
+  uint64_t pass_ms() const { return now_us_ / 1000; }
+  time_t wall_now() const {
+    return wall_off_s_ + static_cast<time_t>(now_us_ / 1000000);
+  }
+
+  void pass_begin() {
+    uint64_t t = mono_us();
+    uint64_t gap_ms = (t - last_pass_us_) / 1000;
+    if (gap_ms > release_.loop_gap_max_ms) release_.loop_gap_max_ms = gap_ms;
+    if (gap_ms >= kWedgeMs) note_wedge(gap_ms, t / 1000);
+    last_pass_us_ = now_us_ = t;
+    stats_.passes++;
+    entry_passes_++;
+    pass_worked_ = false;
+    now_ = wall_now();
+  }
+
+  void account(int phase, uint64_t t) {
+    uint64_t d = t - mark_us_;
+    stats_.loop_us[phase] += d;
+    entry_us_[phase] += d;
+    mark_us_ = now_us_ = t;
+  }
+
+  // The work since the last boundary was phase p's; on the pass's first
+  // work, the wait before it ends at the pass stamp.
+  void phase_done(int p) {
+    if (!pass_worked_) {
+      account(kPhWait, now_us_);
+      pass_worked_ = true;
+    }
+    account(p, mono_us());
+  }
+
+  // An empty pass leaves its time to `wait`, accounted by the next pass
+  // that works (or by publish_slot, so a sibling's scrape is current);
+  // a pass that worked closes its entry in the pass ring.
+  void pass_end() {
+    if (!pass_worked_) {
+      stats_.empty_passes++;
+      return;
+    }
+    uint64_t head = pass_ring_->head;
+    PassEntry& e = pass_ring_->e[head % kPassRingN];
+    e.start_us = entry_start_us_;
+    for (int p = 0; p < kPhases; ++p) {
+      e.us[p] = entry_us_[p] > UINT32_MAX
+                    ? UINT32_MAX
+                    : static_cast<uint32_t>(entry_us_[p]);
+      entry_us_[p] = 0;
+    }
+    e.passes = entry_passes_;
+    __atomic_store_n(&pass_ring_->head, head + 1, __ATOMIC_RELEASE);
+    entry_passes_ = 0;
+    entry_start_us_ = mark_us_;
+  }
+
+  // Once a second (the sweep's second), from the pass stamp: the wall
+  // clock's offset and this worker's run-queue wait.
+  bool second_changed() {
+    uint64_t s = now_us_ / 1000000;
+    if (s == proc_read_s_) return false;
+    proc_read_s_ = s;
+    wall_off_s_ = ::time(nullptr) - static_cast<time_t>(s);
+    now_ = wall_now();
+    uint64_t ns;
+    if (read_schedstat(&ns)) {
+      runqueue_ns_ = ns;
+      stats_.runqueue_us = ns / 1000;
+      slot_->has_runqueue = 1;
+    }
+    return true;
+  }
+
+  // /proc/thread-self/{schedstat,stat}, opened once: this thread's
+  // numbers, read with pread (no path walk a second).
+  void open_proc_readings() {
+    schedstat_fd_ = open("/proc/thread-self/schedstat", O_RDONLY | O_CLOEXEC);
+    stat_fd_ = open("/proc/thread-self/stat", O_RDONLY | O_CLOEXEC);
+    uint64_t v;
+    if (read_schedstat(&v)) runqueue_ns_ = v;
+    if (read_blkio(&v)) blkio_ticks_ = v;
+  }
+
+  // schedstat: time on the CPU, time waiting on a run queue (ns), and
+  // timeslices run; the second field is the reading.
+  bool read_schedstat(uint64_t* wait_ns) const {
+    if (schedstat_fd_ < 0) return false;
+    char buf[128];
+    ssize_t n = pread(schedstat_fd_, buf, sizeof(buf) - 1, 0);
+    if (n <= 0) return false;
+    buf[n] = 0;
+    unsigned long long run = 0, wait = 0;
+    if (std::sscanf(buf, "%llu %llu", &run, &wait) != 2) return false;
+    *wait_ns = wait;
+    return true;
+  }
+
+  // stat field 42, delayacct_blkio_ticks: the fields after the
+  // parenthesised name, which may hold spaces, start at field 3.
+  bool read_blkio(uint64_t* ticks) const {
+    if (stat_fd_ < 0) return false;
+    char buf[1024];
+    ssize_t n = pread(stat_fd_, buf, sizeof(buf) - 1, 0);
+    if (n <= 0) return false;
+    buf[n] = 0;
+    const char* p = std::strrchr(buf, ')');
+    if (p == nullptr) return false;
+    int field = 2;
+    while (*p && field < 42) {
+      if (*p == ' ') ++field;
+      ++p;
+    }
+    if (field != 42) return false;
+    *ticks = std::strtoull(p, nullptr, 10);
+    return true;
+  }
+
+  // A pass gap of kWedgeMs or more: what this thread waited for over it.
+  void note_wedge(uint64_t gap_ms, uint64_t at_ms) {
+    uint64_t rq_ns = runqueue_ns_, ticks = blkio_ticks_;
+    bool have_rq = read_schedstat(&rq_ns);
+    bool have_io = read_blkio(&ticks);
+    Release& r = release_;
+    r.wedges++;
+    r.wedge_gap_ms = gap_ms;
+    r.wedge_at_ms = at_ms;
+    r.wedge_runqueue_us = have_rq ? (rq_ns - runqueue_ns_) / 1000 : 0;
+    r.wedge_blkio_ticks = have_io ? ticks - blkio_ticks_ : 0;
+    runqueue_ns_ = rq_ns;
+    blkio_ticks_ = ticks;
+    std::fprintf(stderr,
+                 "pingoo-httpd: WEDGE pass gap %llu ms (run-queue wait %s%llu "
+                 "us over the gap since the last reading, blkio ticks %s%llu, "
+                 "at %llu ms, worker %d of %d)\n",
+                 static_cast<unsigned long long>(gap_ms), have_rq ? "" : "n/a ",
+                 static_cast<unsigned long long>(r.wedge_runqueue_us),
+                 have_io ? "" : "n/a ",
+                 static_cast<unsigned long long>(r.wedge_blkio_ticks),
+                 static_cast<unsigned long long>(at_ms), worker_, workers_);
+  }
+
+  // The ring header's liveness words, with this pass's stamp for `now`
+  // (pingoo_ring_liveness reads the clock again).
+  void ring_liveness(uint64_t lv[5]) const {
+    const auto* h = static_cast<const PingooRingHeader*>(ring_);
+    lv[0] = __atomic_load_n(&h->sidecar_epoch, __ATOMIC_ACQUIRE);
+    lv[1] = __atomic_load_n(&h->sidecar_heartbeat_ms, __ATOMIC_RELAXED);
+    lv[2] = __atomic_load_n(&h->posted_floor, __ATOMIC_RELAXED);
+    lv[3] = __atomic_load_n(&h->req_tail, __ATOMIC_RELAXED);
+    lv[4] = pass_ms();
   }
 
   void record_wait(uint64_t ms) {
@@ -2299,6 +2571,11 @@ class Server {
     kv_u64("heartbeat_age_max_ms", rel.heartbeat_age_max_ms);
     kv_u64("heartbeat_late", rel.heartbeat_late);
     kv_u64("loop_gap_max_ms", rel.loop_gap_max_ms);
+    kv_u64("wedges", rel.wedges);
+    kv_u64("wedge_gap_ms", rel.wedge_gap_ms);
+    kv_u64("wedge_at_ms", rel.wedge_at_ms);
+    kv_u64("wedge_runqueue_us", rel.wedge_runqueue_us);
+    kv_u64("wedge_blkio_ticks", rel.wedge_blkio_ticks);
     kv_u64("worker", static_cast<uint64_t>(worker));  // of the last_* event
     out += "}";
     return out;
@@ -2323,6 +2600,22 @@ class Server {
       out += key;
       out += "\": ";
       out += std::to_string(v);
+    };
+    // the loop account and the chain: a worker's, or their sums
+    auto clock_keys = [&out, &kv_u64](const Stats& s, uint64_t has_rq) {
+      out += ", \"loop_us\": {";
+      for (int p = 0; p < kPhases; ++p)
+        kv_u64(kPhaseNames[p], s.loop_us[p], p == 0);
+      out += "}";
+      kv_u64("passes", s.passes);
+      kv_u64("empty_passes", s.empty_passes);
+      out += ", \"chain_us\": {";
+      for (int g = 0; g < kSegs; ++g)
+        kv_u64(kSegNames[g], s.chain_us[g], g == 0);
+      out += "}";
+      kv_u64("chain_requests", s.chain_requests);
+      kv_u64("chain_upstream_requests", s.chain_upstream_requests);
+      if (has_rq) kv_u64("runqueue_us", s.runqueue_us);
     };
     kv_u64("requests", st.requests, true);
     kv_u64("blocked", st.blocked);
@@ -2376,6 +2669,7 @@ class Server {
     kv_u64("verdict_post_full", tel[6]);
     kv_u64("wait_sum_ms", tel[7]);
     out += "}";
+    clock_keys(st, sum.has_runqueue);
     // which worker answered, and each worker's own numbers: the totals
     // above are their sums (depth_hwm, loop_gap_max_ms: their maxima)
     kv_u64("workers", static_cast<uint64_t>(workers_));
@@ -2396,6 +2690,8 @@ class Server {
       kv_u64("awaiting", s.awaiting);
       kv_u64("connections", s.connections);
       kv_u64("loop_gap_max_ms", s.release.loop_gap_max_ms);
+      clock_keys(s.stats, s.has_runqueue);
+      kv_u64("loop_at_us", s.loop_at_us);
       out += ", \"ring\": {";
       kv_u64("enqueued", s.tel[0], true);
       kv_u64("depth", s.tel[3]);
@@ -2581,6 +2877,10 @@ class Server {
     uint8_t decided = 0;           // 0 proxy 1 block 2 captcha 3 fail-open
     char method[8] = {0};
     char path[48] = {0};           // sanitized prefix, for humans
+    // the chain: first byte read and last byte written (µs), and the
+    // segments between, which add up to end_us - first_us
+    uint64_t first_us = 0, end_us = 0;
+    uint32_t seg_us[kSegs] = {};
   };
   static constexpr size_t kFlightN = 256;
   FlightEntry flight_[kFlightN];
@@ -2594,13 +2894,51 @@ class Server {
     return h;
   }
 
-  void flight_record(const Parsed& req, uint64_t ticket, uint64_t enq_ms,
-                     uint8_t verdict, uint8_t decided) {
+  // A request's chain ends: its segments into the counters and the
+  // flight recorder. The stamps are the loop clock's, so they only grow.
+  void chain_end(Chain& ch, const Parsed& req) {
+    if (!ch.live) return;
+    ch.live = false;
+    uint64_t end = now_us_;
+    uint64_t first = ch.first_us ? ch.first_us : end;
+    uint64_t enq = std::max(first, ch.enq_us ? ch.enq_us : first);
+    uint64_t applied = std::max(enq, ch.applied_us ? ch.applied_us : enq);
+    uint64_t up = std::max(applied, ch.up_us ? ch.up_us : applied);
+    end = std::max(end, up);
+    uint64_t seg[kSegs] = {enq - first, applied - enq, up - applied,
+                           end - up};
+    for (int g = 0; g < kSegs; ++g) stats_.chain_us[g] += seg[g];
+    stats_.chain_requests++;
+    if (ch.up_us) stats_.chain_upstream_requests++;
+    FlightEntry& e = flight_record(
+        req, ch.ticket, ch.ticket == UINT64_MAX ? 0 : seg[kSegVerdict] / 1000,
+        ch.verdict, ch.decided);
+    e.first_us = first;
+    e.end_us = end;
+    for (int g = 0; g < kSegs; ++g)
+      e.seg_us[g] = seg[g] > UINT32_MAX ? UINT32_MAX
+                                        : static_cast<uint32_t>(seg[g]);
+  }
+
+  // The verdict (or the decision before the ring) reached the request.
+  void chain_decided(Chain& ch, uint64_t ticket, uint8_t verdict,
+                     uint8_t decided) {
+    if (!ch.applied_us) ch.applied_us = now_us_;
+    ch.ticket = ticket;
+    ch.verdict = verdict;
+    ch.decided = decided;
+  }
+
+  FlightEntry& flight_record(const Parsed& req, uint64_t ticket,
+                             uint64_t wait_ms, uint8_t verdict,
+                             uint8_t decided) {
     FlightEntry& e = flight_[flight_next_++ % kFlightN];
-    uint64_t now = now_ms();
+    uint64_t now = pass_ms();
     e.ticket = ticket;
-    e.wait_ms = enq_ms ? now - enq_ms : 0;
+    e.wait_ms = wait_ms;
     e.ts_ms = now;
+    e.first_us = e.end_us = 0;
+    std::memset(e.seg_us, 0, sizeof(e.seg_us));
     uint32_t h = 2166136261u;
     h = fnv1a(h, req.method);
     h = fnv1a(h, req.host);
@@ -2620,6 +2958,7 @@ class Server {
     e.path[n] = 0;
     e.verdict = verdict;
     e.decided = decided;
+    return e;
   }
 
   std::string flightrecorder_json() {
@@ -2646,7 +2985,12 @@ class Server {
              ", \"verdict\": " + std::to_string(e.verdict) +
              ", \"decided\": " + std::to_string(e.decided) +
              ", \"method\": \"" + e.method + "\", \"path\": \"" + e.path +
-             "\"}";
+             "\", \"first_us\": " + std::to_string(e.first_us) +
+             ", \"end_us\": " + std::to_string(e.end_us) + ", \"seg_us\": {";
+      for (int g = 0; g < kSegs; ++g)
+        out += std::string(g ? ", \"" : "\"") + kSegNames[g] +
+               "\": " + std::to_string(e.seg_us[g]);
+      out += "}}";
     }
     out += "]}";
     return out;
@@ -2661,41 +3005,101 @@ class Server {
   }
 
   // -- cross-plane timeline (ISSUE 17) ---------------------------------------
-  // Chrome-trace JSON synthesized from the SAME FlightEntry stamps the
-  // flight recorder keeps: one "verdict_wait" span per recorded request,
-  // [ts_ms - wait_ms, ts_ms] on the CLOCK_MONOTONIC timebase the ring
-  // and both Python planes share, so tools/timeline_capture.py can
-  // merge this dump with /__pingoo/timeline from the Python plane by
-  // plain concatenation (same clock; the `clock` block pins it to wall
-  // time for offline viewing). No extra hot-path stamps: this endpoint
-  // only re-reads what flight_record() already wrote.
+  // Chrome-trace JSON on the CLOCK_MONOTONIC timebase the ring and both
+  // Python planes share, so tools/timeline_capture.py can merge this
+  // dump with /__pingoo/timeline from the Python plane by plain
+  // concatenation (same clock; the `clock` block pins it to wall time
+  // for offline viewing). Two sources, both written as they happen and
+  // only re-read here:
+  //   * every worker's pass ring (pid 10 + worker): its passes laid end
+  //     to end as `httpd/<phase>` spans, each phase's time in the order
+  //     of LoopPhase (a pass's events interleave; their sums are exact);
+  //   * this worker's flight recorder (pid 3): one `request` span per
+  //     recorded request, on a row (tid) of its own, with its chain's
+  //     segments nested in it,
+  //     `read`, `verdict_wait` (enqueued -> verdict applied), `upstream`
+  //     and `reply`, the ring ticket as its trace id.
+
+  // A worker's pass ring as it stands: the entries its owner did not
+  // overwrite while they were copied.
+  static std::vector<PassEntry> read_pass_ring(const PassRing* r) {
+    uint64_t head = __atomic_load_n(&r->head, __ATOMIC_ACQUIRE);
+    uint64_t lo = head > kPassRingN ? head - kPassRingN : 0;
+    std::vector<PassEntry> out(static_cast<size_t>(head - lo));
+    for (uint64_t i = lo; i < head; ++i)
+      std::memcpy(static_cast<void*>(&out[i - lo]), &r->e[i % kPassRingN],
+                  sizeof(PassEntry));
+    __atomic_thread_fence(__ATOMIC_ACQUIRE);
+    uint64_t now = __atomic_load_n(&r->head, __ATOMIC_RELAXED);
+    uint64_t valid = now >= kPassRingN ? now - kPassRingN + 1 : 0;
+    if (valid > lo)
+      out.erase(out.begin(),
+                out.begin() + static_cast<long>(
+                                  std::min<uint64_t>(valid - lo, out.size())));
+    return out;
+  }
 
   std::string timeline_json() {
+    std::string out =
+        "{\"displayTimeUnit\": \"ms\", \"answered_by\": " +
+        std::to_string(worker_) + ", \"workers\": " +
+        std::to_string(workers_) + ", \"clock\": {\"unit\": "
+        "\"monotonic_us\", \"monotonic_now_us\": " +
+        std::to_string(now_us_) +
+        ", \"wall_now_s\": " + std::to_string(wall_now()) +
+        "}, \"traceEvents\": [{\"ph\": \"M\", \"name\": \"process_name\", "
+        "\"pid\": 3, \"tid\": 0, \"args\": {\"name\": \"pingoo:native\"}}";
+    auto span = [&out](int pid, const char* name, uint64_t ts, uint64_t dur,
+                       size_t tid = 1) {
+      out += ", {\"ph\": \"X\", \"pid\": " + std::to_string(pid) +
+             ", \"tid\": " + std::to_string(tid) + ", \"name\": \"";
+      out += name;
+      out += "\", \"ts\": " + std::to_string(ts) +
+             ", \"dur\": " + std::to_string(dur);
+    };
+    for (int w = 0; w < workers_; ++w) {
+      int pid = 10 + w;
+      out += ", {\"ph\": \"M\", \"name\": \"process_name\", \"pid\": " +
+             std::to_string(pid) +
+             ", \"tid\": 0, \"args\": {\"name\": \"pingoo:httpd/" +
+             std::to_string(w) + "\"}}";
+      for (const PassEntry& e : read_pass_ring(&rings_[w])) {
+        uint64_t t = e.start_us;
+        for (int p = 0; p < kPhases; ++p) {
+          if (e.us[p] == 0) continue;
+          span(pid, kPhaseSpans[p], t, e.us[p]);
+          out += p == kPhWait
+                     ? ", \"args\": {\"passes\": " + std::to_string(e.passes) +
+                           "}}"
+                     : std::string("}");
+          t += e.us[p];
+        }
+      }
+    }
     uint64_t total = flight_next_;
     size_t live = total < kFlightN ? static_cast<size_t>(total) : kFlightN;
     uint64_t start = total - live;
-    std::string out =
-        "{\"displayTimeUnit\": \"ms\", \"answered_by\": " +
-        std::to_string(worker_) + ", \"clock\": {\"unit\": "
-        "\"monotonic_us\", \"monotonic_now_us\": " +
-        std::to_string(now_ms() * 1000) +
-        ", \"wall_now_s\": " + std::to_string(::time(nullptr)) +
-        "}, \"traceEvents\": [{\"ph\": \"M\", \"name\": \"process_name\", "
-        "\"pid\": 3, \"tid\": 0, \"args\": {\"name\": \"pingoo:native\"}}";
+    static const char* kSpanNames[kSegs] = {"read", "verdict_wait",
+                                            "upstream", "reply"};
     for (size_t i = 0; i < live; ++i) {
       const FlightEntry& e = flight_[(start + i) % kFlightN];
-      if (!e.ts_ms) continue;
-      uint64_t t0_us = (e.ts_ms - e.wait_ms) * 1000;
-      out += ", {\"ph\": \"X\", \"pid\": 3, \"tid\": 1, \"name\": "
-             "\"verdict_wait\", \"cat\": \"native\", \"ts\": " +
-             std::to_string(t0_us) +
-             ", \"dur\": " + std::to_string(e.wait_ms * 1000) +
-             ", \"args\": {\"trace_id\": ";
+      if (!e.end_us) continue;  // a transition, not a request
+      size_t tid = 2 + i;  // a row a request: concurrent ones overlap
+      span(3, "request", e.first_us, e.end_us - e.first_us, tid);
+      out += ", \"cat\": \"native\", \"args\": {\"trace_id\": ";
       out += e.ticket == UINT64_MAX
                  ? std::string("null")
                  : "\"t-" + std::to_string(e.ticket) + "\"";
       out += ", \"decided\": " + std::to_string(e.decided) +
              ", \"path\": \"" + e.path + "\"}}";
+      uint64_t t = e.first_us;
+      for (int g = 0; g < kSegs; ++g) {
+        if (e.seg_us[g] != 0) {
+          span(3, kSpanNames[g], t, e.seg_us[g], tid);
+          out += ", \"cat\": \"native\"}";
+        }
+        t += e.seg_us[g];
+      }
     }
     out += "]}";
     return out;
@@ -2908,6 +3312,7 @@ class Server {
       return;
     }
     if (c->outbuf.empty()) {
+      chain_end(c->ch, c->req);
       mark_close(c);
       return;
     }
@@ -3603,7 +4008,7 @@ class Server {
       c->body_win.erase(0, n);
       if (fin) {
         c->body_final_sent = true;
-        c->body_fin_ms = now_ms();
+        c->body_fin_ms = pass_ms();
       }
     }
   }
@@ -3681,11 +4086,14 @@ class Server {
 
   // -- verdict flow ---------------------------------------------------------
 
-  void drain_verdicts() {
+  // -> whether a verdict was polled (the loop account's `verdicts`).
+  bool drain_verdicts() {
     uint64_t ticket;
     uint8_t action;
     float score;
+    bool any = false;
     while (pingoo_ring_poll_verdict(ring_, &ticket, &action, &score) == 0) {
+      any = true;
       if (ticket & PINGOO_BODY_VERDICT_BIT) {
         on_body_verdict(ticket & ~PINGOO_BODY_VERDICT_BIT, action);
         continue;
@@ -3707,6 +4115,7 @@ class Server {
         apply_verdict(c, action, ticket);
       }
     }
+    return any;
   }
 
   // -- sidecar supervision (ISSUE 10, docs/RESILIENCE.md) --------------------
@@ -3732,7 +4141,7 @@ class Server {
                     uint64_t last_ticket = 0) {
     if (tickets == 0) return;
     Release& r = release_;
-    uint64_t now = now_ms();
+    uint64_t now = pass_ms();
     bool event = cause == kRelDeadline || cause == kRelDegraded ||
                  now - r.last_ms[cause] >= 1000;
     r.last_ms[cause] = now;
@@ -3744,7 +4153,7 @@ class Server {
     uint64_t tel[PINGOO_TELEMETRY_WORDS];
     pingoo_ring_telemetry_snapshot(ring_, tel);
     uint64_t lv[5];
-    pingoo_ring_liveness(ring_, lv);
+    ring_liveness(lv);
     // odd while last_* change: a sibling answering a scrape reads again
     __atomic_store_n(&slot_->release_seq, slot_->release_seq + 1,
                      __ATOMIC_RELAXED);
@@ -3801,22 +4210,23 @@ class Server {
       auto sit = c->h2_streams.find(sid);
       if (sit == c->h2_streams.end()) return;  // stream reset meanwhile
       sit->second.ticket = UINT64_MAX;
-      flight_record(sit->second.p, ticket, sit->second.enq_ms, 0, 3);
+      chain_decided(sit->second.ch, ticket, 0, 3);
       h2_stream_fail_open(c, sid);
       h2_flush(c);
     } else {
       c->ticket = UINT64_MAX;
       body_abort(c);  // dispatching without a verdict: stop inspecting
-      flight_record(c->req, ticket, c->enq_ms, 0, 3);
+      chain_decided(c->ch, ticket, 0, 3);
       fail_open_proxy(c);
     }
   }
 
-  void sweep_verdict_deadlines() {
-    sweep_body_deadlines();
-    if (awaiting_.empty()) return;
-    uint64_t now = now_ms();
-    if (now == last_deadline_sweep_ms_) return;  // at most one pass per ms
+  // -> whether it swept (the loop account's `supervise`).
+  bool sweep_verdict_deadlines() {
+    bool swept = sweep_body_deadlines();
+    if (awaiting_.empty()) return swept;
+    uint64_t now = pass_ms();
+    if (now == last_deadline_sweep_ms_) return swept;  // once a ms at most
     last_deadline_sweep_ms_ = now;
     // Collect first: fail_open_ticket mutates conns/streams and must
     // not run under the awaiting_ iterator.
@@ -3842,6 +4252,7 @@ class Server {
     }
     note_release(kRelDeadline, stats_.fail_open - before, oldest_age, first,
                  last);
+    return true;
   }
 
   // When an awaiting ticket was enqueued (this plane's ms clock), 0
@@ -3855,9 +4266,9 @@ class Server {
   // A request whose metadata verdict already said "proxy" is blocked
   // solely on the body verdict once its FINAL window is enqueued; the
   // same kVerdictTimeoutMs budget bounds that wait (ISSUE 13).
-  void sweep_body_deadlines() {
-    if (body_awaiting_.empty()) return;
-    uint64_t now = now_ms();
+  bool sweep_body_deadlines() {
+    if (body_awaiting_.empty()) return false;
+    uint64_t now = pass_ms();
     body_expired_.clear();
     for (const auto& kv : body_awaiting_) {
       Conn* c = kv.second;
@@ -3867,12 +4278,13 @@ class Server {
     }
     for (Conn* c : body_expired_)
       if (!c->dead && c->body_inspect) body_fail_open(c);
+    return true;
   }
 
   void fail_open_all_awaiting() {
     std::vector<std::pair<uint64_t, Awaiting>> inflight;
     inflight.reserve(awaiting_.size());
-    uint64_t now = now_ms(), oldest_age = 0, first = UINT64_MAX, last = 0;
+    uint64_t now = pass_ms(), oldest_age = 0, first = UINT64_MAX, last = 0;
     for (const auto& kv : awaiting_) {
       inflight.push_back(kv);
       uint64_t enq = enqueued_at(kv.second.conn, kv.second.sid);
@@ -3895,7 +4307,7 @@ class Server {
   void check_sidecar_liveness() {
     if (kSidecarTimeoutMs == 0 || tcp_mode_) return;
     uint64_t lv[5];  // epoch, heartbeat_ms, posted_floor, req_tail, now_ms
-    pingoo_ring_liveness(ring_, lv);
+    ring_liveness(lv);
     sidecar_epoch_ = lv[0];
     // Bootstrap: until a sidecar has ever attached (heartbeat 0) the
     // per-request deadline governs — flipping degraded here would only
@@ -3904,16 +4316,14 @@ class Server {
     sidecar_seen_ = true;
     uint64_t age = lv[4] > lv[1] ? lv[4] - lv[1] : 0;
     bool stale = age > kSidecarTimeoutMs;
-    // Near misses for the release witness: how old a heartbeat got,
-    // how often it crossed half the window, this loop's longest gap.
+    // Near misses for the release witness: how old a heartbeat got and
+    // how often it crossed half the window (pass_begin keeps the loop's
+    // longest gap).
     Release& r = release_;
     if (age > r.heartbeat_age_max_ms) r.heartbeat_age_max_ms = age;
     bool late = age > kSidecarTimeoutMs / 2;
     if (late && !r.heartbeat_is_late) r.heartbeat_late++;
     r.heartbeat_is_late = late;
-    if (r.last_pass_ms != 0 && lv[4] - r.last_pass_ms > r.loop_gap_max_ms)
-      r.loop_gap_max_ms = lv[4] - r.last_pass_ms;
-    r.last_pass_ms = lv[4];
     if (stale && !degraded_) {
       degraded_ = true;
       stats_.degraded_entered++;
@@ -3971,14 +4381,14 @@ class Server {
       }
     }
     stats_.verdicts++;
-    if (c->enq_ms) record_wait(now_ms() - c->enq_ms);
+    if (c->enq_ms) record_wait(pass_ms() - c->enq_ms);
     uint8_t decided;  // 0 proxy, 1 block, 2 captcha
     if (c->captcha_verified) {
       decided = (action & 4) ? 1 : 0;
     } else {
       decided = action & 3;
     }
-    flight_record(c->req, ticket, c->enq_ms, action, decided);
+    chain_decided(c->ch, ticket, action, decided);
     if (decided == 1) {
       stats_.blocked++;
       stats_.closed_after_block++;
@@ -3995,9 +4405,9 @@ class Server {
                         uint64_t ticket = UINT64_MAX) {
     stats_.verdicts++;
     H2Stream& st = c->h2_streams[sid];
-    if (st.enq_ms) record_wait(now_ms() - st.enq_ms);
+    if (st.enq_ms) record_wait(pass_ms() - st.enq_ms);
     uint8_t decided = st.verified ? ((action & 4) ? 1 : 0) : (action & 3);
-    flight_record(st.p, ticket, st.enq_ms, action, decided);
+    chain_decided(st.ch, ticket, action, decided);
     if (decided == 1) {
       stats_.blocked++;
       h2_respond_simple(c, sid, 403, "Forbidden");
@@ -4013,6 +4423,7 @@ class Server {
 
   void begin_request_cycle(Conn* c) {
     body_abort(c);  // stray inspection state never crosses cycles
+    c->ch = Chain();
     c->state = ConnState::kReadingHead;
     c->req = Parsed();
     c->req_body = BodyFramer();
@@ -4070,6 +4481,7 @@ class Server {
         mark_close(c);
       return;
     }
+    if (!c->ch.first_us && !c->inbuf.empty()) c->ch.first_us = now_us_;
     // HTTP/2 detection: every h2 client (ALPN-negotiated or cleartext
     // prior knowledge) opens with the 24-byte preface (RFC 7540 §3.5),
     // mirroring the reference's hyper auto h1/h2 builder.
@@ -4174,7 +4586,6 @@ class Server {
         return;
       case Policy::kFailOpenProxy:
         note_unenqueued_release();
-        flight_record(c->req, UINT64_MAX, 0, 0, 3);  // 3 = fail-open
         fail_open_proxy(c);
         return;
       case Policy::kAwaitVerdict:
@@ -4201,7 +4612,24 @@ class Server {
     kAwaitVerdict,     // enqueued; verdict callback decides
   };
 
+  // The policy's decision, stamped on the request's chain: it is
+  // enqueued (or decided before the ring) now.
   Policy run_policy(Conn* c, int32_t sid = 0) {
+    Chain& ch = sid != 0 ? c->h2_streams[sid].ch : c->ch;
+    ch.live = true;
+    if (!ch.first_us) ch.first_us = now_us_;
+    ch.enq_us = now_us_;
+    Policy outcome = decide_policy(c, sid);
+    if (outcome != Policy::kAwaitVerdict)
+      chain_decided(ch, UINT64_MAX, 0,
+                    outcome == Policy::kBlock             ? 1
+                    : outcome == Policy::kCaptchaRedirect ? 2
+                    : outcome == Policy::kFailOpenProxy   ? 3
+                                                          : 0);
+    return outcome;
+  }
+
+  Policy decide_policy(Conn* c, int32_t sid) {
     stats_.requests++;
     Parsed& req = sid != 0 ? c->h2_streams[sid].p : c->req;
     // Empty or oversized UA -> 403 before the ring. The >= is the
@@ -4264,11 +4692,11 @@ class Server {
       H2Stream& st = c->h2_streams[sid];
       st.ticket = ticket;
       st.verdict_at = now_;
-      st.enq_ms = now_ms();
+      st.enq_ms = pass_ms();
     } else {
       c->ticket = ticket;
       c->verdict_at = now_;
-      c->enq_ms = now_ms();
+      c->enq_ms = pass_ms();
     }
     awaiting_[ticket] = Awaiting{c, sid};
     return Policy::kAwaitVerdict;
@@ -4446,7 +4874,6 @@ class Server {
           break;
         case Policy::kFailOpenProxy:
           note_unenqueued_release();
-          flight_record(it->second.p, UINT64_MAX, 0, 0, 3);  // fail-open
           h2_stream_fail_open(c, sid);
           break;
         case Policy::kAwaitVerdict:
@@ -4926,6 +5353,7 @@ class Server {
                  !st.up_trunc);
     if (done && !st.data_eof) {
       st.data_eof = true;
+      if (!st.ch.up_us) st.ch.up_us = now_us_;
       if (st.resp_body.mode == BodyFramer::kUntilEof)
         st.resp_body.done = true;  // EOF framing: input ended the body
       nghttp2_session_resume_data(c->h2, sid);
@@ -5231,6 +5659,8 @@ class Server {
     Conn* c = static_cast<Conn*>(user_data);
     const auto* hd = static_cast<const nghttp2_frame_hd*>(frame);
     H2Stream& st = c->h2_streams[hd->stream_id];
+    if (!st.ch.first_us && g_server != nullptr)
+      st.ch.first_us = g_server->now_us();
     std::string n(reinterpret_cast<const char*>(name), namelen);
     std::string v(reinterpret_cast<const char*>(value), valuelen);
     Parsed& p = st.p;
@@ -5346,8 +5776,10 @@ class Server {
             sess, static_cast<size_t>(it->second.window_debt));
         it->second.window_debt = 0;
       }
-      if (g_server != nullptr)
+      if (g_server != nullptr) {
+        g_server->chain_end(it->second.ch, it->second.p);
         g_server->h2_release_stream_resources(c, it->second);
+      }
       c->h2_streams.erase(it);
     }
     c->h2_send.erase(stream_id);
@@ -5748,6 +6180,7 @@ class Server {
           !c->resp_head_done) {
         if (try_pooled_retry(c)) return;
         stats_.upstream_fail++;
+        if (!c->ch.up_us) c->ch.up_us = now_us_;
         respond_close(c, k502);
       }
       return;
@@ -5768,7 +6201,9 @@ class Server {
         return;
       }
     }
+    if (!c->ch.up_us) c->ch.up_us = now_us_;  // the upstream's part is whole
     if (!c->outbuf.empty()) return;  // keep draining first
+    chain_end(c->ch, c->req);
     // Reuse the upstream connection when the response left it in a
     // known-clean state: explicit framing fully consumed, no EOF, no
     // bytes past the response end, and the upstream allows keep-alive.
@@ -5895,7 +6330,9 @@ class Server {
         if (events & (EPOLLHUP | EPOLLERR)) mark_close(c);
         else if (events & EPOLLOUT) {
           c->ssl_want_write = false;
-          if (!flush_out(c) || c->outbuf.empty()) mark_close(c);
+          bool flushed = flush_out(c);
+          if (flushed && c->outbuf.empty()) chain_end(c->ch, c->req);
+          if (!flushed || c->outbuf.empty()) mark_close(c);
         }
         break;
     }
@@ -5927,6 +6364,22 @@ class Server {
   int worker_;
   WorkerSlot* slot_;
   Stats& stats_;
+  std::unique_ptr<PassRing> own_ring_;  // without the block
+  PassRing* rings_ = nullptr;           // every worker's, as slots_
+  PassRing* pass_ring_ = nullptr;       // this worker's
+  // The loop clock: now_us_ is the stamp of the running phase's start
+  // (every stamp a pass hands out reads it), mark_us_ where the time not
+  // yet accounted begins, entry_us_ the pass entry being filled.
+  uint64_t now_us_ = 0, mark_us_ = 0, last_pass_us_ = 0;
+  uint64_t entry_start_us_ = 0;
+  uint64_t entry_us_[kPhases] = {};
+  uint32_t entry_passes_ = 0;
+  bool pass_worked_ = false;
+  time_t wall_off_s_ = 0;        // wall clock - monotonic, once a second
+  uint64_t proc_read_s_ = 0;     // the second of the last schedstat read
+  int schedstat_fd_ = -1, stat_fd_ = -1;  // /proc/thread-self/{schedstat,stat}
+  uint64_t runqueue_ns_ = 0;     // the last schedstat reading
+  uint64_t blkio_ticks_ = 0;     // the last delayacct_blkio_ticks reading
   std::unordered_set<Conn*> conns_;
   struct Awaiting {
     Conn* conn;
@@ -6289,7 +6742,6 @@ int main(int argc, char** argv) {
   constexpr time_t kDrainCapS = 20;
   bool draining = false;
   time_t drain_start = 0;
-  time_t last_sweep = time(nullptr);
   while (true) {
     epoll_event events[256];
     // Busy-poll while requests are awaiting verdicts: the sidecar posts
@@ -6298,16 +6750,20 @@ int main(int argc, char** argv) {
     // verdicts outstanding, 1 ms keeps the idle loop cheap.
     int n = epoll_wait(ep, events, 256,
                        server.awaiting_verdicts() ? 0 : 1);
-    time_t now = time(nullptr);
-    server.set_now(now);
-    server.drain_verdicts();
+    // The pass's stamp: every clock this pass reads is a phase stamp
+    // (Server's loop clock), and each piece of work below is closed by
+    // phase_done() into the worker's loop account.
+    server.pass_begin();
+    time_t now = server.wall_now();
+    if (server.drain_verdicts()) server.phase_done(Server::kPhVerdicts);
     // Sidecar supervision (ISSUE 10): heartbeat check (a few shm
     // loads) + ms-granularity verdict deadlines (self-throttled to one
     // pass per ms) run every iteration, so a dead sidecar costs one
     // detection window, not a seconds-long stall.
     server.check_sidecar_liveness();
-    server.sweep_verdict_deadlines();
+    bool swept = server.sweep_verdict_deadlines();
     server.publish_slot();  // once a ms: what a sibling's scrape reads
+    if (swept) server.phase_done(Server::kPhSupervise);
 
     if (g_sigterm && !draining) {
       draining = true;
@@ -6337,13 +6793,16 @@ int main(int argc, char** argv) {
           setsockopt(cfd, IPPROTO_TCP, TCP_NODELAY, &nd, sizeof(nd));
           server.add_client(cfd, peer, base_ctx);
         }
+        server.phase_done(Server::kPhAccept);
         continue;
       }
       SockRef* ref = static_cast<SockRef*>(events[i].data.ptr);
+      bool upstream = ref->is_upstream;
       server.handle(ref, events[i].events);
+      server.phase_done(upstream ? Server::kPhUpstream : Server::kPhClient);
     }
-    server.process_ssl_resume();
-    server.flush_doomed();
+    if (server.process_ssl_resume()) server.phase_done(Server::kPhTls);
+    if (server.flush_doomed()) server.phase_done(Server::kPhClose);
     if (draining) {
       size_t live = server.drain_tick();
       if (live == 0 || now - drain_start >= kDrainCapS) {
@@ -6354,13 +6813,14 @@ int main(int argc, char** argv) {
         return 0;
       }
     }
-    if (now != last_sweep) {
+    if (server.second_changed()) {  // also reads the run-queue wait
       server.sweep_idle();
       server.sweep_pool();
       server.flush_doomed();
-      services.maybe_reload(now);
-      last_sweep = now;
+      services.maybe_reload(server.wall_now());
+      server.phase_done(Server::kPhSweep);
     }
+    server.pass_end();
   }
   return 0;
 }

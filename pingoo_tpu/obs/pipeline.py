@@ -62,6 +62,7 @@ first, so each pair is counted exactly once.
 from __future__ import annotations
 
 import logging
+import os
 import time
 from collections import deque
 
@@ -100,8 +101,43 @@ STALL_MS = 250.0
 # chips: a batch on a slow chip outlasted the encoder's rotation).
 COMPLETIONS = ("ready", "depth", "drain", "staging")
 _IDLE_FLUSH_S = 1.0
+# The drain thread's own scheduler readings (the calling thread's, read
+# through fds opened on it): the run-queue wait in nanoseconds, the
+# second field of schedstat, and delayacct_blkio_ticks, field 42 of stat.
+SCHEDSTAT = "/proc/thread-self/schedstat"
+THREAD_STAT = "/proc/thread-self/stat"
+_RUNQUEUE_READ_S = 1.0
 
 _log = logging.getLogger(__name__)
+
+
+def _open_reading(path: str):
+    try:
+        return os.open(path, os.O_RDONLY | os.O_CLOEXEC)
+    except OSError:
+        return None
+
+
+def read_runqueue_ns(fd) -> "int | None":
+    """The run-queue wait (ns) from a schedstat fd, None without one."""
+    if fd is None:
+        return None
+    try:
+        return int(os.pread(fd, 128, 0).split()[1])
+    except (OSError, IndexError, ValueError):
+        return None
+
+
+def read_blkio_ticks(fd) -> "int | None":
+    """delayacct_blkio_ticks from a stat fd: the fields after the
+    parenthesised name start at field 3."""
+    if fd is None:
+        return None
+    try:
+        data = os.pread(fd, 4096, 0)
+        return int(data[data.rindex(b")") + 2:].split()[42 - 3])
+    except (OSError, IndexError, ValueError):
+        return None
 
 
 CASCADE_STAGES = ("live", "candidate", "recheck")
@@ -362,6 +398,10 @@ class PipelineStats:
                 labels={"plane": self.plane, "phase": p})
             for p in LOOP_PHASES}
         self._stall_ctr: dict[str, object] = {}
+        self._runqueue_ctr = None   # made at loop_start, where readable
+        self._sched_fd = self._stat_fd = None
+        self._runqueue_ns = self._blkio_ticks = None
+        self._t_runqueue = 0.0
         self._completion_ctr = {
             how: self._registry.counter(
                 "pingoo_sidecar_completions_total",
@@ -413,8 +453,22 @@ class PipelineStats:
 
     def loop_start(self) -> None:
         """The drain loop begins: everything until loop_stop() is
-        accounted to exactly one phase."""
+        accounted to exactly one phase. Called on the drain thread: the
+        run-queue and blkio readings are this thread's."""
+        from . import schema
+
         now = time.monotonic()
+        self._close_readings()
+        self._sched_fd = _open_reading(SCHEDSTAT)
+        self._stat_fd = _open_reading(THREAD_STAT)
+        self._runqueue_ns = read_runqueue_ns(self._sched_fd)
+        self._blkio_ticks = read_blkio_ticks(self._stat_fd)
+        self._t_runqueue = now
+        if self._runqueue_ns is not None and self._runqueue_ctr is None:
+            self._runqueue_ctr = self._registry.counter(
+                "pingoo_sidecar_runqueue_us_total",
+                schema.PIPELINE_METRICS["pingoo_sidecar_runqueue_us_total"],
+                labels={"plane": self.plane})
         self._stack.clear()
         self._base = "poll"
         self._t_flush = now
@@ -428,6 +482,13 @@ class PipelineStats:
             self._cur = None
             self._flush(now)
             self.loop_stamps[1] = now
+        self._close_readings()
+
+    def _close_readings(self) -> None:
+        for fd in (self._sched_fd, self._stat_fd):
+            if fd is not None:
+                os.close(fd)
+        self._sched_fd = self._stat_fd = None
 
     def begin(self, mode: str, rows: int, rings: int = 1) -> BatchSpans:
         """enter() for the drain loop: the batch's span record."""
@@ -550,6 +611,22 @@ class PipelineStats:
                 self._loop_ctr[phase].inc(ms)
                 self._acc[phase] = 0.0
         self._t_flush = now
+        if self._runqueue_ctr is not None and \
+                now - self._t_runqueue >= _RUNQUEUE_READ_S:
+            self._t_runqueue = now
+            self._note_runqueue()
+
+    def _note_runqueue(self) -> "int | None":
+        """The run-queue wait since the last reading into the counter;
+        -> it in ns, None where it cannot be read."""
+        ns = read_runqueue_ns(self._sched_fd)
+        if ns is None or self._runqueue_ns is None:
+            return None
+        waited = ns - self._runqueue_ns
+        self._runqueue_ns = ns
+        if self._runqueue_ctr is not None and waited > 0:
+            self._runqueue_ctr.inc(waited / 1e3)
+        return waited
 
     def _stall(self, name: str, ms: float, rec) -> None:
         from . import schema
@@ -562,11 +639,20 @@ class PipelineStats:
                 labels={"plane": self.plane, "phase": name})
         ctr.inc()
         depths = self._depth_fn() if self._depth_fn else None
+        # the wedge's witness: what this thread waited for meanwhile, a
+        # run queue (a descheduled vCPU) or block I/O (writeback)
+        waited = self._note_runqueue()
+        ticks = read_blkio_ticks(self._stat_fd)
+        blkio = None if ticks is None or self._blkio_ticks is None \
+            else ticks - self._blkio_ticks
+        self._blkio_ticks = ticks
         _log.warning("drain loop stalled", extra={"fields": {
             "phase": name, "ms": round(ms, 1),
             "batch": rec.seq if rec is not None else None,
             "ring_depth": sum(depths.values()) if depths else depths,
-            "ring_depths": depths}})
+            "ring_depths": depths,
+            "runqueue_ms": None if waited is None else waited / 1e6,
+            "blkio_ticks": blkio}})
 
     def snapshot(self) -> dict:
         wall = max(time.monotonic() - self._t_boot, 1e-9)

@@ -166,6 +166,10 @@ PIPELINE_METRICS = {
         "drain-thread wall time (ms) by loop phase (poll|encode|"
         "prefilter|dispatch|host_rules|device_wait|resolve|provenance|"
         "bodies|swap|idle); the phases partition the loop's time",
+    "pingoo_sidecar_runqueue_us_total":
+        "drain thread's wait on a CPU run queue (us; the second field of "
+        "/proc/thread-self/schedstat), read once a second; absent where "
+        "that file is missing",
     "pingoo_sidecar_stall_total":
         "spans of one non-idle drain-loop phase longer than 250 ms, by "
         "phase (each also logs its batch and the ring depth)",

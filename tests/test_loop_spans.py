@@ -220,6 +220,79 @@ def test_a_held_phase_counts_one_stall(tmp_path, monkeypatch, caplog):
     assert "ring_depth" in lines[0].fields
 
 
+def _bare_loop(registry):
+    """A PipelineStats wired as the drain loop wires it, with sinks that
+    keep nothing."""
+    from pingoo_tpu.obs import pipeline
+
+    class _Sink:
+        def observe(self, _ms):
+            pass
+
+    ps = pipeline.PipelineStats("sidecar", 2, registry=registry)
+    ps.attach_loop({s: _Sink() for s in PHASE_STAGE.values()},
+                   lambda *_: None, 16)
+    return ps
+
+
+@pytest.mark.parametrize("schedstat", ["readable", "missing"])
+def test_the_drain_thread_counts_its_run_queue_wait(monkeypatch, tmp_path,
+                                                     schedstat):
+    """pingoo_sidecar_runqueue_us_total{plane} is the drain thread's own
+    schedstat run-queue wait, read at a flush at most once a second; where
+    the file is missing the series does not exist (not a 0)."""
+    import os
+
+    from pingoo_tpu.obs import pipeline
+    from pingoo_tpu.obs.registry import MetricRegistry
+
+    if schedstat == "missing":
+        monkeypatch.setattr(pipeline, "SCHEDSTAT", str(tmp_path / "none"))
+    elif not os.path.exists(pipeline.SCHEDSTAT):
+        pytest.skip("no /proc/thread-self/schedstat here")
+    monkeypatch.setattr(pipeline, "_RUNQUEUE_READ_S", 0.0)
+    reg = MetricRegistry()
+    ps = _bare_loop(reg)
+    ps.loop_start()
+    with ps.stage("encode"):
+        sum(range(200_000))
+    ps.loop_stop()   # its flush reads the wait
+    text = reg.prometheus_text()
+    if schedstat == "missing":
+        assert "pingoo_sidecar_runqueue_us_total" not in text
+        return
+    assert 'pingoo_sidecar_runqueue_us_total{plane="sidecar"}' in text
+    assert reg.counter("pingoo_sidecar_runqueue_us_total",
+                       labels={"plane": "sidecar"}).value >= 0.0
+    # the fds the loop opened are closed with it
+    assert ps._sched_fd is None and ps._stat_fd is None
+
+
+def test_a_stall_line_says_what_the_thread_waited_for(caplog):
+    """The wedge's witness: a stalled phase's log line carries the run
+    queue wait over it and the blkio ticks, None where unreadable."""
+    import os
+
+    from pingoo_tpu.obs.registry import MetricRegistry
+
+    ps = _bare_loop(MetricRegistry())
+    ps.loop_start()
+    with caplog.at_level(logging.WARNING, logger="pingoo_tpu.obs.pipeline"):
+        with ps.stage("encode"):
+            time.sleep(STALL_MS / 1e3 + 0.05)
+    ps.loop_stop()
+    lines = [r for r in caplog.records
+             if r.getMessage() == "drain loop stalled"]
+    assert len(lines) == 1
+    fields = lines[0].fields
+    assert fields["phase"] == "encode" and fields["ms"] > STALL_MS
+    if os.path.exists("/proc/thread-self/schedstat"):
+        # asleep, not runnable: the thread waited on no run queue
+        assert 0.0 <= fields["runqueue_ms"] < STALL_MS
+    if os.path.exists("/proc/thread-self/stat"):
+        assert fields["blkio_ticks"] >= 0
+
+
 def _scopes_of(lowered) -> set:
     """Every vocabulary scope in a lowered program's locations."""
     from pingoo_tpu.engine.verdict import SCOPE_KINDS

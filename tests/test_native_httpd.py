@@ -2,6 +2,7 @@
 TPU sidecar -> 403/proxy, driven over real sockets."""
 
 import http.server
+import json
 import os
 import socket
 import subprocess
@@ -273,3 +274,223 @@ class TestReleaseWitness:
         moving = {"heartbeat_age_max_ms", "loop_gap_max_ms"}  # still stale
         assert {k: v for k, v in summary.items() if k not in moving} \
             == {k: v for k, v in rel.items() if k not in moving}
+
+
+# -- the worker's own clock ------------------------------------------------------
+
+
+class FakeSidecar:
+    """A ring drainer without the engine: every request `delay_s` after
+    it is dequeued gets block (1) when its path starts with /evil, else
+    proxy (0). Heartbeats like the sidecar, so httpd supervises it."""
+
+    def __init__(self, ring, delay_s=0.0):
+        self.ring, self.delay_s = ring, delay_s
+        self.stop = threading.Event()
+        ring.sidecar_attach()
+        self.thread = threading.Thread(target=self._run, daemon=True)
+        self.thread.start()
+
+    def _run(self):
+        while not self.stop.is_set():
+            self.ring.heartbeat()
+            rows = self.ring.dequeue_batch(64)
+            if not len(rows):
+                time.sleep(0.0005)
+                continue
+            if self.delay_s:
+                time.sleep(self.delay_s)
+            for row in rows:
+                path = bytes(row["path"][:row["path_len"]])
+                self.ring.post_verdict(int(row["ticket"]),
+                                       1 if path.startswith(b"/evil") else 0)
+
+    def close(self):
+        self.stop.set()
+        self.thread.join(5)
+
+
+class SlowUpstream(http.server.BaseHTTPRequestHandler):
+    """`/slow...` answers after UPSTREAM_S, everything else at once."""
+
+    UPSTREAM_S = 0.06
+
+    def do_GET(self):
+        if self.path.startswith("/slow"):
+            time.sleep(self.UPSTREAM_S)
+        self.send_response(200)
+        self.send_header("content-length", "2")
+        self.end_headers()
+        self.wfile.write(b"up")
+
+    def log_message(self, *a):
+        pass
+
+
+class ClockStack:
+    """One httpd (or `workers` of them on one port and one counter
+    block, a ring and a FakeSidecar each), a slow-on-demand upstream."""
+
+    def __init__(self, tmp_path, workers=1, started=None, delay_s=0.02):
+        self.upstream = http.server.ThreadingHTTPServer(
+            ("127.0.0.1", 0), SlowUpstream)
+        threading.Thread(target=self.upstream.serve_forever,
+                         daemon=True).start()
+        self.port = _free_port()
+        self.stats_fd = os.memfd_create("workers")
+        self.rings, self.sidecars, self.procs, self.errs = [], [], [], []
+        for w in (range(workers) if started is None else started):
+            ring = Ring(str(tmp_path / f"ring_{w}"), capacity=1024,
+                        create=True)
+            self.rings.append(ring)
+            self.sidecars.append(FakeSidecar(ring, delay_s))
+            self.errs.append(open(tmp_path / f"httpd_{w}.err", "wb"))
+            self.procs.append(subprocess.Popen(
+                [HTTPD, str(self.port), str(tmp_path / f"ring_{w}"),
+                 "127.0.0.1", str(self.upstream.server_address[1]),
+                 "--worker-stats-fd", str(self.stats_fd),
+                 "--workers", str(workers), "--worker", str(w)],
+                stdout=subprocess.PIPE, stderr=self.errs[-1],
+                pass_fds=(self.stats_fd,)))
+            assert b"listening" in self.procs[-1].stdout.readline()
+
+    def json(self, path):
+        extra = "accept: application/json\r\n" if path.endswith("metrics") \
+            else ""
+        return json.loads(_raw_get(self.port, path, extra=extra).partition(
+            b"\r\n\r\n")[2])
+
+    def stop(self):
+        for proc in self.procs:
+            proc.terminate()
+            proc.wait(timeout=10)
+        for sidecar in self.sidecars:
+            sidecar.close()
+        for err in self.errs:
+            err.close()
+        for ring in self.rings:
+            ring.close()
+        self.upstream.shutdown()
+        os.close(self.stats_fd)
+
+
+def exchange(port, paths):
+    """Requests one after another on one keep-alive connection ->
+    [(status, client round trip µs)]; a 403 ends the connection."""
+    from test_native_plane import recv_one_response
+
+    c = socket.create_connection(("127.0.0.1", port), timeout=30)
+    out = []
+    for path in paths:
+        t0 = time.monotonic()
+        c.sendall(f"GET {path} HTTP/1.1\r\nhost: c.test\r\n"
+                  f"user-agent: ua\r\n\r\n".encode())
+        status = int(recv_one_response(c).split(b" ", 2)[1])
+        out.append((status, (time.monotonic() - t0) * 1e6))
+        if status != 200:
+            break
+    c.close()
+    return out
+
+
+PHASES = ("wait", "verdicts", "supervise", "accept", "client", "upstream",
+          "tls", "close", "sweep")
+SEGMENTS = ("read", "verdict", "upstream", "reply")
+
+
+class TestWorkerClock:
+    def test_the_phases_partition_a_workers_wall_time(self, tmp_path):
+        stack = ClockStack(tmp_path)
+        try:
+            exchange(stack.port, ["/a", "/b", "/evil"])
+            t1 = time.monotonic()
+            m1 = stack.json("/__pingoo/metrics")
+            load = threading.Thread(target=exchange, args=(
+                stack.port, [f"/n{i}" for i in range(20)]))
+            load.start()
+            time.sleep(1.0)
+            load.join(30)
+            t2 = time.monotonic()
+            m2 = stack.json("/__pingoo/metrics")
+        finally:
+            stack.stop()
+        w1, w2 = m1["per_worker"][0], m2["per_worker"][0]
+        assert set(w2["loop_us"]) == set(PHASES)
+        spent = sum(w2["loop_us"][p] - w1["loop_us"][p] for p in PHASES)
+        # the answering worker: the phases add up to its clock exactly,
+        # and its clock to the client's within 1 %
+        assert spent == w2["loop_at_us"] - w1["loop_at_us"]
+        assert spent == pytest.approx((t2 - t1) * 1e6, rel=0.01)
+        for p in ("wait", "verdicts", "client", "upstream"):
+            assert w2["loop_us"][p] > w1["loop_us"][p], p
+        assert m2["passes"] > m2["empty_passes"] > 0
+        assert m2["loop_us"] == w2["loop_us"]  # one worker: its own
+
+    def test_a_requests_segments_add_up_to_its_residence(self, tmp_path):
+        stack = ClockStack(tmp_path, delay_s=0.02)
+        try:
+            rtts = dict(zip(("/slow", "/fast", "/evil"), (
+                rtt for _, rtt in exchange(stack.port,
+                                           ["/slow", "/fast", "/evil"]))))
+            fr = stack.json("/__pingoo/flightrecorder")
+        finally:
+            stack.stop()
+        entries = {e["path"]: e for e in fr["entries"] if e["end_us"]}
+        assert set(entries) == {"/slow", "/fast", "/evil"}
+        for path, e in entries.items():
+            seg = e["seg_us"]
+            assert set(seg) == set(SEGMENTS)
+            assert sum(seg.values()) == e["end_us"] - e["first_us"], path
+            # the residence is inside the client's round trip
+            assert e["end_us"] - e["first_us"] <= rtts[path] + 1000, path
+            # the sidecar held every verdict 20 ms
+            assert seg["verdict"] >= 20_000, path
+            assert e["wait_ms"] == seg["verdict"] // 1000
+            assert e["ticket"] is not None
+        assert entries["/slow"]["seg_us"]["upstream"] >= \
+            SlowUpstream.UPSTREAM_S * 1e6
+        assert entries["/fast"]["seg_us"]["upstream"] > 0
+        assert entries["/evil"]["seg_us"]["upstream"] == 0  # a 403
+        assert entries["/evil"]["decided"] == 1
+
+    def test_chain_requests_equal_the_requests_answered(self, tmp_path):
+        stack = ClockStack(tmp_path, delay_s=0.005)
+        try:
+            m1 = stack.json("/__pingoo/metrics")
+            answered = []
+            for i in range(4):
+                answered += exchange(stack.port, [f"/ok{i}/{j}"
+                                                  for j in range(10)]
+                                     + [f"/evil{i}"])
+            m2 = stack.json("/__pingoo/metrics")
+        finally:
+            stack.stop()
+        proxied = sum(status == 200 for status, _ in answered)
+        assert (proxied, len(answered)) == (40, 44)
+        assert m2["chain_requests"] - m1["chain_requests"] == 44
+        assert m2["requests"] - m1["requests"] == 44
+        assert m2["chain_upstream_requests"] \
+            - m1["chain_upstream_requests"] == 40
+        verdict_ms = (m2["chain_us"]["verdict"] - m1["chain_us"]["verdict"]) \
+            / 44 / 1e3
+        assert verdict_ms >= 5.0
+
+    def test_a_long_pass_gap_leaves_the_wedges_witness(self, tmp_path):
+        import signal
+
+        stack = ClockStack(tmp_path)
+        try:
+            exchange(stack.port, ["/a"])
+            stack.procs[0].send_signal(signal.SIGSTOP)
+            time.sleep(0.4)
+            stack.procs[0].send_signal(signal.SIGCONT)
+            m = stack.json("/__pingoo/metrics")
+        finally:
+            stack.stop()
+        rel = m["release"]
+        assert rel["wedges"] >= 1 and rel["wedge_gap_ms"] >= 350
+        assert rel["loop_gap_max_ms"] >= rel["wedge_gap_ms"]
+        # stopped, not runnable: it waited on no run queue meanwhile
+        assert rel["wedge_runqueue_us"] < 250_000
+        log = (tmp_path / "httpd_0.err").read_text()
+        assert "WEDGE pass gap" in log and "worker 0 of 1)" in log

@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import ipaddress
 import json
+import os
 import random
 import socket
 import threading
@@ -218,6 +219,15 @@ def _check_totals(m: dict) -> None:
     assert sum(m["release"][f"tickets_{c}"] for c in
                ("deadline", "degraded", "bypass", "ring_full")) \
         == m["fail_open"]
+    # the worker's own clock: the listener's account is the workers' sum
+    for key in ("passes", "empty_passes", "chain_requests",
+                "chain_upstream_requests"):
+        assert m[key] == sum(p[key] for p in per), key
+    for key in ("loop_us", "chain_us"):
+        assert m[key] == {k: sum(p[key][k] for p in per)
+                          for k in m[key]}, key
+    if "runqueue_us" in m:
+        assert m["runqueue_us"] == sum(p.get("runqueue_us", 0) for p in per)
 
 
 def _exchange(port, paths) -> list:
@@ -398,6 +408,85 @@ def test_a_release_on_one_worker_shows_in_a_scrape_by_another(tmp_path):
             "release summary")[0]
 
 
+def test_the_timeline_renders_every_workers_ring_from_any_worker(tmp_path):
+    """Two workers, a ring of passes each in the shared block: whichever
+    answers /__pingoo/timeline renders both as `httpd/<phase>` spans
+    (pid 10 + worker), laid end to end, beside its own requests' chains
+    (`request` with `read`, `verdict_wait`, `upstream`, `reply` nested
+    in it)."""
+    from test_native_httpd import ClockStack, exchange
+
+    stack = ClockStack(tmp_path, workers=2)
+    try:
+        threads = [threading.Thread(target=exchange, args=(
+            stack.port, [f"/t{i}/{j}" for j in range(8)]))
+            for i in range(16)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(60)
+        docs = {}
+        for _ in range(200):
+            doc = stack.json("/__pingoo/timeline")
+            docs.setdefault(doc["answered_by"], doc)
+            if len(docs) == 2:
+                break
+        m = stack.json("/__pingoo/metrics")
+    finally:
+        stack.stop()
+    assert set(docs) == {0, 1}
+    assert all(p["requests"] > 0 for p in m["per_worker"]), \
+        "the kernel gave one worker every connection"
+    _check_totals(m)
+    for answered_by, doc in docs.items():
+        assert doc["workers"] == 2
+        spans = {}
+        for ev in doc["traceEvents"]:
+            if ev["ph"] == "X" and ev["name"].startswith("httpd/"):
+                spans.setdefault(ev["pid"], []).append(ev)
+        assert set(spans) == {10, 11}, answered_by
+        for pid, evs in spans.items():
+            phases = {ev["name"][len("httpd/"):] for ev in evs}
+            assert {"wait", "client", "verdicts"} <= phases, (pid, phases)
+            evs.sort(key=lambda ev: ev["ts"])
+            for a, b in zip(evs, evs[1:]):   # end to end, no overlap
+                assert a["ts"] + a["dur"] <= b["ts"], pid
+        requests = [ev for ev in doc["traceEvents"]
+                    if ev["ph"] == "X" and ev["name"] == "request"]
+        assert requests and all(ev["pid"] == 3 for ev in requests)
+        assert len({ev["tid"] for ev in requests}) == len(requests)
+        for req in requests:   # its row holds its segments, end to end
+            inner = [ev for ev in doc["traceEvents"]
+                     if ev["ph"] == "X" and ev["pid"] == 3
+                     and ev["tid"] == req["tid"] and ev is not req]
+            assert {"verdict_wait", "upstream"} <= \
+                {ev["name"] for ev in inner}
+            assert all(req["ts"] <= ev["ts"] and ev["ts"] + ev["dur"]
+                       <= req["ts"] + req["dur"] for ev in inner)
+            assert sum(ev["dur"] for ev in inner) == req["dur"]
+
+
+def test_runqueue_us_is_published_only_where_it_was_read(tmp_path):
+    """A block of two slots with only worker 0 running: its slot read
+    /proc/thread-self/schedstat and carries `runqueue_us`, the slot that
+    never ran has no such key (not a 0), and the listener has it."""
+    from test_native_httpd import ClockStack, exchange
+
+    if not os.path.exists("/proc/thread-self/schedstat"):
+        pytest.skip("no /proc/thread-self/schedstat here")
+    stack = ClockStack(tmp_path, workers=2, started=[0])
+    try:
+        exchange(stack.port, ["/a"])
+        time.sleep(1.1)   # the once-a-second reading
+        m = stack.json("/__pingoo/metrics")
+    finally:
+        stack.stop()
+    assert "runqueue_us" in m["per_worker"][0]
+    assert "runqueue_us" not in m["per_worker"][1]
+    assert m["runqueue_us"] == m["per_worker"][0]["runqueue_us"] >= 0
+    _check_totals(m)
+
+
 # what /__pingoo/metrics (JSON) held before ISSUE 31, top level
 KEYS_BEFORE = {
     "requests", "blocked", "captcha", "ua_rejected", "fail_open",
@@ -405,6 +494,14 @@ KEYS_BEFORE = {
     "verdict_wait_ms_hist", "ring_pending", "awaiting", "connections",
     "pooled_upstreams", "degraded", "degraded_entered", "sidecar_up",
     "sidecar_epoch", "body", "release", "ring"}
+
+
+# the worker's own clock (the loop account, the request chain, the
+# run-queue wait where /proc/thread-self/schedstat exists)
+CLOCK_KEYS = {"loop_us", "passes", "empty_passes", "chain_us",
+              "chain_requests", "chain_upstream_requests"}
+RUNQUEUE_KEY = {"runqueue_us"} if os.path.exists(
+    "/proc/thread-self/schedstat") else set()
 
 
 @pytest.mark.parametrize("flags", ["no worker flags", "one of one"])
@@ -433,7 +530,8 @@ def test_one_worker_keeps_its_keys(tmp_path, flags):
     finally:
         stack.stop()
     assert set(m) == KEYS_BEFORE | {"workers", "answered_by", "per_worker",
-                                    "accepted", "closed_after_block"}
+                                    "accepted", "closed_after_block"} \
+        | CLOCK_KEYS | RUNQUEUE_KEY
     assert (m["workers"], m["answered_by"]) == (1, 0)
     # two scrapes and the exchange's one connection, which the 403 closed
     assert (m["accepted"], m["closed_after_block"]) == (3, 1)
