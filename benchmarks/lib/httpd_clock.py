@@ -1,6 +1,5 @@
 """What the native httpd workers say about their own time: the loop
-account, the per-request chain and the run-queue wait on the native
-JSON (`/__pingoo/metrics`), and each worker's ring of recent passes at
+account and the per-request chain on the native JSON (`/__pingoo/metrics`), and each worker's ring of recent passes at
 `/__pingoo/timeline`, placed here on the device trace's clock.
 
 The counters (docs/OBSERVABILITY.md "Workers"), listener totals over the
@@ -12,9 +11,7 @@ workers:
     residence cut by its stamps into read (first byte -> enqueued),
     verdict (-> applied), upstream (-> upstream response whole) and reply
     (-> last byte written); `chain_upstream_requests` have an upstream
-    leg;
-  * `runqueue_us`: the workers' wait on a CPU run queue, absent where
-    /proc/thread-self/schedstat is missing.
+    leg.
 
 A program without them (the commit before they existed) reads as None
 everywhere here, and nothing raises.
@@ -49,7 +46,6 @@ WAIT = "wait"
 UNCOVERED = "uncovered"
 PASS_PREFIX = "httpd/"
 PASS_PID0 = 10          # a worker's pid in the timeline: 10 + worker
-RUNQUEUE_COUNTER = "pingoo_sidecar_runqueue_us_total"
 
 
 # -- the counters -------------------------------------------------------------
@@ -88,9 +84,6 @@ def counters(obs: dict) -> Optional[dict]:
            "empty_passes": _delta(obs, "empty_passes"),
            "chain_requests": _delta(obs, "chain_requests"),
            "chain_upstream_requests": _delta(obs, "chain_upstream_requests"),
-           "runqueue_us": (_delta(obs, "runqueue_us")
-                           if "runqueue_us" in before
-                           and "runqueue_us" in after else None),
            "workers": int(after.get("workers") or 1),
            "wall_s": wall_s(obs)}
     obs["_httpd_clock"] = out

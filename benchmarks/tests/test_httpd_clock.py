@@ -1,6 +1,6 @@
 """The httpd workers' own clock as the benchmark reads it
-(lib/httpd_clock.py): the readers of the loop account, the chain and the
-run-queue waits, and the join of the workers' pass rings with the
+(lib/httpd_clock.py): the readers of the loop account and the chain,
+and the join of the workers' pass rings with the
 device's idle gaps, on hand-made snapshots and a hand-made trace."""
 
 import os
@@ -12,8 +12,7 @@ from lib import harness, httpd_clock, metrics
 
 METRICS = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "metrics")
-NEW = ("httpd_busy_share", "httpd_upstream_ms_mean", "outside_httpd_ms_mean",
-       "httpd_runqueue_share", "sidecar_runqueue_share")
+NEW = ("httpd_busy_share", "httpd_upstream_ms_mean", "outside_httpd_ms_mean")
 
 
 def _native(loop, chain, n, n_up, runqueue=None, workers=2):
@@ -66,9 +65,6 @@ def test_the_readers_read_the_clock():
     assert _read("httpd_upstream_ms_mean", obs) == pytest.approx(5.0)
     # the client's 40 ms, less httpd's 17 (2 + 10 + 4000/1000 + 1)
     assert _read("outside_httpd_ms_mean", obs) == pytest.approx(23.0)
-    # 80 ms over 2 s on two workers; 20 ms over 2 s on one drain thread
-    assert _read("httpd_runqueue_share", obs) == pytest.approx(2.0)
-    assert _read("sidecar_runqueue_share", obs) == pytest.approx(1.0)
     assert metrics.load_reader(METRICS, "httpd_busy_share.steady")(obs) \
         == pytest.approx(25.0)
 
@@ -89,11 +85,13 @@ def test_each_reader_reads_none_without_its_counter(name):
     assert _read(name, {"all_records": obs["all_records"]}) is None
 
 
-def test_the_run_queue_readers_need_their_own_counter():
+def test_the_readers_need_no_run_queue_reading():
+    """A machine without /proc/thread-self/schedstat has no run-queue
+    counters; the clock's readers read all the same."""
     obs = _obs(runqueue=False, sidecar_rq=False)
-    assert _read("httpd_runqueue_share", obs) is None
-    assert _read("sidecar_runqueue_share", obs) is None
     assert _read("httpd_busy_share", obs) == pytest.approx(25.0)
+    assert _read("httpd_upstream_ms_mean", obs) == pytest.approx(5.0)
+    assert _read("outside_httpd_ms_mean", obs) == pytest.approx(23.0)
 
 
 def _timeline(workers):
