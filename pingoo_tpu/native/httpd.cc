@@ -1191,6 +1191,9 @@ enum class ConnState {
 
 struct Conn;
 
+// Conn::client_ev / up_ev for an fd epoll does not hold.
+constexpr uint32_t kUnregistered = ~0u;
+
 struct SockRef {
   Conn* conn = nullptr;  // nullptr = the listening socket
   bool is_upstream = false;
@@ -1275,6 +1278,10 @@ struct Conn {
   time_t last_active = 0;
   SockRef client_ref;
   SockRef upstream_ref;
+  // The interest last given to epoll for fd and for upstream_fd:
+  // update_*_events call epoll_ctl only when it changes.
+  uint32_t client_ev = kUnregistered;
+  uint32_t up_ev = kUnregistered;
 
   // -- HTTP/2 mode (state == kH2) --
   nghttp2_session* h2 = nullptr;
@@ -1929,10 +1936,8 @@ class Server {
       ssl_conn_[c->ssl] = c;
     }
     conns_.insert(c);
-    epoll_event ce{};
-    ce.events = EPOLLIN;
-    ce.data.ptr = &c->client_ref;
-    epoll_ctl(ep_, EPOLL_CTL_ADD, cfd, &ce);
+    ctl(EPOLL_CTL_ADD, cfd, EPOLLIN, &c->client_ref);
+    c->client_ev = EPOLLIN;
     if (tcp_mode_ && c->ssl == nullptr) start_tcp_proxy(c);
     // tcp+tls: the handshake completes first (SNI cert store +
     // acme-tls/1 interception run exactly as for https — reference
@@ -1975,10 +1980,7 @@ class Server {
     reset_up_link(c);
     c->tcp_connect_at = now_;
     c->state = ConnState::kTunnel;
-    epoll_event ue{};
-    ue.events = EPOLLOUT | EPOLLIN;
-    ue.data.ptr = &c->upstream_ref;
-    epoll_ctl(ep_, EPOLL_CTL_ADD, ufd, &ue);
+    add_upstream(c, EPOLLOUT | EPOLLIN);
     update_client_events(c);
   }
 
@@ -2035,7 +2037,8 @@ class Server {
       }
       if (c->owned_ctx) SSL_CTX_free(c->owned_ctx);
       if (c->fd >= 0) {
-        epoll_ctl(ep_, EPOLL_CTL_DEL, c->fd, nullptr);
+        if (c->client_ev != kUnregistered)
+          ctl(EPOLL_CTL_DEL, c->fd, 0, nullptr);
         close(c->fd);
       }
       close_upstream(c);
@@ -2161,6 +2164,13 @@ class Server {
     uint64_t chain_us[kSegs] = {};
     uint64_t chain_requests = 0;  // requests whose chain ended (answered)
     uint64_t chain_upstream_requests = 0;  // of them, with an upstream leg
+    // The calls a request costs the worker: every read, recv, send,
+    // SSL_read, SSL_write and SSL_peek on a socket, every epoll_ctl, and
+    // the requests whose head went to a pooled link on the verdict's
+    // own pass (start_proxy) instead of waiting for its EPOLLOUT.
+    uint64_t io_calls = 0;
+    uint64_t epoll_ctl_calls = 0;
+    uint64_t upstream_direct_sends = 0;
     // this worker's run-queue wait (/proc/thread-self/schedstat, second
     // field), read once a second; published only where the file exists
     uint64_t runqueue_us = 0;
@@ -2615,6 +2625,9 @@ class Server {
       out += "}";
       kv_u64("chain_requests", s.chain_requests);
       kv_u64("chain_upstream_requests", s.chain_upstream_requests);
+      kv_u64("io_calls", s.io_calls);
+      kv_u64("epoll_ctl_calls", s.epoll_ctl_calls);
+      kv_u64("upstream_direct_sends", s.upstream_direct_sends);
       if (has_rq) kv_u64("runqueue_us", s.runqueue_us);
     };
     kv_u64("requests", st.requests, true);
@@ -3183,6 +3196,7 @@ class Server {
 
   // >0 bytes, 0 clean EOF, -1 would-block, -2 error.
   ssize_t t_read(Conn* c, char* buf, size_t n) {
+    stats_.io_calls++;
     if (c->ssl == nullptr) {
       ssize_t r = read(c->fd, buf, n);
       if (r > 0) return r;
@@ -3203,6 +3217,7 @@ class Server {
   }
 
   ssize_t t_write(Conn* c, const char* buf, size_t n) {
+    stats_.io_calls++;
     if (c->ssl == nullptr) {
       ssize_t w = send(c->fd, buf, n, MSG_NOSIGNAL);
       if (w >= 0) return w;
@@ -3243,12 +3258,18 @@ class Server {
         ev = EPOLLIN;
         break;
       case ConnState::kAwaitingVerdict:
-        // Verdict quiesce — except under streaming body inspection
-        // (ISSUE 13), which keeps pulling body bytes (bounded by the
-        // hold cap) while the verdicts compute.
-        if (c->body_inspect && !c->body_final_sent && !c->client_eof &&
-            c->inbuf.size() < kMaxBuffered)
-          ev = EPOLLIN;
+        // Streaming body inspection (docs/BODY_STREAMING.md) keeps pulling
+        // body bytes (bounded by the hold cap) while the verdicts compute.
+        // Without it the read side stays as it was armed: handle()
+        // disarms it if an event comes (a pipelined request, a FIN), so
+        // a request that only waits costs no epoll_ctl either way.
+        if (c->body_inspect) {
+          if (!c->body_final_sent && !c->client_eof &&
+              c->inbuf.size() < kMaxBuffered)
+            ev = EPOLLIN;
+        } else {
+          ev = c->client_ev & EPOLLIN;
+        }
         break;
       case ConnState::kProxying:
         // Level-triggered epoll: a half-closed or backpressured client
@@ -3269,10 +3290,32 @@ class Server {
         break;
     }
     if (!c->outbuf.empty() || c->ssl_want_write) ev |= EPOLLOUT;
+    arm_client(c, ev);
+  }
+
+  // Every epoll_ctl of the worker (counted: `epoll_ctl_calls`).
+  void ctl(int op, int fd, uint32_t events, SockRef* ref) {
+    stats_.epoll_ctl_calls++;
     epoll_event e{};
-    e.events = ev;
-    e.data.ptr = &c->client_ref;
-    epoll_ctl(ep_, EPOLL_CTL_MOD, c->fd, &e);
+    e.events = events;
+    e.data.ptr = ref;
+    epoll_ctl(ep_, op, fd, &e);
+  }
+
+  void arm_client(Conn* c, uint32_t ev) {
+    if (c->client_ev == kUnregistered || ev == c->client_ev) return;
+    ctl(EPOLL_CTL_MOD, c->fd, ev, &c->client_ref);
+    c->client_ev = ev;
+  }
+
+  void add_upstream(Conn* c, uint32_t ev) {
+    ctl(EPOLL_CTL_ADD, c->upstream_fd, ev, &c->upstream_ref);
+    c->up_ev = ev;
+  }
+
+  void del_upstream(Conn* c) {
+    ctl(EPOLL_CTL_DEL, c->upstream_fd, 0, nullptr);
+    c->up_ev = kUnregistered;
   }
 
   void update_upstream_events(Conn* c) {
@@ -3297,10 +3340,9 @@ class Server {
       if (can_read && c->up_ssl != nullptr && SSL_pending(c->up_ssl) > 0)
         queue_ssl_resume(c, 0);
     }
-    epoll_event e{};
-    e.events = ev;
-    e.data.ptr = &c->upstream_ref;
-    epoll_ctl(ep_, EPOLL_CTL_MOD, c->upstream_fd, &e);
+    if (ev == c->up_ev) return;
+    ctl(EPOLL_CTL_MOD, c->upstream_fd, ev, &c->upstream_ref);
+    c->up_ev = ev;
   }
 
   // Queue a canned response and switch to drain-then-close.
@@ -3331,7 +3373,7 @@ class Server {
       c->up_ssl = nullptr;
     }
     if (c->upstream_fd >= 0) {
-      epoll_ctl(ep_, EPOLL_CTL_DEL, c->upstream_fd, nullptr);
+      del_upstream(c);
       close(c->upstream_fd);
       c->upstream_fd = -1;
     }
@@ -3421,8 +3463,9 @@ class Server {
   // plaintext or TLS. Cross-direction wants (renegotiation-free TLS 1.3
   // still hits them on KeyUpdate) are surfaced through the flags so the
   // event mask can arm the other direction.
-  static ssize_t up_send_raw(int fd, SSL* ssl, const void* p, size_t n,
-                             bool* wr_want_read) {
+  ssize_t up_send_raw(int fd, SSL* ssl, const void* p, size_t n,
+                      bool* wr_want_read) {
+    stats_.io_calls++;
     if (ssl == nullptr) {
       ssize_t w = send(fd, p, n, MSG_NOSIGNAL);
       if (w >= 0) return w;
@@ -3441,8 +3484,9 @@ class Server {
     return kIoErr;
   }
 
-  static ssize_t up_recv_raw(int fd, SSL* ssl, void* p, size_t n,
-                             bool* rd_want_write) {
+  ssize_t up_recv_raw(int fd, SSL* ssl, void* p, size_t n,
+                      bool* rd_want_write) {
+    stats_.io_calls++;
     if (ssl == nullptr) {
       ssize_t r = read(fd, p, n);
       if (r >= 0) return r;
@@ -3487,10 +3531,7 @@ class Server {
     c->upstream_pooled = false;  // one retry only
     reset_up_link(c);  // a TLS target re-handshakes on the fresh socket
     c->upbuf = c->up_replay;
-    epoll_event ue{};
-    ue.events = EPOLLOUT | EPOLLIN;
-    ue.data.ptr = &c->upstream_ref;
-    epoll_ctl(ep_, EPOLL_CTL_ADD, ufd, &ue);
+    add_upstream(c, EPOLLOUT | EPOLLIN);
     update_client_events(c);
     return true;
   }
@@ -3554,11 +3595,12 @@ class Server {
   // Drain whatever session frames an idle pooled h2 connection has
   // pending (PING, SETTINGS, GOAWAY) through its nghttp2 session.
   // Returns false when the session is no longer usable.
-  static bool h2_pool_prefeed(PooledUpstream* pc) {
+  bool h2_pool_prefeed(PooledUpstream* pc) {
     char buf[4096];
     std::string sink;  // no stream is open: nothing synthesizes
     for (;;) {
       ssize_t r;
+      stats_.io_calls++;
       if (pc->ssl != nullptr) {
         ERR_clear_error();
         int rr = SSL_read(pc->ssl, buf, sizeof(buf));
@@ -3610,6 +3652,7 @@ class Server {
         // h2 links do not carry).
         char probe;
         ERR_clear_error();
+        stats_.io_calls++;
         int r = SSL_peek(pc.ssl, &probe, 1);
         bool alive =
             r <= 0 && SSL_get_error(pc.ssl, r) == SSL_ERROR_WANT_READ;
@@ -3627,6 +3670,7 @@ class Server {
         continue;
       }
       char probe;
+      stats_.io_calls++;
       ssize_t r = recv(pc.fd, &probe, 1, MSG_PEEK | MSG_DONTWAIT);
       bool alive = r < 0 && (errno == EAGAIN || errno == EWOULDBLOCK);
       if (!alive && r > 0 && pc.h2link != nullptr)
@@ -3647,7 +3691,7 @@ class Server {
       close_upstream(c);
       return;
     }
-    epoll_ctl(ep_, EPOLL_CTL_DEL, c->upstream_fd, nullptr);
+    del_upstream(c);
     vec.push_back(PooledUpstream{c->upstream_fd, c->up_ssl,
                                  c->up_target.sni, now_, c->up_h2});
     c->upstream_fd = -1;
@@ -3794,10 +3838,18 @@ class Server {
     }
     if (!c->up_proto_pending) finish_upstream_send_setup(c);
 
-    epoll_event ue{};
-    ue.events = EPOLLOUT | EPOLLIN;
-    ue.data.ptr = &c->upstream_ref;
-    epoll_ctl(ep_, EPOLL_CTL_ADD, ufd, &ue);
+    // A pooled link that is already connected (plain h1, or an
+    // established TLS session) takes the request on this pass; a fresh
+    // connect, a handshake and an h2 link wait for their EPOLLOUT. The
+    // fd is registered first, so a failed write's retry or 502 tears
+    // down a registered fd.
+    bool direct = pooled && !c->dead && c->up_h2 == nullptr;
+    add_upstream(c, direct ? EPOLLIN : EPOLLOUT | EPOLLIN);
+    if (direct) {
+      stats_.upstream_direct_sends++;
+      if (!flush_upstream(c)) return;  // replayed on a fresh link, or 502
+      update_upstream_events(c);  // EPOLLOUT only while bytes remain
+    }
     update_client_events(c);
   }
 
@@ -4903,7 +4955,7 @@ class Server {
     st.up_rd_want_write = false;
     st.up_wr_want_read = false;
     if (st.up_fd >= 0) {
-      epoll_ctl(ep_, EPOLL_CTL_DEL, st.up_fd, nullptr);
+      ctl(EPOLL_CTL_DEL, st.up_fd, 0, nullptr);
       close(st.up_fd);
       st.up_fd = -1;
       c->h2_upstreams--;
@@ -4941,7 +4993,7 @@ class Server {
                      (!st.up_h2->goaway && !st.up_h2->failed)) &&
                     upstream_pool_[st.up_key].size() < kPoolPerTarget;
     if (can_pool) {
-      epoll_ctl(ep_, EPOLL_CTL_DEL, st.up_fd, nullptr);
+      ctl(EPOLL_CTL_DEL, st.up_fd, 0, nullptr);
       upstream_pool_[st.up_key].push_back(
           PooledUpstream{st.up_fd, st.up_ssl, st.up_target.sni, now_,
                          st.up_h2});
@@ -4980,10 +5032,7 @@ class Server {
       if (can_read && st.up_ssl != nullptr && SSL_pending(st.up_ssl) > 0)
         queue_ssl_resume(c, st.up_ref->h2_sid);
     }
-    epoll_event e{};
-    e.events = ev;
-    e.data.ptr = st.up_ref;
-    epoll_ctl(ep_, EPOLL_CTL_MOD, st.up_fd, &e);
+    ctl(EPOLL_CTL_MOD, st.up_fd, ev, st.up_ref);
   }
 
   // Put the head + whatever body bytes are buffered onto an h1
@@ -5130,10 +5179,7 @@ class Server {
       st.up_pooled = false;
     }
     st.up_ref = new SockRef{c, true, sid};
-    epoll_event ue{};
-    ue.events = EPOLLOUT | EPOLLIN;
-    ue.data.ptr = st.up_ref;
-    epoll_ctl(ep_, EPOLL_CTL_ADD, ufd, &ue);
+    ctl(EPOLL_CTL_ADD, ufd, EPOLLOUT | EPOLLIN, st.up_ref);
     // Pre-dispatch bytes may have closed the client's window; now that
     // they are on the forwarding path the drain hook will reopen it —
     // kick once for the case where everything already fits.
@@ -5160,10 +5206,7 @@ class Server {
     st.upbuf = st.up_replay;
     st.up_ref = new SockRef{c, true, sid};
     c->h2_upstreams++;
-    epoll_event ue{};
-    ue.events = EPOLLOUT | EPOLLIN;
-    ue.data.ptr = st.up_ref;
-    epoll_ctl(ep_, EPOLL_CTL_ADD, ufd, &ue);
+    ctl(EPOLL_CTL_ADD, ufd, EPOLLOUT | EPOLLIN, st.up_ref);
     return true;
   }
 
@@ -5906,7 +5949,9 @@ class Server {
     update_upstream_events(c);
   }
 
-  void flush_upstream(Conn* c) {
+  // -> false when the write failed and the request was handed on (a
+  // pooled link's replay, a 502) or the connection closed.
+  bool flush_upstream(Conn* c) {
     while (!c->upbuf.empty() && c->upstream_fd >= 0 && c->upstream_connected) {
       c->up_wr_want_read = false;
       ssize_t w = up_send_raw(c->upstream_fd, c->up_ssl, c->upbuf.data(),
@@ -5920,9 +5965,10 @@ class Server {
         // else close.
         if (c->resp_head_done && c->state != ConnState::kH2) mark_close(c);
         else respond_502(c);
-        return;
+        return false;
       }
     }
+    return true;
   }
 
   bool proxy_live(Conn* c) const {
@@ -6023,6 +6069,12 @@ class Server {
         } else if (r > 0) {
           on_upstream_data(c, buf, static_cast<size_t>(r));
           if (c->dead || !proxy_live(c)) return;
+          // A framed response is whole: a read past it would only
+          // return EAGAIN, or bytes that bar the link from the pool,
+          // which pop_pooled's probe finds before the link is reused.
+          if (c->state == ConnState::kProxying && c->resp_head_done &&
+              c->resp_body.done)
+            break;
         } else if (r == 0) {
           c->upstream_eof = true;
           break;
@@ -6285,7 +6337,15 @@ class Server {
         break;
       case ConnState::kAwaitingVerdict:
         if ((events & EPOLLIN) && c->body_inspect) on_body_readable(c);
-        if (!c->dead && (events & (EPOLLHUP | EPOLLERR))) mark_close(c);
+        if (c->dead) break;
+        if (events & (EPOLLHUP | EPOLLERR)) {
+          mark_close(c);
+        } else if ((events & EPOLLIN) && !c->body_inspect) {
+          // The read side was left armed (update_client_events): what
+          // came waits for the verdict, and level-triggered epoll would
+          // report it on every pass until then.
+          arm_client(c, c->client_ev & ~static_cast<uint32_t>(EPOLLIN));
+        }
         break;
       case ConnState::kProxying:
         if (events & (EPOLLHUP | EPOLLERR)) {
@@ -6311,7 +6371,8 @@ class Server {
           if (c->upbuf.empty()) {
             mark_close(c);
           } else {
-            epoll_ctl(ep_, EPOLL_CTL_DEL, c->fd, nullptr);
+            ctl(EPOLL_CTL_DEL, c->fd, 0, nullptr);
+            c->client_ev = kUnregistered;
             update_upstream_events(c);
           }
           return;

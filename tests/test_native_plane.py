@@ -1385,6 +1385,194 @@ class TestUpstreamPooling:
             srv.shutdown()
 
 
+class _ScriptedUpstream:
+    """A raw keep-alive upstream whose every response leaves in ONE
+    write (http.server writes head and body apart, which would split a
+    response over two reads). `script(i, n)` says what connection `i`
+    does with its request `n`: "ok" answers `up:<path>`, "ok+junk"
+    answers with bytes past its content-length in the same write,
+    "ok,junk" sends them 0.2 s later, "close" reads the request and
+    closes (an idle timeout that fired as the request arrived).
+    `served` holds (connection, path) per request read."""
+
+    def __init__(self, script=lambda i, n: "ok"):
+        self.script, self.served, self.accepts = script, [], 0
+        self.lsock = socket.socket()
+        self.lsock.bind(("127.0.0.1", 0))
+        self.lsock.listen(16)
+        self.port = self.lsock.getsockname()[1]
+        threading.Thread(target=self._accept, daemon=True).start()
+
+    def _accept(self):
+        while True:
+            try:
+                conn, _ = self.lsock.accept()
+            except OSError:
+                return
+            i, self.accepts = self.accepts, self.accepts + 1
+            threading.Thread(target=self._serve, args=(conn, i),
+                             daemon=True).start()
+
+    def _serve(self, conn, i):
+        data, n = b"", 0
+        with conn:
+            while True:
+                while b"\r\n\r\n" not in data:
+                    try:
+                        chunk = conn.recv(65536)
+                    except ConnectionResetError:  # closed holding junk
+                        return
+                    if not chunk:
+                        return
+                    data += chunk
+                head, _, data = data.partition(b"\r\n\r\n")
+                path = head.split(b" ", 2)[1].decode()
+                self.served.append((i, path))
+                what, n = self.script(i, n), n + 1
+                if what == "close":
+                    return
+                body = f"up:{path}".encode()
+                resp = (b"HTTP/1.1 200 OK\r\ncontent-length: %d\r\n\r\n"
+                        % len(body)) + body
+                conn.sendall(resp + (b"JUNK" if what == "ok+junk" else b""))
+                if what == "ok,junk":
+                    time.sleep(0.2)
+                    conn.sendall(b"JUNK")
+
+    def close(self):
+        self.lsock.close()
+
+
+class TestProxiedRequestCalls:
+    """The calls a proxied keep-alive request costs the worker: its head
+    leaves for a pooled link on the verdict's own pass, the upstream is
+    read up to the framed end of its response and no further, and
+    epoll_ctl runs only where an fd's interest changes. The counters
+    (`io_calls`, `epoll_ctl_calls`, `upstream_direct_sends`) are the
+    worker's own; the verdicts come from a ring drainer without the
+    engine."""
+
+    @pytest.fixture()
+    def calls_stack(self, tmp_path):
+        from test_native_httpd import FakeSidecar
+
+        made = []
+
+        def make(script=lambda i, n: "ok", verdict_delay_s=0.0):
+            up = _ScriptedUpstream(script)
+            ring = Ring(str(tmp_path / "ring"), capacity=256, create=True)
+            sidecar = FakeSidecar(ring, verdict_delay_s)
+            port = _free_port()
+            proc = subprocess.Popen(
+                [HTTPD, str(port), str(tmp_path / "ring"), "127.0.0.1",
+                 str(up.port)], stdout=subprocess.PIPE)
+            made.append((proc, sidecar, ring, up))
+            assert b"listening" in proc.stdout.readline()
+            up.port_front = port
+            return up
+
+        yield make
+        for proc, sidecar, ring, up in made:
+            proc.kill()
+            proc.wait()
+            sidecar.close()
+            ring.close()
+            up.close()
+
+    @staticmethod
+    def _scrape(port):
+        out = raw_request(port, b"GET /__pingoo/metrics HTTP/1.1\r\n"
+                          b"host: t\r\nuser-agent: u\r\n"
+                          b"accept: application/json\r\n"
+                          b"connection: close\r\n\r\n")
+        return json.loads(out.partition(b"\r\n\r\n")[2])
+
+    @staticmethod
+    def _get(c, path):
+        c.sendall(f"GET {path} HTTP/1.1\r\nhost: t\r\n"
+                  "user-agent: u\r\n\r\n".encode())
+        return recv_one_response(c)
+
+    def test_a_pooled_request_costs_seven_calls(self, calls_stack):
+        up = calls_stack()
+        n = 20
+        m1 = self._scrape(up.port_front)
+        c = socket.create_connection(("127.0.0.1", up.port_front),
+                                     timeout=10)
+        with c:
+            for i in range(n):
+                resp = self._get(c, f"/k{i}")
+                assert resp.startswith(b"HTTP/1.1 200"), resp
+                assert resp.endswith(f"up:/k{i}".encode()), resp
+            m2 = self._scrape(up.port_front)
+        d = {k: m2[k] - m1[k] for k in
+             ("io_calls", "epoll_ctl_calls", "upstream_direct_sends",
+              "chain_upstream_requests")}
+        assert up.accepts == 1  # one link, pooled between the requests
+        # the first request connected; every later one took the pooled
+        # link and wrote its head on its verdict's pass
+        assert d["upstream_direct_sends"] == n - 1
+        assert d["chain_upstream_requests"] == n
+        # read, recv MSG_PEEK, ADD, send, read, send, DEL a request, and
+        # the two scrapes' own four calls
+        assert (d["io_calls"] + d["epoll_ctl_calls"]) / n <= 8, d
+
+    def test_a_pooled_link_closed_under_its_request_is_replayed(
+            self, calls_stack):
+        # connection 0 answers its first request and closes on its second
+        up = calls_stack(lambda i, n: "close" if (i, n) == (0, 1) else "ok")
+        c = socket.create_connection(("127.0.0.1", up.port_front),
+                                     timeout=10)
+        with c:
+            assert self._get(c, "/r0").endswith(b"up:/r0")
+            m1 = self._scrape(up.port_front)
+            resp = self._get(c, "/r1")
+            m2 = self._scrape(up.port_front)
+        assert resp.startswith(b"HTTP/1.1 200") and resp.endswith(b"up:/r1")
+        assert m2["upstream_direct_sends"] - m1["upstream_direct_sends"] == 1
+        assert m2["upstream_fail"] == m1["upstream_fail"]  # no 502
+        # sent once on the pooled link, replayed once on a fresh one
+        assert up.served == [(0, "/r0"), (0, "/r1"), (1, "/r1")]
+
+    def test_a_pipelined_request_waits_for_its_turn_without_spinning(
+            self, calls_stack):
+        up = calls_stack(verdict_delay_s=0.3)
+        m1 = self._scrape(up.port_front)
+        t1 = time.monotonic()
+        c = socket.create_connection(("127.0.0.1", up.port_front),
+                                     timeout=10)
+        with c:
+            c.sendall(b"GET /p0 HTTP/1.1\r\nhost: t\r\nuser-agent: u\r\n\r\n")
+            time.sleep(0.1)  # /p0 is awaiting its verdict
+            c.sendall(b"GET /p1 HTTP/1.1\r\nhost: t\r\nuser-agent: u\r\n\r\n")
+            first, second = recv_responses(c, 2)
+        ms = (time.monotonic() - t1) * 1e3
+        m2 = self._scrape(up.port_front)
+        assert first.endswith(b"up:/p0") and second.endswith(b"up:/p1")
+        assert [path for _, path in up.served] == ["/p0", "/p1"]
+        # /p1's bytes came while /p0's client fd was left armed: one
+        # event disarms it. While a verdict is awaited the loop polls
+        # without sleeping and checks the deadlines once a millisecond
+        # (a pass that works); a read side left armed would make every
+        # pass of the 0.6 s the two verdicts take one that works.
+        worked = (m2["passes"] - m1["passes"]) \
+            - (m2["empty_passes"] - m1["empty_passes"])
+        assert worked < ms + 100, (worked, ms, m2["passes"] - m1["passes"])
+
+    @pytest.mark.parametrize("junk", ["ok+junk", "ok,junk"])
+    def test_a_link_with_bytes_past_its_response_is_not_reused(
+            self, calls_stack, junk):
+        up = calls_stack(lambda i, n: junk if i == 0 else "ok")
+        c = socket.create_connection(("127.0.0.1", up.port_front),
+                                     timeout=10)
+        with c:
+            assert self._get(c, "/j0").endswith(b"up:/j0")
+            time.sleep(1.0)  # the late bytes are in by now
+            resp = self._get(c, "/j1")
+        assert resp.startswith(b"HTTP/1.1 200") and resp.endswith(b"up:/j1")
+        assert up.served == [(0, "/j0"), (1, "/j1")]
+
+
 class TestOverflowFieldParity:
     """VERDICT r2 item 5: a >2048-byte URL must still match content
     rules past the slot cap when fronted by the C++ plane — the spill

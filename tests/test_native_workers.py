@@ -221,7 +221,8 @@ def _check_totals(m: dict) -> None:
         == m["fail_open"]
     # the worker's own clock: the listener's account is the workers' sum
     for key in ("passes", "empty_passes", "chain_requests",
-                "chain_upstream_requests"):
+                "chain_upstream_requests", "io_calls", "epoll_ctl_calls",
+                "upstream_direct_sends"):
         assert m[key] == sum(p[key] for p in per), key
     for key in ("loop_us", "chain_us"):
         assert m[key] == {k: sum(p[key][k] for p in per)
@@ -497,9 +498,11 @@ KEYS_BEFORE = {
 
 
 # the worker's own clock (the loop account, the request chain, the
-# run-queue wait where /proc/thread-self/schedstat exists)
+# calls it makes, the run-queue wait where /proc/thread-self/schedstat
+# exists)
 CLOCK_KEYS = {"loop_us", "passes", "empty_passes", "chain_us",
-              "chain_requests", "chain_upstream_requests"}
+              "chain_requests", "chain_upstream_requests", "io_calls",
+              "epoll_ctl_calls", "upstream_direct_sends"}
 RUNQUEUE_KEY = {"runqueue_us"} if os.path.exists(
     "/proc/thread-self/schedstat") else set()
 
